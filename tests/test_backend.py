@@ -22,6 +22,31 @@ def test_ladder_explicit_prefer():
     assert backend.detect_backend("cpu") == "cpu"
 
 
+def test_named_backend_is_required(monkeypatch):
+    """The CPU world has no TPU: a backend asked for by name (argument or
+    environment) raises and never walks on to another; only with neither
+    set does the ladder reach cpu."""
+    with pytest.raises(backend.BackendUnavailableError, match="'tpu'"):
+        backend.detect_backend("tpu")
+    monkeypatch.setenv("TPUDDP_BACKEND", "tpu")
+    with pytest.raises(backend.BackendUnavailableError, match="'tpu'"):
+        backend.detect_backend()
+    with pytest.raises(backend.BackendUnavailableError, match="'tpu'"):
+        backend.resolve_devices()
+    monkeypatch.delenv("TPUDDP_BACKEND")
+    assert backend.detect_backend() == "cpu"
+
+
+def test_serving_main_refuses_unavailable_device(tmp_path):
+    """``python -m tpuddp.serving`` honours local.device or refuses it."""
+    from tpuddp.serving.__main__ import main
+
+    settings = tmp_path / "s.yaml"
+    settings.write_text("local: {device: tpu}\n")
+    with pytest.raises(backend.BackendUnavailableError, match="'tpu'"):
+        main(["--settings", str(settings), "--demo", "1"])
+
+
 def test_available_backends_contains_cpu():
     assert "cpu" in backend.available_backends()
 

@@ -1,4 +1,4 @@
-"""bench.py's driver-parseable output contract (VERDICT r5: the artifact's
+"""bench.py's driver-parseable output contract (round 5: the artifact's
 ``parsed`` field was null because the full results dict was the stdout line).
 
 The contract: the FULL per-config payload lands in ``bench_results.json``;

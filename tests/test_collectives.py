@@ -3,11 +3,11 @@ contracts (SURVEY.md §2b #11, reference multi-GPU-training-torch.py:194-204,245
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from tpuddp.utils.compat import shard_map
 from tpuddp.parallel import collectives as col
 from tpuddp.parallel.mesh import DATA_AXIS
 

@@ -230,7 +230,7 @@ def test_tp2_forward_matches_single_device_reference(cpu_devices):
     the column-split attention and the vocab-split head/lookup are exact;
     the two row-split projections psum M partials, changing only the
     contraction's summation order — asserted tight."""
-    from tpuddp.utils.compat import shard_map
+    from jax import shard_map
 
     ddp, model = make_tp(cpu_devices)
     st = ddp.init_state(KEY, jnp.zeros((1, T), jnp.int32))

@@ -4,12 +4,12 @@ cross-entropy)."""
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 import numpy as np
 import pytest
 import torch
 import torch.nn.functional as F
 
-from tpuddp.utils.compat import shard_map
 from tpuddp import nn
 
 KEY = jax.random.key(0)

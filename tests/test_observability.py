@@ -475,6 +475,27 @@ def test_epoch_rows_carry_step_fields_and_no_recompilation(mesh, tmp_path):
     assert lower_text(ddp, fresh_state) == lower_text(fresh, fresh_state)
 
 
+def test_flops_probe_is_per_chip(cpu_devices):
+    """The probe reads the COMPILED program, which is the per-device one:
+    the same per-chip batch gives the same figure on 1 and on 8 devices (it
+    is not a whole-program figure to divide by the world), and a probe that
+    cannot resolve returns None."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from tpuddp.observability import estimate_step_flops
+
+    def flops(devices):
+        sh = NamedSharding(make_mesh(devices), P("data"))
+        x = jax.ShapeDtypeStruct((16 * len(devices), 64), jnp.float32, sharding=sh)
+        w = jax.ShapeDtypeStruct((64, 64), jnp.float32)
+        return estimate_step_flops(lambda: jax.jit(lambda a, b: a @ b).lower(x, w))
+
+    one, eight = flops(cpu_devices[:1]), flops(cpu_devices)
+    assert one == pytest.approx(2 * 16 * 64 * 64, rel=0.1)
+    assert eight == pytest.approx(one, rel=0.1)
+    assert estimate_step_flops(lambda: 1 / 0) is None
+
+
 def test_mfu_populates_when_chip_peak_known(mesh, tmp_path, monkeypatch):
     """End-to-end MFU plumbing: with the device kind in the peak table (as
     on a real TPU), the FLOPs probe resolves and the epoch row's MFU fields

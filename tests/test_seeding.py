@@ -5,10 +5,10 @@ import random
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from tpuddp.utils.compat import shard_map
 from tpuddp import seeding
 from tpuddp.parallel.mesh import DATA_AXIS
 

@@ -280,4 +280,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from tpuddp.utils import compile_cache
+
+    compile_cache.enable()
     sys.exit(main())

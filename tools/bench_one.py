@@ -20,15 +20,24 @@ import jax, jax.numpy as jnp
 from tpuddp import nn, optim
 from tpuddp.models import load_model
 from tpuddp.data.transforms import make_train_augment
+from tpuddp.observability.recorder import PEAK_FLOPS
 from tpuddp.parallel import make_mesh
 from tpuddp.parallel.ddp import DistributedDataParallel
 from tpuddp.training.step import stack_batches
+from tpuddp.utils import compile_cache
 
-PEAK = 197e12
+compile_cache.enable()
+devices = jax.devices()
+kind = devices[0].device_kind
+if kind not in PEAK_FLOPS:
+    sys.exit(
+        f"bench_one: no peak FLOP/s for device kind {kind!r} (platform "
+        f"{devices[0].platform}); known kinds: {sorted(PEAK_FLOPS)}"
+    )
+PEAK = PEAK_FLOPS[kind]
 
 model = load_model(args.model, 10)
 augment = make_train_augment(size=args.size if args.size else None, compute_dtype=jnp.bfloat16)
-devices = jax.devices()
 mesh = make_mesh(devices)
 opt = optim.Adam(1e-3, state_dtype=args.opt_dtype or None)
 ddp = DistributedDataParallel(model, opt, nn.CrossEntropyLoss(), mesh=mesh,
@@ -79,4 +88,4 @@ if args.trace:
     jax.profiler.stop_trace()
 ms = dt / steps * 1e3
 mfu = f_single / (ms / 1e3) / PEAK if f_single else float("nan")
-print(f"{args.model} b{args.batch} K={args.scan}: {steps*args.batch/dt:,.0f} samples/s  {ms:.3f} ms/step  MFU {100*mfu:.2f}%")
+print(f"{kind} x{len(devices)} {args.model} b{args.batch} K={args.scan}: {steps*args.batch/dt:,.0f} samples/s  {ms:.3f} ms/step  MFU {100*mfu:.2f}%")

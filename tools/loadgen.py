@@ -367,6 +367,7 @@ def run_decode(args) -> int:
     request-level sequential decode as the vs_baseline anchor."""
     from tpuddp import config as config_lib
     from tpuddp.observability import json_sanitize
+    from tpuddp.parallel.backend import resolve_devices
     from tpuddp.serving.decode import DecodeEngine
 
     settings = (
@@ -395,7 +396,8 @@ def run_decode(args) -> int:
     if args.exporter is not None:
         observability = {"exporter": True, "exporter_port": args.exporter}
     engine = DecodeEngine.from_config(
-        cfg, out_dir=args.history_dir, observability=observability
+        cfg, out_dir=args.history_dir, observability=observability,
+        devices=resolve_devices(backend=config_lib.device_from(settings)),
     )
     log(
         f"decode engine: model={cfg['model']} replicas={len(engine.replicas)} "
@@ -590,6 +592,7 @@ def main(argv=None) -> int:
 
     from tpuddp import config as config_lib
     from tpuddp.observability import json_sanitize
+    from tpuddp.parallel.backend import resolve_devices
     from tpuddp.serving import ServingEngine
 
     settings = (
@@ -612,7 +615,8 @@ def main(argv=None) -> int:
     if args.exporter is not None:
         observability = {"exporter": True, "exporter_port": args.exporter}
     engine = ServingEngine.from_config(
-        cfg, out_dir=args.history_dir, observability=observability
+        cfg, out_dir=args.history_dir, observability=observability,
+        devices=resolve_devices(backend=config_lib.device_from(settings)),
     )
     log(
         f"engine: model={cfg['model']} replicas={len(engine.pool)} "
@@ -797,4 +801,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from tpuddp.utils import compile_cache
+
+    compile_cache.enable()
     sys.exit(main())

@@ -1,6 +1,7 @@
 """ctypes bridge to the native (C++) data-path library, with lazy on-demand
-compilation and a clean unavailable -> numpy-fallback story (the loader never
-requires the native path)."""
+compilation. The loader never requires the native path: when the library
+cannot be built or loaded, ONE warning says why and numpy serves the same
+bytes."""
 
 from __future__ import annotations
 
@@ -60,7 +61,11 @@ def _build() -> bool:
         os.replace(tmp, _LIB)
         return True
     except Exception as e:
-        logger.info("native gather build failed (%s); using numpy fallback", e)
+        stderr = getattr(e, "stderr", None) or b""
+        logger.warning(
+            "native gather build failed (%s %s); the loader gathers in numpy",
+            e, stderr.decode(errors="replace").strip()[-400:],
+        )
         try:
             os.unlink(tmp)
         except OSError:
@@ -96,7 +101,9 @@ def load() -> Optional[ctypes.CDLL]:
             assert lib.tpuddp_native_abi_version() == 1
             _lib = lib
         except Exception as e:  # pragma: no cover - load failure path
-            logger.info("native gather load failed (%s); using numpy fallback", e)
+            logger.warning(
+                "native gather load failed (%s); the loader gathers in numpy", e
+            )
             _lib = None
         return _lib
 

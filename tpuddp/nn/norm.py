@@ -31,7 +31,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from tpuddp.nn.core import Context, Module, Sequential
-from tpuddp.utils.compat import axis_size
 
 
 class BatchNorm(Module):
@@ -129,7 +128,7 @@ class BatchNorm(Module):
             if self.track_running_stats and ctx.train:
                 m = self.momentum
                 # total element count behind the stats (all replicas when sync)
-                n = denom * (axis_size(ax) if ax is not None else 1)
+                n = denom * (lax.axis_size(ax) if ax is not None else 1)
                 unbiased = var * (n / jnp.maximum(n - 1.0, 1.0))
                 # a fully-padded (count==0) shard must leave the running
                 # buffers untouched, not decay them toward mean=0/var=0

@@ -21,17 +21,20 @@ Achieved MFU is best-effort: FLOPs come from XLA cost analysis of the exact
 step program when a probe is available (``estimate_step_flops``), the peak
 from the chip's spec-sheet bf16 ceiling (:data:`PEAK_FLOPS` — also the
 bench's table). Unknown chip or unresolvable FLOPs -> MFU fields are null,
-never guessed.
+never guessed — and a probe that fails says why at warning level.
 """
 
 from __future__ import annotations
 
+import logging
 import time
 from typing import Callable, Optional
 
 import numpy as np
 
 from tpuddp.observability import schema
+
+logger = logging.getLogger("tpuddp")
 
 # Peak bf16 MXU FLOP/s per chip by device kind (public spec sheets). MFU is
 # always reported against the bf16 peak: on TPU, f32 matmuls execute on the
@@ -92,29 +95,39 @@ def step_time_fields(step_times_s, flops_per_step=None, peak_flops=None) -> dict
     return fields
 
 
-def estimate_step_flops(
-    lower_fn: Callable[[], "object"], world_size: int = 1
-) -> Optional[float]:
-    """Per-chip FLOPs of one step from XLA cost analysis of the LOWERED
-    single-step program — never compiled: a second full XLA compile of a
-    large model's step (minutes on TPU) is not an acceptable price for a
-    telemetry field, so this stays with the HLO estimate (the bench, whose
-    job is rigorous MFU, pays for the compiled figure instead).
+def estimate_step_flops(lower_fn: Callable[[], "object"]) -> Optional[float]:
+    """Per-chip FLOPs of one step from XLA cost analysis of the COMPILED
+    single-step program. The TPU plug-in offers no cost analysis of a program
+    that is only lowered (``Lowered.cost_analysis`` is unimplemented on PJRT
+    C-API backends and returns None), so the probe pays one compile of the
+    single-step program, once per run; with the persistent compile cache on
+    (utils/compile_cache.py) later runs load it instead.
 
     ``lower_fn`` returns a ``jax.stages.Lowered`` for the SINGLE-step program
-    (no scan-body counting ambiguity). The whole-program figure is divided by
-    ``world_size`` — the cost convention the in-repo bench disambiguated for
-    multi-chip programs. Any failure (tracing, unsupported backend, zero
-    figure) returns None: MFU is reported as unknown, never guessed."""
+    (no scan-body counting ambiguity). A compiled program is the partitioned,
+    per-device one, so its figure is already per chip: one chip at batch 128
+    and four chips at global batch 512 both report 4.88e11 for AlexNet@224
+    (chip runs, PR 21), in ``shard_map`` and ``auto`` mode alike. Any failure
+    (tracing, unsupported backend, zero figure) returns None and logs the
+    reason: MFU is reported as unknown, never guessed, and never quietly."""
     try:
-        cost = lower_fn().cost_analysis()
+        t0 = time.perf_counter()
+        cost = lower_fn().compile().cost_analysis()
         if isinstance(cost, (list, tuple)):
             cost = cost[0]
         flops = float(cost.get("flops", 0.0))
         if flops <= 0:
-            return None
-        return flops / max(1, int(world_size))
-    except Exception:
+            raise ValueError(f"cost analysis reports {flops} flops")
+        logger.info(
+            "FLOPs probe: %.3g flops per step per chip, resolved in %.1f s",
+            flops, time.perf_counter() - t0,
+        )
+        return flops
+    except Exception as e:
+        logger.warning(
+            "FLOPs probe failed (%s: %s); the MFU fields stay null",
+            type(e).__name__, e,
+        )
         return None
 
 

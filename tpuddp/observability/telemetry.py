@@ -62,13 +62,13 @@ class RunTelemetry:
         writer=None,
         save_dir: Optional[str] = None,
         step_stats_every: int = 0,
-        world_size: int = 1,
         flops_lower_fn: Optional[Callable] = None,
         device_kind: Optional[str] = None,
     ):
         """``flops_lower_fn``: zero-arg callable returning the lowered
-        single-step program, used once (lazily, failure-tolerant) to resolve
-        per-step FLOPs for the MFU fields; None leaves MFU null.
+        single-step program, compiled once (lazily, failure-tolerant, and
+        only where the chip has a peak to divide by) to resolve per-step
+        FLOPs for the MFU fields; None leaves MFU null.
         ``device_kind``: the MESH device's kind (for the peak-FLOPs lookup)
         — pass it so a CPU-ladder run on a TPU-attached host (or the
         reverse) reports MFU against the right ceiling."""
@@ -82,7 +82,6 @@ class RunTelemetry:
         self.window_profiler = profiling.StepWindowProfiler(save_dir)
         self.writer = writer
         self.save_dir = save_dir
-        self.world_size = max(1, int(world_size))
         self.flops_lower_fn = flops_lower_fn
         self.batch_struct = None
         self._flops_probed = False
@@ -278,12 +277,15 @@ class RunTelemetry:
         epoch's metric fetch — the device is already fenced there)."""
         if stop_trace:
             self.stop_epoch_trace()
-        if not self._flops_probed and self.flops_lower_fn is not None:
+        if (
+            not self._flops_probed and self.flops_lower_fn is not None
+            and self.recorder.peak_flops  # no table entry: MFU is null anyway
+        ):
             # once per run, at the FIRST epoch boundary (never in the hot
-            # loop): lowering traces the step but compiles/executes nothing
+            # loop): compiles the single-step program, executes nothing
             self._flops_probed = True
             self.recorder.flops_per_step = estimate_step_flops(
-                self.flops_lower_fn, self.world_size
+                self.flops_lower_fn
             )
         return self.recorder.epoch_summary()
 

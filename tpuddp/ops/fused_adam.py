@@ -11,10 +11,10 @@ reshape around each leaf costs extra HBM copies that XLA's native fusion never
 materializes. The lesson is recorded here deliberately: on TPU, custom kernels
 pay off for ops XLA *can't* fuse (attention-style memory patterns, remote
 DMA), not for elementwise chains. ``impl="auto"`` therefore resolves to the
-XLA path; ``impl="pallas"`` opts into the kernel (native on TPU, interpret
-elsewhere), which remains the framework's validated example of integrating a
-custom Pallas op into the training stack (grid/BlockSpec tiling, SMEM scalars,
-interpret-mode CPU testing).
+XLA path; ``impl="pallas"`` opts into the kernel (compiled for the TPU unless
+the caller passes ``interpret=True``, as the CPU tests do), which remains the
+framework's example of integrating a custom Pallas op into the training stack
+(grid/BlockSpec tiling, SMEM scalars, interpret-mode CPU testing).
 
 Update rule matches tpuddp.optim.Adam (== torch.optim.Adam) exactly:
     m <- b1*m + (1-b1)*g ;  v <- b2*v + (1-b2)*g^2
@@ -108,37 +108,24 @@ class FusedAdam(Adam):
     """Drop-in Adam whose update can run as a Pallas kernel.
 
     ``impl``: "auto" (XLA math — measured faster, see module docstring),
-    "pallas" (force the kernel; ``interpret=True`` off-TPU so CPU tests run),
-    or "xla" (inherit tpuddp.optim.Adam explicitly).
+    "pallas" (force the kernel), or "xla" (inherit tpuddp.optim.Adam
+    explicitly). ``interpret``: run the kernel in the Pallas interpreter —
+    never guessed from the platform; the caller (a CPU test) says so.
     """
 
     def __init__(self, lr: float = 1e-3, betas=(0.9, 0.999), eps: float = 1e-8,
-                 impl: str = "auto"):
+                 impl: str = "auto", interpret: bool = False):
         super().__init__(lr=lr, betas=betas, eps=eps, weight_decay=0.0)
         if impl not in ("auto", "pallas", "xla"):
             raise ValueError(f"unknown impl {impl!r}")
         self.impl = impl
-
-    @staticmethod
-    def _platform() -> str:
-        # honor an explicit jax_default_device override (e.g. CPU-pinned test
-        # environments where a TPU plugin is registered but unused)
-        dev = jax.config.jax_default_device
-        if dev is not None:
-            return dev.platform
-        return jax.default_backend()
-
-    def _use_pallas(self):
-        if self.impl != "pallas":
-            return False, False  # auto == xla: measured faster on TPU
-        return True, self._platform() != "tpu"  # interpret off-TPU
+        self.interpret = interpret
 
     def update(self, grads, opt_state, params):
-        use, interpret = self._use_pallas()
-        if not use:
+        if self.impl != "pallas":  # auto == xla: measured faster on TPU
             return super().update(grads, opt_state, params)
         return fused_adam_update(
             params, grads, opt_state,
             lr=self.lr, b1=self.b1, b2=self.b2, eps=self.eps,
-            interpret=interpret,
+            interpret=self.interpret,
         )

@@ -8,8 +8,10 @@ This is the tpuddp equivalent of the reference's process-group setup
   ladder, then pins the process to ``cuda:rank``;
 - here, rendezvous is ``jax.distributed.initialize`` (only needed multi-host —
   on a TPU pod slice each host runs ONE process that owns all of its local
-  chips, so there is no per-device process spawn), and the backend ladder is
-  **TPU -> CPU -> error**.  The CPU rung uses XLA's host-platform devices
+  chips, so there is no per-device process spawn). A backend named in the
+  settings file or the environment is required; the **TPU -> CPU -> error**
+  ladder walks only when none is named, and says which rung it took. The CPU
+  backend uses XLA's host-platform devices
   (``--xla_force_host_platform_device_count=N``) and replaces the reference's
   Gloo fallback as the no-accelerator development/test path.
 
@@ -20,6 +22,7 @@ placement is expressed through shardings on the mesh, not a per-process device.
 
 from __future__ import annotations
 
+import functools
 import logging
 import os
 from typing import Optional, Sequence
@@ -29,7 +32,7 @@ import numpy as np
 
 logger = logging.getLogger("tpuddp")
 
-# Environment override for the backend ladder, e.g. TPUDDP_BACKEND=cpu in CI.
+# Names the backend from the environment, e.g. TPUDDP_BACKEND=cpu in CI.
 _BACKEND_ENV = "TPUDDP_BACKEND"
 
 # Module-level runtime state (the "process group").
@@ -42,45 +45,71 @@ _state = {
 
 
 class BackendUnavailableError(RuntimeError):
-    """No usable accelerator backend. Mirrors the reference's terminal error
+    """The backend that was asked for (or, with none named, every rung of the
+    ladder) has no usable devices. Mirrors the reference's terminal error
     (`multi-GPU-training-torch.py:38-42`) raised when neither NCCL nor Gloo is
     available."""
 
 
-def _try_devices(backend: str):
+def _devices(name: str):
+    """``jax.devices(name)``, or :class:`BackendUnavailableError` carrying
+    JAX's own message — a chip that fails to initialise or is held by another
+    process must surface, not turn into a run on the host."""
     try:
-        devs = jax.devices(backend)
-        return devs if devs else None
-    except RuntimeError:
-        return None
+        devs = jax.devices(name)
+    except RuntimeError as e:
+        raise BackendUnavailableError(f"backend {name!r} is not available: {e}") from e
+    if not devs:
+        raise BackendUnavailableError(f"backend {name!r} reports no devices")
+    return devs
 
 
 def available_backends() -> list:
-    """List usable backends in ladder order (TPU first, CPU fallback)."""
+    """List usable backends in ladder order (TPU first, then CPU)."""
     out = []
     for name in ("tpu", "cpu"):
-        if _try_devices(name):
-            out.append(name)
+        try:
+            _devices(name)
+        except BackendUnavailableError:
+            continue
+        out.append(name)
     return out
 
 
-def detect_backend(prefer: Optional[str] = None) -> str:
-    """Backend selection ladder: ``prefer`` (or $TPUDDP_BACKEND) -> tpu -> cpu -> error.
-
-    Mirrors the NCCL -> Gloo -> raise ladder at multi-GPU-training-torch.py:34-42.
-    """
-    ladder = []
-    prefer = prefer or os.environ.get(_BACKEND_ENV)
-    if prefer:
-        ladder.append(prefer)
-    ladder += ["tpu", "cpu"]
-    for backend in ladder:
-        if _try_devices(backend):
-            return backend
+@functools.lru_cache(maxsize=None)
+def _ladder() -> str:
+    """The unnamed case: tpu -> cpu -> error, saying once per process which
+    rung it took (JAX's backends do not change within a process)."""
+    reasons = []
+    for name in ("tpu", "cpu"):
+        try:
+            _devices(name)
+        except BackendUnavailableError as e:
+            reasons.append(str(e))
+            continue
+        logger.warning(
+            "no backend named (local.device / $%s unset): using %s%s",
+            _BACKEND_ENV, name,
+            f" — {'; '.join(reasons)}" if reasons else "",
+        )
+        return name
     raise BackendUnavailableError(
         "Both backends tpu and cpu not available for multi-chip training with "
-        "distributed data parallel. No XLA devices found."
+        "distributed data parallel. " + "; ".join(reasons)
     )
+
+
+def detect_backend(prefer: Optional[str] = None) -> str:
+    """A backend asked for by name — ``prefer`` (``local.device``) or
+    $TPUDDP_BACKEND — is REQUIRED: if it has no devices this raises
+    :class:`BackendUnavailableError` and never trains on another one. Only
+    with neither set does the tpu -> cpu ladder walk, mirroring the NCCL ->
+    Gloo -> raise ladder at multi-GPU-training-torch.py:34-42."""
+    prefer = prefer or os.environ.get(_BACKEND_ENV)
+    if prefer:
+        _devices(prefer)
+        return prefer
+    return _ladder()
 
 
 def setup(
@@ -132,7 +161,7 @@ def setup(
         )
 
     chosen = detect_backend(backend)
-    devices = jax.devices(chosen)
+    devices = _devices(chosen)
     if world_size is None:
         world_size = len(devices)
     if world_size > len(devices) and jax.process_count() == 1:
@@ -202,7 +231,7 @@ def resolve_devices(
     the detected backend.
     """
     chosen = backend or _state["backend"] or detect_backend()
-    devices = jax.devices(chosen)
+    devices = _devices(chosen)
     if jax.process_count() > 1:
         return devices
     if world_size is None:

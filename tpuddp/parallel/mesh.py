@@ -123,6 +123,28 @@ def replicate(mesh: Mesh, tree):
     return jax.jit(lambda t: t, out_shardings=NamedSharding(mesh, P()))(tree)
 
 
+def place_like(template, tree):
+    """Put ``tree``'s leaves (host arrays out of a checkpoint) where the
+    matching mesh-placed leaves of ``template`` live. A resumed run then
+    dispatches the very programs a fresh run compiled — same input shardings,
+    same compile-cache keys — instead of lowering each step once more for
+    uncommitted host inputs. Same jitted identity as :func:`replicate`, so it
+    works multi-process; template leaves that are not on a mesh pass through."""
+    t_leaves, treedef = jax.tree_util.tree_flatten(template)
+    leaves = treedef.flatten_up_to(tree)
+    on_mesh = [
+        i for i, t in enumerate(t_leaves)
+        if isinstance(t, jax.Array) and isinstance(t.sharding, NamedSharding)
+    ]
+    if on_mesh:
+        placed = jax.jit(
+            lambda xs: xs, out_shardings=[t_leaves[i].sharding for i in on_mesh]
+        )([leaves[i] for i in on_mesh])
+        for i, leaf in zip(on_mesh, placed):
+            leaves[i] = leaf
+    return treedef.unflatten(leaves)
+
+
 def data_sharded(mesh: Mesh, ndim: int = 1) -> NamedSharding:
     """Sharding for a batch: leading axis split over the data mesh axis
     (the factored axis tuple on a hierarchical mesh)."""

@@ -52,7 +52,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from tpuddp.parallel import collectives as col
@@ -60,7 +60,6 @@ from tpuddp.parallel.mesh import DATA_AXIS
 from tpuddp.parallel.mesh2d import MODEL_AXIS
 from tpuddp.resilience import guard as guard_lib
 from tpuddp.training.train_state import TrainState
-from tpuddp.utils.compat import shard_map
 
 # The tensor-parallel rule set: SNIPPETS.md [2]'s table (heads/mlp/joined_kv
 # -> "model") EXTENDED with the vocab split — the embedding and the tied LM

@@ -47,12 +47,11 @@ from typing import Any, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from tpuddp.parallel.mesh import DATA_AXIS
 from tpuddp.resilience.preemption import EXIT_DESYNC
-from tpuddp.utils.compat import shard_map
 
 _ON_DESYNC = ("exit", "rollback")
 

@@ -44,8 +44,10 @@ import numpy as np
 
 from tpuddp import config as config_lib
 from tpuddp.observability import json_sanitize
+from tpuddp.parallel import backend as backend_lib
 from tpuddp.resilience import preemption
 from tpuddp.serving.engine import ServingEngine
+from tpuddp.utils import compile_cache
 
 
 def _demo_prompts(engine, n: int, tenants: int, seed: int = 0):
@@ -107,6 +109,11 @@ def main(argv=None) -> int:
     out_dir = settings.get("out_dir")
     if out_dir:
         out_dir = config_lib.prepare_out_dir(settings, args.settings)
+    # local.device is honoured or refused (BackendUnavailableError), never
+    # ignored: the replicas go on that backend's devices
+    devices = backend_lib.resolve_devices(
+        backend=config_lib.device_from(settings)
+    )
 
     if args.decode:
         from tpuddp.serving.decode import DecodeEngine
@@ -115,11 +122,13 @@ def main(argv=None) -> int:
         if decode_cfg is None:
             parser.error("--decode needs a serving.decode block in the settings")
         engine = DecodeEngine.from_config(
-            decode_cfg, out_dir=out_dir, observability=observability
+            decode_cfg, out_dir=out_dir, devices=devices,
+            observability=observability,
         )
     else:
         engine = ServingEngine.from_config(
-            serving, out_dir=out_dir, observability=observability
+            serving, out_dir=out_dir, devices=devices,
+            observability=observability,
         )
     engine.start()
 
@@ -160,4 +169,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     sys.exit(main())

@@ -79,6 +79,7 @@ import ml_dtypes
 import numpy as np
 
 from tpuddp.parallel import collectives as col
+from tpuddp.parallel.mesh import place_like
 from tpuddp.resilience import faults, integrity
 
 logger = logging.getLogger("tpuddp")
@@ -1015,8 +1016,10 @@ def restore_latest(
     reshard_on_mismatch: bool = False,
     cursor_out: Optional[List[dict]] = None,
 ) -> Tuple[Any, int]:
-    """Load the newest intact checkpoint into ``like``'s structure. Returns
-    ``(tree, next_epoch)``; ``(like, 0)`` when none exists. An emergency save
+    """Load the newest intact checkpoint into ``like``'s structure — and,
+    for the leaves of ``like`` that live on a mesh, onto the same devices
+    with the same shardings. Returns ``(tree, next_epoch)``; ``(like, 0)``
+    when none exists. An emergency save
     (``completed=0`` meta, written during a preemption drain) yields its own
     epoch as ``next_epoch`` so the interrupted epoch is redone from the saved
     mid-epoch state; end-of-epoch saves yield ``epoch + 1``.
@@ -1059,6 +1062,9 @@ def restore_latest(
         path, like, world_size=world_size, reshard_actions=actions,
         model_size=model_size, reshard_on_mismatch=reshard_on_mismatch,
     )
+    # back onto the mesh where ``like`` lives: the resumed run dispatches the
+    # programs the fresh run compiled (and cached), not ones for host inputs
+    tree = place_like(like, tree)
     if reshard_log is not None:
         reshard_log.extend(
             build_reshard_events(

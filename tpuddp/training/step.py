@@ -34,6 +34,7 @@ from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -41,7 +42,6 @@ from tpuddp import optim as _optim
 from tpuddp.nn.core import Context
 from tpuddp.parallel import collectives as col
 from tpuddp.resilience import guard as guard_lib
-from tpuddp.utils.compat import shard_map
 from tpuddp.parallel.mesh import DATA_AXIS, data_axes, data_sharded, replicated
 from tpuddp.seeding import fold_in_axis_index
 from tpuddp.training.train_state import TrainState
@@ -860,9 +860,9 @@ def build_train_scan_step(
     Takes batches stacked on a leading steps axis ``(K, batch, ...)`` and
     returns summed metrics. Semantically identical to K calls of the single
     step (same RNG fold per state.step, same metric totals) but amortizes
-    per-dispatch host/runtime latency K-fold — on remote-tunneled or
-    dispatch-bound runtimes this is the difference between RPC-bound and
-    MXU-bound throughput. K is static per compilation (one cache entry per
+    per-dispatch host/runtime latency K-fold — on dispatch-bound runtimes
+    this is the difference between dispatch-bound and MXU-bound throughput.
+    K is static per compilation (one cache entry per
     distinct K, so group epochs into fixed-size chunks).
 
     ``grad_accumulation=A > 1`` turns every A consecutive micro-batches into

@@ -1,4 +1,4 @@
-"""Utility subsystems: compat shims, debugging. (Observability graduated to
+"""Utility subsystems: debugging. (Observability graduated to
 the ``tpuddp.observability`` package; the re-exports below keep old import
 paths working.)"""
 

@@ -28,9 +28,9 @@ from typing import Optional, Tuple
 import numpy as np
 
 # Bound on one staged (K, batch, ...) chunk / one K-deep device queue. The
-# number every auto depth policy caps against (BASELINE.md "Dispatch-RTT
-# variance" measured depth as the amortization lever; this budget is what
-# keeps depth from staging past HBM).
+# number every auto depth policy caps against (depth amortizes the
+# per-dispatch latency; this budget is what keeps depth from staging past
+# HBM).
 STAGE_BYTES_BUDGET = 256 * 1024 * 1024
 
 

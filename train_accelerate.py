@@ -43,6 +43,8 @@ from tpuddp.data import (
     norm_stats_for,
 )
 from tpuddp.data.transforms import make_eval_transform, make_train_augment
+from tpuddp.parallel.mesh import data_mesh
+from tpuddp.utils import compile_cache
 
 logging.basicConfig(level=logging.INFO, format="%(message)s")
 
@@ -401,7 +403,6 @@ def run_training_loop(
         writer=metrics_writer,
         save_dir=save_dir,
         step_stats_every=step_stats_every,
-        world_size=int(acc_mesh.devices.size) if acc_mesh is not None else 1,
         device_kind=(
             acc_mesh.devices.flat[0].device_kind if acc_mesh is not None else None
         ),
@@ -784,14 +785,17 @@ def run_training_loop(
 
 
 def basic_accelerate_training(
-    out_dir: str, training=None, num_chips=None, observability=None
+    out_dir: str, training=None, num_chips=None, observability=None,
+    backend=None,
 ):
+    """``backend``: ``local.device`` from the settings file — honoured or
+    refused (BackendUnavailableError), like the native entrypoint's."""
     training = training or cfg_lib.TRAINING_DEFAULTS
     # SIGTERM/SIGINT -> drain flag (polled at managed-loop boundaries);
     # main-thread only, a no-op under threaded test runners
     install_preemption_handler()
-    # Topology discovery happens inside the Accelerator (reference :115);
-    # num_chips honors a configured sub-world on multi-chip hosts.
+    # Topology discovery (reference :115): the first num_chips devices of
+    # the named backend — a configured sub-world on multi-chip hosts.
     # fuse_steps batches K optimizer.step()s into one scan dispatch; it only
     # pays off when loss reads are deferred, so "auto" keys off that.
     accum = int(training.get("gradient_accumulation_steps") or 1)
@@ -824,7 +828,7 @@ def basic_accelerate_training(
     accelerator = Accelerator(
         seed=training.get("seed"),
         fuse_steps=fuse if fuse == "auto" else int(fuse),
-        num_chips=num_chips,
+        mesh=data_mesh(num_chips, backend),
         clip_grad_norm=training.get("clip_grad_norm"),
         gradient_accumulation_steps=accum,
         weight_update_sharding=bool(training.get("weight_update_sharding", False)),
@@ -967,6 +971,7 @@ def load_model_for(training):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     parser = argparse.ArgumentParser(
         description="tpuddp managed-API training (Accelerator over the XLA "
         "mesh backend).",
@@ -1006,6 +1011,7 @@ if __name__ == "__main__":
         basic_accelerate_training(
             out_dir, training, num_chips=world_size,
             observability=cfg_lib.observability_config(settings),
+            backend=cfg_lib.device_from(settings),
         )
     except TrainingPreempted as e:
         # the exit-code contract (README "Fault tolerance"): 75 = EX_TEMPFAIL,

@@ -3,7 +3,7 @@ reference's ``multi-GPU-training-torch.py`` (call stack SURVEY.md §3.1).
 
 Same shape, TPU-native pieces:
 
-    setup/process group        -> tpuddp.parallel.backend (TPU->CPU ladder)
+    setup/process group        -> tpuddp.parallel.backend (local.device, required)
     mp.spawn per-GPU workers   -> one process drives all local chips
                                   (tpuddp.parallel.spawn.run_ddp_training)
     set_seed_based_on_rank     -> tpuddp.seeding
@@ -38,6 +38,7 @@ from tpuddp.models import load_model
 from tpuddp.parallel.ddp import DistributedDataParallel
 from tpuddp.parallel.spawn import run_ddp_training
 from tpuddp.training.loop import run_training_loop
+from tpuddp.utils import compile_cache
 
 logging.basicConfig(level=logging.INFO, format="%(message)s")
 
@@ -223,6 +224,7 @@ def basic_ddp_training_loop(
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     parser = argparse.ArgumentParser(
         description="tpuddp explicit-API DP training (ShardedDataLoader + "
         "DistributedDataParallel over the XLA mesh backend).",
