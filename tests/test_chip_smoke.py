@@ -43,12 +43,16 @@ def _cache_probe(cwd, placed=None):
 
 
 def test_compile_cache_placement(tmp_path):
-    """Variable set: JAX already holds that directory before enable() runs
-    and the code sets nothing. Unset: one fixed path under the checkout,
-    whatever the working directory."""
+    """Variable set: the cache lives inside that directory. Unset: inside
+    one fixed path under the checkout, whatever the working directory. In
+    both, in the subdirectory named for the version of the names device
+    operations carry (a cache keeps the names it was compiled with)."""
+    from tpuddp.observability.profiling import NAMES_VERSION
+
     placed = str(tmp_path / "placed")
-    assert _cache_probe(str(tmp_path), placed) == [placed, placed, placed]
-    fixed = os.path.join(REPO, ".jax_cache")
+    inside = os.path.join(placed, NAMES_VERSION)
+    assert _cache_probe(str(tmp_path), placed) == [placed, inside, inside]
+    fixed = os.path.join(REPO, ".jax_cache", NAMES_VERSION)
     assert _cache_probe(str(tmp_path)) == ["None", fixed, fixed]
     assert _cache_probe(REPO) == ["None", fixed, fixed]
 

@@ -9,6 +9,25 @@ import jax
 
 from tpuddp import nn
 from tpuddp.nn.core import Context, Module
+from tpuddp.observability import profiling as _prof
+
+
+def _conv(block, key: str, params, x, ctx: Context):
+    """Child ``key`` of a residual block (a bufferless convolution), its
+    device operations named for the key."""
+    with _prof.scope(key):
+        y, _ = getattr(block, key).apply(params[key], (), x, ctx)
+    return y
+
+
+def _norm(block, key: str, params, state, new_state, x, ctx: Context):
+    """Child ``key`` of a residual block (a BatchNorm), its device operations
+    named for the key; its new buffers go to ``new_state[key]``."""
+    with _prof.scope(key):
+        y, new_state[key] = getattr(block, key).apply(
+            params[key], state[key], x, ctx
+        )
+    return y
 
 
 class BasicBlock(Module):
@@ -45,16 +64,14 @@ class BasicBlock(Module):
 
     def apply(self, params, state, x, ctx: Context):
         new_state = dict(state)
-        h, _ = self.conv1.apply(params["conv1"], (), x, ctx)
-        h, new_state["bn1"] = self.bn1.apply(params["bn1"], state["bn1"], h, ctx)
+        h = _conv(self, "conv1", params, x, ctx)
+        h = _norm(self, "bn1", params, state, new_state, h, ctx)
         h, _ = nn.ReLU().apply((), (), h, ctx)
-        h, _ = self.conv2.apply(params["conv2"], (), h, ctx)
-        h, new_state["bn2"] = self.bn2.apply(params["bn2"], state["bn2"], h, ctx)
+        h = _conv(self, "conv2", params, h, ctx)
+        h = _norm(self, "bn2", params, state, new_state, h, ctx)
         if "down_conv" in params:
-            sc, _ = self.down_conv.apply(params["down_conv"], (), x, ctx)
-            sc, new_state["down_bn"] = self.down_bn.apply(
-                params["down_bn"], state["down_bn"], sc, ctx
-            )
+            sc = _conv(self, "down_conv", params, x, ctx)
+            sc = _norm(self, "down_bn", params, state, new_state, sc, ctx)
         else:
             sc = x
         return jax.nn.relu(h + sc), new_state
@@ -108,19 +125,17 @@ class Bottleneck(Module):
 
     def apply(self, params, state, x, ctx: Context):
         new_state = dict(state)
-        h, _ = self.conv1.apply(params["conv1"], (), x, ctx)
-        h, new_state["bn1"] = self.bn1.apply(params["bn1"], state["bn1"], h, ctx)
+        h = _conv(self, "conv1", params, x, ctx)
+        h = _norm(self, "bn1", params, state, new_state, h, ctx)
         h = jax.nn.relu(h)
-        h, _ = self.conv2.apply(params["conv2"], (), h, ctx)
-        h, new_state["bn2"] = self.bn2.apply(params["bn2"], state["bn2"], h, ctx)
+        h = _conv(self, "conv2", params, h, ctx)
+        h = _norm(self, "bn2", params, state, new_state, h, ctx)
         h = jax.nn.relu(h)
-        h, _ = self.conv3.apply(params["conv3"], (), h, ctx)
-        h, new_state["bn3"] = self.bn3.apply(params["bn3"], state["bn3"], h, ctx)
+        h = _conv(self, "conv3", params, h, ctx)
+        h = _norm(self, "bn3", params, state, new_state, h, ctx)
         if "down_conv" in params:
-            sc, _ = self.down_conv.apply(params["down_conv"], (), x, ctx)
-            sc, new_state["down_bn"] = self.down_bn.apply(
-                params["down_bn"], state["down_bn"], sc, ctx
-            )
+            sc = _conv(self, "down_conv", params, x, ctx)
+            sc = _norm(self, "down_bn", params, state, new_state, sc, ctx)
         else:
             sc = x
         return jax.nn.relu(h + sc), new_state

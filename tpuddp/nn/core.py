@@ -21,6 +21,8 @@ from typing import Any, Optional, Tuple
 
 import jax
 
+from tpuddp.observability import profiling as _prof
+
 
 class Context:
     """Dynamic forward-pass context.
@@ -124,7 +126,8 @@ class Sequential(Module):
     def apply(self, params, state, x, ctx: Context):
         new_states = []
         for i, layer in enumerate(self.layers):
-            x, s = layer.apply(params[i], state[i], x, ctx.child(i))
+            with _prof.scope(_prof.layer_scope(i, layer)):
+                x, s = layer.apply(params[i], state[i], x, ctx.child(i))
             new_states.append(s)
         return x, tuple(new_states)
 

@@ -15,6 +15,11 @@
 jax.profiler supports one active trace, so all three funnel through the
 module latch; a trigger that finds a trace already running is skipped with
 a warning instead of crashing the run.
+
+Whatever the trigger, the capture's device operations carry the program's own
+names (:func:`scope`): the phase of the step each belongs to
+(:data:`PHASE_SCOPES`) and, under ``tpuddp.forward``, the model layer it came
+from (``3_Conv2d``, ``12_Bottleneck/conv2``).
 """
 
 from __future__ import annotations
@@ -28,6 +33,48 @@ from typing import Optional, Tuple
 import jax
 
 logger = logging.getLogger("tpuddp")
+
+# -- the names device operations carry ---------------------------------------
+#
+# One vocabulary for the whole program. The step builders open a phase scope
+# round each part of the step while it is traced; ``nn.Sequential`` and the
+# residual blocks open one scope per child under ``tpuddp.forward``. XLA keeps
+# the scope path as each operation's ``op_name``, which a profiler capture
+# shows as ``tf_op``. The backward pass needs no scope of its own: JAX writes
+# it as ``transpose(jvp(tpuddp.forward))/<layer path>/<primitive>``. The names
+# are metadata only: the compiled program is the same with and without them
+# (tests/test_device_scopes.py holds that).
+SCOPE_PREFIX = "tpuddp."
+AUGMENT = SCOPE_PREFIX + "augment"  # the on-device augment/resize of a batch
+FORWARD = SCOPE_PREFIX + "forward"  # model.apply; layer scopes nest under it
+LOSS = SCOPE_PREFIX + "loss"  # the criterion
+BUFFERS = SCOPE_PREFIX + "buffers"  # sync_buffers' broadcast / pmean
+EXCHANGE = SCOPE_PREFIX + "exchange"  # cross-replica gradient exchange
+CLIP = SCOPE_PREFIX + "clip"  # clip-after-aggregate
+GUARD = SCOPE_PREFIX + "guard"  # the firewall's verdict and its lax.cond
+OPTIMIZER = SCOPE_PREFIX + "optimizer"  # optimizer.update
+METRICS = SCOPE_PREFIX + "metrics"  # the step's metric sums
+PHASE_SCOPES = (
+    AUGMENT, FORWARD, LOSS, BUFFERS, EXCHANGE, CLIP, GUARD, OPTIMIZER, METRICS,
+)
+# Names are metadata, and JAX leaves metadata out of the persistent compile
+# cache's key: a program cached under older names is served with them. So the
+# cache lives in a subdirectory named for this version
+# (utils/compile_cache.py). Change it when, and only when, a scope's name or
+# its place in the step changes; the cost is one cold start per checkout.
+NAMES_VERSION = "names-v1"
+
+
+def scope(name: str):
+    """Name the device operations traced inside the ``with`` block. Runs
+    while a step is traced, never per step on the host."""
+    return jax.named_scope(name)
+
+
+def layer_scope(index: int, layer) -> str:
+    """The scope ``nn.Sequential`` gives child ``index``: ``3_Conv2d``."""
+    return f"{index}_{type(layer).__name__}"
+
 
 _PROFILE_ENV = "TPUDDP_PROFILE"
 _PROFILE_STEPS_ENV = "TPUDDP_PROFILE_STEPS"

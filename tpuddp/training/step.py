@@ -29,6 +29,7 @@ fused into the forward pass by XLA.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Callable, NamedTuple, Optional, Tuple
 
@@ -40,6 +41,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from tpuddp import optim as _optim
 from tpuddp.nn.core import Context
+from tpuddp.observability import profiling as _prof
 from tpuddp.parallel import collectives as col
 from tpuddp.resilience import guard as guard_lib
 from tpuddp.parallel.mesh import DATA_AXIS, data_axes, data_sharded, replicated
@@ -170,6 +172,20 @@ def _validate_sync_buffers(model, axis_name: Optional[str], sync_buffers: str):
             )
 
 
+def _sync_model_state(model_state, axis_name, sync_buffers: str):
+    """The ``sync_buffers`` policy over the step's new module buffers."""
+    if axis_name is None or sync_buffers == "none":
+        return model_state
+    with _prof.scope(_prof.BUFFERS):
+        if sync_buffers == "broadcast":
+            # torch DDP's default broadcast_buffers=True: unsynced BN buffers
+            # follow rank 0. Synced BN already produced identical buffers.
+            return col.broadcast(model_state, root=0, axis_name=axis_name)
+        # pmean: average instead of rank-0-wins, every replica's statistics
+        # contribute (identical when BN is already synced)
+        return col.pmean(model_state, axis_name)
+
+
 def _make_grad_core(
     model,
     criterion,
@@ -197,7 +213,8 @@ def _make_grad_core(
     def grad_core(state: TrainState, x, y, w):
         aug_rng, dropout_rng = _split_step_rng(state, axis_name)
         if augment is not None:
-            x = augment(aug_rng, x)
+            with _prof.scope(_prof.AUGMENT):
+                x = augment(aug_rng, x)
 
         def loss_fn(params):
             # sample_weight masks padded rows out of BatchNorm statistics,
@@ -205,23 +222,16 @@ def _make_grad_core(
             ctx = Context(
                 train=True, rng=dropout_rng, axis_name=axis_name, sample_weight=w
             )
-            logits, model_state = apply_fn(params, state.model_state, x, ctx)
-            loss = criterion(logits, y, w)
+            with _prof.scope(_prof.FORWARD):
+                logits, model_state = apply_fn(params, state.model_state, x, ctx)
+            with _prof.scope(_prof.LOSS):
+                loss = criterion(logits, y, w)
             return loss, model_state
 
         (loss, model_state), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             state.params
         )
-
-        if axis_name is not None and sync_buffers == "broadcast":
-            # torch DDP's default broadcast_buffers=True: unsynced BN buffers
-            # follow rank 0. Synced BN already produced identical buffers.
-            model_state = col.broadcast(model_state, root=0, axis_name=axis_name)
-        elif axis_name is not None and sync_buffers == "pmean":
-            # average instead of rank-0-wins: every replica's statistics
-            # contribute (identical when BN is already synced)
-            model_state = col.pmean(model_state, axis_name)
-
+        model_state = _sync_model_state(model_state, axis_name, sync_buffers)
         return grads, model_state, loss, jnp.sum(w)
 
     return grad_core
@@ -303,54 +313,59 @@ def _make_update_fn(
             # IS an allreduce), 1/N of the optimizer's HBM round trip.
             world = wus_spec.world
             shard_n = wus_spec.total // world
-            g_vec = _tree_to_vec(grads, wus_spec)
-            if comm is not None and comm.compressed:
-                # comm-hook composition: scatter the COMPRESSED payload —
-                # half the gradient wire bytes; the bf16_ef residual stays
-                # full-length and replica-local (see comm.reduce_scatter)
-                g_shard, new_comm = comm.reduce_scatter(
-                    g_vec, comm_state, axis_name
-                )
-            else:
-                g_shard = (
-                    jax.lax.psum_scatter(
-                        g_vec, axis_name, scatter_dimension=0, tiled=True
+            with _prof.scope(_prof.EXCHANGE):
+                g_vec = _tree_to_vec(grads, wus_spec)
+                if comm is not None and comm.compressed:
+                    # comm-hook composition: scatter the COMPRESSED payload —
+                    # half the gradient wire bytes; the bf16_ef residual stays
+                    # full-length and replica-local (see comm.reduce_scatter)
+                    g_shard, new_comm = comm.reduce_scatter(
+                        g_vec, comm_state, axis_name
                     )
-                    / world
-                )
-                new_comm = comm_state
+                else:
+                    g_shard = (
+                        jax.lax.psum_scatter(
+                            g_vec, axis_name, scatter_dimension=0, tiled=True
+                        )
+                        / world
+                    )
+                    new_comm = comm_state
 
             def wus_update(g_shard=g_shard, new_comm=new_comm):
                 g = g_shard
                 if clip_grad_norm is not None:
                     # the global norm of a sharded vector is one scalar psum
                     # away; padding zeros contribute nothing
-                    norm = jnp.sqrt(
-                        jax.lax.psum(jnp.sum(jnp.square(g)), axis_name)
+                    with _prof.scope(_prof.CLIP):
+                        norm = jnp.sqrt(
+                            jax.lax.psum(jnp.sum(jnp.square(g)), axis_name)
+                        )
+                        g = g * jnp.minimum(1.0, clip_grad_norm / (norm + 1e-6))
+                with _prof.scope(_prof.OPTIMIZER):
+                    idx = jax.lax.axis_index(axis_name)
+                    p_vec = _tree_to_vec(params, wus_spec)
+                    p_shard = jax.lax.dynamic_slice(
+                        p_vec, (idx * shard_n,), (shard_n,)
                     )
-                    g = g * jnp.minimum(1.0, clip_grad_norm / (norm + 1e-6))
-                idx = jax.lax.axis_index(axis_name)
-                p_vec = _tree_to_vec(params, wus_spec)
-                p_shard = jax.lax.dynamic_slice(
-                    p_vec, (idx * shard_n,), (shard_n,)
-                )
-                update_flat = getattr(optimizer, "update_flat", None)
-                if update_flat is not None:
-                    # layer-boundary-aware flat update (LARS/LAMB trust
-                    # ratios over the spec's leaf offsets; per-layer norms
-                    # psum across the axis since shards straddle layers)
-                    new_p_shard, new_opt_state = update_flat(
-                        g, opt_state, p_shard, spec=wus_spec,
-                        axis_name=axis_name, shard_index=idx,
+                    update_flat = getattr(optimizer, "update_flat", None)
+                    if update_flat is not None:
+                        # layer-boundary-aware flat update (LARS/LAMB trust
+                        # ratios over the spec's leaf offsets; per-layer norms
+                        # psum across the axis since shards straddle layers)
+                        new_p_shard, new_opt_state = update_flat(
+                            g, opt_state, p_shard, spec=wus_spec,
+                            axis_name=axis_name, shard_index=idx,
+                        )
+                    else:
+                        new_p_shard, new_opt_state = optimizer.update(
+                            g, opt_state, p_shard
+                        )
+                with _prof.scope(_prof.EXCHANGE):
+                    new_p_vec = jax.lax.all_gather(
+                        new_p_shard, axis_name, tiled=True
                     )
-                else:
-                    new_p_shard, new_opt_state = optimizer.update(
-                        g, opt_state, p_shard
-                    )
-                new_p_vec = jax.lax.all_gather(
-                    new_p_shard, axis_name, tiled=True
-                )
-                return _vec_to_tree(new_p_vec, wus_spec), new_opt_state, new_comm
+                    new_params = _vec_to_tree(new_p_vec, wus_spec)
+                return new_params, new_opt_state, new_comm
 
             if not guard:
                 new_params, new_opt_state, new_comm = wus_update()
@@ -360,64 +375,113 @@ def _make_update_fn(
             # globally: one scalar pmin next to the scatter. Every other
             # collective (clip psum, all-gather) sits inside the cond — all
             # replicas take the same branch, so they still pair up.
-            ok = (
-                col.pmin(
-                    guard_lib.tree_all_finite(g_shard).astype(jnp.int32),
-                    axis_name,
+            with _prof.scope(_prof.GUARD):
+                ok = (
+                    col.pmin(
+                        guard_lib.tree_all_finite(g_shard).astype(jnp.int32),
+                        axis_name,
+                    )
+                    == 1
                 )
-                == 1
-            )
-            return gate(ok, wus_update, params, opt_state, comm_state, skipped)
+                return gate(
+                    ok, wus_update, params, opt_state, comm_state, skipped
+                )
 
         ok = None
         if guard and axis_name is None:
             # auto/managed mode: XLA's partitioner already aggregated inside
             # backward — `grads` IS the global-batch f32 gradient, checked
             # here BEFORE the hook quantizes it (the f32-payload contract)
-            ok = guard_lib.tree_all_finite(grads)
-        if hier is not None and comm is not None:
-            # hierarchical multi-hop reduction over the factored data mesh:
-            # intra-host f32 reduce-scatter -> compressed inter-host
-            # exchange -> all-gather (comm.reduce_hierarchical)
-            agg_grads, new_comm = comm.reduce_hierarchical(
-                grads, comm_state, hier[0], hier[1]
-            )
-        elif comm is not None and comm.compressed:
-            # bucketed compressed allreduce (torch DDP comm-hook analog):
-            # flatten -> per-bucket compress -> collective -> f32 decompress
-            # -> mean. With axis_name=None (auto mode) this is the local
-            # quantization emulation — XLA's implicit psum already aggregated.
-            agg_grads, new_comm = comm.reduce(grads, comm_state, axis_name)
-        elif axis_name is not None:
-            # THE DDP step: average gradients across replicas (reference
-            # :125's implicit NCCL allreduce). In auto mode XLA inserts
-            # this itself.
-            agg_grads, new_comm = col.pmean(grads, axis_name), comm_state
-        else:
-            agg_grads, new_comm = grads, comm_state
-        if guard and ok is None:
-            # post-allreduce f32 gradient: the sum propagated any replica's
-            # NaN/Inf everywhere, so this replica-local check IS the global
-            # verdict — no extra collective on the replicated path. (bf16
-            # keeps the f32 exponent range, so quantization cannot mask a
-            # non-finite f32 payload from the post-reduce check.)
-            ok = guard_lib.tree_all_finite(agg_grads)
-
-        def plain_update(agg_grads=agg_grads, new_comm=new_comm):
-            g = agg_grads
-            if clip_grad_norm is not None:
-                # clip-before-aggregate caveat (reference README): clip the
-                # *averaged* grad, identically on all replicas.
-                g, _ = _optim.clip_grad_norm_(g, clip_grad_norm)
-            new_params, new_opt_state = optimizer.update(g, opt_state, params)
-            return new_params, new_opt_state, new_comm
-
-        if not guard:
-            new_params, new_opt_state, new_comm = plain_update()
-            return new_params, new_opt_state, new_comm, skipped
-        return gate(ok, plain_update, params, opt_state, comm_state, skipped)
+            with _prof.scope(_prof.GUARD):
+                ok = guard_lib.tree_all_finite(grads)
+        with _prof.scope(_prof.EXCHANGE):
+            if hier is not None and comm is not None:
+                # hierarchical multi-hop reduction over the factored data
+                # mesh: intra-host f32 reduce-scatter -> compressed inter-host
+                # exchange -> all-gather (comm.reduce_hierarchical)
+                agg_grads, new_comm = comm.reduce_hierarchical(
+                    grads, comm_state, hier[0], hier[1]
+                )
+            elif comm is not None and comm.compressed:
+                # bucketed compressed allreduce (torch DDP comm-hook analog):
+                # flatten -> per-bucket compress -> collective -> f32
+                # decompress -> mean. With axis_name=None (auto mode) this is
+                # the local quantization emulation — XLA's implicit psum
+                # already aggregated.
+                agg_grads, new_comm = comm.reduce(grads, comm_state, axis_name)
+            elif axis_name is not None:
+                # THE DDP step: average gradients across replicas (reference
+                # :125's implicit NCCL allreduce). In auto mode XLA inserts
+                # this itself.
+                agg_grads, new_comm = col.pmean(grads, axis_name), comm_state
+            else:
+                agg_grads, new_comm = grads, comm_state
+        return _update_reduced(
+            optimizer, clip_grad_norm, guard, params, opt_state, agg_grads,
+            new_comm, comm_state, skipped, ok,
+        )
 
     return apply_update
+
+
+def _update_reduced(optimizer, clip_grad_norm, guard, params, opt_state,
+                    agg_grads, cand_comm, comm_state, skipped, ok=None):
+    """Verdict + clip + update over an ALREADY cross-replica-reduced f32
+    gradient, behind the ``lax.cond`` firewall when ``guard``: the tail the
+    barrier update and the segmented-overlap step share. ``ok`` is a verdict
+    taken before the exchange (auto mode); without one the post-allreduce
+    gradient is checked — the sum propagated any replica's NaN/Inf
+    everywhere, so this replica-local check IS the global verdict, no extra
+    collective on the replicated path. (bf16 keeps the f32 exponent range, so
+    quantization cannot mask a non-finite f32 payload from the post-reduce
+    check.)"""
+
+    def plain_update():
+        g = agg_grads
+        if clip_grad_norm is not None:
+            # clip-before-aggregate caveat (reference README): clip the
+            # *averaged* grad, identically on all replicas.
+            with _prof.scope(_prof.CLIP):
+                g, _ = _optim.clip_grad_norm_(g, clip_grad_norm)
+        with _prof.scope(_prof.OPTIMIZER):
+            new_params, new_opt_state = optimizer.update(g, opt_state, params)
+        return new_params, new_opt_state, cand_comm
+
+    if not guard:
+        new_params, new_opt_state, new_comm = plain_update()
+        return new_params, new_opt_state, new_comm, skipped
+    with _prof.scope(_prof.GUARD):
+        if ok is None:
+            ok = guard_lib.tree_all_finite(agg_grads)
+        return _firewall_gate(
+            ok, plain_update, params, opt_state, comm_state, skipped
+        )
+
+
+def _revert_buffers_on_skip(old_state, new_state, skipped, new_skipped):
+    """Extend the firewall's no-op to the module buffers: BatchNorm running
+    stats computed from the poisoned forward must not outlive the skipped
+    update (the counters move only on a skip, so the select is exactly the
+    firewall's verdict)."""
+    with _prof.scope(_prof.GUARD):
+        skipped_now = new_skipped["total"] != skipped["total"]
+        return jax.tree_util.tree_map(
+            lambda old, new: jnp.where(skipped_now, old, new),
+            old_state, new_state,
+        )
+
+
+def _step_metrics(loss, n):
+    with _prof.scope(_prof.METRICS):
+        return {
+            "loss_sum": (loss * n)[None],  # sample-weighted, reference :131
+            "n": n[None],
+        }
+
+
+def _sum_metrics(stacked):
+    with _prof.scope(_prof.METRICS):
+        return jax.tree_util.tree_map(lambda a: jnp.sum(a, axis=0), stacked)
 
 
 def _make_train_core(
@@ -456,19 +520,11 @@ def _make_train_core(
             state.skipped_steps,
         )
         if guard:
-            # extend the no-op to the module buffers: BatchNorm running
-            # stats computed from the poisoned forward must not outlive the
-            # skipped update (the counters move only on a skip, so the
-            # select is exactly the firewall's verdict)
-            skipped_now = new_skipped["total"] != state.skipped_steps["total"]
-            model_state = jax.tree_util.tree_map(
-                lambda old, new: jnp.where(skipped_now, old, new),
-                state.model_state, model_state,
+            model_state = _revert_buffers_on_skip(
+                state.model_state, model_state, state.skipped_steps,
+                new_skipped,
             )
-        metrics = {
-            "loss_sum": (loss * n)[None],  # sample-weighted, reference :131
-            "n": n[None],
-        }
+        metrics = _step_metrics(loss, n)
         new_state = TrainState(
             params=new_params,
             model_state=model_state,
@@ -550,26 +606,7 @@ def _make_apply_reduced(optimizer, clip_grad_norm: Optional[float], guard: bool)
     inside its backward walk and lands here with the aggregated f32 gradient
     and the candidate comm_state in hand."""
 
-    def apply_reduced(params, opt_state, agg_grads, cand_comm, comm_state,
-                      skipped):
-        def plain_update():
-            g = agg_grads
-            if clip_grad_norm is not None:
-                g, _ = _optim.clip_grad_norm_(g, clip_grad_norm)
-            new_params, new_opt_state = optimizer.update(g, opt_state, params)
-            return new_params, new_opt_state, cand_comm
-
-        if not guard:
-            new_params, new_opt_state, new_comm = plain_update()
-            return new_params, new_opt_state, new_comm, skipped
-        # post-allreduce f32 gradient: the sum propagated any replica's
-        # NaN/Inf everywhere, so this replica-local check IS the global
-        # verdict — same contract as the barrier path.
-        ok = guard_lib.tree_all_finite(agg_grads)
-        return _firewall_gate(ok, plain_update, params, opt_state, comm_state,
-                              skipped)
-
-    return apply_reduced
+    return functools.partial(_update_reduced, optimizer, clip_grad_norm, guard)
 
 
 def _make_segmented_vjp(model, criterion, axis_name, sync_buffers: str,
@@ -589,7 +626,8 @@ def _make_segmented_vjp(model, criterion, axis_name, sync_buffers: str,
     def seg_vjp(state: TrainState, x, y, w):
         aug_rng, dropout_rng = _split_step_rng(state, axis_name)
         if augment is not None:
-            x = augment(aug_rng, x)
+            with _prof.scope(_prof.AUGMENT):
+                x = augment(aug_rng, x)
         ctx = Context(
             train=True, rng=dropout_rng, axis_name=axis_name, sample_weight=w
         )
@@ -603,9 +641,14 @@ def _make_segmented_vjp(model, criterion, axis_name, sync_buffers: str,
             def seg_fwd(p, v, a=a, b=b, s_seg=s_seg):
                 out = v
                 states = []
-                for j, i in enumerate(range(a, b)):
-                    out, s = model[i].apply(p[j], s_seg[j], out, ctx.child(i))
-                    states.append(s)
+                with _prof.scope(_prof.FORWARD):
+                    for j, i in enumerate(range(a, b)):
+                        # the name Sequential.apply gives the same call
+                        with _prof.scope(_prof.layer_scope(i, model[i])):
+                            out, s = model[i].apply(
+                                p[j], s_seg[j], out, ctx.child(i)
+                            )
+                        states.append(s)
                 return out, tuple(states)
 
             act, pull, st_seg = jax.vjp(
@@ -615,12 +658,14 @@ def _make_segmented_vjp(model, criterion, axis_name, sync_buffers: str,
             new_states.extend(st_seg)
         # loss head: criterion value + logits cotangent in one VJP — the same
         # criterion backward the barrier step's whole-model grad begins with
-        loss, ct = jax.value_and_grad(lambda lg: criterion(lg, y, w))(act)
-        model_state = tuple(new_states)
-        if axis_name is not None and sync_buffers == "broadcast":
-            model_state = col.broadcast(model_state, root=0, axis_name=axis_name)
-        elif axis_name is not None and sync_buffers == "pmean":
-            model_state = col.pmean(model_state, axis_name)
+        def loss_head(logits):
+            with _prof.scope(_prof.LOSS):
+                return criterion(logits, y, w)
+
+        loss, ct = jax.value_and_grad(loss_head)(act)
+        model_state = _sync_model_state(
+            tuple(new_states), axis_name, sync_buffers
+        )
         return pullbacks, ct, model_state, loss, jnp.sum(w)
 
     return seg_vjp
@@ -645,24 +690,26 @@ def _segmented_exchange(pullbacks, ct, residual, comm, segments, axis_name,
         dp_seg, ct = pullbacks[k](ct)
         g_seg = grad_of_seg(k, dp_seg)
         seg = segments[k]
-        if comm is not None and comm.compressed:
-            lo, hi = seg.flat
-            g_vec = _subtree_to_vec(g_seg, hi - lo)
-            if comm.needs_residual:
-                send = g_vec + jax.lax.slice(residual, (lo,), (hi,))
+        with _prof.scope(_prof.EXCHANGE):
+            if comm is not None and comm.compressed:
+                lo, hi = seg.flat
+                g_vec = _subtree_to_vec(g_seg, hi - lo)
+                if comm.needs_residual:
+                    send = g_vec + jax.lax.slice(residual, (lo,), (hi,))
+                else:
+                    send = g_vec
+                summed, kept = comm.exchange_segment(send, seg, axis_name)
+                red[k] = summed / comm.world
+                if comm.needs_residual:
+                    res[k] = send - kept
             else:
-                send = g_vec
-            summed, kept = comm.exchange_segment(send, seg, axis_name)
-            red[k] = summed / comm.world
-            if comm.needs_residual:
-                res[k] = send - kept
-        else:
-            # hook "none": the segment's slice of THE DDP pmean — identical
-            # leaves to the barrier col.pmean over the whole tree
-            red[k] = col.pmean(g_seg, axis_name)
+                # hook "none": the segment's slice of THE DDP pmean —
+                # identical leaves to the barrier col.pmean over the whole tree
+                red[k] = col.pmean(g_seg, axis_name)
     if comm is not None and comm.compressed:
-        agg_grads = _vec_to_tree(jnp.concatenate(red), comm.spec)
-        new_comm = jnp.concatenate(res) if comm.needs_residual else residual
+        with _prof.scope(_prof.EXCHANGE):
+            agg_grads = _vec_to_tree(jnp.concatenate(red), comm.spec)
+            new_comm = jnp.concatenate(res) if comm.needs_residual else residual
     else:
         layers = []
         for r in red:
@@ -711,15 +758,11 @@ def _make_segmented_train_core(
             state.comm_state, state.skipped_steps,
         )
         if guard:
-            skipped_now = new_skipped["total"] != state.skipped_steps["total"]
-            model_state = jax.tree_util.tree_map(
-                lambda old, new: jnp.where(skipped_now, old, new),
-                state.model_state, model_state,
+            model_state = _revert_buffers_on_skip(
+                state.model_state, model_state, state.skipped_steps,
+                new_skipped,
             )
-        metrics = {
-            "loss_sum": (loss * n)[None],
-            "n": n[None],
-        }
+        metrics = _step_metrics(loss, n)
         new_state = TrainState(
             params=new_params,
             model_state=model_state,
@@ -737,18 +780,22 @@ def _make_segmented_train_core(
 def _make_eval_core(model, criterion, axis_name, transform: Optional[Callable]):
     def core(state: TrainState, x, y, w):
         if transform is not None:
-            x = transform(x)
+            with _prof.scope(_prof.AUGMENT):
+                x = transform(x)
         ctx = Context(train=False, rng=None, axis_name=axis_name, sample_weight=w)
-        logits, _ = model.apply(state.params, state.model_state, x, ctx)
-        loss = criterion(logits, y, w)
-        n = jnp.sum(w)
-        predicted = jnp.argmax(logits, axis=-1)
-        correct = jnp.sum((predicted == y) * w)
-        return {
-            "loss_sum": (loss * n)[None],
-            "correct": correct[None],
-            "n": n[None],
-        }
+        with _prof.scope(_prof.FORWARD):
+            logits, _ = model.apply(state.params, state.model_state, x, ctx)
+        with _prof.scope(_prof.LOSS):
+            loss = criterion(logits, y, w)
+        with _prof.scope(_prof.METRICS):
+            n = jnp.sum(w)
+            predicted = jnp.argmax(logits, axis=-1)
+            correct = jnp.sum((predicted == y) * w)
+            return {
+                "loss_sum": (loss * n)[None],
+                "correct": correct[None],
+                "n": n[None],
+            }
 
     return core
 
@@ -918,8 +965,7 @@ def build_train_scan_step(
                 return st, m
 
             state, stacked = jax.lax.scan(body, state, (xs, ys, ws))
-            metrics = jax.tree_util.tree_map(lambda a: jnp.sum(a, axis=0), stacked)
-            return state, metrics
+            return state, _sum_metrics(stacked)
     else:
         grad_core = _make_grad_core(
             model, criterion, axis_name, sync_buffers, augment, remat
@@ -981,7 +1027,7 @@ def build_train_scan_step(
                         comm_state=st.comm_state,
                         skipped_steps=st.skipped_steps,
                     )
-                    m = {"loss_sum": (loss * n)[None], "n": n[None]}
+                    m = _step_metrics(loss, n)
                     return (st, gacc, nacc + n), m
 
                 if segments is None:
@@ -1032,14 +1078,17 @@ def build_train_scan_step(
                         comm_state=st.comm_state,
                         skipped_steps=st.skipped_steps,
                     )
-                    m_l = {"loss_sum": (loss_l * n_l)[None], "n": n_l[None]}
+                    m_l = _step_metrics(loss_l, n_l)
                     # stack the peeled micro back onto the head so the metric
                     # sum reduces over the SAME length-A array as the barrier
                     # cycle (identical reduction order, bitwise totals)
-                    stacked = jax.tree_util.tree_map(
-                        lambda h, last: jnp.concatenate([h, last[None]], axis=0),
-                        head_stacked, m_l,
-                    )
+                    with _prof.scope(_prof.METRICS):
+                        stacked = jax.tree_util.tree_map(
+                            lambda h, last: jnp.concatenate(
+                                [h, last[None]], axis=0
+                            ),
+                            head_stacked, m_l,
+                        )
                     new_params, new_opt_state, new_comm, new_skipped = apply_reduced(
                         st.params, st.opt_state, agg, cand_comm,
                         st.comm_state, st.skipped_steps,
@@ -1049,10 +1098,8 @@ def build_train_scan_step(
                     # a skipped cycle also reverts the buffers the cycle's
                     # forwards (poisoned micro-batch included) accumulated —
                     # the cycle is the atomic update unit
-                    skipped_now = new_skipped["total"] != st.skipped_steps["total"]
-                    model_state = jax.tree_util.tree_map(
-                        lambda old, new: jnp.where(skipped_now, old, new),
-                        ms0, st.model_state,
+                    model_state = _revert_buffers_on_skip(
+                        ms0, st.model_state, st.skipped_steps, new_skipped
                     )
                 st = TrainState(
                     params=new_params,
@@ -1063,14 +1110,10 @@ def build_train_scan_step(
                     comm_state=new_comm,
                     skipped_steps=new_skipped,
                 )
-                metrics = jax.tree_util.tree_map(
-                    lambda a: jnp.sum(a, axis=0), stacked
-                )
-                return st, metrics
+                return st, _sum_metrics(stacked)
 
             state, stacked = jax.lax.scan(cycle, state, cyc)
-            metrics = jax.tree_util.tree_map(lambda a: jnp.sum(a, axis=0), stacked)
-            return state, metrics
+            return state, _sum_metrics(stacked)
 
     if mode == "shard_map":
         st_spec = state_spec if state_spec is not None else P()
@@ -1178,7 +1221,7 @@ def build_eval_scan_step(
             return carry, core(state, *batch)
 
         _, stacked = jax.lax.scan(body, 0, (xs, ys, ws))
-        return jax.tree_util.tree_map(lambda a: jnp.sum(a, axis=0), stacked)
+        return _sum_metrics(stacked)
 
     if mode == "shard_map":
         in_batch = P(None, axis)
