@@ -270,3 +270,143 @@ def test_space_to_depth_fuzz_matches_conv2d():
             np.asarray(y_ref), np.asarray(y_s2d), rtol=1e-4, atol=1e-4,
             err_msg=f"trial {trial}: k={k} s={s} p={p} h={h} w={w} c={c}",
         )
+
+
+# --- nn.Conv2d picks the thin-channel strided stem's lowering itself --------
+
+_DN = ("NHWC", "HWIO", "NHWC")
+
+
+def _direct_conv(layer, params, x):
+    """The direct lowering, written out: what ``Conv2d.apply`` was before it
+    chose, and still is wherever the rule says no."""
+    y = jax.lax.conv_general_dilated(
+        x, params["weight"].astype(x.dtype), window_strides=layer.strides,
+        padding=layer._pad_arg(), dimension_numbers=_DN,
+    )
+    if layer.use_bias:
+        y = y + params["bias"].astype(y.dtype)
+    return y
+
+
+def _lowered_text(fn, *args):
+    def f(*a):
+        return fn(*a)
+
+    return jax.jit(f).lower(*args).as_text()
+
+
+# (in_channels, kernel, strides, padding) -> block size, or None for direct
+_RULE_CASES = {
+    "alexnet_stem": ((3, 11, 4, 2), 2),  # blocks of 2 span 12 rows, of 4 span 16
+    "rgba_7x7_s4": ((4, 7, 4, 3), 4),  # 8 rows either way: the larger block
+    "grey_7x7_s5": ((1, 7, 5, 0), 5),
+    "resnet_stem": ((3, 7, 2, 3), None),  # measured slower blocked (PERF.md, PR 25)
+    "5x5_s3": ((3, 5, 3, 2), None),
+    "8ch_11x11_s4": ((8, 11, 4, 2), None),
+    "3x3_s1": ((3, 3, 1, 1), None),
+    "5x5_s1": ((3, 5, 1, 2), None),
+    "1x1_s2_256ch": ((256, 1, 2, 0), None),
+    "3x3_s2_64ch": ((64, 3, 2, 1), None),
+    "kernel_not_over_stride": ((3, 2, 2, 0), None),
+    "string_padding": ((3, 7, 2, "SAME"), None),
+    "asymmetric_padding": ((3, 7, 2, ((3, 2), (3, 2))), None),
+    "non_square_stride": ((3, 7, (2, 1), 3), None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_RULE_CASES))
+def test_conv_lowering_rule(case):
+    """The one rule (nn.layers.space_to_depth_block) on static shapes: it
+    takes the thin-channel, widely strided stems and nothing else, ``conv_lowering``
+    states the choice, and a convolution it rejects lowers to the direct
+    form's HLO, character for character."""
+    from tpuddp.nn.layers import space_to_depth_block
+
+    (c, k, s, p), block = _RULE_CASES[case]
+    layer = nn.Conv2d(8, kernel_size=k, strides=s, padding=p)
+    assert space_to_depth_block(c, layer.kernel_size, layer.strides, p) == block
+    want = "direct" if block is None else f"space-to-depth, block {block}"
+    assert nn.conv_lowering(layer, c) == want
+    x = jnp.zeros((2, 24, 24, c), jnp.bfloat16)
+    params, _ = layer.init(KEY, x)
+    auto = _lowered_text(lambda pr, v: layer.apply(pr, (), v, Context())[0], params, x)
+    direct = _lowered_text(lambda pr, v: _direct_conv(layer, pr, v), params, x)
+    assert (auto == direct) == (block is None)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize(
+    "hw,k,s,p",
+    [((224, 224), 11, 4, 2), ((63, 63), 11, 4, 2), ((67, 93), 7, 4, 3), ((225, 226), 9, 5, 1)],
+    ids=["alexnet224", "alexnet63", "odd67x93", "odd225x226"],
+)
+def test_conv_auto_lowering_is_exact(hw, k, s, p, dtype):
+    """``Conv2d`` with the rule engaged against a direct
+    ``lax.conv_general_dilated`` on the same parameters: forward, weight and
+    bias gradients, at the AlexNet stem's real shape, its 63x63 minimum and
+    sizes that are no multiple of the stride."""
+    dtype = jnp.dtype(dtype)
+    layer = nn.Conv2d(16, kernel_size=k, strides=s, padding=p)
+    assert nn.conv_lowering(layer, 3).startswith("space-to-depth, block ")
+    x = jnp.asarray(
+        np.random.RandomState(0).randn(2, *hw, 3).astype(np.float32)
+    ).astype(dtype)
+    params, _ = layer.init(KEY, x)
+
+    def run(forward):
+        def loss(pr):
+            y = forward(pr)
+            return jnp.sum(jnp.square(y.astype(jnp.float32))), y
+
+        (_, y), g = jax.value_and_grad(loss, has_aux=True)(params)
+        return y, g
+
+    y_auto, g_auto = run(lambda pr: layer.apply(pr, (), x, Context())[0])
+    y_ref, g_ref = run(lambda pr: _direct_conv(layer, pr, x))
+    assert y_auto.shape == y_ref.shape and y_auto.dtype == y_ref.dtype
+    # bf16: the two forms round a different order of the same f32 sum once
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    scale = float(jnp.abs(y_ref.astype(jnp.float32)).max())
+    np.testing.assert_allclose(
+        np.asarray(y_auto, np.float32), np.asarray(y_ref, np.float32),
+        rtol=tol, atol=tol * scale,
+    )
+    for name in ("weight", "bias"):
+        a, b = np.asarray(g_auto[name]), np.asarray(g_ref[name])
+        np.testing.assert_allclose(a, b, rtol=tol, atol=tol * np.abs(b).max())
+
+
+@pytest.mark.parametrize(
+    "plain,alias,hw", [("alexnet", "alexnet_s2d", 64), ("resnet18", "resnet18_s2d", 32),
+                       ("resnet50", "resnet50_s2d", 32)],
+)
+def test_s2d_names_build_the_plain_program(plain, alias, hw):
+    """The ``*_s2d`` registry names and ``space_to_depth=`` are aliases: one
+    jitted forward of either lowers to the same HLO text."""
+    x = jnp.zeros((2, hw, hw, 3), jnp.bfloat16)
+    texts = []
+    for name in (plain, alias):
+        model = load_model(name, 10)
+        params, state = jax.eval_shape(lambda: model.init(KEY, x))
+        texts.append(_lowered_text(
+            lambda pr, st, v: model.apply(pr, st, v, Context(train=False))[0],
+            params, state, x,
+        ))
+    assert texts[0] == texts[1]
+
+
+def test_stem_keeps_its_device_scope():
+    """The stem is a ``Conv2d`` whatever it lowers to, so its device
+    operations stay under ``0_Conv2d`` and the benchmark's layer table
+    (benchmark/scope_reduce.py, benchmark/flops/alexnet_cifar224.py) keeps
+    joining on that name."""
+    model = load_model("alexnet_s2d", 10)
+    x = jnp.zeros((2, 64, 64, 3), jnp.bfloat16)
+    params, state = jax.eval_shape(lambda: model.init(KEY, x))
+    assert nn.conv_lowering(model[0], 3) == "space-to-depth, block 2"
+    text = jax.jit(
+        lambda pr, st, v: model.apply(pr, st, v, Context(train=False))[0]
+    ).lower(params, state, x).as_text(debug_info=True)
+    assert "0_Conv2d/conv_general_dilated" in text
+    assert "SpaceToDepthConv2d" not in text

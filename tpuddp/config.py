@@ -96,7 +96,7 @@ TRAINING_DEFAULTS = {
     "optimizer_state_dtype": None,  # Adam m/v storage dtype ("bfloat16" halves
     # optimizer HBM traffic; math stays f32). None -> params' dtype.
     "pretrained_path": None,  # torch checkpoint to fine-tune from (alexnet,
-    # vgg11/13/16, resnet18/34 — incl. the _s2d stem variants, same checkpoints)
+    # vgg11/13/16, resnet18/34; their _s2d names are aliases of the plain ones)
     "num_classes": None,  # None -> derived from training.dataset
     "resume": False,  # restore the newest checkpoint from out_dir (native:
     # ckpt_{epoch}.npz full TrainState; managed: state_{epoch}.npz)

@@ -2,7 +2,8 @@
 its classifier head swapped for CIFAR-10 (data_and_toy_model.py:41-45); tpuddp
 adds genuinely small toy models for fast CI (per SURVEY.md scale calibration),
 ResNet-18/34 (BasicBlock) + ResNet-50/101/152 (Bottleneck), VGG-11/13/16/19, and
-CIFAR-stem/space-to-depth variants; all torch-importable."""
+CIFAR-stem variants (the ``*_s2d`` names are aliases of the plain ones); all
+torch-importable."""
 
 from tpuddp.models.toy import ToyCNN, ToyMLP  # noqa: F401
 from tpuddp.models.alexnet import AlexNet  # noqa: F401
@@ -42,8 +43,9 @@ _REGISTRY = {
     "transformer_small": _partial(
         TransformerLM, d_model=128, n_heads=8, n_layers=4, max_seq_len=256,
     ),
-    # exact space-to-depth stem reparameterization (same params/checkpoints;
-    # faster MXU mapping for the thin-channel strided stems)
+    # aliases of the plain names: nn.Conv2d picks the space-to-depth lowering
+    # of a thin-channel strided stem from its own shapes, so these build the
+    # same program (kept for settings files and checkpoints that name them)
     "alexnet_s2d": _partial(AlexNet, space_to_depth=True),
     "resnet18_s2d": _partial(ResNet18, space_to_depth=True),
     "resnet34_s2d": _partial(ResNet34, space_to_depth=True),

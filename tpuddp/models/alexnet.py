@@ -22,13 +22,13 @@ def AlexNet(
     3-layer classifier. Input is NHWC, any spatial size >= 63 (reference feeds
     224x224 CIFAR upsamples).
 
-    ``space_to_depth=True`` swaps the 11x11/s4 3-channel stem for its exact
-    space-to-depth reparameterization (nn.SpaceToDepthConv2d) — same math,
-    same parameter shapes (checkpoints/torch imports interchangeable), far
-    better MXU utilization on the thin-channel strided stem."""
-    stem_cls = nn.SpaceToDepthConv2d if space_to_depth else nn.Conv2d
+    The 11x11/s4 3-channel stem lowers through space-to-depth by itself
+    (``nn.Conv2d`` chooses from its shapes: same math, same parameter
+    shapes). ``space_to_depth`` is accepted for the callers that used to ask
+    for that lowering and changes nothing: ``alexnet_s2d`` is ``alexnet``."""
+    del space_to_depth
     features = [
-        stem_cls(64, kernel_size=11, strides=4, padding=2),
+        nn.Conv2d(64, kernel_size=11, strides=4, padding=2),
         nn.ReLU(),
         nn.MaxPool2d(3, strides=2),
         nn.Conv2d(192, kernel_size=5, padding=2),

@@ -157,10 +157,13 @@ def _resnet(
     """stem + ``block`` stages at widths [64,128,256,512] + GAP head.
     ``small_input=True`` uses the CIFAR stem (3x3/1 conv, no maxpool) for
     native 32x32 training — the TPU-friendly alternative to the reference's
-    resize-everything-to-224. ``space_to_depth=True`` swaps the full stem's
-    7x7/s2 3-channel conv for its exact space-to-depth reparameterization
-    (same parameters/checkpoints; see nn.SpaceToDepthConv2d). ``block`` is
-    BasicBlock (ResNet-18/34) or Bottleneck (ResNet-50)."""
+    resize-everything-to-224. The full stem's 7x7/s2 3-channel conv is a
+    plain ``nn.Conv2d``, which picks its own lowering from its shapes
+    (``nn.conv_lowering``: direct at stride 2, where the blocked form was
+    measured to lose, PERF.md PR 25); ``space_to_depth`` is accepted for the
+    callers and ``*_s2d`` names that used to ask for the blocked stem, and
+    builds the same program either way. ``block`` is BasicBlock (ResNet-18/34) or
+    Bottleneck (ResNet-50)."""
     if small_input:
         if space_to_depth:
             raise ValueError(
@@ -173,9 +176,8 @@ def _resnet(
             nn.ReLU(),
         ]
     else:
-        stem_cls = nn.SpaceToDepthConv2d if space_to_depth else nn.Conv2d
         stem = [
-            stem_cls(64, 7, strides=2, padding=3, use_bias=False),
+            nn.Conv2d(64, 7, strides=2, padding=3, use_bias=False),
             nn.BatchNorm(sync=sync_bn),
             nn.ReLU(),
             nn.MaxPool2d(3, strides=2, padding=1),

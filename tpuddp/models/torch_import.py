@@ -145,8 +145,9 @@ def load_pretrained_alexnet(
     import the weights, then swap in a fresh ``num_classes`` head when the
     widths differ. Returns ``(model, params, model_state)`` ready for
     ``DistributedDataParallel.init_state`` / ``Accelerator.prepare``.
-    ``space_to_depth`` builds the s2d-stem variant — the parameter layout is
-    identical, so the same checkpoint loads either way.
+    ``space_to_depth`` is accepted and changes nothing: the stem picks its
+    own lowering (``nn.conv_lowering``) and the parameter layout is the
+    direct form's, so the same checkpoint loads either way.
     """
     from tpuddp.models.alexnet import AlexNet
 
@@ -509,8 +510,8 @@ _PRETRAINED_LOADERS = {
     "vgg13": _pt(load_pretrained_vgg, "vgg13"),
     "vgg16": _pt(load_pretrained_vgg, "vgg16"),
     "vgg19": _pt(load_pretrained_vgg, "vgg19"),
-    # s2d stems share the exact parameter layout, so the same torch
-    # checkpoints load into them (the "_s2d = same checkpoints" promise)
+    # aliases of the plain names (models/__init__.py): same model, same
+    # parameter layout, same torch checkpoints
     "alexnet_s2d": _pt(load_pretrained_alexnet, space_to_depth=True),
     "resnet18_s2d": _pt(load_pretrained_resnet18, space_to_depth=True),
     "resnet34_s2d": _pt(load_pretrained_resnet34, space_to_depth=True),

@@ -12,6 +12,7 @@ from tpuddp.nn.layers import (  # noqa: F401
     AvgPool2d,
     Conv2d,
     SpaceToDepthConv2d,
+    conv_lowering,
     Dropout,
     Embedding,
     Flatten,
@@ -33,6 +34,7 @@ __all__ = [
     "Linear",
     "Conv2d",
     "SpaceToDepthConv2d",
+    "conv_lowering",
     "MaxPool2d",
     "AvgPool2d",
     "AdaptiveAvgPool2d",

@@ -8,6 +8,8 @@ so loss curves are comparable at matched seeds-in-distribution.
 
 from __future__ import annotations
 
+import functools
+import logging
 import math
 from typing import Optional, Sequence, Tuple, Union
 
@@ -18,6 +20,8 @@ from jax import lax
 from tpuddp.nn.core import Context, Module
 
 IntOr2 = Union[int, Tuple[int, int]]
+
+logger = logging.getLogger(__name__)
 
 
 def _pair(v: IntOr2) -> Tuple[int, int]:
@@ -86,9 +90,118 @@ class Embedding(Module):
         return False  # parameters only, no buffers
 
 
+# A strided convolution is a "thin-channel stem" up to this many input
+# channels (image-like inputs: grey, RGB, RGBA): the direct lowering contracts
+# only C_in values a tap there, over windows strided along H and W.
+_THIN_CHANNELS = 4
+# The least stride at which the blocked form is taken. Measured on the v5e
+# (PERF.md, PR 25): at s = 4 (AlexNet 11x11/s4, batch 2048) the stem falls
+# 16.8 -> 12.2 ms a step; at s = 2 (ResNet 7x7/s2, batch 256) the stem itself
+# gains 0.2 ms of 2.6 and the step loses 0.7 ms, because XLA no longer fuses
+# the flip's reverse into the stem's producer.
+_MIN_STRIDE = 4
+
+
+def _row_block(kh: int, s: int, p: int) -> int:
+    """The divisor ``b >= 2`` of the stride whose blocks of ``b`` rows leave
+    the fewest zero rows round the ``kh`` kernel rows once the kernel is
+    shifted down by the padding; the larger on a tie. 11 rows at stride 4,
+    padding 2: blocks of 2 span 12 rows, blocks of 4 span 16."""
+
+    def rows(b):
+        shift = -p % b
+        return -(-(shift + kh) // b) * b
+
+    return min((b for b in range(2, s + 1) if s % b == 0), key=lambda b: (rows(b), -b))
+
+
+def space_to_depth_block(
+    in_channels: int, kernel_size, strides, padding
+) -> Optional[int]:
+    """The block size when a convolution of these static shapes should
+    lower through space-to-depth (:func:`_space_to_depth_conv`), else None
+    for the direct lowering. Selected: a square stride ``s >= _MIN_STRIDE``,
+    integer (symmetric) padding, a kernel larger than the stride in both
+    dimensions, and at most ``_THIN_CHANNELS`` input channels: AlexNet's
+    11x11/s4 stem. Everything else (ResNet's 7x7/s2 stem, 1x1/s2
+    projections, 3x3/s1, wide strided 3x3s, string or per-side padding)
+    lowers directly."""
+    (kh, kw), (sh, sw) = kernel_size, strides
+    if sh != sw or sh < _MIN_STRIDE or not isinstance(padding, int):
+        return None
+    if min(kh, kw) <= sh or in_channels > _THIN_CHANNELS:
+        return None
+    return _row_block(kh, sh, padding)
+
+
+def _space_to_depth_conv(x, w, s: int, p: int, b: int):
+    """``conv(x, w)`` at stride ``s`` and symmetric padding ``p``, computed
+    as the same sum re-associated: the rows of the input blocked ``b`` at a
+    time into its channels, ``(H, W, C) -> (H/b, W, b*C)`` (``b`` divides
+    ``s``), the kernel shifted down by the padding inside a window of whole
+    blocks (zero taps above and below) and blocked to match, one convolution
+    at stride ``s/b`` along ``H`` that keeps stride ``s`` and padding ``p``
+    along ``W``.
+
+    Only ``H`` is blocked, and the input is never padded: XLA lays a
+    thin-channel activation out with ``H`` as the major dimension (batch in
+    the lanes, ``W`` in the sublanes), where splitting ``H`` and merging the
+    split into ``C`` moves no data, while padding or blocking ``W`` costs a
+    pass over the input each (PERF.md, PR 25). The convolution pads whole
+    blocks itself. ``w`` keeps the ``(kh, kw, C, F)`` layout; its blocked
+    view and the un-blocking of its gradient are small reshapes."""
+    n, h, wd, c = x.shape
+    kh, kw, _, f = w.shape
+    oh = (h + 2 * p - kh) // s + 1
+    top = -(-p // b)  # blocks of padding above
+    shift = top * b - p  # zero taps above the kernel
+    kb = -(-(shift + kh) // b)  # blocks the shifted kernel spans
+    wb = (
+        jnp.pad(w, ((shift, kb * b - kh - shift), (0, 0), (0, 0), (0, 0)))
+        .reshape(kb, b, kw, c, f)
+        .transpose(0, 2, 1, 3, 4)
+        .reshape(kb, kw, b * c, f)
+    )
+    bh = -(-h // b)
+    if h % b:  # the stems' own sizes (224) are whole blocks
+        x = jnp.pad(x, ((0, 0), (0, bh * b - h), (0, 0), (0, 0)))
+    xb = x.reshape(n, bh, b, wd, c).transpose(0, 1, 3, 2, 4).reshape(n, bh, wd, b * c)
+    step = s // b
+    bottom = (oh - 1) * step + kb - top - bh  # blocks below the last window
+    if bottom < 0:
+        xb, bottom = xb[:, : bh + bottom], 0
+    return lax.conv_general_dilated(
+        xb, wb, window_strides=(step, s), padding=[(top, bottom), (p, p)],
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+    )
+
+
+def _lowering_name(block: Optional[int]) -> str:
+    return "direct" if block is None else f"space-to-depth, block {block}"
+
+
+@functools.lru_cache(maxsize=None)
+def _log_lowering(kind, features, kernel_size, strides, in_channels, block) -> None:
+    """One line per distinct layer and choice, the first time it is traced:
+    ``Conv2d(64, 11x11/s4, C_in=3): space-to-depth, block 4``."""
+    (kh, kw), (sh, sw) = kernel_size, strides
+    stride = f"s{sh}" if sh == sw else f"s{sh}x{sw}"
+    logger.log(
+        logging.DEBUG if block is None else logging.INFO,
+        "%s(%d, %dx%d/%s, C_in=%d): %s",
+        kind, features, kh, kw, stride, in_channels, _lowering_name(block),
+    )
+
+
 class Conv2d(Module):
     """2-D convolution, NHWC / HWIO. ``padding`` is 'SAME', 'VALID', or an int
-    (symmetric, torch-style)."""
+    (symmetric, torch-style).
+
+    A widely strided convolution over very few input channels (the AlexNet
+    stem) lowers through space-to-depth; the choice is made where the
+    layer is traced, from its own static shapes (:func:`space_to_depth_block`),
+    and :func:`conv_lowering` states it. Parameters, init and results are
+    those of the direct form."""
 
     def __init__(
         self,
@@ -131,14 +244,29 @@ class Conv2d(Module):
             )
         return params, ()
 
-    def apply(self, params, state, x, ctx: Context):
-        y = lax.conv_general_dilated(
-            x,
-            params["weight"].astype(x.dtype),
-            window_strides=self.strides,
-            padding=self._pad_arg(),
-            dimension_numbers=("NHWC", "HWIO", "NHWC"),
+    def _block(self, in_channels: int) -> Optional[int]:
+        """Space-to-depth block size for this input, None for direct."""
+        return space_to_depth_block(
+            in_channels, self.kernel_size, self.strides, self.padding
         )
+
+    def apply(self, params, state, x, ctx: Context):
+        block = self._block(x.shape[-1])
+        _log_lowering(
+            type(self).__name__, self.features, self.kernel_size, self.strides,
+            x.shape[-1], block,
+        )
+        w = params["weight"].astype(x.dtype)
+        if block is None:
+            y = lax.conv_general_dilated(
+                x,
+                w,
+                window_strides=self.strides,
+                padding=self._pad_arg(),
+                dimension_numbers=("NHWC", "HWIO", "NHWC"),
+            )
+        else:
+            y = _space_to_depth_conv(x, w, self.strides[0], self.padding, block)
         if self.use_bias:
             y = y + params["bias"].astype(y.dtype)
         return y, state
@@ -148,72 +276,32 @@ class Conv2d(Module):
 
 
 class SpaceToDepthConv2d(Conv2d):
-    """Exact reparameterization of a strided conv as space-to-depth + a
-    unit-stride conv — the classic TPU recipe for thin-channel strided stems
-    (MLPerf ResNet's conv1 trick, here for AlexNet's 11x11/s4 3-channel
-    stem): the original form contracts only ``C*kw`` values per MXU pass and
-    its backward needs strided grad-convolutions; the blocked form contracts
-    ``s*s*C`` channels per tap at stride 1.
-
-    Mathematically identical to :class:`Conv2d` (same sum, re-associated):
-    the input is blocked ``(H, W, C) -> (H/s, W/s, s*s*C)`` and the kernel is
-    zero-padded to an ``s`` multiple and reshaped to match. Parameters keep
-    the ORIGINAL ``(kh, kw, C, F)`` layout — torch imports, checkpoints, and
-    init are interchangeable with ``Conv2d``; the blocked weight view is a
-    tiny reshape XLA fuses into the conv. Requires square integer stride
-    (= the block size) and integer symmetric padding."""
+    """:class:`Conv2d` forced through the space-to-depth lowering at any
+    channel count and kernel size: what the exactness and fuzz tests hold
+    against the direct form. ``Conv2d`` takes this lowering by itself where
+    it was measured to pay, so models build ``Conv2d``. Requires a
+    square integer stride and integer symmetric padding."""
 
     def __init__(self, features, kernel_size, strides, padding=0, use_bias=True, dtype=jnp.float32):
         super().__init__(features, kernel_size, strides, padding, use_bias, dtype)
         if self.strides[0] != self.strides[1] or self.strides[0] < 2:
             raise ValueError(
-                f"SpaceToDepthConv2d needs a square stride >= 2 (the block "
-                f"size); got {self.strides}"
+                f"SpaceToDepthConv2d needs a square stride >= 2 (its rows "
+                f"are blocked by a divisor of it); got {self.strides}"
             )
         if not isinstance(padding, int):
             raise ValueError(
                 "SpaceToDepthConv2d supports integer (symmetric) padding only"
             )
 
-    def apply(self, params, state, x, ctx: Context):
-        s = self.strides[0]
-        kh, kw = self.kernel_size
-        p = self.padding
-        n, h, w, c = x.shape
-        oh = (h + 2 * p - kh) // s + 1
-        ow = (w + 2 * p - kw) // s + 1
-        kbh, kbw = -(-kh // s), -(-kw // s)  # ceil
-        # pre-pad so every window start (s*i - p) + p is block-aligned, with
-        # enough right/bottom slack for the last window and an s multiple
-        def pads(dim, o, k):
-            right = max(p, s * (o - 1) + k - dim - p)
-            total = dim + p + right
-            right += (-total) % s
-            return (p, right)
+    def _block(self, in_channels: int) -> int:
+        return _row_block(self.kernel_size[0], self.strides[0], self.padding)
 
-        ph, pw = pads(h, oh, kbh * s), pads(w, ow, kbw * s)
-        xp = jnp.pad(x, ((0, 0), ph, pw, (0, 0)))
-        bh, bw = xp.shape[1] // s, xp.shape[2] // s
-        xb = (
-            xp.reshape(n, bh, s, bw, s, c)
-            .transpose(0, 1, 3, 2, 4, 5)
-            .reshape(n, bh, bw, s * s * c)
-        )
-        wk = params["weight"].astype(x.dtype)
-        wk = jnp.pad(wk, ((0, kbh * s - kh), (0, kbw * s - kw), (0, 0), (0, 0)))
-        wb = (
-            wk.reshape(kbh, s, kbw, s, c, self.features)
-            .transpose(0, 2, 1, 3, 4, 5)
-            .reshape(kbh, kbw, s * s * c, self.features)
-        )
-        y = lax.conv_general_dilated(
-            xb, wb, window_strides=(1, 1), padding="VALID",
-            dimension_numbers=("NHWC", "HWIO", "NHWC"),
-        )
-        y = y[:, :oh, :ow, :]
-        if self.use_bias:
-            y = y + params["bias"].astype(y.dtype)
-        return y, state
+
+def conv_lowering(layer: Conv2d, in_channels: int) -> str:
+    """How ``layer`` lowers over ``in_channels`` input channels: ``"direct"``
+    or ``"space-to-depth, block <s>"``."""
+    return _lowering_name(layer._block(in_channels))
 
 
 class _Pool2d(Module):
