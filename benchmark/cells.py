@@ -1,11 +1,14 @@
 """Find a cell and everything that belongs to it by name.
 
-``BENCHMARK.json`` at the root of the checkout is the one list of cells,
-configurations and metrics. A cell names a configuration (a JSON file of
-sizes), a traffic mix (``benchmark/traffic/<traffic>.json``) and its chips;
-the traffic file names its feed (``benchmark/feeds/<feed>.py``). Per-layer
-metrics are ``benchmark/layer_metrics/<metric>.py``; a configuration's plain
-reference and analytic FLOPs are ``benchmark/reference/<config>.py`` and
+``BENCHMARK.json`` at the root of the checkout is the one list of cells
+(four of them), configurations and metrics. A cell names a configuration (a
+JSON file of sizes), a traffic mix (``benchmark/traffic/<traffic>.json``) and
+its chips; the traffic file names its feed (``benchmark/feeds/<feed>.py``)
+and the configuration's file its system under test
+(``benchmark/systems/<system>.py``: how the model, its state and its seeded
+batches are built, and what unit the step counts). Per-layer metrics are
+``benchmark/layer_metrics/<metric>.py``; a configuration's plain reference
+and analytic FLOPs are ``benchmark/reference/<config>.py`` and
 ``benchmark/flops/<config>.py``. A later PR adds files and entries; nothing
 here branches on a name.
 """
@@ -100,6 +103,15 @@ def load_module(kind: str, name: str, root: str = ROOT):
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module
+
+
+def load_system(cell: Cell):
+    """The system under test that the cell's configuration names:
+    ``benchmark/systems/<system>.py``."""
+    name = cell.config.get("system")
+    if not isinstance(name, str):
+        raise BenchmarkError(f"configuration {cell.config_name!r} names no \"system\"")
+    return load_module("systems", name, cell.root)
 
 
 def load_peaks(device_kind: str, root: str = ROOT) -> dict:

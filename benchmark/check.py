@@ -13,7 +13,7 @@ import math
 import jax
 import numpy as np
 
-from benchmark import cells, system
+from benchmark import cells
 
 
 def _update_norm(new, old) -> float:
@@ -32,15 +32,16 @@ def against_reference(cell, mesh, seed: int, feed) -> dict:
     cfg, chk = cell.config, cell.config["check"]
     if chk["batch"] % cell.chips:
         raise ValueError(f"check batch {chk['batch']} does not divide over {cell.chips} chips")
+    system = cells.load_system(cell)
     batches = feed.sample_batches(chk["steps"], chk["batch"])
     model, ddp = system.build_ddp(cell, mesh, check=True)
     variables = system.init_variables(model, cfg, seed)
     init_params, init_mstate = jax.device_get(variables)
     state = system.init_state(model, ddp, cfg, seed, variables)
     losses, norms, prev = [], [], init_params
-    ones = np.ones(chk["batch"], np.float32)
-    for x, y in batches:
-        state, m = ddp.train_step(state, ddp.shard((x, y, ones)))
+    ones = system.unit_weights(cfg, chk["batch"])
+    for batch in batches:
+        state, m = ddp.train_step(state, ddp.shard((*batch, ones)))
         m, new = jax.device_get((m, state.params))
         losses.append(float(np.sum(m["loss_sum"]) / np.sum(m["n"])))
         norms.append(_update_norm(new, prev))

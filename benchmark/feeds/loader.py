@@ -19,7 +19,7 @@ import time
 import jax
 import numpy as np
 
-from benchmark import data
+from benchmark import cells
 from benchmark.spans import RunnerTracer
 from tpuddp.data import PrefetchLoader, ShardedDataLoader
 from tpuddp.data.synthetic import SyntheticClassification
@@ -89,13 +89,10 @@ class Feed:
         self.k = None
 
     def setup(self) -> None:
-        cfg = self.cell.config
         n_gen = -(-self.n_samples // _GENERATE_BY)
-        images, labels = data.make_batches(
-            self.seed, n_gen, _GENERATE_BY, cfg["input"]["shape"],
-            cfg["model"]["num_classes"],
-        )
-        images, labels = jax.device_get((images, labels))
+        images, labels = jax.device_get(cells.load_system(self.cell).make_batches(
+            self.cell.config, self.seed, n_gen, _GENERATE_BY
+        ))
         images = images.reshape(-1, *images.shape[2:])[: self.n_samples]
         labels = labels.reshape(-1)[: self.n_samples]
         self.dataset = SyntheticClassification.from_arrays(

@@ -17,7 +17,7 @@ import jax
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from benchmark import data
+from benchmark import cells
 from tpuddp.parallel.mesh import data_axes
 
 
@@ -36,22 +36,19 @@ class Feed:
 
     def setup(self) -> None:
         mesh, axis = self.ddp.mesh, data_axes(self.ddp.mesh)
-        inp = self.cell.config["input"]
+        system, config = cells.load_system(self.cell), self.cell.config
         layout = lambda ndim: NamedSharding(mesh, P(None, axis, *([None] * (ndim - 2))))
-        images, labels = data.make_batches(
-            self.seed, self.n_batches, self.global_batch, inp["shape"],
-            self.cell.config["model"]["num_classes"],
-            shardings=(layout(2 + len(inp["shape"])), layout(2)),
+        self.arrays = system.make_batches(
+            config, self.seed, self.n_batches, self.global_batch, layout
         )
         weights = self.ddp.shard_stacked(
-            np.ones((self.k, self.global_batch), np.float32)
+            system.unit_weights(config, self.k, self.global_batch)
         )
-        self.images, self.labels = images, labels
         if self.n_batches == self.k:
-            self.chunks = [(images, labels, weights)]
+            self.chunks = [(*self.arrays, weights)]
         else:
             self.chunks = [
-                self.ddp.shard_stacked((images[i:i + self.k], labels[i:i + self.k])) + (weights,)
+                self.ddp.shard_stacked(tuple(a[i:i + self.k] for a in self.arrays)) + (weights,)
                 for i in range(0, self.n_batches, self.k)
             ]
 
@@ -60,10 +57,7 @@ class Feed:
         the correctness check."""
         if n > self.n_batches or batch > self.global_batch:
             raise ValueError(f"the feed holds {self.n_batches} batches of {self.global_batch}")
-        return [
-            (np.asarray(self.images[i, :batch]), np.asarray(self.labels[i, :batch]))
-            for i in range(n)
-        ]
+        return [tuple(np.asarray(a[i, :batch]) for a in self.arrays) for i in range(n)]
 
     def warm(self, state):
         """One dispatch of each program the window uses, fenced."""

@@ -21,6 +21,7 @@ _T_START = time.perf_counter()  # before the imports: they are set-up too
 
 import argparse
 import json
+import math
 import os
 import shutil
 import sys
@@ -94,6 +95,19 @@ def require_devices(chips: int, root: str):
     return devices[:chips], cells.load_peaks(devices[0].device_kind, root)
 
 
+def make_mesh(cell, devices):
+    """The mesh the cell's traffic file lays out, over the cell's chips."""
+    from tpuddp.parallel import make_mesh as program_mesh
+
+    axes = {k: int(v) for k, v in cell.traffic["mesh"].items()}
+    if math.prod(axes.values()) != cell.chips:
+        raise BenchmarkError(
+            f"traffic {cell.traffic_name!r} lays out a mesh {axes} but the "
+            f"cell has {cell.chips} chip(s)"
+        )
+    return program_mesh(list(devices)[: cell.chips], axes)
+
+
 def peak_memory_bytes(devices) -> int:
     """Peak bytes on the fullest of the cell's chips, as the runtime counts
     them: ``peak_bytes_in_use`` is the arrays (state, data, results) and
@@ -121,7 +135,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, root: str = 
 
     import jax
 
-    from benchmark import check, spans as spans_lib, system, trace_reduce
+    from benchmark import check, spans as spans_lib, trace_reduce
     from tpuddp.utils import compile_cache
 
     # the program's own cache directory (inside the checkout, or where
@@ -139,8 +153,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, root: str = 
         phases[name], mark[0] = now - mark[0], now
 
     phase_done("imports_and_devices")
-    spans = spans_lib.Spans(annotate=trace)
-    mesh = system.make_mesh_for(cell, devices)
+    spans = spans_lib.Spans()
+    system = cells.load_system(cell)
+    mesh = make_mesh(cell, devices)
     model, ddp = system.build_ddp(cell, mesh)
     state = system.init_state(model, ddp, cell.config, seed)
     jax.block_until_ready(state)
@@ -169,13 +184,10 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, root: str = 
         os.makedirs(trace_dir)
         seconds = min(seconds, float(cell.traffic.get("trace_seconds", seconds)))
         options = jax.profiler.ProfileOptions()
-        options.python_tracer_level = 0  # the annotations say what the host did
-        # level 1 keeps the annotations and the runtime's critical events
-        # only. It does not keep out the ~1,000,000 "Transpose" slices that
-        # staging one 157 MB chunk logs, which fill the trace viewer's
-        # million-event file before any device row: why the loader-fed cell
-        # is not listed yet (chip runs, PR 22; PERF.md section 5)
-        options.host_tracer_level = 1
+        # the device's timeline only: what the host did is in the harness's
+        # own spans, and the host tracer distorts what it watches (spans.py)
+        options.python_tracer_level = 0
+        options.host_tracer_level = 0
         jax.profiler.start_trace(trace_dir, profiler_options=options)
     lowered_before = monitor.lowered
     setup_s = time.perf_counter() - _T_START
@@ -207,9 +219,16 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, root: str = 
     failed = non_finite_steps + (int(skipped["total"]) if skipped is not None else 0)
     correct = bool(reference["ok"] and learned["ok"] and compiles_in_window == 0)
 
-    reduced = trace_reduce.reduce_capture(trace_dir, param_shapes) if trace else None
+    events = reduced = None
+    if trace:
+        # one reading of the capture, the host spans beside it, serves both reductions
+        spans.save(os.path.join(
+            os.path.dirname(trace_reduce.find_capture(trace_dir)), trace_reduce.HOST_SPANS_FILE
+        ))
+        events = trace_reduce.capture_events(trace_dir)
+        reduced = trace_reduce.reduce_events(events, param_shapes)
     run = {
-        "cell": cell, "window": window, "setup": setup, "trace": reduced,
+        "cell": cell, "window": window, "setup": setup, "trace": reduced, "events": events,
         "spans": {"seconds": dict(spans.seconds), "counts": dict(spans.counts)},
         "counters": {"grad_comm_bytes_per_step": ddp.grad_comm_bytes_per_step},
         "flops_per_sample": flops_per_sample, "peaks": peaks,
@@ -244,9 +263,10 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, root: str = 
         overlap=ddp.comm_overlap_meta, counters=window["counters"],
         spans=run["spans"], memory_peak_bytes=memory_peak, memory_stats=memory_stats,
     )
+    unit = cell.config["sample_unit"]  # what the step counts with weight 1
     print(
-        f"{workload} seed {seed}: {per_chip:.1f} samples/s/chip, MFU {100 * mfu:.2f}% "
-        f"of bf16 peak ({flops_per_sample:.4g} analytic FLOPs a sample), "
+        f"{workload} seed {seed}: {per_chip:.1f} {unit}s/s/chip, MFU {100 * mfu:.2f}% "
+        f"of bf16 peak ({flops_per_sample:.4g} analytic FLOPs a {unit}), "
         f"{window['steps']} steps in {window['window_s']:.2f} s",
         flush=True,
     )

@@ -99,9 +99,7 @@ def first_plane_leaves(events) -> list:
     ``bench:window`` annotation: the operations ``trace_reduce`` sums into
     that plane's ``op_s``."""
     process, thread = tr._index(events)
-    device_pids = {
-        pid: name for pid, name in process.items() if "TPU" in name or "GPU" in name
-    }
+    device_pids = {pid: name for pid, name in process.items() if tr._is_device(name)}
     if not device_pids:
         raise tr.TraceError("the capture has no device plane (no process named TPU or GPU)")
     windows = sorted(
@@ -154,8 +152,7 @@ def reduce_events(events, top: int = 10) -> dict:
 
 
 def reduce_capture(capture: str) -> dict:
-    path = capture if os.path.isfile(capture) else tr.find_capture(capture)
-    return reduce_events(tr.load_events(path))
+    return reduce_events(tr.capture_events(capture))
 
 
 def phase_seconds(reduced: dict, *phases: str) -> float:
@@ -205,9 +202,8 @@ def for_run(run: dict):
     run[_KEY] = None
     if run.get("trace") is None:
         return None
-    cell = run["cell"]
     try:
-        reduced = reduce_capture(os.path.join(cell.root, ".bench_out", cell.name, "trace"))
+        reduced = reduce_events(run["events"])
     except tr.TraceError as e:
         print(f"benchmark/scope_reduce.py: {e}", file=sys.stderr, flush=True)
         return None

@@ -59,6 +59,11 @@ def test_configuration_files(config):
         cfg = json.load(f)
     assert cfg["name"] == config and cfg["source"] == entry["source"]
     assert cfg["reduced"] == entry["reduced"]
+    system = cells.load_module("systems", cfg["system"])
+    for asked in ("build_ddp", "init_variables", "init_state", "make_batches",
+                  "unit_weights", "shrunk"):
+        assert callable(getattr(system, asked)), asked
+    assert cfg["sample_unit"] and system.unit_weights(cfg, 3, 2).sum() >= 6
     assert {"steps", "batch", "loss_rtol", "update_norm_rtol", "reason"} <= set(cfg["check"])
     assert callable(cells.load_module("reference", config).train_steps)
     assert cells.load_module("flops", config).train_flops_per_sample(cfg) > 0

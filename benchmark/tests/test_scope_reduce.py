@@ -3,7 +3,6 @@ on the older capture that has no scope (absent metrics and a reason, never a
 zero), and on hand-made events (the phase rule, the layer path, the readers).
 """
 
-import gzip
 import json
 import os
 import types
@@ -11,6 +10,7 @@ import types
 import pytest
 
 from benchmark import cells, scope_reduce as sr, trace_reduce as tr
+from benchmark.tests import xspace
 from benchmark.tests.test_trace_reduce import _close, _host, _meta, _op
 
 DATA = os.path.join(os.path.dirname(__file__), "data")
@@ -136,15 +136,15 @@ def test_achieved_tflops_per_layer():
 
 
 def _run_with_capture(tmp_path, events, steps=2):
-    """What ``run.py`` hands a reader, with ``events`` as the capture a
-    traced window left under ``<root>/.bench_out/<cell>/trace``."""
-    profile = tmp_path / ".bench_out" / "a_cell" / "trace" / "plugins" / "profile" / "t"
-    profile.mkdir(parents=True)
-    with gzip.open(profile / "host.trace.json.gz", "wt") as f:
-        json.dump({"traceEvents": events}, f)
+    """What ``run.py`` hands a reader, with ``events`` written as the
+    profiler writes a capture under ``<root>/.bench_out/<cell>/trace`` and
+    read back from there."""
+    trace_dir = tmp_path / ".bench_out" / "a_cell" / "trace"
+    xspace.write_capture(events, str(trace_dir / "plugins" / "profile" / "t"))
+    events = tr.capture_events(str(trace_dir))
     return {
         "cell": types.SimpleNamespace(root=str(tmp_path), name="a_cell"),
-        "trace": tr.reduce_events(events), "window": {"steps": steps},
+        "trace": tr.reduce_events(events), "events": events, "window": {"steps": steps},
     }
 
 
@@ -194,12 +194,12 @@ def test_a_capture_with_no_scope_yields_no_value_and_says_why(tmp_path, capsys, 
     assert "absent, not zero" in lines[0]
 
 
-def test_no_capture_and_no_traced_window_yield_nothing(tmp_path, capsys):
+def test_no_device_plane_and_no_traced_window_yield_nothing(tmp_path, capsys):
     run = _run_with_capture(tmp_path, _scoped_events())
-    os.remove(tr.find_capture(os.path.join(str(tmp_path), ".bench_out", "a_cell", "trace")))
+    run["events"] = [e for e in run["events"] if e.get("pid") == -1]  # the host's spans alone
     assert _read_all(run) == dict.fromkeys(READERS)
-    assert "no *.trace.json.gz" in capsys.readouterr().err
-    untraced = dict(run, trace=None)
+    assert "no device plane" in capsys.readouterr().err
+    untraced = dict(run, trace=None, events=None)
     untraced.pop(sr._KEY)
     assert _read_all(untraced) == dict.fromkeys(READERS)
     assert capsys.readouterr().err == ""

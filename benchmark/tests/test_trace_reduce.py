@@ -6,7 +6,8 @@ import os
 
 import pytest
 
-from benchmark import trace_reduce as tr
+from benchmark import scope_reduce as sr, trace_reduce as tr
+from benchmark.tests import xspace
 
 DATA = os.path.join(os.path.dirname(__file__), "data")
 ALEXNET_SHAPES = {
@@ -45,6 +46,58 @@ def test_recorded_capture_reduces_to_fixed_numbers():
         assert sum(plane["buckets_s"].values()) == pytest.approx(plane["op_s"])
     shown = tr.breakdown(got)
     assert len(shown["device_ops"]) <= 10 and len(shown["idle_gaps"]) <= 10
+
+
+@pytest.mark.parametrize("fixture,reduce,shapes", [
+    ("recorded", tr.reduce_capture, (ALEXNET_SHAPES,)),
+    ("scoped", sr.reduce_capture, ()),
+])
+def test_the_profilers_own_file_reduces_to_the_same_numbers(tmp_path, fixture, reduce, shapes):
+    """The fixtures are the trace viewer's JSON; the chip's captures are read
+    from the ``.xplane.pb``. The same device events in that file (names and
+    stats on the event metadata, times in picoseconds from the line's start)
+    with the annotations as host spans beside it give the fixture's numbers,
+    through ``find_capture``'s choice of file."""
+    events = tr.load_events(os.path.join(DATA, fixture + ".trace.json.gz"))
+    profile = tmp_path / "plugins" / "profile" / "t"
+    xspace.write_capture(events, str(profile))
+    (profile / "host.trace.json.gz").write_bytes(b"not read where there is an .xplane.pb")
+    assert tr.find_capture(str(tmp_path)).endswith("host.xplane.pb")
+    with open(os.path.join(DATA, fixture + ".expected.json")) as f:
+        want = json.load(f)
+    _close(json.loads(json.dumps(reduce(str(tmp_path), *shapes))), want)
+
+
+def test_a_capture_above_the_viewers_cap_still_reduces(tmp_path):
+    """Staging one 157 MB chunk logs about a million host "Transpose"
+    slices, and the trace viewer's file stops at a million events, before
+    the first device row: the loader-fed cell's traced run died there (PR
+    22). The ``.xplane.pb`` has no cap, a host plane in it is passed over
+    whole, and what the host did is in the harness's own spans."""
+    cap = 1_000_000
+    flood = [
+        {"ph": "X", "pid": 9, "tid": 1, "ts": 10 + i * 1e-3, "dur": 5e-4, "name": "Transpose", "args": {}}
+        for i in range(cap + 1)
+    ]
+    events = _meta() + [_host("bench:window", 0, 2000), _host("bench:stage", 0, 1500)] + flood + [
+        _op("fusion.1", 1500, 400, tf_op="jit(f)/tpuddp.forward/0_Conv2d/conv_general_dilated:"),
+    ]
+    kept = tr.capture_events(xspace.write_capture(events, str(tmp_path)))
+    assert len(kept) < 20  # the flood is passed over, not copied
+    r = tr.reduce_events(kept)
+    plane = tr.first_plane(r)
+    assert plane["busy_s"] == pytest.approx(400e-6) and r["window_s"] == pytest.approx(2000e-6)
+    assert plane["idle_by_host_activity_s"] == {"stage": pytest.approx(1500e-6), "none": pytest.approx(100e-6)}
+    assert sr.reduce_events(kept)["layers_s"]["0_Conv2d"]["forward"] == pytest.approx(400e-6)
+
+
+def test_a_file_that_is_no_capture_is_an_error(tmp_path):
+    path = tmp_path / "host.xplane.pb"
+    path.write_bytes(b"\x0a\xff\xff\xff\x7f truncated")
+    with pytest.raises(tr.TraceError, match="not an XSpace"):
+        tr.load_events(str(path))
+    with pytest.raises(tr.TraceError, match="no \\*.xplane.pb"):
+        tr.find_capture(str(tmp_path / "nowhere"))
 
 
 def test_interval_arithmetic():
@@ -96,10 +149,13 @@ def test_busy_idle_containers_and_gap_attribution():
     assert plane["buckets_s"]["fwd/input-grad conv+matmul"] == pytest.approx(150e-6)
     assert plane["buckets_s"]["weight-grad + optimizer (fused)"] == pytest.approx(200e-6)
     assert plane["buckets_s"]["copies/slices"] == pytest.approx(100e-6)
-    # gaps: 0-100 under dispatch, 250-300 and 500-700 and 800-1000 under readback
-    assert plane["longest_gaps"][0] == ["loader_next", pytest.approx(200e-6)]
-    assert plane["idle_by_host_activity_s"]["dispatch"] == pytest.approx(100e-6)
-    assert sum(plane["idle_by_host_activity_s"].values()) == pytest.approx(550e-6)
+    # gaps: 0-100 under dispatch; 250-300, 500-700 and 800-1000 under
+    # readback, but for 600-650 of the longest, which the loader's span takes
+    assert plane["longest_gaps"][0] == ["readback", pytest.approx(200e-6)]
+    assert plane["idle_by_host_activity_s"] == {
+        "readback": pytest.approx(400e-6), "dispatch": pytest.approx(100e-6),
+        "loader_next": pytest.approx(50e-6),
+    }
 
 
 def test_collectives_synchronous_and_asynchronous():

@@ -7,6 +7,8 @@ import json
 import os
 import shutil
 
+from benchmark import cells
+
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 FAKE_PEAKS = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11, "hbm_bytes": 1e9,
@@ -27,10 +29,7 @@ def make_root(tmp_path) -> str:
     for name in os.listdir(os.path.join(root, "benchmark", "configs")):
         path = os.path.join(root, "benchmark", "configs", name)
         cfg = json.load(open(path))
-        if cfg["input"]["resize_to"]:
-            cfg["input"]["resize_to"] = 64  # AlexNet's stem needs 63 at least
-        else:
-            cfg["input"]["shape"] = [32, 32, 3]
+        cfg = cells.load_module("systems", cfg["system"], root).shrunk(cfg)
         cfg["check"]["batch"] = 4
         # the CPU computes the "bfloat16" system in float32-accumulated
         # bf16 too, but tiny batches make BatchNorm statistics noisy
