@@ -1,0 +1,310 @@
+"""The hybrid linear-attention mixture-of-experts family (models/hybrid_moe.py,
+nn/deltanet.py, nn/moe.py, nn/sequence.py) against its plain reference
+(benchmark/reference/qwen3_next_80b_a3b_ep16.py) at the tiny preset on the
+CPU: seeded random weights, float32 unless a test says otherwise."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import cells
+from tpuddp import nn
+from tpuddp.models import QWEN3_NEXT_EP16, load_model
+from tpuddp.nn import moe as moe_lib
+from tpuddp.nn.core import Context
+from tpuddp.nn.deltanet import _invert_unit_lower, chunk_gated_delta_rule
+
+CONFIG_NAME = "qwen3_next_80b_a3b_ep16"
+WORKLOAD = "qwen3next_ep16_t8k_fused"
+VOCAB = 96
+
+
+@pytest.fixture(scope="module")
+def reference():
+    return cells.load_module("reference", CONFIG_NAME)
+
+
+@pytest.fixture(scope="module")
+def system():
+    return cells.load_module("systems", "token_moe_lm")
+
+
+@pytest.fixture(scope="module")
+def published():
+    return cells.load_cell(WORKLOAD).config
+
+
+@pytest.fixture(scope="module")
+def tiny(system, published):
+    """The configuration at the tiny preset's sizes, as the reference reads it."""
+    return system.shrunk(published)
+
+
+def _model(system, config, **over):
+    return load_model(
+        config["model"]["registry_name"], config["vocab_size"],
+        **{**system.model_kwargs(config), "compute_dtype": "float32", **over},
+    )
+
+
+def _perturbed(params, scale=0.3):
+    """Norm weights and decay parameters off their initial 0/1, projections
+    large enough that every gate and the router are away from their flat
+    middle: a mistake in any of them then shows."""
+    leaves, tree = jax.tree_util.tree_flatten(params)
+    keys = jax.random.split(jax.random.key(7), len(leaves))
+    return jax.tree_util.tree_unflatten(tree, [
+        l + scale * jax.random.normal(k, l.shape) if l.ndim == 1 else l * 8.0
+        for l, k in zip(leaves, keys)
+    ])
+
+
+def _close(ours, theirs, rtol):
+    for (path, a), b in zip(
+        jax.tree_util.tree_leaves_with_path(ours), jax.tree_util.tree_leaves(theirs)
+    ):
+        err = float(jnp.linalg.norm(a - b)) / (float(jnp.linalg.norm(b)) + 1e-12)
+        assert err <= rtol, (jax.tree_util.keystr(path), err)
+
+
+def _hidden(rng, tiny, batch, t, scale=1.0):
+    return jnp.asarray(scale * rng.randn(batch, t, tiny["hidden_size"]), jnp.float32)
+
+
+# -- the scan --------------------------------------------------------------------
+
+@pytest.mark.parametrize("t,chunk", [(37, 16), (64, 16), (100, 64), (5, 16)])
+def test_chunked_delta_rule_matches_token_by_token(reference, t, chunk):
+    """Forward and every input's gradient, at lengths that are not multiples
+    of the chunk, with decays from nearly none to nearly all."""
+    rng = np.random.RandomState(t)
+    b, h, dk, dv = 2, 3, 16, 8
+    unit = lambda a: a / np.linalg.norm(a, axis=-1, keepdims=True)
+    q = jnp.asarray(unit(rng.randn(b, t, h, dk)) * dk ** -0.5, jnp.float32)
+    k = jnp.asarray(unit(rng.randn(b, t, h, dk)), jnp.float32)
+    v = jnp.asarray(rng.randn(b, t, h, dv), jnp.float32)
+    g = jnp.asarray(-np.exp(rng.uniform(-7, 1.5, (b, t, h))), jnp.float32)
+    beta = jnp.asarray(rng.uniform(0.02, 0.98, (b, t, h)), jnp.float32)
+    probe = jnp.asarray(rng.randn(b, t, h, dv), jnp.float32)
+    ours = lambda *a: jnp.sum(chunk_gated_delta_rule(*a, chunk=chunk) * probe)
+    theirs = lambda *a: jnp.sum(reference.delta_rule(*a) * probe)
+    np.testing.assert_allclose(
+        chunk_gated_delta_rule(q, k, v, g, beta, chunk=chunk), reference.delta_rule(q, k, v, g, beta),
+        rtol=2e-4, atol=2e-5,
+    )
+    args = (q, k, v, g, beta)
+    _close(jax.grad(ours, argnums=range(5))(*args), jax.grad(theirs, argnums=range(5))(*args), 2e-4)
+
+
+def test_unit_lower_inverse_with_identical_keys():
+    """The worst case for a series in powers of ``a``: every entry below the
+    diagonal is 1 (identical keys, beta 1, no decay). Forward substitution
+    and the joins by halves stay exact."""
+    a = jnp.tril(jnp.ones((64, 64), jnp.float32), -1)
+    inverse = _invert_unit_lower(a[None])[0]
+    np.testing.assert_allclose(inverse @ (jnp.eye(64) + a), np.eye(64), atol=1e-5)
+
+
+def test_chunked_delta_rule_in_bfloat16_keeps_its_sums_in_float32():
+    """bfloat16 product inputs over 512 tokens of slow decay: the state and
+    the decay sums stay float32, so the result stays within bfloat16's own
+    rounding of the float32 one and does not drift with length."""
+    rng = np.random.RandomState(0)
+    b, t, h, d = 1, 512, 2, 16
+    unit = lambda a: a / np.linalg.norm(a, axis=-1, keepdims=True)
+    q, k = (jnp.asarray(unit(rng.randn(b, t, h, d)), jnp.float32) for _ in range(2))
+    v = jnp.asarray(rng.randn(b, t, h, d), jnp.float32)
+    g = jnp.full((b, t, h), -0.002, jnp.float32)
+    beta = jnp.full((b, t, h), 0.5, jnp.float32)
+    exact = chunk_gated_delta_rule(q, k, v, g, beta, chunk=64)
+    rounded = chunk_gated_delta_rule(q, k, v, g, beta, chunk=64, compute_dtype=jnp.bfloat16)
+    err = jnp.linalg.norm(rounded - exact, axis=-1) / jnp.linalg.norm(exact, axis=-1)
+    assert float(jnp.max(err)) < 0.03 and float(jnp.mean(err[:, -64:])) < 2 * float(jnp.mean(err[:, :64])) + 0.01
+
+
+# -- the mixers and the expert layer ----------------------------------------------
+
+def _layer_params(model, kind_index):
+    params, _ = model.init(jax.random.key(3), jnp.zeros((1, 8), jnp.int32))
+    return _perturbed(params)["layers"][kind_index]
+
+
+def test_deltanet_mixer_matches_the_reference(reference, system, tiny):
+    model = _model(system, tiny)
+    p = _layer_params(model, 0)["mixer"]
+    x = _hidden(np.random.RandomState(1), tiny, 2, 37)
+    ours = lambda p, x: jnp.sum(jnp.sin(model._deltanet(p, x)))
+    theirs = lambda p, x: jnp.sum(jnp.sin(reference.deltanet_mixer(tiny, p, x)))
+    np.testing.assert_allclose(model._deltanet(p, x), reference.deltanet_mixer(tiny, p, x), rtol=2e-4, atol=2e-5)
+    _close(jax.grad(ours, argnums=(0, 1))(p, x), jax.grad(theirs, argnums=(0, 1))(p, x), 5e-4)
+
+
+def test_gated_attention_mixer_matches_the_reference(reference, system, tiny):
+    """Partial rotary (a quarter of the head), one key/value head serving
+    eight query heads as published, the per-head norms and the output gate;
+    queries in blocks of 16 over 50 positions."""
+    config = {**tiny, "num_attention_heads": 8, "num_key_value_heads": 1}
+    model = _model(system, config, attention_q_block=16)
+    p = _layer_params(model, 3)["mixer"]
+    x = _hidden(np.random.RandomState(2), tiny, 2, 50)
+    ours = lambda p, x: jnp.sum(jnp.sin(model._attention(p, x)))
+    theirs = lambda p, x: jnp.sum(jnp.sin(reference.attention_mixer(config, p, x)))
+    np.testing.assert_allclose(model._attention(p, x), reference.attention_mixer(config, p, x), rtol=2e-4, atol=2e-5)
+    _close(jax.grad(ours, argnums=(0, 1))(p, x), jax.grad(theirs, argnums=(0, 1))(p, x), 5e-4)
+
+
+def _moe_ours(model, p, x, **kw):
+    y, aux, counters = moe_lib.expert_share_moe(
+        p, x.reshape(-1, x.shape[-1]), top_k=model.top_k, first_expert=model.first_expert,
+        compute_dtype=jnp.float32, **kw,
+    )
+    return y.reshape(x.shape), aux, counters
+
+
+def test_expert_layer_matches_the_reference(reference, system, tiny):
+    model = _model(system, tiny)
+    p = _layer_params(model, 1)["moe"]
+    x = _hidden(np.random.RandomState(3), tiny, 2, 40)
+    y, aux, counters = _moe_ours(model, p, x)
+    ref_y, ref_aux = reference.moe(tiny, p, x)
+    np.testing.assert_allclose(y, ref_y, rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(aux, ref_aux, rtol=1e-5)
+    assigned = x.shape[0] * x.shape[1] * model.top_k
+    assert counters["moe_expert_tokens_held"] + counters["moe_absent_assignments"] == assigned
+    assert counters["moe_dropped_assignments"] == 0
+    ours = lambda p, x: jnp.sum(jnp.sin(_moe_ours(model, p, x)[0])) + _moe_ours(model, p, x)[1]
+    theirs = lambda p, x: jnp.sum(jnp.sin(reference.moe(tiny, p, x)[0])) + reference.moe(tiny, p, x)[1]
+    _close(jax.grad(ours, argnums=(0, 1))(p, x), jax.grad(theirs, argnums=(0, 1))(p, x), 5e-4)
+
+
+def test_the_shares_add_up_to_the_uncut_layer(reference, system, tiny):
+    """Four shares of 2 of the 8 experts each: their routed parts, plus the
+    shared expert counted once, are the uncut reference's layer output."""
+    n_all, held = tiny["deployment"]["experts_published"], tiny["num_experts"]
+    uncut = {**tiny, "num_experts": n_all}
+    model = _model(system, uncut)
+    p = _layer_params(model, 0)["moe"]
+    x = _hidden(np.random.RandomState(4), tiny, 2, 33)
+    flat = x.reshape(-1, x.shape[-1])
+    whole, _ = reference.moe(uncut, p, x)
+    shared = whole.reshape(flat.shape) - reference.routed_part(uncut, p, flat, 0)[0]
+    total, seen = shared, 0.0
+    for share in range(n_all // held):
+        mine = {**p, "experts": jax.tree_util.tree_map(lambda w: w[share * held:(share + 1) * held], p["experts"])}
+        y, _, counters = moe_lib.expert_share_moe(
+            mine, flat, top_k=model.top_k, first_expert=share * held, compute_dtype=jnp.float32
+        )
+        ref_y, _ = reference.moe({**tiny, "deployment": {**tiny["deployment"], "first_expert": share * held}}, mine, x)
+        np.testing.assert_allclose(y, ref_y.reshape(flat.shape), rtol=2e-4, atol=2e-5)
+        total = total + (y - shared)
+        seen += float(counters["moe_expert_tokens_held"])
+    np.testing.assert_allclose(total, whole.reshape(flat.shape), rtol=2e-4, atol=2e-5)
+    assert seen == flat.shape[0] * model.top_k  # every assignment is some share's
+
+
+@pytest.mark.parametrize("round_rows", [None, 16])
+def test_no_token_is_dropped_when_every_token_chooses_held_experts(reference, system, tiny, round_rows):
+    """A router forced to send every token to the two held experts: N k rows,
+    many rounds of the grouped product, none dropped, result and gradients
+    the reference's."""
+    model = _model(system, tiny)
+    p = _layer_params(model, 0)["moe"]
+    router = jnp.zeros_like(p["router"]).at[:, :2].set(jnp.abs(p["router"][:, :2]) + 1.0)
+    p = {**p, "router": router}
+    x = jnp.abs(_hidden(np.random.RandomState(5), tiny, 1, 40)) + 0.1  # positive: logits 0 and 1 lead
+    y, _, counters = _moe_ours(model, p, x, round_rows=round_rows)
+    assert counters["moe_expert_tokens_held"] == 40 * model.top_k
+    assert counters["moe_absent_assignments"] == 0 and counters["moe_dropped_assignments"] == 0
+    assert counters["moe_expert_tokens_max"] == 40
+    np.testing.assert_allclose(y, reference.moe(tiny, p, x)[0], rtol=2e-4, atol=2e-5)
+    ours = lambda p, x: jnp.sum(jnp.sin(_moe_ours(model, p, x, round_rows=round_rows)[0]))
+    theirs = lambda p, x: jnp.sum(jnp.sin(reference.moe(tiny, p, x)[0]))
+    _close(jax.grad(ours, argnums=(0, 1))(p, x), jax.grad(theirs, argnums=(0, 1))(p, x), 5e-4)
+
+
+# -- the model -------------------------------------------------------------------
+
+def test_registry_builds_the_published_cut_and_the_tiny_preset(system, published):
+    """The published cut, as shapes only: its registry preset is the
+    configuration file's numbers, its parameters the file's count, and every
+    published width is unchanged in the file."""
+    model = load_model("qwen3_next_ep16", published["vocab_size"])
+    from_file = _model(system, published)
+    ours = {"compute_dtype": None, "aux_loss_weight": None}  # the file's own choices (`assumed`)
+    assert {**vars(from_file), **ours} == {**vars(model), **ours}
+    assert all(hasattr(model, name) or name == "partial_rotary_factor" for name in QWEN3_NEXT_EP16)
+    assert [model.layer_kind(i) for i in range(4)] == ["GatedDeltaNet"] * 3 + ["GatedAttention"]
+    shapes, _ = jax.eval_shape(lambda k: model.init(k, jnp.zeros((1, 8), jnp.int32)), jax.random.key(0))
+    count = sum(int(np.prod(l.shape)) for l in jax.tree_util.tree_leaves(shapes))
+    assert count == published["parameters"]
+    assert shapes["layers"][0]["moe"]["router"].shape == (2048, 512)
+    assert shapes["layers"][0]["moe"]["experts"]["gate_up"].shape == (32, 2048, 1024)
+    assert shapes["layers"][0]["mixer"]["in_proj_qkvz"].shape == (2048, 12288)
+    assert shapes["layers"][3]["mixer"]["q_proj"].shape == (2048, 8192)
+    assert shapes["head"]["weight"].shape == (2048, 18992)
+    catalog = {
+        "head_dim": 256, "hidden_size": 2048, "linear_key_head_dim": 128, "linear_value_head_dim": 128,
+        "linear_num_key_heads": 16, "linear_num_value_heads": 32, "moe_intermediate_size": 512,
+        "num_attention_heads": 16, "num_key_value_heads": 2, "num_experts_per_tok": 10,
+        "shared_expert_intermediate_size": 512, "linear_conv_kernel_dim": 4, "full_attention_interval": 4,
+    }
+    assert {k: published[k] for k in catalog} == catalog
+    assert published["reduced"] == ["num_hidden_layers", "num_experts", "vocab_size"]
+    assert published["published"] == {"num_hidden_layers": 48, "num_experts": 512, "vocab_size": 151936}
+    tiny_model = load_model("qwen3_next_tiny", VOCAB)
+    assert tiny_model.hidden_size <= 64 and (tiny_model.n_experts, tiny_model.top_k) == (8, 2)
+    assert tiny_model.n_layers == tiny_model.full_attention_interval  # one period
+
+
+def test_evaluation_returns_logits_and_training_defers_them(system, tiny):
+    model = _model(system, tiny)
+    tokens = jnp.asarray(np.random.RandomState(0).randint(0, VOCAB, (2, 20)))
+    params, state = model.init(jax.random.key(0), tokens)
+    logits, _ = model.apply(params, state, tokens, Context(train=False))
+    deferred, _ = model.apply(params, state, tokens, Context(train=True))
+    assert logits.shape == (2, 20, VOCAB) and isinstance(deferred, nn.DeferredLogits)
+    np.testing.assert_allclose(deferred.logits(), logits, rtol=1e-5, atol=1e-6)
+    labels = jnp.roll(tokens, -1, axis=1)
+    criterion = nn.CrossEntropyLoss()
+    np.testing.assert_allclose(criterion(deferred, labels), criterion(logits, labels), rtol=1e-6)
+    # per-sequence weights (the loaders' padding mask) cover their tokens
+    mask = jnp.asarray([1.0, 0.0])
+    np.testing.assert_allclose(
+        criterion(deferred, labels, mask), criterion(logits[:1], labels[:1]), rtol=1e-6
+    )
+    np.testing.assert_allclose(criterion(logits, labels, mask), criterion(logits[:1], labels[:1]), rtol=1e-6)
+
+
+def test_auxiliary_loss_is_in_the_gradient_and_not_in_the_loss(system, tiny):
+    tokens = jnp.asarray(np.random.RandomState(1).randint(0, VOCAB, (2, 24)))
+    labels = jnp.roll(tokens, -1, axis=1)
+    with_aux, without = _model(system, tiny, aux_loss_weight=0.5), _model(system, tiny, aux_loss_weight=0.0)
+    params, state = with_aux.init(jax.random.key(0), tokens)
+
+    def loss(model, p):
+        return nn.CrossEntropyLoss()(model.apply(p, state, tokens, Context(train=True))[0], labels)
+
+    a, grad_a = jax.value_and_grad(lambda p: loss(with_aux, p))(params)
+    b, grad_b = jax.value_and_grad(lambda p: loss(without, p))(params)
+    assert float(a) == float(b)
+    moved = lambda g: float(jnp.linalg.norm(g["layers"][0]["moe"]["router"]))
+    assert abs(moved(grad_a) - moved(grad_b)) > 1e-3 * moved(grad_b)
+
+
+def test_the_references_blocks_change_no_arithmetic(reference, system, tiny, monkeypatch):
+    """The reference takes attention and the loss in blocks for memory only:
+    with blocks short enough that the rolled loop over whole blocks and the
+    call for what is left both run (44 tokens: two of 16 and one of 12), loss,
+    auxiliary loss and gradients are those of one block over everything."""
+    model = _model(system, tiny)
+    params, _ = model.init(jax.random.key(3), None)
+    params = _perturbed(params)
+    tokens, targets = (jnp.asarray(a[0]) for a in system.make_batches(tiny, 5, 1, 2))
+    objective = lambda p: sum(reference.loss_and_aux(tiny, p, tokens, targets))
+    whole = jax.value_and_grad(objective)(params)
+    monkeypatch.setattr(reference, "_QUERY_BLOCK", 16)
+    monkeypatch.setattr(reference, "_LOSS_BLOCK", 16)
+    blocked = jax.value_and_grad(objective)(params)
+    _close(blocked[0], whole[0], 1e-6)
+    _close(blocked[1], whole[1], 1e-4)

@@ -54,3 +54,18 @@ def test_replicate_places_full_copy_everywhere(cpu_devices):
     assert len(placed["w"].addressable_shards) == 4
     for s in placed["w"].addressable_shards:
         np.testing.assert_array_equal(np.asarray(s.data), np.arange(6.0))
+
+
+def test_replicate_leaves_replicated_leaves_where_they_are(cpu_devices):
+    """A leaf born replicated on the mesh is not copied (a state of several GB
+    must not peak at twice its size); host arrays beside it are placed; and
+    under ``jax.eval_shape`` tracers, which have no sharding to ask, pass."""
+    import jax
+
+    mesh = make_mesh(cpu_devices[:2])
+    born = jax.device_put(jnp.arange(6.0), replicated(mesh))
+    placed = replicate(mesh, {"born": born, "host": np.ones(3, np.float32)})
+    assert placed["born"] is born
+    assert placed["host"].sharding == replicated(mesh)
+    shapes = jax.eval_shape(lambda t: replicate(mesh, t), {"w": jnp.zeros((2, 3))})
+    assert shapes["w"].shape == (2, 3)

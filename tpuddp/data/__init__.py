@@ -14,7 +14,8 @@ def load_datasets_for(training: Dict[str, Any], synthetic_fallback: bool = True)
     """(train, test) datasets for ``training.dataset`` — the dataset-dispatch
     layer both entrypoints share (the reference hardcodes CIFAR-10,
     data_and_toy_model.py:8-38; tpuddp adds ``digits`` — real offline data —
-    and ``synthetic`` for CI/benchmarks)."""
+    ``synthetic`` for CI/benchmarks, and ``markov_tokens``, a seeded token
+    stream for the language models, data/tokens.py)."""
     name = str(training.get("dataset") or "cifar10")
     if name == "cifar10":
         from tpuddp.data import cifar10
@@ -36,8 +37,16 @@ def load_datasets_for(training: Dict[str, Any], synthetic_fallback: bool = True)
 
         n = tuple(training.get("synthetic_n") or (2048, 512))
         return synthetic_uint8_datasets(n[0], n[1])
+    if name == "markov_tokens":
+        from tpuddp.data.tokens import markov_token_datasets
+
+        n = tuple(training.get("synthetic_n") or (256, 64))
+        return markov_token_datasets(
+            n[0], n[1], int(training.get("seq_len") or 64), int(training["num_classes"]),
+            seed=int(training.get("seed") or 0),
+        )
     raise ValueError(
-        f"unknown training.dataset {name!r}; one of cifar10, digits, synthetic"
+        f"unknown training.dataset {name!r}; one of cifar10, digits, synthetic, markov_tokens"
     )
 
 

@@ -72,7 +72,7 @@ def _fetch_padded(dataset, indices: np.ndarray, batch_size: int):
         if x is not None:
             w = np.ones(batch_size, np.float32)
             w[n:] = 0.0
-            y = np.zeros(batch_size, labels.dtype)
+            y = np.zeros((batch_size, *labels.shape[1:]), labels.dtype)  # a token set's labels are rows
             y[:n] = labels[np.asarray(indices)]
             return x, y, w
     x, y = _fetch(dataset, indices)

@@ -12,6 +12,9 @@ from tpuddp.models.resnet import (  # noqa: F401
     ResNet18, ResNet34, ResNet50, ResNet101, ResNet152,
 )
 from tpuddp.models.vgg import VGG11, VGG13, VGG16, VGG19  # noqa: F401
+from tpuddp.models.hybrid_moe import (  # noqa: F401
+    QWEN3_NEXT_EP16, QWEN3_NEXT_TINY, HybridMoELM,
+)
 
 from functools import partial as _partial
 
@@ -43,6 +46,11 @@ _REGISTRY = {
     "transformer_small": _partial(
         TransformerLM, d_model=128, n_heads=8, n_layers=4, max_seq_len=256,
     ),
+    # hybrid linear-attention mixture-of-experts family (models/hybrid_moe.py):
+    # num_classes is the slice of the vocabulary held. The published widths as
+    # one chip of a 16-way expert-parallel job holds them, and a CPU-test size
+    "qwen3_next_ep16": _partial(HybridMoELM, **QWEN3_NEXT_EP16),
+    "qwen3_next_tiny": _partial(HybridMoELM, **QWEN3_NEXT_TINY),
     # aliases of the plain names: nn.Conv2d picks the space-to-depth lowering
     # of a thin-channel strided stem from its own shapes, so these build the
     # same program (kept for settings files and checkpoints that name them)
@@ -68,6 +76,7 @@ __all__ = [
     "ToyMLP", "ToyCNN", "AlexNet", "ResNet18", "ResNet34", "ResNet50",
     "ResNet101", "ResNet152",
     "TransformerLM",
+    "HybridMoELM",
     "VGG11", "VGG13", "VGG16", "VGG19",
     "load_model",
 ]

@@ -1,0 +1,286 @@
+"""Hybrid linear-attention mixture-of-experts language models: the
+Qwen3-Next family's layer pattern, as one chip of an expert-parallel job
+holds it.
+
+Every layer is ``h = x + Mixer(RMSNorm(x))``, ``y = h + MoE(RMSNorm(h))``
+with a zero-centred RMSNorm (scale ``1 + w``). Layer ``i`` is gated softmax
+attention where ``(i + 1) % full_attention_interval == 0`` and Gated DeltaNet
+otherwise (``nn/deltanet.py``). Every layer's feed-forward is sparse: a
+router over all ``n_experts``, the ``top_k`` largest renormalised, of which
+this chip computes the ``experts_held`` it holds, plus one shared expert
+behind a sigmoid gate (``nn/moe.py``). The embedding and the untied head
+cover the ``num_classes`` rows of the vocabulary that this chip holds.
+
+The training forward returns :class:`~tpuddp.nn.sequence.DeferredLogits`
+(the criterion takes the loss from the hidden states in chunks); evaluation
+returns ``(B, T, V)`` float32 logits. Each layer's mixer and expert layer are
+recomputed in the backward pass of a training step. Inside a layer every
+loop is rolled (sequences, attention's query blocks, the DeltaNet chunks and
+the rows of its inverse, the expert rounds, the loss chunks); the layers of
+the period are a Python loop, because their parameters are one tree a layer,
+as the published checkpoint has them, and each layer keeps its own scope name.
+
+Registry names (``models/__init__.py``): ``qwen3_next_ep16``, the published
+widths of Qwen3-Next-80B-A3B as share 0 of 16 chips that divide each layer's
+experts (one period of four layers); ``qwen3_next_tiny`` for the CPU tests.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+
+from tpuddp.nn import deltanet, moe
+from tpuddp.nn import sequence as seq
+from tpuddp.nn.core import Context, Module
+from tpuddp.observability import profiling as _prof
+
+DELTANET, ATTENTION = "GatedDeltaNet", "GatedAttention"
+
+
+class HybridMoELM(Module):
+    """``num_classes`` is the number of vocabulary rows held (the zoo's
+    ``load_model(name, num_classes)`` protocol)."""
+
+    counter_names = moe.COUNTERS  # what the step carries out beside its metrics
+
+    def __init__(
+        self,
+        num_classes: int,
+        hidden_size: int = 2048,
+        n_layers: int = 4,
+        full_attention_interval: int = 4,
+        # gated softmax attention
+        n_heads: int = 16,
+        n_kv_heads: int = 2,
+        head_dim: int = 256,
+        partial_rotary_factor: float = 0.25,
+        rope_theta: float = 1e7,
+        # Gated DeltaNet
+        linear_k_heads: int = 16,
+        linear_v_heads: int = 32,
+        linear_k_dim: int = 128,
+        linear_v_dim: int = 128,
+        conv_kernel: int = 4,
+        chunk: int = 64,
+        # experts
+        n_experts: int = 512,
+        experts_held: int = 32,
+        first_expert: int = 0,
+        top_k: int = 10,
+        expert_width: int = 512,
+        shared_width: int = 512,
+        aux_loss_weight: float = 0.001,
+        rms_eps: float = 1e-6,
+        init_std: float = 0.02,
+        compute_dtype=jnp.float32,
+        attention_q_block: int = 512,
+        loss_chunk: int = 2048,
+    ):
+        if first_expert < 0 or first_expert + experts_held > n_experts:
+            raise ValueError(
+                f"experts {first_expert}..{first_expert + experts_held - 1} are not among {n_experts}"
+            )
+        if n_heads % n_kv_heads or linear_v_heads % linear_k_heads:
+            raise ValueError("query/value heads must be a multiple of the key/value heads they share")
+        self.vocab_size = int(num_classes)
+        self.hidden_size, self.n_layers = int(hidden_size), int(n_layers)
+        self.full_attention_interval = int(full_attention_interval)
+        self.n_heads, self.n_kv_heads, self.head_dim = int(n_heads), int(n_kv_heads), int(head_dim)
+        self.rotary_dim = int(head_dim * partial_rotary_factor)
+        self.rope_theta = float(rope_theta)
+        self.linear_k_heads, self.linear_v_heads = int(linear_k_heads), int(linear_v_heads)
+        self.linear_k_dim, self.linear_v_dim = int(linear_k_dim), int(linear_v_dim)
+        self.conv_kernel, self.chunk = int(conv_kernel), int(chunk)
+        self.n_experts, self.experts_held = int(n_experts), int(experts_held)
+        self.first_expert, self.top_k = int(first_expert), int(top_k)
+        self.expert_width, self.shared_width = int(expert_width), int(shared_width)
+        self.aux_loss_weight = float(aux_loss_weight)
+        self.rms_eps, self.init_std = float(rms_eps), float(init_std)
+        self.compute_dtype = jnp.dtype(compute_dtype)
+        self.attention_q_block, self.loss_chunk = int(attention_q_block), int(loss_chunk)
+
+    def layer_kind(self, i: int) -> str:
+        return ATTENTION if (i + 1) % self.full_attention_interval == 0 else DELTANET
+
+    def divergent_state(self) -> bool:
+        return False  # parameters only, no buffers
+
+    # ------------------------------------------------------------------ init --
+    def init(self, key, x):
+        e, std = self.hidden_size, self.init_std
+        normal = lambda k, shape: std * jax.random.normal(k, shape, jnp.float32)
+        zeros = lambda n: jnp.zeros((n,), jnp.float32)
+
+        def deltanet_mixer(k):
+            ks = jax.random.split(k, 6)
+            kd, vd = self.linear_k_heads * self.linear_k_dim, self.linear_v_heads * self.linear_v_dim
+            hv = self.linear_v_heads
+            # decay parameters as the Gated DeltaNet reference code draws them:
+            # A uniform in (0, 16), dt log-uniform in [1e-3, 1e-1] through the
+            # inverse of softplus
+            dt = jnp.exp(jax.random.uniform(ks[4], (hv,), jnp.float32, math.log(1e-3), math.log(1e-1)))
+            return {
+                # columns: q (kd) | k (kd) | v (vd) | z (vd)
+                "in_proj_qkvz": normal(ks[0], (e, 2 * kd + 2 * vd)),
+                "in_proj_ba": normal(ks[1], (e, 2 * hv)),  # b (hv) | a (hv)
+                "conv": jax.random.uniform(
+                    ks[2], (self.conv_kernel, 2 * kd + vd), jnp.float32, -1.0, 1.0
+                ) / math.sqrt(self.conv_kernel),
+                "A_log": jnp.log(jax.random.uniform(ks[3], (hv,), jnp.float32, 1e-2, 16.0)),
+                "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+                "norm": jnp.ones((self.linear_v_dim,), jnp.float32),
+                "out_proj": normal(ks[5], (vd, e)),
+            }
+
+        def attention_mixer(k):
+            ks = jax.random.split(k, 4)
+            hq, hkv, d = self.n_heads, self.n_kv_heads, self.head_dim
+            return {
+                "q_proj": normal(ks[0], (e, hq * 2 * d)),  # per head: query (d) | gate (d)
+                "k_proj": normal(ks[1], (e, hkv * d)),
+                "v_proj": normal(ks[2], (e, hkv * d)),
+                "q_norm": zeros(d), "k_norm": zeros(d),
+                "o_proj": normal(ks[3], (hq * d, e)),
+            }
+
+        def experts(k):
+            ks = jax.random.split(k, 6)
+            f, s, h = self.expert_width, self.shared_width, self.experts_held
+            return {
+                "router": normal(ks[0], (e, self.n_experts)),
+                "experts": {"gate_up": normal(ks[1], (h, e, 2 * f)), "down": normal(ks[2], (h, f, e))},
+                "shared": {"gate_up": normal(ks[3], (e, 2 * s)), "down": normal(ks[4], (s, e))},
+                "shared_gate": normal(ks[5], (e, 1)),
+            }
+
+        k_embed, k_head, k_layers = jax.random.split(key, 3)
+        layers = []
+        for i in range(self.n_layers):
+            k_mixer, k_moe = jax.random.split(jax.random.fold_in(k_layers, i))
+            mixer = attention_mixer if self.layer_kind(i) == ATTENTION else deltanet_mixer
+            layers.append({
+                "input_norm": zeros(e), "mixer": mixer(k_mixer),
+                "post_norm": zeros(e), "moe": experts(k_moe),
+            })
+        params = {
+            "embed": {"weight": normal(k_embed, (self.vocab_size, e))},
+            "layers": tuple(layers),
+            "final_norm": zeros(e),
+            "head": {"weight": normal(k_head, (e, self.vocab_size))},
+        }
+        return params, ()
+
+    # --------------------------------------------------------------- mixers --
+    def _norm(self, x, w):
+        return seq.rms_norm(x, w, self.rms_eps, zero_centred=True)
+
+    def _deltanet(self, p, x):
+        b, t, _ = x.shape
+        cd, f32 = self.compute_dtype, jnp.float32
+        hk, hv, dk, dv = self.linear_k_heads, self.linear_v_heads, self.linear_k_dim, self.linear_v_dim
+        with _prof.scope("in_proj"):
+            qkvz = seq.matmul(x, p["in_proj_qkvz"], cd)
+            ba = seq.matmul(x, p["in_proj_ba"], cd, f32)
+            qkv, z = qkvz[..., : 2 * hk * dk + hv * dv], qkvz[..., 2 * hk * dk + hv * dv:]
+            beta = jax.nn.sigmoid(ba[..., :hv])
+            g = -jnp.exp(p["A_log"]) * jax.nn.softplus(ba[..., hv:] + p["dt_bias"])
+        with _prof.scope("conv"):
+            qkv = jax.nn.silu(seq.causal_conv1d(qkv, p["conv"]))
+            q = qkv[..., : hk * dk].reshape(b, t, hk, dk)
+            k = qkv[..., hk * dk: 2 * hk * dk].reshape(b, t, hk, dk)
+            v = qkv[..., 2 * hk * dk:].reshape(b, t, hv, dv)
+            q = seq.l2_normalise(q) * (dk ** -0.5)
+            k = seq.l2_normalise(k)
+            # each key head serves hv / hk value heads
+            q, k = (jnp.repeat(a, hv // hk, axis=2) for a in (q, k))
+        with _prof.scope("scan"):
+            o = deltanet.chunk_gated_delta_rule(q, k, v, g, beta, chunk=self.chunk, compute_dtype=cd)
+        with _prof.scope("out_proj"):
+            o = seq.rms_norm(o, p["norm"], self.rms_eps, gate=z.reshape(b, t, hv, dv))
+            return seq.matmul(o.reshape(b, t, hv * dv), p["out_proj"], cd)
+
+    def _attention(self, p, x):
+        b, t, _ = x.shape
+        cd = self.compute_dtype
+        hq, hkv, d = self.n_heads, self.n_kv_heads, self.head_dim
+        with _prof.scope("qkv"):
+            qg = seq.matmul(x, p["q_proj"], cd).reshape(b, t, hq, 2 * d)
+            q, gate = qg[..., :d], qg[..., d:]
+            k = seq.matmul(x, p["k_proj"], cd).reshape(b, t, hkv, d)
+            v = seq.matmul(x, p["v_proj"], cd).reshape(b, t, hkv, d)
+            positions = jnp.arange(t)
+            rope = functools.partial(
+                seq.rotary, positions=positions, rotary_dim=self.rotary_dim, theta=self.rope_theta
+            )
+            q, k = rope(self._norm(q, p["q_norm"])), rope(self._norm(k, p["k_norm"]))
+        with _prof.scope("attention"):
+            o = seq.causal_attention(
+                q, k, v, scale=d ** -0.5, compute_dtype=cd, q_block=self.attention_q_block
+            )
+        with _prof.scope("o_proj"):
+            o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
+            return seq.matmul(o.reshape(b, t, hq * d), p["o_proj"], cd)
+
+    def _mix(self, kind, p, x):
+        """``x + Mixer(RMSNorm(x))`` for one sequence ``(T, E)``."""
+        mixer = self._attention if kind == ATTENTION else self._deltanet
+        return x + mixer(p["mixer"], self._norm(x[None], p["input_norm"]))[0]
+
+    def _experts(self, p, h):
+        with _prof.scope("moe"):
+            y, aux, counters = moe.expert_share_moe(
+                p["moe"], self._norm(h, p["post_norm"]).reshape(-1, self.hidden_size),
+                top_k=self.top_k, first_expert=self.first_expert, compute_dtype=self.compute_dtype,
+            )
+        return h + y.reshape(h.shape), aux, counters
+
+    def _layer(self, kind, p, x, remat: bool):
+        """One layer. The mixer takes the batch's sequences one at a time and
+        the expert layer all their tokens at once; with ``remat`` each of the
+        two is recomputed in the backward pass, so a step keeps the residual
+        stream at both and one sequence's mixer or one expert layer's
+        activations."""
+        mix, experts = functools.partial(self._mix, kind), self._experts
+        if remat:
+            mix, experts = jax.checkpoint(mix), jax.checkpoint(experts)
+        return experts(p, jax.lax.map(lambda sequence: mix(p, sequence), x))
+
+    # -------------------------------------------------------------- forward --
+    def apply(self, params, state, x, ctx: Context):
+        tokens = jnp.asarray(x).astype(jnp.int32)
+        # the residual stream is kept in the products' input type (an 8-bit
+        # type only rounds the products' inputs: round_to)
+        h = seq.round_to(jnp.take(params["embed"]["weight"], tokens, axis=0), self.compute_dtype)
+        aux_total = jnp.zeros((), jnp.float32)
+        totals = {name: jnp.zeros((), jnp.float32) for name in self.counter_names}
+        for i, p in enumerate(params["layers"]):
+            kind = self.layer_kind(i)
+            with _prof.scope(f"{i}_{kind}"):
+                h, aux, counters = self._layer(kind, p, h, ctx.train)
+            aux_total = aux_total + aux
+            totals = {name: totals[name] + counters[name] for name in totals}
+        h = self._norm(h, params["final_norm"])
+        out = seq.DeferredLogits(
+            h, params["head"]["weight"], self.aux_loss_weight * aux_total, totals,
+            compute_dtype=self.compute_dtype, chunk=self.loss_chunk,
+        )
+        return (out if ctx.train else out.logits()), state
+
+
+QWEN3_NEXT_EP16 = dict(  # Qwen3-Next-80B-A3B's widths; depth, experts held and vocabulary cut
+    hidden_size=2048, n_layers=4, full_attention_interval=4,
+    n_heads=16, n_kv_heads=2, head_dim=256, partial_rotary_factor=0.25, rope_theta=1e7,
+    linear_k_heads=16, linear_v_heads=32, linear_k_dim=128, linear_v_dim=128, conv_kernel=4,
+    n_experts=512, experts_held=32, first_expert=0, top_k=10, expert_width=512, shared_width=512,
+)
+QWEN3_NEXT_TINY = dict(
+    hidden_size=64, n_layers=4, full_attention_interval=4,
+    n_heads=4, n_kv_heads=2, head_dim=16, partial_rotary_factor=0.25, rope_theta=1e7,
+    linear_k_heads=2, linear_v_heads=4, linear_k_dim=16, linear_v_dim=16, conv_kernel=4, chunk=16,
+    n_experts=8, experts_held=2, first_expert=0, top_k=2, expert_width=32, shared_width=32,
+    attention_q_block=32, loss_chunk=64,
+)

@@ -26,6 +26,17 @@ from tpuddp.nn.norm import (  # noqa: F401
     convert_sync_batchnorm,
 )
 from tpuddp.nn.loss import CrossEntropyLoss, cross_entropy  # noqa: F401
+from tpuddp.nn.sequence import (  # noqa: F401
+    DeferredLogits,
+    causal_attention,
+    causal_conv1d,
+    linear_cross_entropy,
+    rms_norm,
+    rotary,
+    swiglu,
+)
+from tpuddp.nn.deltanet import chunk_gated_delta_rule  # noqa: F401
+from tpuddp.nn.moe import expert_share_moe  # noqa: F401
 
 __all__ = [
     "Context",
@@ -47,4 +58,13 @@ __all__ = [
     "convert_sync_batchnorm",
     "CrossEntropyLoss",
     "cross_entropy",
+    "DeferredLogits",
+    "causal_attention",
+    "causal_conv1d",
+    "linear_cross_entropy",
+    "rms_norm",
+    "rotary",
+    "swiglu",
+    "chunk_gated_delta_rule",
+    "expert_share_moe",
 ]

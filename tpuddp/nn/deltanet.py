@@ -1,0 +1,144 @@
+"""The gated delta rule in chunks.
+
+Per head and token ``t``, with a state ``S`` of ``Dk x Dv`` (key by value)
+that starts at zero::
+
+    S <- exp(g_t) S;  u = beta_t (v_t - S^T k_t);  S <- S + k_t u^T;  o_t = S^T q_t
+
+A chunk of ``C`` tokens is one block step (Yang et al., "Gated Delta
+Networks", 2024; the WY form of "Parallelizing Linear Transformers with the
+Delta Rule", 2024). With ``G`` the running sum of ``g`` inside the chunk and
+``D[i, j] = exp(G_i - G_j)`` for ``i >= j``::
+
+    A = strictly_lower((beta k) k^T * D);  T = (I + A)^-1
+    U = T (beta v);  W = T (beta k * exp(G))
+    V' = U - W S;  O = (q * exp(G)) S + lower((q k^T) * D) V'
+    S <- exp(G_C) S + (k * exp(G_C - G))^T V'
+
+Only the last line and ``V'`` depend on the chunk before, so the scan over
+chunks carries ``S`` through two small products a chunk and everything else
+is batched over chunks. Every exponent is a difference of running sums with
+the later one first, so none is positive. Decay sums, ``T`` and the state are
+float32; products take ``compute_dtype`` inputs and accumulate in float32.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from tpuddp.nn.sequence import round_to
+
+_BASE = 16  # block inverted by forward substitution; larger blocks by halves
+_EXACT = jax.lax.Precision.HIGHEST
+
+
+def _forward_substitute(a):
+    """``(I + a)^-1`` row by row: row ``i`` is ``e_i - a[i] @ rows`` (exact,
+    no powers of ``a``). Rows not yet done still hold the identity's, and
+    ``a[i, j]`` is zero for ``j >= i``, so every step is the same product
+    over all rows and the loop is rolled."""
+    n = a.shape[-1]
+    eye = jnp.eye(n, dtype=a.dtype)
+
+    def row(i, inv):
+        a_i = jax.lax.dynamic_index_in_dim(a, i, axis=-2, keepdims=False)  # (..., n)
+        new = eye[i] - jnp.sum(a_i[..., :, None] * inv, axis=-2)
+        return jax.lax.dynamic_update_index_in_dim(inv, new, i, axis=-2)
+
+    return jax.lax.fori_loop(1, n, row, jnp.broadcast_to(eye, a.shape))
+
+
+@jax.custom_vjp
+def _invert_unit_lower(a):
+    """``(I + a)^-1`` for strictly lower-triangular ``a`` of ``(..., n, n)``,
+    float32. The diagonal blocks of at most 16 go through forward
+    substitution together; two halves ``[[P, 0], [R, Q]]`` join as
+    ``[[P^-1, 0], [-Q^-1 R P^-1, Q^-1]]``, which is all matrix products, level
+    by level until one block is left. The backward pass is the inverse's own
+    derivative, ``-T^T g T^T`` below the diagonal: it keeps ``T`` and nothing
+    of the loop."""
+    n, lead = a.shape[-1], a.shape[:-2]
+    size = n
+    while size > _BASE and size % 2 == 0:
+        size //= 2
+    blocks = n // size
+    tiles = a.reshape(*lead, blocks, size, blocks, size)
+    inv = _forward_substitute(jnp.stack([tiles[..., i, :, i, :] for i in range(blocks)], axis=-3))
+    while blocks > 1:
+        blocks //= 2
+        tiles = a.reshape(*lead, blocks, 2, size, blocks, 2, size)
+        below = jnp.stack([tiles[..., i, 1, :, i, 0, :] for i in range(blocks)], axis=-3)
+        p, q = inv[..., 0::2, :, :], inv[..., 1::2, :, :]
+        r = -jnp.matmul(jnp.matmul(q, below, precision=_EXACT), p, precision=_EXACT)
+        top = jnp.concatenate([p, jnp.zeros_like(p)], axis=-1)
+        inv = jnp.concatenate([top, jnp.concatenate([r, q], axis=-1)], axis=-2)
+        size *= 2
+    return inv[..., 0, :, :]
+
+
+def _invert_fwd(a):
+    inv = _invert_unit_lower(a)
+    return inv, inv
+
+
+def _invert_bwd(inv, g):
+    inv_t = jnp.swapaxes(inv, -1, -2)
+    return (-jnp.tril(jnp.matmul(jnp.matmul(inv_t, g, precision=_EXACT), inv_t, precision=_EXACT), -1),)
+
+
+_invert_unit_lower.defvjp(_invert_fwd, _invert_bwd)
+
+
+def chunk_gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64, compute_dtype=jnp.float32):
+    """``q``, ``k``: ``(B, T, H, Dk)`` (already normalised and scaled);
+    ``v``: ``(B, T, H, Dv)``; ``g`` (log decay, <= 0) and ``beta``:
+    ``(B, T, H)`` float32. Returns ``o`` of ``(B, T, H, Dv)`` in ``v``'s type.
+    ``T`` need not be a multiple of ``chunk``: the tail is padded with tokens
+    that leave the state as it is (``beta`` 0, ``g`` 0)."""
+    b, t, h, dk = q.shape
+    dv = v.shape[-1]
+    pad = -t % chunk
+    if pad:
+        widen = lambda a: jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+        q, k, v, g, beta = (widen(a) for a in (q, k, v, g, beta))
+    n = (t + pad) // chunk
+
+    def chunked(a):  # (B, T, H, ...) -> (B, H, N, C, ...)
+        a = a.reshape(b, n, chunk, *a.shape[2:])
+        return jnp.moveaxis(a, 3, 1)
+
+    q, k, v = chunked(q), chunked(k), chunked(v)
+    g, beta = chunked(g.astype(jnp.float32)), chunked(beta.astype(jnp.float32))
+    f32 = jnp.float32
+    product = lambda spec, x, y: jnp.einsum(
+        spec, round_to(x, compute_dtype), round_to(y, compute_dtype), preferred_element_type=f32
+    )
+
+    gsum = jnp.cumsum(g, axis=-1)  # (B, H, N, C)
+    i, j = jnp.arange(chunk)[:, None], jnp.arange(chunk)[None, :]
+    decay = jnp.exp(jnp.where(i >= j, gsum[..., :, None] - gsum[..., None, :], -jnp.inf))
+    k_beta = k.astype(f32) * beta[..., None]
+    a = jnp.where(i > j, product("bhnid,bhnjd->bhnij", k_beta, k) * decay, 0.0)
+    t_inv = _invert_unit_lower(a)
+    u = product("bhnij,bhnjd->bhnid", t_inv, v.astype(f32) * beta[..., None])
+    w = product("bhnij,bhnjd->bhnid", t_inv, k_beta * jnp.exp(gsum)[..., None])
+    g_last = gsum[..., -1]  # (B, H, N)
+    k_tail = k.astype(f32) * jnp.exp(g_last[..., None] - gsum)[..., None]
+
+    def step(state, xs):
+        w_i, u_i, k_i, decay_i = xs
+        v_new = u_i - product("bhcd,bhde->bhce", w_i, state)
+        nxt = state * decay_i[..., None, None] + product("bhcd,bhce->bhde", k_i, v_new)
+        return nxt, (round_to(state, compute_dtype), round_to(v_new, compute_dtype))
+
+    over_chunks = lambda x: jnp.moveaxis(x, 2, 0)
+    _, (starts, v_new) = jax.lax.scan(
+        step, jnp.zeros((b, h, dk, dv), f32),
+        (over_chunks(w), over_chunks(u), over_chunks(k_tail), over_chunks(jnp.exp(g_last))),
+    )
+    starts, v_new = jnp.moveaxis(starts, 0, 2), jnp.moveaxis(v_new, 0, 2)
+    inter = product("bhncd,bhnde->bhnce", q.astype(f32) * jnp.exp(gsum)[..., None], starts)
+    intra = product("bhnij,bhnje->bhnie", product("bhnid,bhnjd->bhnij", q, k) * decay, v_new)
+    o = jnp.moveaxis(inter + intra, 1, 3).reshape(b, n * chunk, h, dv)
+    return o[:, :t].astype(v.dtype)

@@ -31,10 +31,12 @@ def cross_entropy(
     """
     logits = logits.astype(jnp.float32)  # stable softmax even for bf16 nets
     if logits.ndim > 2:
+        if weights is not None:
+            from tpuddp.nn.sequence import per_token_weights
+
+            weights = per_token_weights(weights, labels.shape).reshape(-1)
         logits = logits.reshape(-1, logits.shape[-1])
         labels = labels.reshape(-1)
-        if weights is not None:
-            weights = weights.reshape(-1)
     logz = jax.scipy.special.logsumexp(logits, axis=-1)
     true_logit = jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0]
     losses = logz - true_logit

@@ -1,0 +1,236 @@
+"""Layers of a token model: RMSNorm (plain, zero-centred and gated), rotary
+positions over part of a head, a causal depthwise 1-D convolution, SwiGLU,
+causal attention over grouped key/value heads that never holds a ``T x T``
+score block, and a head-plus-cross-entropy that never holds ``(B, T, V)``
+logits.
+
+Functions over explicit arrays (the family file ``models/hybrid_moe.py``
+owns the parameter tree). Products take their inputs in ``compute_dtype``
+and accumulate in float32 (:func:`matmul`); statistics, softmax, the loss and
+whatever a recurrence carries stay float32.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+
+_NEG_INF = -1e30  # masked-score fill: finite, so a fully masked row stays NaN-free
+
+
+def round_to(x, dtype):
+    """``x`` rounded to ``dtype`` as a product's input. An 8-bit float is
+    rounded to and widened again to bfloat16: backends without 8-bit products
+    then compute what a backend with them would be fed."""
+    dtype = jnp.dtype(dtype)
+    if dtype.itemsize == 1:
+        return x.astype(dtype).astype(jnp.bfloat16)
+    return x.astype(dtype)
+
+
+def matmul(x, w, compute_dtype, out_dtype=None):
+    """``x @ w`` with both inputs rounded to ``compute_dtype`` and float32
+    accumulation; the result in ``out_dtype`` (default: the rounded type)."""
+    x, w = round_to(x, compute_dtype), round_to(w, compute_dtype)
+    y = jnp.matmul(x, w, preferred_element_type=jnp.float32)
+    return y.astype(out_dtype or x.dtype)
+
+
+def rms_norm(x, weight, eps: float = 1e-6, *, zero_centred: bool = False, gate=None):
+    """``x / sqrt(mean(x^2) + eps) * w`` over the last axis, statistics in
+    float32. ``zero_centred``: the scale is ``1 + w`` (``w`` starts at 0).
+    ``gate``: the gated form, the normalised value times ``SiLU(gate)``."""
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps)
+    w = weight.astype(jnp.float32)
+    y = y * (1.0 + w if zero_centred else w)
+    if gate is not None:
+        y = y * jax.nn.silu(gate.astype(jnp.float32))
+    return y.astype(x.dtype)
+
+
+def l2_normalise(x, eps: float = 1e-6):
+    x32 = x.astype(jnp.float32)
+    return (x32 * jax.lax.rsqrt(jnp.sum(jnp.square(x32), axis=-1, keepdims=True) + eps)).astype(x.dtype)
+
+
+def rotary(x, positions, *, rotary_dim: int, theta: float):
+    """Rotate-half rotary positions on the first ``rotary_dim`` of the last
+    axis; the rest passes. ``x``: ``(B, T, H, D)``, ``positions``: ``(T,)``."""
+    half = rotary_dim // 2
+    inv_freq = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) * 2.0 / rotary_dim))
+    angles = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]  # (T, half)
+    cos, sin = jnp.cos(angles)[None, :, None, :], jnp.sin(angles)[None, :, None, :]
+    rot, rest = x[..., :rotary_dim].astype(jnp.float32), x[..., rotary_dim:]
+    a, b = rot[..., :half], rot[..., half:]
+    rot = jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+    return jnp.concatenate([rot.astype(x.dtype), rest], axis=-1)
+
+
+def causal_conv1d(x, kernel):
+    """Depthwise causal convolution along time: ``y[t] = sum_j kernel[j] *
+    x[t - (K - 1) + j]`` with zeros before the sequence. ``x``: ``(B, T, C)``,
+    ``kernel``: ``(K, C)``. Written as ``K`` shifted multiply-adds, which is
+    what a depthwise kernel of 4 is on a vector unit."""
+    k, t = kernel.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    w = kernel.astype(jnp.float32)
+    y = sum(padded[:, j:j + t].astype(jnp.float32) * w[j] for j in range(k))
+    return y.astype(x.dtype)
+
+
+def swiglu(x, gate_up, down, compute_dtype):
+    """``down(SiLU(x W_gate) * (x W_up))`` with gate and up joined column-wise
+    in ``gate_up`` (``(E, 2F)``: gate first)."""
+    h = matmul(x, gate_up, compute_dtype, jnp.float32)
+    f = h.shape[-1] // 2
+    return matmul(jax.nn.silu(h[..., :f]) * h[..., f:], down, compute_dtype)
+
+
+def _attend_block(q, q_start, k, v, *, scale, compute_dtype):
+    """One block of queries, the first at position ``q_start``, against keys
+    that reach at least to the block's end. ``q``: ``(B, Q, Hkv, G, D)``;
+    ``k``, ``v``: ``(B, S, Hkv, D)``."""
+    scores = jnp.einsum(
+        "bqhgd,bshd->bhgqs", round_to(q, compute_dtype), round_to(k, compute_dtype),
+        preferred_element_type=jnp.float32,
+    ) * scale
+    q_pos = q_start + jnp.arange(q.shape[1])
+    visible = jnp.arange(k.shape[1])[None, :] <= q_pos[:, None]
+    probs = jax.nn.softmax(jnp.where(visible, scores, _NEG_INF), axis=-1)
+    out = jnp.einsum(
+        "bhgqs,bshd->bqhgd", round_to(probs, compute_dtype), round_to(v, compute_dtype),
+        preferred_element_type=jnp.float32,
+    )
+    return out.astype(q.dtype)
+
+
+def causal_attention(q, k, v, *, scale: float, compute_dtype, q_block: int = 512):
+    """Causal softmax attention, each key/value head serving ``Hq / Hkv``
+    query heads. ``q``: ``(B, T, Hq, D)``; ``k``, ``v``: ``(B, T, Hkv, D)``.
+    Queries go ``q_block`` at a time, so the largest score block is ``q_block
+    x T`` a head, and each block is recomputed in the backward pass, so no
+    block's probabilities are kept. About ``sqrt(T / q_block)`` neighbouring
+    blocks form a group that shares the keys up to the group's end and one
+    rolled loop, so the program holds a loop a group and not a copy a block;
+    what a group computes above its blocks' diagonals is masked (at 16 blocks,
+    160 block products where 136 are needed)."""
+    b, t, hq, d = q.shape
+    hkv = k.shape[2]
+    q = q.reshape(b, t, hkv, hq // hkv, d)
+    block = jax.checkpoint(functools.partial(_attend_block, scale=scale, compute_dtype=compute_dtype))
+    span = q_block * (math.isqrt(max(-(-t // q_block) - 1, 0)) + 1)  # tokens a group
+    outs = []
+    for start in range(0, t, span):
+        end = min(start + span, t)
+        keys, values = k[:, :end], v[:, :end]
+        whole = (end - start) // q_block
+        if whole:
+            queries = q[:, start:start + whole * q_block].reshape(b, whole, q_block, *q.shape[2:])
+            out = jax.lax.map(
+                lambda args: block(*args, keys, values),
+                (jnp.moveaxis(queries, 1, 0), start + q_block * jnp.arange(whole)),
+            )
+            outs.append(jnp.moveaxis(out, 0, 1).reshape(b, whole * q_block, *q.shape[2:]))
+        if start + whole * q_block < end:  # what is left of a sequence that is no multiple
+            outs.append(block(q[:, start + whole * q_block:end], start + whole * q_block, keys, values))
+    return jnp.concatenate(outs, axis=1).reshape(b, t, hq, d)
+
+
+# -- the head and its loss ------------------------------------------------------
+
+def linear_cross_entropy(hidden, head, labels, weights, *, compute_dtype, chunk: int = 2048):
+    """Sum over tokens of ``weights * cross_entropy(hidden @ head, labels)``
+    without the ``(N, V)`` logits: tokens go ``chunk`` at a time, each chunk's
+    logits live in float32 for its own logsumexp only and are recomputed in
+    the backward pass. ``hidden``: ``(N, E)``, ``head``: ``(E, V)``."""
+    n = hidden.shape[0]
+    chunk = min(chunk, n)
+    pad = -n % chunk
+    if pad:
+        hidden = jnp.pad(hidden, ((0, pad), (0, 0)))
+        labels, weights = jnp.pad(labels, (0, pad)), jnp.pad(weights, (0, pad))
+    head = round_to(head, compute_dtype)
+
+    @jax.checkpoint
+    def chunk_loss(h, y, w):
+        logits = jnp.matmul(round_to(h, compute_dtype), head, preferred_element_type=jnp.float32)
+        logz = jax.scipy.special.logsumexp(logits, axis=-1)
+        true = jnp.take_along_axis(logits, y[:, None], axis=-1)[:, 0]
+        return jnp.sum((logz - true) * w)
+
+    def body(total, args):
+        return total + chunk_loss(*args), None
+
+    split = lambda a: a.reshape(-1, chunk, *a.shape[1:])
+    total, _ = jax.lax.scan(
+        body, jnp.zeros((), jnp.float32),
+        (split(hidden), split(labels), split(weights.astype(jnp.float32))),
+    )
+    return total
+
+
+@jax.tree_util.register_pytree_node_class
+class DeferredLogits:
+    """What a token model's training forward returns in place of ``(B, T, V)``
+    logits: the final hidden states and the head, for the criterion to take
+    the loss from in chunks (``nn.CrossEntropyLoss`` binds itself through
+    ``_tpuddp_bind_loss``, as it does to the managed path's lazy forward); ``aux_loss``, which enters the gradient and not
+    the reported loss; and ``counters``, additive program counters that the
+    step carries out beside its metrics (``training/step.py``)."""
+
+    def __init__(self, hidden, head, aux_loss=None, counters=None, *, compute_dtype, chunk=2048):
+        self.hidden, self.head = hidden, head
+        self.aux_loss = aux_loss
+        self.counters = counters or {}
+        self.compute_dtype, self.chunk = jnp.dtype(compute_dtype), chunk
+
+    def tree_flatten(self):
+        return (self.hidden, self.head, self.aux_loss, self.counters), (self.compute_dtype, self.chunk)
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(*children, compute_dtype=aux[0], chunk=aux[1])
+
+    def logits(self):
+        """The logits whole, in float32: for evaluation and small sizes."""
+        return matmul(self.hidden, self.head, self.compute_dtype, jnp.float32)
+
+    # hook consumed by tpuddp criterions (see nn/loss.py)
+    def _tpuddp_bind_loss(self, criterion, labels, weights=None):
+        return self.cross_entropy(labels, weights, criterion.reduction)
+
+    def cross_entropy(self, labels, weights: Optional[jax.Array], reduction: str = "mean"):
+        labels = labels.reshape(-1)
+        if weights is None:
+            weights = jnp.ones(labels.shape, jnp.float32)
+        else:
+            weights = per_token_weights(weights, self.hidden.shape[:-1]).reshape(-1)
+        total = linear_cross_entropy(
+            self.hidden.reshape(-1, self.hidden.shape[-1]), self.head, labels, weights,
+            compute_dtype=self.compute_dtype, chunk=self.chunk,
+        )
+        if reduction == "sum":
+            loss = total
+        elif reduction == "mean":
+            denom = jnp.sum(weights)
+            loss = total / jnp.where(denom == 0, 1.0, denom)
+        else:
+            raise ValueError(f"deferred logits reduce to 'mean' or 'sum', not {reduction!r}")
+        if self.aux_loss is not None:
+            # value of the cross-entropy alone, gradient of the sum
+            loss = loss + (self.aux_loss - jax.lax.stop_gradient(self.aux_loss))
+        return loss
+
+
+def per_token_weights(weights, token_shape):
+    """Per-sequence weights ``(B,)`` (the loaders' padding mask) spread over
+    the sequence's tokens; per-token weights pass."""
+    weights = weights.astype(jnp.float32)
+    if weights.ndim < len(token_shape):
+        weights = weights.reshape(weights.shape + (1,) * (len(token_shape) - weights.ndim))
+    return jnp.broadcast_to(weights, token_shape)

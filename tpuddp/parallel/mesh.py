@@ -120,7 +120,17 @@ def replicate(mesh: Mesh, tree):
     (where a plain device_put cannot target non-addressable devices): a jitted
     identity with replicated out_shardings lets each process contribute its
     (identical — broadcast first!) local copy to the global array."""
-    return jax.jit(lambda t: t, out_shardings=NamedSharding(mesh, P()))(tree)
+    target = NamedSharding(mesh, P())
+    leaves, treedef = jax.tree_util.tree_flatten(tree)
+    # a leaf that was made replicated on this mesh stays where it is: the
+    # jitted identity would copy it, and a state of several GB then peaks at
+    # twice its size before the first step (a tracer has no sharding to ask)
+    todo = [i for i, leaf in enumerate(leaves) if getattr(leaf, "sharding", None) != target]
+    if todo:
+        placed = jax.jit(lambda t: t, out_shardings=target)([leaves[i] for i in todo])
+        for i, leaf in zip(todo, placed):
+            leaves[i] = leaf
+    return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
 def place_like(template, tree):

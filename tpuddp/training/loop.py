@@ -1013,6 +1013,9 @@ def run_training_loop(
                 "test_samples": eval_m["n"],
                 "epoch_time_s": epoch_time,
                 "samples_per_sec": (train_m["n"] + eval_m["n"]) / max(epoch_time, 1e-9),
+                # the model's own additive counters (model.counter_names), summed
+                # over the epoch's train steps: the expert layer's load, for one
+                **{k: v for k, v in train_m.items() if k not in ("loss_sum", "n")},
             }
             # step-time percentiles + achieved-MFU from the train-pass
             # recorder (the finalize_metrics fetch above already fenced the

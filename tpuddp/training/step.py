@@ -195,7 +195,8 @@ def _make_grad_core(
     remat: bool = False,
 ):
     """The forward+backward half of the train step: one micro-batch in,
-    ``(grads, synced_model_state, loss, n)`` out. Gradients are this replica's
+    ``(grads, synced_model_state, loss, n, counters)`` out (``counters``: the
+    additive program counters a model's output carries, ``{}`` for most). Gradients are this replica's
     LOCAL batch-mean gradient — cross-replica reduction belongs to the update
     half (:func:`_make_update_fn`), so gradient accumulation can sum local
     grads over K micro-batches and pay for ONE collective per cycle."""
@@ -226,13 +227,13 @@ def _make_grad_core(
                 logits, model_state = apply_fn(params, state.model_state, x, ctx)
             with _prof.scope(_prof.LOSS):
                 loss = criterion(logits, y, w)
-            return loss, model_state
+            return loss, (model_state, getattr(logits, "counters", {}))
 
-        (loss, model_state), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            state.params
-        )
+        (loss, (model_state, counters)), grads = jax.value_and_grad(
+            loss_fn, has_aux=True
+        )(state.params)
         model_state = _sync_model_state(model_state, axis_name, sync_buffers)
-        return grads, model_state, loss, jnp.sum(w)
+        return grads, model_state, loss, jnp.sum(w), counters
 
     return grad_core
 
@@ -471,12 +472,21 @@ def _revert_buffers_on_skip(old_state, new_state, skipped, new_skipped):
         )
 
 
-def _step_metrics(loss, n):
+def _step_metrics(loss, n, counters=None):
     with _prof.scope(_prof.METRICS):
         return {
             "loss_sum": (loss * n)[None],  # sample-weighted, reference :131
             "n": n[None],
+            # the model's own additive counters (model.counter_names)
+            **{name: value[None] for name, value in (counters or {}).items()},
         }
+
+
+def _metric_specs(model, spec):
+    """The step's metrics as ``shard_map`` returns them: the loss sums and,
+    for a model that counts (``counter_names``), its counters."""
+    names = ("loss_sum", "n", *getattr(model, "counter_names", ()))
+    return {name: spec for name in names}
 
 
 def _sum_metrics(stacked):
@@ -514,7 +524,7 @@ def _make_train_core(
     )
 
     def core(state: TrainState, x, y, w):
-        grads, model_state, loss, n = grad_core(state, x, y, w)
+        grads, model_state, loss, n, counters = grad_core(state, x, y, w)
         new_params, new_opt_state, new_comm, new_skipped = apply_update(
             state.params, state.opt_state, grads, state.comm_state,
             state.skipped_steps,
@@ -524,7 +534,7 @@ def _make_train_core(
                 state.model_state, model_state, state.skipped_steps,
                 new_skipped,
             )
-        metrics = _step_metrics(loss, n)
+        metrics = _step_metrics(loss, n, counters)
         new_state = TrainState(
             params=new_params,
             model_state=model_state,
@@ -790,7 +800,8 @@ def _make_eval_core(model, criterion, axis_name, transform: Optional[Callable]):
         with _prof.scope(_prof.METRICS):
             n = jnp.sum(w)
             predicted = jnp.argmax(logits, axis=-1)
-            correct = jnp.sum((predicted == y) * w)
+            # per-sequence weights cover their tokens (a no-op for per-sample ones)
+            correct = jnp.sum((predicted == y) * w.reshape(w.shape + (1,) * (y.ndim - w.ndim)))
             return {
                 "loss_sum": (loss * n)[None],
                 "correct": correct[None],
@@ -854,7 +865,7 @@ def build_train_step(
             core,
             mesh=mesh,
             in_specs=(st_spec, P(axis), P(axis), P(axis)),
-            out_specs=(st_spec, {"loss_sum": P(axis), "n": P(axis)}),
+            out_specs=(st_spec, _metric_specs(model, P(axis))),
             check_vma=False,
         )
         jitted = jax.jit(fn, donate_argnums=0)
@@ -1010,7 +1021,7 @@ def build_train_scan_step(
                 def micro(carry, mb):
                     st, gacc, nacc = carry
                     x, y, w = mb
-                    grads, model_state, loss, n = grad_core(st, x, y, w)
+                    grads, model_state, loss, n, counters = grad_core(st, x, y, w)
                     # n-weighted gradient sum: micro-batch i's local grad is
                     # the mean over its n_i live samples, so Σ n_i·g_i / Σ n_i
                     # is EXACTLY the mean gradient of the concatenated batch,
@@ -1027,7 +1038,7 @@ def build_train_scan_step(
                         comm_state=st.comm_state,
                         skipped_steps=st.skipped_steps,
                     )
-                    m = _step_metrics(loss, n)
+                    m = _step_metrics(loss, n, counters)
                     return (st, gacc, nacc + n), m
 
                 if segments is None:
@@ -1121,7 +1132,7 @@ def build_train_scan_step(
             multi,
             mesh=mesh,
             in_specs=(st_spec, in_batch, in_batch, in_batch),
-            out_specs=(st_spec, {"loss_sum": metric_spec, "n": metric_spec}),
+            out_specs=(st_spec, _metric_specs(model, metric_spec)),
             check_vma=False,
         )
         jitted = jax.jit(fn, donate_argnums=0)

@@ -22,6 +22,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from tpuddp import config as cfg_lib
 from tpuddp import nn, observability as obs, seeding
@@ -96,10 +97,11 @@ def basic_ddp_training_loop(
     size = training.get("image_size")
     mean, std = norm_stats_for(training)
     cdtype = compute_dtype_for(training)
-    is_token_model = str(training.get("model") or "").startswith("transformer")
-    if is_token_model:
-        # token models take int sequences: the image augment/normalize
-        # pipeline does not apply (and the TP wrap refuses it outright)
+    sample, _ = train_ds[0]
+    if np.ndim(sample) != 3:
+        # the data says what it is: a sample that is not (H, W, C) is a row of
+        # token ids, and the image augment/normalize pipeline does not apply
+        # (the TP wrap refuses it outright)
         augment = eval_transform = None
     else:
         augment = make_train_augment(
@@ -171,10 +173,12 @@ def basic_ddp_training_loop(
         # unless the training.guard block asks for it
         guard=training.get("guard"),
     )
-    in_hw = size if size else train_ds.images.shape[1]
-    state = ddp.init_state(
-        key, jnp.zeros((1, in_hw, in_hw, 3)), params=init_params, model_state=init_mstate
-    )
+    if augment is None:
+        init_sample = jnp.asarray(sample)[None]
+    else:
+        in_hw = size if size else train_ds.images.shape[1]
+        init_sample = jnp.zeros((1, in_hw, in_hw, 3))
+    state = ddp.init_state(key, init_sample, params=init_params, model_state=init_mstate)
 
     # Resume path (the reference only documents loading, README.md:51-52):
     # training.resume: true restores the newest ckpt_{epoch}.npz in out_dir —
