@@ -3,15 +3,19 @@ nn/deltanet.py, nn/moe.py, nn/sequence.py) against its plain reference
 (benchmark/reference/qwen3_next_80b_a3b_ep16.py) at the tiny preset on the
 CPU: seeded random weights, float32 unless a test says otherwise."""
 
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from benchmark import cells
 from tpuddp import nn
 from tpuddp.models import QWEN3_NEXT_EP16, load_model
 from tpuddp.nn import moe as moe_lib
+from tpuddp.nn import sequence as seq
 from tpuddp.nn.core import Context
 from tpuddp.nn.deltanet import _invert_unit_lower, chunk_gated_delta_rule
 
@@ -308,3 +312,182 @@ def test_the_references_blocks_change_no_arithmetic(reference, system, tiny, mon
     blocked = jax.value_and_grad(objective)(params)
     _close(blocked[0], whole[0], 1e-6)
     _close(blocked[1], whole[1], 1e-4)
+
+
+# -- softmax attention's two lowerings (nn/sequence.py) -----------------------
+
+# kernel-eligible and small: three blocks of 512 (the diagonal's, whole and
+# skipped blocks all occur), two query heads a key/value head
+_T, _HQ, _HKV, _D = 1536, 4, 2, 128
+
+
+def _qkvw(dtype, t=_T):
+    keys = jax.random.split(jax.random.key(11), 4)
+    shapes = ((1, t, _HQ, _D), (1, t, _HKV, _D), (1, t, _HKV, _D), (1, t, _HQ, _D))
+    return [jax.random.normal(k, s, jnp.float32).astype(dtype) for k, s in zip(keys, shapes)]
+
+
+def _lowerings(dtype):
+    kw = dict(scale=_D ** -0.5, compute_dtype=dtype)
+    return {
+        "fused": lambda q, k, v: seq._fused_causal_attention(q, k, v, interpret=True, **kw),
+        "blockwise": lambda q, k, v: seq._blockwise_causal_attention(q, k, v, q_block=512, **kw),
+    }
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-5), ("bfloat16", 2 ** -6)])
+@pytest.mark.parametrize("what", ["out", "dq", "dk", "dv"])
+def test_fused_attention_agrees_with_the_blockwise_path(dtype, tol, what):
+    """The kernel in interpret mode against the blockwise path: float32
+    inputs to float32 tolerance, bfloat16 inputs to bfloat16's rounding (8
+    bits: half a unit in the last place of values up to 4, and a sum of
+    two)."""
+    q, k, v, w = _qkvw(dtype)
+    got = {}
+    for name, f in _lowerings(jnp.dtype(dtype)).items():
+        loss = lambda q, k, v: jnp.sum(f(q, k, v).astype(jnp.float32) * w.astype(jnp.float32))
+        got[name] = f(q, k, v) if what == "out" else jax.grad(loss, argnums="qkv".index(what[1]))(q, k, v)
+    assert got["fused"].dtype == got["blockwise"].dtype == jnp.dtype(dtype)
+    a, b = (np.asarray(got[n], np.float32) for n in ("fused", "blockwise"))
+    assert np.abs(b).max() > 1.0  # the tolerance is against values of this size
+    np.testing.assert_allclose(a, b, rtol=0, atol=tol * np.abs(b).max())
+
+
+@pytest.mark.parametrize("lowering", ["fused", "blockwise"])
+@pytest.mark.parametrize("cut", [511, 512, 1100])
+def test_attention_is_causal_to_the_bit(lowering, cut):
+    """Other tokens after position ``cut`` leave the outputs up to it as they
+    were, bit for bit."""
+    q, k, v, _ = _qkvw(jnp.bfloat16)
+    q2, k2, v2, _ = (jnp.concatenate([a[:, :cut + 1], -2.0 * a[:, cut + 1:]], axis=1) for a in _qkvw(jnp.bfloat16))
+    f = jax.jit(_lowerings(jnp.bfloat16)[lowering])
+    before, after = f(q, k, v), f(q2, k2, v2)
+    np.testing.assert_array_equal(np.asarray(before[:, :cut + 1]), np.asarray(after[:, :cut + 1]))
+    assert not np.array_equal(np.asarray(before[:, cut + 1:]), np.asarray(after[:, cut + 1:]))
+
+
+@pytest.mark.parametrize("backend,head_dim,t,per_replica,want", [
+    ("tpu", 256, 8192, True, "fused"),       # the published widths at the cell's length
+    ("tpu", 128, 1536, True, "fused"),       # blocks of 512
+    ("tpu", 256, 8192, False, "blockwise"),  # mode="auto": GSPMD cannot partition a custom call
+    ("cpu", 256, 8192, True, "blockwise"),
+    ("gpu", 256, 8192, True, "blockwise"),
+    ("tpu", 16, 8192, True, "blockwise"),    # the tiny preset's heads
+    ("tpu", 192, 8192, True, "blockwise"),   # no whole number of lane registers
+    ("tpu", 512, 8192, True, "blockwise"),   # wider than the blocks were sized for
+    ("tpu", 256, 8000, True, "blockwise"),   # ragged lengths
+    ("tpu", 256, 8192 + 256, True, "blockwise"),
+    ("tpu", 256, 48, True, "blockwise"),
+])
+def test_attention_lowering_rule(backend, head_dim, t, per_replica, want):
+    assert seq.attention_lowering(backend, head_dim, t, per_replica=per_replica) == want
+
+
+@pytest.mark.parametrize("t,block", [(8192, 1024), (3072, 1024), (1536, 512), (512, 512)])
+def test_fused_attention_blocks_divide_the_sequence(t, block):
+    blocks = seq.fused_attention_blocks(t, 256)
+    assert {blocks.block_q, blocks.block_kv, blocks.block_q_dkv, blocks.block_kv_dkv} == {block}
+    assert blocks.use_fused_bwd_kernel and blocks.has_backward_blocks
+    assert block % blocks.block_kv_compute == 0 and block % blocks.block_kv_dkv_compute == 0
+
+
+@pytest.mark.parametrize("mode,want", [("shard_map", True), ("auto", False), ("shard_map_unchecked", True)])
+def test_a_call_knows_whether_it_is_traced_per_replica(mode, want):
+    """Inside the wrap's ``shard_map`` (with and without its replication
+    check) the traced code sees a mesh whose every axis is manual; under
+    ``jit`` over the same mesh of eight it sees none, and eight devices."""
+    mesh = Mesh(np.array(jax.devices()), ("data",))
+    seen = []
+
+    def f(x):
+        seen.append(seq._traced_per_replica())
+        return jax.lax.map(jax.checkpoint(lambda row: seen.append(seq._traced_per_replica()) or 2 * row), x)
+
+    x = jnp.ones((len(jax.devices()), 4))
+    if mode == "auto":
+        jax.jit(f, in_shardings=NamedSharding(mesh, P("data")))(x)
+    else:
+        wrapped = jax.shard_map(
+            f, mesh=mesh, in_specs=P("data"), out_specs=P("data"), check_vma=mode == "shard_map"
+        )
+        jax.jit(wrapped)(x)
+    assert len(jax.devices()) > 1 and seen and all(s is want for s in seen)
+
+
+def test_the_tiny_preset_and_the_cpu_stay_on_the_blockwise_path(system, tiny, monkeypatch):
+    """Whatever the model, a CPU run never reaches the kernel."""
+    monkeypatch.setattr(seq, "_fused_causal_attention", lambda *a, **k: pytest.fail("the kernel on the CPU"))
+    model = _model(system, tiny)
+    params, _ = model.init(jax.random.key(0), jnp.zeros((1, 48), jnp.int32))
+    model._attention(params["layers"][3]["mixer"], jnp.ones((1, 48, tiny["hidden_size"])))
+
+
+def test_the_kernel_runs_inside_the_wraps_shard_map():
+    """A ``pallas_call`` inside ``shard_map`` over the data axis as the step
+    builders wrap it (``check_vma=False``: the library's kernels declare no
+    ``vma``, so the replication check refuses them while a step is traced, and
+    the wraps have it off), forward and backward: each device attends to its
+    own sequence."""
+    mesh = Mesh(np.array(jax.devices()[:2]), ("data",))
+    q, k, v, _ = (jnp.concatenate([a, a[:, ::-1]]) for a in _qkvw(jnp.float32, t=512))
+    fused = _lowerings(jnp.float32)["fused"]
+    grad = jax.grad(lambda q, k, v: jnp.sum(jnp.sin(fused(q, k, v))), argnums=(0, 1, 2))
+    wrapped = jax.jit(jax.shard_map(
+        grad, mesh=mesh, in_specs=P("data"), out_specs=P("data"), check_vma=False
+    ))
+    for got, want in zip(wrapped(q, k, v), grad(q, k, v)):
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+
+
+# -- the fused lowering compiled for the chip, with no chip attached ----------
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One described v5e chip: the TPU's compiler is installed, and compiles
+    for a chip that is described and not attached."""
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("t,hq,hkv,d", [(8192, 16, 2, 256), (1536, 4, 2, 128), (3072, 8, 8, 256)])
+def test_the_fused_lowering_compiles_for_a_v5e(v5e, t, hq, hkv, d):
+    """Forward and backward kernels at the blocks the rule picks (the first
+    shape is the token cell's): Mosaic refuses here what it would refuse on
+    the chip, a block that does not fit VMEM first of all."""
+    sds = lambda h: jax.ShapeDtypeStruct((1, t, h, d), jnp.bfloat16, sharding=v5e)
+    fused = lambda q, k, v: seq._fused_causal_attention(q, k, v, scale=d ** -0.5, compute_dtype=jnp.bfloat16)
+    grad = jax.grad(lambda q, k, v: jnp.sum(jax.checkpoint(fused)(q, k, v).astype(jnp.float32)), argnums=(0, 1, 2))
+    text = jax.jit(grad).lower(sds(hq), sds(hkv), sds(hkv)).compile().as_text()
+    assert "splash_mha_fwd" in text and "splash_mha_dkv" in text and "tpu_custom_call" in text
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("sequences,method", [(1, "train_step"), (2, "train_step_many")])
+def test_the_token_cells_step_compiles_for_a_v5e(v5e, published, system, monkeypatch, sequences, method):
+    """The whole step at published widths (the check's single step at one
+    sequence, the timed 8-step program at two): inside it XLA keeps buffers of
+    its own in VMEM, and a block that compiled alone did not fit (PERF.md, PR
+    29). About a minute each."""
+    from tpuddp.training.train_state import create_train_state
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # this process sees the CPU
+    mesh = Mesh(np.array(list(v5e.device_set)), ("data",))
+    cell = cells.load_cell(WORKLOAD)
+    model, ddp = system.build_ddp(cell, mesh)
+    t = published["tokens"]["seq_len"]
+    state = jax.eval_shape(
+        lambda k: create_train_state(model, ddp.optimizer, k, jnp.zeros((1, t), jnp.int32)), jax.random.key(0)
+    )
+    state = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=NamedSharding(mesh, P())), state)
+    lead, spec = ((), P("data")) if method == "train_step" else ((8,), P(None, "data"))
+    rows = lambda dtype: jax.ShapeDtypeStruct((*lead, sequences, t), dtype, sharding=NamedSharding(mesh, spec))
+    batch = (rows(jnp.int32), rows(jnp.int32), rows(jnp.float32))
+    text = jax.jit(getattr(ddp, method)).lower(state, batch).compile().as_text()
+    assert "splash_mha_fwd" in text and "splash_mha_dkv" in text

@@ -15,8 +15,11 @@ The training forward returns :class:`~tpuddp.nn.sequence.DeferredLogits`
 (the criterion takes the loss from the hidden states in chunks); evaluation
 returns ``(B, T, V)`` float32 logits. Each layer's mixer and expert layer are
 recomputed in the backward pass of a training step. Inside a layer every
-loop is rolled (sequences, attention's query blocks, the DeltaNet chunks and
-the rows of its inverse, the expert rounds, the loss chunks); the layers of
+loop is rolled (sequences, the DeltaNet chunks and the rows of its inverse,
+the expert rounds, the loss chunks, and attention's query blocks where
+attention runs blockwise: ``nn/sequence.py`` lowers it to one fused kernel a
+sequence on a TPU at the published widths, and to rolled query blocks on the
+CPU, at the tiny preset and under ``mode="auto"``); the layers of
 the period are a Python loop, because their parameters are one tree a layer,
 as the published checkpoint has them, and each layer keeps its own scope name.
 
@@ -220,7 +223,7 @@ class HybridMoELM(Module):
         with _prof.scope("attention"):
             o = seq.causal_attention(
                 q, k, v, scale=d ** -0.5, compute_dtype=cd, q_block=self.attention_q_block
-            )
+            )  # q_block: the blockwise lowering's; the fused kernel has its own
         with _prof.scope("o_proj"):
             o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
             return seq.matmul(o.reshape(b, t, hq * d), p["o_proj"], cd)

@@ -4,6 +4,15 @@ causal attention over grouped key/value heads that never holds a ``T x T``
 score block, and a head-plus-cross-entropy that never holds ``(B, T, V)``
 logits.
 
+Attention has two lowerings and picks one from what a call can see
+(:func:`attention_lowering`): on a TPU, for heads of 128 or 256 and
+sequences that are a multiple of 512, traced once a device (the default
+``mode="shard_map"`` step, a one-device process), the library's fused
+kernel, which keeps score blocks, softmax statistics and the accumulator in
+VMEM and has its own backward kernels; everywhere else (the CPU, the tiny
+preset's heads of 16, ragged lengths, ``mode="auto"``) query blocks in plain
+XLA, each a ``q_block x T`` score block in HBM.
+
 Functions over explicit arrays (the family file ``models/hybrid_moe.py``
 owns the parameter tree). Products take their inputs in ``compute_dtype``
 and accumulate in float32 (:func:`matmul`); statistics, softmax, the loss and
@@ -112,13 +121,51 @@ def _attend_block(q, q_start, k, v, *, scale, compute_dtype):
 def causal_attention(q, k, v, *, scale: float, compute_dtype, q_block: int = 512):
     """Causal softmax attention, each key/value head serving ``Hq / Hkv``
     query heads. ``q``: ``(B, T, Hq, D)``; ``k``, ``v``: ``(B, T, Hkv, D)``.
-    Queries go ``q_block`` at a time, so the largest score block is ``q_block
-    x T`` a head, and each block is recomputed in the backward pass, so no
-    block's probabilities are kept. About ``sqrt(T / q_block)`` neighbouring
-    blocks form a group that shares the keys up to the group's end and one
-    rolled loop, so the program holds a loop a group and not a copy a block;
-    what a group computes above its blocks' diagonals is masked (at 16 blocks,
-    160 block products where 136 are needed)."""
+    One contract (product inputs in ``compute_dtype``, float32 accumulation
+    and softmax statistics, the exact causal mask, the output in ``q``'s
+    type) and two lowerings, chosen by :func:`attention_lowering` from the
+    backend, the shapes and where the call is traced: one fused kernel that
+    keeps every score block in VMEM, or ``q_block`` queries at a time in plain
+    XLA (:func:`_blockwise_causal_attention`)."""
+    lowering = attention_lowering(
+        jax.default_backend(), q.shape[-1], q.shape[1], per_replica=_traced_per_replica()
+    )
+    if lowering == "fused":
+        return _fused_causal_attention(q, k, v, scale=scale, compute_dtype=compute_dtype)
+    return _blockwise_causal_attention(q, k, v, scale=scale, compute_dtype=compute_dtype, q_block=q_block)
+
+
+def attention_lowering(backend: str, head_dim: int, t: int, *, per_replica: bool) -> str:
+    """``"fused"`` or ``"blockwise"``. The kernel is written for the TPU's
+    tiles: a head fills whole 128-lane registers and the sequence whole
+    blocks (:func:`fused_attention_blocks`); it is a custom call, which GSPMD
+    cannot partition, so it serves only a call that is traced once a device
+    (``per_replica``). Everything else, the CPU first, takes the blockwise
+    path."""
+    if backend == "tpu" and per_replica and fused_attention_blocks(t, head_dim) is not None:
+        return "fused"
+    return "blockwise"
+
+
+def _traced_per_replica() -> bool:
+    """Whether what is being traced runs once a device: inside ``shard_map``
+    (every axis of the mesh manual: the default ``mode="shard_map"`` step), or
+    in a process that has one device. Under ``jit`` over a mesh of several
+    (``mode="auto"``) XLA partitions the program after the fact."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.empty:
+        return set(mesh.manual_axes) == set(mesh.axis_names)
+    return jax.device_count() == 1
+
+
+def _blockwise_causal_attention(q, k, v, *, scale, compute_dtype, q_block):
+    """Queries go ``q_block`` at a time, so the largest score block is
+    ``q_block x T`` a head, and each block is recomputed in the backward pass,
+    so no block's probabilities are kept. About ``sqrt(T / q_block)``
+    neighbouring blocks form a group that shares the keys up to the group's
+    end and one rolled loop, so the program holds a loop a group and not a
+    copy a block; what a group computes above its blocks' diagonals is masked
+    (at 16 blocks, 160 block products where 136 are needed)."""
     b, t, hq, d = q.shape
     hkv = k.shape[2]
     q = q.reshape(b, t, hkv, hq // hkv, d)
@@ -139,6 +186,70 @@ def causal_attention(q, k, v, *, scale: float, compute_dtype, q_block: int = 512
         if start + whole * q_block < end:  # what is left of a sequence that is no multiple
             outs.append(block(q[:, start + whole * q_block:end], start + whole * q_block, keys, values))
     return jnp.concatenate(outs, axis=1).reshape(b, t, hq, d)
+
+
+# -- the fused lowering -----------------------------------------------------------
+
+_LANES = 128  # a vector register's minor width
+_WIDEST_HEAD = 256  # the blocks below fill VMEM at this width; a wider head does not compile with them
+
+
+def fused_attention_blocks(t: int, head_dim: int):
+    """The fused kernels' block sizes for sequences of ``t`` tokens and heads
+    of ``head_dim``, or ``None`` where the kernel is not for the shapes: a
+    head that is no whole number of lane registers or wider than the blocks
+    were sized for, or a sequence that is no multiple of 512 (at blocks of
+    256 and 128 the kernel is slower than the blockwise path: 6.3 and 18.8 ms
+    a forward against 12.3 at 8,192 tokens; PERF.md, PR 29).
+
+    Constants from that sweep, on a v5e at ``(8192, 256)``: blocks of 1024
+    queries and keys (512 where 1024 does not divide ``t``), the forward's
+    inner key step 256; the backward pass as one kernel (5 products where the
+    library's two kernels make 7), which writes a partial ``dq`` a key block
+    and sums them. Its key block stays 1024: 2048 read 0.3 ms a sequence
+    faster alone and then did not fit VMEM inside the whole step, where XLA
+    keeps buffers of its own there."""
+    if head_dim % _LANES or head_dim > _WIDEST_HEAD or t % 512:
+        return None
+    block = 1024 if t % 1024 == 0 else 512
+    return _splash()[0].BlockSizes(
+        block_q=block, block_kv=block, block_kv_compute=256,
+        block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=512,
+        use_fused_bwd_kernel=True,
+    )
+
+
+def _splash():
+    """The library's kernel and mask modules, imported where they are used:
+    they pull in Pallas and Mosaic, which nothing else of the package needs."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel, splash_attention_mask,
+    )
+
+    return splash_attention_kernel, splash_attention_mask
+
+
+def _fused_causal_attention(q, k, v, *, scale, compute_dtype, interpret: bool = False):
+    """The library's splash attention (``jax.experimental.pallas.ops.tpu``):
+    scores, causal mask, running softmax and the value product of a block in
+    one kernel, whose statistics and accumulator stay in VMEM, which skips the
+    blocks above the diagonal, and which brings its own backward kernels (they
+    recompute the scores from the saved log-sum-exp). Grouped heads are the
+    kernel's own: query head ``h`` reads key/value head ``h // (Hq / Hkv)``.
+    The kernel applies no scale, so ``q`` carries it in. ``interpret`` runs
+    the kernels in Pallas's interpreter: the CPU tests' way in."""
+    kernels, masks = _splash()
+    t, hq, d = q.shape[1:]
+    kernel = kernels.make_splash_mha(
+        masks.MultiHeadMask([masks.CausalMask((t, t))] * hq),
+        block_sizes=fused_attention_blocks(t, d), head_shards=1, q_seq_shards=1, interpret=interpret,
+    )
+    heads_first = lambda a: jnp.swapaxes(a, 1, 2)  # (B, H, T, D)
+    out = jax.vmap(kernel)(
+        heads_first(round_to(q, compute_dtype) * scale),
+        heads_first(round_to(k, compute_dtype)), heads_first(round_to(v, compute_dtype)),
+    )
+    return jnp.swapaxes(out, 1, 2).astype(q.dtype)
 
 
 # -- the head and its loss ------------------------------------------------------
