@@ -525,9 +525,7 @@ def _device_ms_denominator(ddp, state, stacked, scan):
     The denominator is only honest for rows dispatching the SAME compiled
     program it was measured under. ``--pipeline`` shares one wrap across its
     on/off rows (the pipeline's HLO-identity contract), so one derivation
-    covers both; ``--overlap`` compiles a DIFFERENT step program per row (K
-    interleaved collectives vs one trailing block), so each row re-derives
-    its denominator here instead of inheriting the other program's number."""
+    covers both."""
     metrics = None
     for _ in range(2):  # compile + warm
         state, metrics = ddp.train_step_many(state, stacked)
@@ -628,12 +626,7 @@ def bench_pipeline_pair(batch_per_chip=64, n_train=4096, repeats=2, scan=8):
     stacked = ddp.shard_stacked(stack_batches(first_chunk))
     device_ms = _device_ms_denominator(ddp, fresh_state(), stacked, scan)
     # one derivation for both rows is correct HERE because both rows
-    # dispatch this one wrap's program (see _device_ms_denominator — rows
-    # that change the step program, like --overlap's, must re-derive)
-    assert not (ddp.comm_overlap_meta or {}).get("enabled"), (
-        "pipeline A/B shares one device denominator; a segmented wrap "
-        "breaks that premise"
-    )
+    # dispatch this one wrap's program (see _device_ms_denominator)
 
     rows = {}
     for on in (False, True):
@@ -684,146 +677,6 @@ def bench_pipeline_pair(batch_per_chip=64, n_train=4096, repeats=2, scan=8):
         f"{rows[True]['loss_sums']} vs {rows[False]['loss_sums']}"
     )
     return rows[True]["sps"], rows[False]["sps"]
-
-
-def bench_overlap_pair(batch_per_chip=64, steps=96, hooks=("none", "bf16_ef")):
-    """The segmented backward/collective overlap A/B (``--overlap``): the
-    same fixed toy-MLP workload per hook, compiled twice — ``comm_overlap``
-    off (the barrier step: all collectives in one trailing block) and on
-    (bucket-aligned backward segments, each segment's collective issued
-    inside the backward walk, training/step.py). Per row:
-
-    - throughput + per-step latency (mean and p50/p99 over unfenced laps);
-    - ``wall_to_device_ratio`` with a PER-ROW device denominator — the two
-      modes compile DIFFERENT step programs, so a denominator staged under
-      one program is not the device time of the other
-      (:func:`_device_ms_denominator`);
-    - the overlap provenance (enabled/segments) and the HLO
-      collective-position evidence (:func:`tpuddp.parallel.comm
-      .hlo_overlap_evidence` over the lowered step): collective line
-      positions, compute line count, and how many backward-compute lines
-      fall between the first and last collective issue.
-
-    In-run assertions make the artifact self-verifying: bitwise
-    loss-trajectory parity overlap-on vs off for every hook row, and the ON
-    row's program holds >= 2 collectives with compute between them while the
-    OFF row's collectives form one block. CPU-rung honesty: the host backend
-    executes collectives inline, so the throughput delta here is dispatch
-    noise, not a latency-hiding win — the artifact's transferable claim is
-    the program SHAPE the interleaving evidence records, which is what a
-    real TPU's async collective scheduler exploits.
-
-    Returns ``(overlap_on_sps, overlap_off_sps)`` of the last hook for the
-    summary line."""
-    import jax
-    import jax.numpy as jnp
-
-    from tpuddp import nn, optim
-    from tpuddp.models import ToyMLP
-    from tpuddp.observability import percentiles as _pct
-    from tpuddp.parallel import comm as comm_lib
-    from tpuddp.parallel import make_mesh
-    from tpuddp.parallel.ddp import DistributedDataParallel
-    from tpuddp.training.step import stack_batches
-
-    mesh = make_mesh(jax.devices())
-    n_chips = mesh.devices.size
-    global_batch = batch_per_chip * n_chips
-    rng = np.random.RandomState(7)
-    x = rng.randn(global_batch, 8, 8, 3).astype(np.float32)
-    y = rng.randint(0, 10, global_batch).astype(np.int32)
-    w = np.ones(global_batch, np.float32)
-    # a cap of 600 f32 elements splits ToyMLP(hidden=(16,))'s two Linears
-    # into separate buckets, so the segmented step genuinely gets K=2
-    cap = 600 * 4 / (1024 * 1024)
-
-    sps_pair = {}
-    for hook in hooks:
-        rows = {}
-        for overlap in (False, True):
-            ddp = DistributedDataParallel(
-                ToyMLP(hidden=(16,)), optim.Adam(1e-2),
-                nn.CrossEntropyLoss(), mesh=mesh, mode="shard_map",
-                comm_hook=hook, bucket_cap_mb=cap, comm_overlap=overlap,
-            )
-            state = ddp.init_state(jax.random.key(0), jnp.zeros((1, 8, 8, 3)))
-            meta = ddp.comm_overlap_meta
-            batch = ddp.shard((x, y, w))
-            # per-row device denominator over the pre-staged batch: the
-            # overlap knob changes the compiled program, so each mode's
-            # denominator comes from ITS program (the satellite fix)
-            stacked = ddp.shard_stacked(stack_batches([(x, y, w)] * 4))
-            device_ms = _device_ms_denominator(ddp, state, stacked, 4)
-            # fresh state: the denominator loop donated its buffers
-            state = ddp.init_state(jax.random.key(0), jnp.zeros((1, 8, 8, 3)))
-            # warm the per-step program (also builds ddp._train_step)
-            metrics = None
-            for _ in range(3):
-                state, metrics = ddp.train_step(state, batch)
-            float(np.sum(np.asarray(metrics["loss_sum"])))
-            # lowered-HLO evidence from the exact step being timed
-            xs, ys, ws = batch
-            ev = comm_lib.hlo_overlap_evidence(
-                ddp._train_step.jitted.lower(state, xs, ys, ws).as_text()
-            )
-            laps = []
-            t_prev = t0 = time.perf_counter()
-            for _ in range(steps):
-                state, metrics = ddp.train_step(state, batch)
-                t_now = time.perf_counter()
-                laps.append(t_now - t_prev)
-                t_prev = t_now
-            loss_sum = float(np.sum(np.asarray(metrics["loss_sum"])))  # fence
-            dt = time.perf_counter() - t0
-            final_loss = loss_sum / float(np.sum(np.asarray(metrics["n"])))
-            assert np.isfinite(final_loss), (hook, overlap)
-            # the parity trajectory: a fresh state through the first 8 steps,
-            # losses fetched per step (outside the timed region)
-            traj_state = ddp.init_state(jax.random.key(0), jnp.zeros((1, 8, 8, 3)))
-            traj = []
-            for _ in range(8):
-                traj_state, m = ddp.train_step(traj_state, batch)
-                mh = np.asarray(m["loss_sum"])
-                traj.append(float(np.sum(mh)))
-            pct = _pct(laps)
-            wall_ms = dt / steps * 1e3
-            name = (
-                f"toy_mlp b{batch_per_chip} comm {hook} "
-                + ("(overlap on)" if overlap else "(overlap off, barrier)")
-            )
-            sps = steps * global_batch / dt
-            extra = {
-                "comm_hook": hook,
-                "comm_overlap": bool(meta["enabled"]),
-                "comm_overlap_segments": meta["segments"],
-                "ms_per_step_p50": round((pct["p50"] or 0.0) * 1e3, 3),
-                "ms_per_step_p99": round((pct["p99"] or 0.0) * 1e3, 3),
-                "wall_to_device_ratio": round(wall_ms / device_ms, 3),
-                "device_ms_per_step": round(device_ms, 3),
-                "grad_comm_bytes_per_step": int(ddp.grad_comm_bytes_per_step),
-                "hlo_collective_lines": ev["collective_lines"],
-                "hlo_compute_lines": len(ev["compute_lines"]),
-                "hlo_interleaved_compute": len(ev["interleaved_compute"]),
-                "hlo_interleaved": ev["interleaved"],
-                "final_loss": round(final_loss, 6),
-            }
-            _record(name, sps / n_chips, wall_ms, None, extra)
-            rows[overlap] = {"sps": sps / n_chips, "traj": traj, "ev": ev,
-                             "meta": meta}
-        # self-verification: the bitwise-parity and program-shape claims
-        assert rows[True]["traj"] == rows[False]["traj"], (
-            f"{hook}: overlap on/off trajectories diverged: "
-            f"{rows[True]['traj']} vs {rows[False]['traj']}"
-        )
-        assert rows[True]["meta"]["enabled"] and rows[True]["meta"]["segments"] >= 2
-        ev_on, ev_off = rows[True]["ev"], rows[False]["ev"]
-        assert len(ev_on["collective_lines"]) >= 2 and ev_on["interleaved"], ev_on
-        assert not ev_off["interleaved"], ev_off
-        log(f"overlap A/B {hook}: K={rows[True]['meta']['segments']} segments, "
-            f"{len(ev_on['interleaved_compute'])} compute lines between "
-            "collectives (barrier: 0), trajectories bitwise-identical")
-        sps_pair = (rows[True]["sps"], rows[False]["sps"])
-    return sps_pair
 
 
 def bench_comm_matrix(batch_per_chip=64, steps=96, density=0.1):
@@ -1072,22 +925,6 @@ def main(argv=None):
             int8_sps, none_sps, out_path=out_path,
             metric="toy_mlp_int8_ef_train_samples_per_sec_per_chip",
             basis="comm-hook-none",
-        )
-        print(json.dumps(json_sanitize(summary), allow_nan=False), flush=True)
-        return
-    if "--overlap" in argv:
-        # the segmented backward/collective overlap A/B: per-hook on/off row
-        # pairs with per-row device denominators, latency percentiles, and
-        # the HLO collective-position interleaving evidence; the headline is
-        # the overlap-on throughput against the barrier row (BENCH_r08
-        # acceptance artifact)
-        from tpuddp.observability import json_sanitize
-
-        on_sps, off_sps = bench_overlap_pair()
-        summary = emit_summary(
-            on_sps, off_sps, out_path=out_path,
-            metric="toy_mlp_overlap_train_samples_per_sec_per_chip",
-            basis="overlap-off",
         )
         print(json.dumps(json_sanitize(summary), allow_nan=False), flush=True)
         return

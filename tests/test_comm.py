@@ -20,6 +20,8 @@ Pinned contracts:
   paths.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -31,6 +33,7 @@ from tpuddp.models import ToyMLP
 from tpuddp.parallel import comm as comm_lib
 from tpuddp.parallel import make_mesh
 from tpuddp.parallel.ddp import DistributedDataParallel
+from tpuddp.resilience import guard as guard_lib
 from tpuddp.training import checkpoint as ckpt
 from tpuddp.training.step import stack_batches
 
@@ -462,6 +465,148 @@ def test_int8_scan_fused_and_accumulation(cpu_devices):
     assert np.isfinite(comp)
     assert abs(comp - base) <= comm_lib.loss_parity_tol("int8_ef", base)
     assert np.any(np.asarray(st.comm_state) != 0)
+
+
+# ------------------------------- the one step program against a plain worker --
+
+HOOKS = ("none", "bf16_ef", "int8_ef", "topk_ef")
+DP = 4  # replicas of the data-parallel side
+SPLIT_CAP = cap_mb(600)  # ToyMLP(hidden=(16,))'s two Linears land in buckets of their own
+
+
+def _plain_worker(params, model_state, dispatches):
+    """One worker, no mesh, no wrap, no exchange: every cycle of micro-batches
+    is ONE Adam update on the mean gradient of their concatenation, which is
+    what data parallelism and accumulation both promise to equal. A dispatch
+    is a list of cycles, a cycle a list of ``(x, y, w)`` micro-batches; ``None``
+    stands for a dispatch the firewall skips (no update, no loss). Returns the
+    mean loss of every dispatch and the final parameters."""
+    model, criterion, optimizer = ToyMLP(hidden=(16,)), nn.CrossEntropyLoss(), optim.Adam(1e-2)
+
+    def loss_fn(p, x, y, w):
+        logits, _ = model.apply(p, model_state, x, nn.Context(train=True))
+        return criterion(logits, y, w)
+
+    @jax.jit
+    def update(p, opt_state, x, y, w):
+        loss, grads = jax.value_and_grad(loss_fn)(p, x, y, w)
+        p, opt_state = optimizer.update(grads, opt_state, p)
+        return p, opt_state, loss
+
+    opt_state = optimizer.init(params)
+    losses = []
+    for cycles in dispatches:
+        if cycles is None:
+            losses.append(None)
+            continue
+        total = 0.0
+        for micros in cycles:
+            x, y, w = (np.concatenate(a) for a in zip(*micros))
+            params, opt_state, loss = update(params, opt_state, x, y, w)
+            total += float(loss) * len(x)
+        losses.append(total / sum(len(m[0]) for c in cycles for m in c))
+    return losses, params
+
+
+@pytest.mark.parametrize("guard", [False, True], ids=["noguard", "guard"])
+@pytest.mark.parametrize("accum", [1, 2], ids=["a1", "a2"])
+@pytest.mark.parametrize("fused", [1, 4], ids=["k1", "k4"])
+@pytest.mark.parametrize("hook", HOOKS)
+def test_dp4_step_tracks_single_worker(cpu_devices, hook, fused, accum, guard):
+    """The data-parallel step over four replicas, ``fused`` updates a dispatch
+    and ``accum`` micro-batches an update, against the plain single worker:
+    the uncompressed exchange to float32 rounding at every dispatch and in
+    the final parameters, a compressed hook within the bound this file holds
+    it to. Under the guard a poisoned dispatch in the middle changes nothing
+    (parameters and the error-feedback residual are the ones before it, bit
+    for bit) and training goes on from there along the worker's trajectory."""
+    ddp = build(make_mesh(cpu_devices[:DP]), hook, accum=accum, cap=SPLIT_CAP, guard=guard)
+    state = ddp.init_state(KEY, jnp.zeros((1, 8, 8, 3)))
+    params0 = jax.device_get(state.params)
+    model_state0 = jax.device_get(state.model_state)
+
+    n_dispatches = 12 if hook == "topk_ef" else 5
+    seeds = iter(range(100, 100 + n_dispatches * fused * accum))
+    dispatches = [
+        [[make_batch(seed=next(seeds)) for _ in range(accum)] for _ in range(fused)]
+        for _ in range(n_dispatches)
+    ]
+    poisoned_at = 2 if guard else None
+
+    def dispatch(state, cycles):
+        micros = [m for cycle in cycles for m in cycle]
+        if len(micros) == 1:
+            state, m = ddp.train_step(state, ddp.shard(micros[0]))
+        else:
+            state, m = ddp.train_step_many(state, ddp.shard_stacked(stack_batches(micros)))
+        m = jax.device_get(m)
+        return state, float(np.sum(m["loss_sum"]) / np.sum(m["n"]))
+
+    losses, plan = [], []
+    for i, cycles in enumerate(dispatches):
+        if i == poisoned_at:
+            before = jax.device_get((state.params, state.opt_state, state.comm_state))
+            bad = [[(np.full_like(x, np.nan), y, w) for x, y, w in c] for c in cycles]
+            state, _ = dispatch(state, bad)
+            after = jax.device_get((state.params, state.opt_state, state.comm_state))
+            for a, b in zip(jax.tree_util.tree_leaves(after), jax.tree_util.tree_leaves(before)):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+            assert guard_lib.read_skip_counters(state) == (fused, fused)
+            plan.append(None)
+            losses.append(None)
+        state, loss = dispatch(state, cycles)
+        plan.append(cycles)
+        losses.append(loss)
+
+    want, want_params = _plain_worker(params0, model_state0, plan)
+    residual = state.comm_state
+    if hook == "none":
+        assert residual is None
+        for got, ref in zip(losses, want):
+            assert (got is None) == (ref is None)
+            if ref is not None:
+                assert got == pytest.approx(ref, rel=2e-5)
+        for a, b in zip(
+            jax.tree_util.tree_leaves(jax.device_get(state.params)),
+            jax.tree_util.tree_leaves(want_params),
+        ):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-6)
+    else:
+        assert np.isfinite(losses[-1])
+        assert abs(losses[-1] - want[-1]) <= comm_lib.loss_parity_tol(hook, want[-1]), (
+            losses, want,
+        )
+        assert np.any(np.asarray(residual) != 0)  # error feedback is live
+
+
+_ITEMSIZE = {"f32": 4, "bf16": 2, "i8": 1, "i32": 4}
+
+
+def _collective_operand_bytes(text):
+    """Bytes entering every cross-replica collective of a lowered (StableHLO)
+    program, from each operation's own operand types."""
+    total = 0
+    for op in re.finditer(
+        r"stablehlo\.(all_reduce|all_gather|reduce_scatter|all_to_all|collective_permute)", text
+    ):
+        operands = re.search(r":\s*\(([^)]*)\)\s*->", text[op.end():]).group(1)
+        for shape in re.findall(r"tensor<([^>]*)>", operands):
+            *dims, dtype = shape.split("x")
+            total += int(np.prod([int(d) for d in dims], dtype=np.int64)) * _ITEMSIZE[dtype]
+    return total
+
+
+@pytest.mark.parametrize("hook", HOOKS)
+def test_wire_bytes_match_lowered_program(cpu_devices, hook):
+    """``grad_comm_bytes_per_step`` (what ``grad_wire_mb_per_step`` and the
+    history's byte counters report) is the operand bytes of the collectives
+    in the step program itself: values, scales and indices, buckets and
+    padding included; the gradient exchange is the step's only collective."""
+    for cap in (SPLIT_CAP, 25):
+        ddp = build(make_mesh(cpu_devices[:DP]), hook, cap=cap)
+        state = ddp.init_state(KEY, jnp.zeros((1, 8, 8, 3)))
+        text = jax.jit(ddp.train_step).lower(state, ddp.shard(make_batch())).as_text()
+        assert _collective_operand_bytes(text) == ddp.grad_comm_bytes_per_step, (hook, cap)
 
 
 # ------------------------------------------------- hierarchical topology --

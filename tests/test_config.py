@@ -201,6 +201,37 @@ def test_training_config_refuses_unknown_keys():
     assert ok["resume"] is True and ok["synthetic_n"] == [64, 32]
 
 
+def _overlap_in_settings():
+    cfg.training_config({"training": {"comm_overlap": "auto"}})
+
+
+def _overlap_to_the_wrap():
+    from tpuddp import nn, optim
+    from tpuddp.models import ToyMLP
+    from tpuddp.parallel.ddp import DistributedDataParallel
+
+    DistributedDataParallel(ToyMLP(), optim.Adam(1e-3), nn.CrossEntropyLoss(), comm_overlap=False)
+
+
+def _overlap_to_the_accelerator():
+    from tpuddp.accelerate import Accelerator
+
+    Accelerator(comm_overlap="auto")
+
+
+@pytest.mark.parametrize("give,error,says", [
+    (_overlap_in_settings, ValueError, "unknown training key.*comm_overlap"),
+    (_overlap_to_the_wrap, TypeError, "unexpected keyword argument 'comm_overlap'"),
+    (_overlap_to_the_accelerator, TypeError, "unexpected keyword argument 'comm_overlap'"),
+], ids=["settings", "ddp", "accelerator"])
+def test_the_removed_overlap_knob_is_refused_as_unknown(give, error, says):
+    """The step is one program, so ``comm_overlap`` selects nothing: a settings
+    file or a caller that still names it is refused like any stale key, by the
+    checks that refuse every unknown name (no special case, no shim)."""
+    with pytest.raises(error, match=says):
+        give()
+
+
 def test_serving_config_defaults_and_merge():
     out = cfg.serving_config({})
     assert out == cfg.SERVING_DEFAULTS

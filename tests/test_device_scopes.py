@@ -33,12 +33,12 @@ MODELS = {
 }
 
 # path -> (DDP kwargs, the method lowered, whether it takes a stacked batch)
-_SEGMENTED = {"comm_overlap": True, "bucket_cap_mb": 600 * 4 / MB, "comm_hook": "bf16_ef"}
+_COMPRESSED = {"bucket_cap_mb": 600 * 4 / MB, "comm_hook": "bf16_ef"}  # several buckets
 PATHS = {
     "train_step": ({"clip_grad_norm": 1.0, "guard": True}, "train_step", False),
     "train_step_many": ({}, "train_step_many", True),
     "accumulate": ({"grad_accumulation": 2, "guard": True}, "train_step_many", True),
-    "segmented": (_SEGMENTED, "train_step", False),
+    "compressed_hook": (_COMPRESSED, "train_step", False),
     "weight_update_sharded": (
         {"weight_update_sharding": True, "clip_grad_norm": 1.0}, "train_step", False,
     ),
@@ -52,7 +52,7 @@ PHASES = {
     "train_step": _TRAIN | {profiling.CLIP, profiling.GUARD},
     "train_step_many": _TRAIN,
     "accumulate": _TRAIN | {profiling.GUARD},
-    "segmented": _TRAIN,
+    "compressed_hook": _TRAIN,
     "weight_update_sharded": _TRAIN | {profiling.CLIP},
     "eval_step": {profiling.AUGMENT, profiling.FORWARD, profiling.LOSS, profiling.METRICS},
 }
@@ -116,8 +116,6 @@ def _parametrised_layer_paths(params):
 def test_every_step_program_carries_its_scopes(cpu_devices, model_name, path):
     ddp, state, lowered = _lower(cpu_devices, model_name, path)
     text = lowered.as_text(debug_info=True)
-    if path == "segmented":
-        assert ddp.comm_overlap_meta["enabled"], ddp.comm_overlap_meta
     want = set(PHASES[path])
     if jax.tree_util.tree_leaves(state.model_state) and path != "eval_step":
         want.add(profiling.BUFFERS)

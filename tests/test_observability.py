@@ -238,6 +238,35 @@ def test_schema_validator_accepts_writer_output(mesh, tmp_path):
     assert meta["api"] == "native"
 
 
+_BARRIER = {"enabled": False, "segments": None, "reason": "barrier step"}
+
+
+def test_run_meta_records_the_one_step_program(mesh, tmp_path):
+    """A run's header says what its exchange is, and since the step is one
+    program that is a constant: from the wrap, through the epoch driver, into
+    ``run_meta.comm.overlap``; the managed wrapper records the same."""
+    from tpuddp.accelerate import Accelerator
+
+    ddp, _ = small_run(mesh, str(tmp_path), num_epochs=1)
+    assert ddp.comm_overlap_meta == _BARRIER
+    meta = read_history(tmp_path / "history.jsonl")[0]
+    assert meta["type"] == "run_meta" and meta["comm"] == {"overlap": _BARRIER}
+    assert Accelerator().comm_overlap_meta == _BARRIER
+
+
+@pytest.mark.parametrize("comm,valid", [
+    ({"overlap": _BARRIER}, True),
+    # a history written while the segmented step existed still reads
+    ({"overlap": {"enabled": True, "segments": 3, "reason": None}}, True),
+    (None, True),  # meshless and serving headers
+    ({"something": 1}, False),  # a v10 header's comm block needs its overlap member
+    (7, False),
+], ids=["barrier", "stored_segmented", "null", "no_overlap_member", "not_a_block"])
+def test_schema_v10_comm_block(comm, valid):
+    rec = schema_mod.make_run_meta(world_size=8, comm=comm)
+    assert (schema_mod.validate_record(rec) == []) == valid
+
+
 def test_schema_rejects_unknown_type_and_missing_header(tmp_path):
     good_meta = schema_mod.make_run_meta(comm_hook="none")
     good_event = stamp("event", {"event": "x"})

@@ -45,7 +45,7 @@ def _run_meta(**overrides):
         "comm_topology": "hierarchical",
         "pipeline": {"depth": 2, "host_workers": 2, "sync_readback": False},
         "scan_steps": 8,
-        "comm": {"overlap": {"enabled": True, "segments": 2}},
+        "comm": {"overlap": {"enabled": False, "segments": None, "reason": "barrier step"}},
         "snapshot": False,
         "tuning": None,
         "grad_comm_bytes_per_update": 0,
@@ -147,13 +147,6 @@ def _arm_comm_topology(d):
     ])
 
 
-def _arm_comm_overlap(d):
-    _write_history(d, [
-        _run_meta(comm={"overlap": {"enabled": False, "reason": "off"}}),
-        _epoch(),
-    ])
-
-
 def _arm_snapshot_backlog(d):
     _write_history(d, [
         _run_meta(snapshot={"every_steps": 50, "inflight": 1}),
@@ -224,7 +217,6 @@ _RULE_BUILDERS = {
     "span_dispatch_share": _arm_span_dispatch,
     "comm_hook_uncompressed": _arm_comm_hook,
     "comm_topology_flat_multihost": _arm_comm_topology,
-    "comm_overlap_disabled": _arm_comm_overlap,
     "snapshot_writer_backlog": _arm_snapshot_backlog,
     "snapshot_cadence_hot": _arm_snapshot_cadence,
     "serving_low_occupancy_linger": _arm_serving_linger,

@@ -30,17 +30,7 @@ fields (host_stall_ms / inflight_depth / staging_queue_depth), bitwise-equal
 checkpoints leaf for leaf, and byte-identical step HLO — the async pipeline's
 "zero semantic cost" contract, enforced every gate run.
 
-Overlap gate (after the pipeline gate): a ``comm_overlap: true`` dryrun and
-a ``comm_overlap: false`` dryrun of the same seed (bucket cap pinned so the
-worker's ToyMLP splits into K>=2 segments) must land bitwise-equal
-checkpoints leaf for leaf, the segmented history must validate under schema
-v10 with a run_meta ``comm.overlap`` provenance block reporting
-``enabled: true`` and ``segments >= 2``, and the dedicated HLO tests must
-show K interleaved collectives overlap-on vs one trailing block overlap-off
-— the segmented backward's "program shape changes, semantics don't"
-contract, enforced every gate run.
-
-Compression-matrix gate (after the overlap gate): dryrun trainings across
+Compression-matrix gate (after the pipeline gate): dryrun trainings across
 the comm hook x topology grid (none/bf16_ef/int8_ef/topk_ef x
 flat/hierarchical) must each produce a schema-valid history whose run_meta
 carries the comm accounting; the quantized/sparse hooks must show their
@@ -1012,112 +1002,6 @@ def _pipeline_gate(env) -> int:
     return 0
 
 
-def _overlap_gate(env) -> int:
-    """Backward/comm-overlap leg (ISSUE 17): a ``comm_overlap: true`` dryrun
-    must produce a schema-v10-valid history whose run_meta ``comm.overlap``
-    block records ``enabled: true`` with ``segments >= 2``, land bitwise-
-    identical checkpoints to a ``comm_overlap: false`` run of the same seed,
-    and the HLO tests must show the K interleaved collectives overlap-on
-    that barrier mode lacks (the program shape is the claim; the bitwise
-    parity is the proof that it cost nothing)."""
-    import json
-
-    import numpy as np
-
-    inspect = os.path.join(REPO, "tools", "tpuddp_inspect.py")
-    worker = os.path.join(REPO, "tests", "_chaos_train_worker.py")
-    with tempfile.TemporaryDirectory(prefix="tpuddp_overlap_gate_") as tmp:
-        base_env = dict(env)
-        base_env.update({
-            "XLA_FLAGS": "--xla_force_host_platform_device_count=4",
-            "TPUDDP_BACKEND": "cpu",
-            "PYTHONPATH": REPO + os.pathsep + env.get("PYTHONPATH", ""),
-        })
-        dirs = {}
-        for mode, flag in (("on", "true"), ("off", "false")):
-            out_dir = os.path.join(tmp, mode)
-            os.makedirs(out_dir)
-            dirs[mode] = out_dir
-            worker_env = dict(base_env)
-            # bucket_cap_mb=2.0 splits the worker's ToyMLP (3072->256->128
-            # ->10 on 32x32x3 synthetic CIFAR) into 2 buckets whose edge
-            # lands on a layer boundary, so overlap-on genuinely runs K=2
-            # segments rather than degenerating to the barrier program.
-            worker_env["TPUDDP_CHAOS_TRAINING"] = (
-                '{"comm_hook": "bf16_ef", "bucket_cap_mb": 2.0, '
-                '"comm_overlap": %s, "step_stats_every": 4}' % flag
-            )
-            rc = subprocess.call(
-                [sys.executable, "-u", worker, out_dir, "2"],
-                cwd=REPO, env=worker_env,
-            )
-            if rc != 0:
-                print(f"overlap gate: {mode} dryrun exited {rc}",
-                      file=sys.stderr)
-                return rc or 1
-        history = os.path.join(dirs["on"], "history.jsonl")
-        rc = subprocess.call(
-            [sys.executable, inspect, "--validate", history],
-            cwd=REPO, env=env,
-        )
-        if rc != 0:
-            print("overlap gate: segmented history.jsonl failed validation",
-                  file=sys.stderr)
-            return rc
-        with open(history) as f:
-            records = [json.loads(line) for line in f if line.strip()]
-        metas = [r for r in records if r.get("type") == "run_meta"]
-        overlaps = [
-            (m.get("comm") or {}).get("overlap") or {} for m in metas
-        ]
-        if not overlaps or any(
-            not o.get("enabled") or int(o.get("segments") or 0) < 2
-            for o in overlaps
-        ):
-            print("overlap gate: run_meta comm.overlap must report "
-                  "enabled=true with segments >= 2, got "
-                  f"{overlaps!r}", file=sys.stderr)
-            return 1
-        # bitwise parity: segmentation reorders the collectives inside the
-        # step, it must not move a single bit of the TrainState — params,
-        # moments, EF residuals (comm_state), counters, all of it.
-        for fname in ("ckpt_0.npz", "ckpt_1.npz"):
-            a = np.load(os.path.join(dirs["on"], fname), allow_pickle=False)
-            b = np.load(os.path.join(dirs["off"], fname), allow_pickle=False)
-            if sorted(a.files) != sorted(b.files):
-                print(f"overlap gate: {fname} key sets differ",
-                      file=sys.stderr)
-                return 1
-            for k in a.files:
-                if a[k].dtype.kind in "SU" or b[k].dtype.kind in "SU":
-                    ok = bool(np.array_equal(a[k], b[k]))
-                else:
-                    ok = a[k].tobytes() == b[k].tobytes()
-                if not ok:
-                    print(
-                        f"overlap gate: {fname} leaf {k!r} differs between "
-                        "segmented and barrier runs", file=sys.stderr,
-                    )
-                    return 1
-        # HLO interleaving: the dedicated tests lower the step program under
-        # both configs and assert K collectives with compute between them
-        # overlap-on vs a single trailing block overlap-off. Plain env:
-        # tests/conftest.py owns its own 8-device XLA_FLAGS.
-        rc = subprocess.call(
-            [
-                sys.executable, "-m", "pytest", "-q",
-                "tests/test_overlap.py", "-k", "hlo",
-                "-p", "no:cacheprovider",
-            ],
-            cwd=REPO, env=env,
-        )
-        if rc != 0:
-            print("overlap gate: HLO interleaving tests failed",
-                  file=sys.stderr)
-            return rc
-    return 0
-
-
 def _mesh_gate(env) -> int:
     """2-D mesh leg (ISSUE 14): ``tools/bench_mesh.py --quick`` trains
     transformer_small TP=2xDP=2 AND pure DP=4 at matched global batch
@@ -1734,9 +1618,6 @@ def main(argv=None):
     if rc != 0:
         return rc
     rc = _pipeline_gate(env)
-    if rc != 0:
-        return rc
-    rc = _overlap_gate(env)
     if rc != 0:
         return rc
     rc = _comm_matrix_gate(env)

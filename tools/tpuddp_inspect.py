@@ -272,8 +272,8 @@ def summarize_history(path: str) -> None:
                   f"recovery_attempts={sur.get('recovery_attempts')} "
                   f"retry_budget={sur.get('retry_budget')}")
         # comm provenance (required since schema v10; null = meshless /
-        # serving header): the overlap sub-block says whether the backward
-        # issued its collectives per segment and into how many segments
+        # serving header): histories written before the step became one
+        # program may say the backward issued its collectives per segment
         comm = m.get("comm")
         if isinstance(comm, dict):
             ov = comm.get("overlap") or {}
@@ -583,24 +583,6 @@ def summarize_trace(path: str) -> None:
             for k, d in by_kind.most_common()
         ]
         _print_table(rows, ["kind", "spans", "ms", "share"])
-    # per-segment collective digest (segmented-backward overlap): the
-    # annotation spans are named grad_comm.seg<k>, one per backward segment,
-    # so an overlapped run shows K distinct collective rows here where a
-    # barrier run shows the single grad_comm span
-    seg_counts = collections.Counter(
-        e.get("name") for e in spans
-        if str(e.get("name") or "").startswith("grad_comm.seg")
-    )
-    if seg_counts:
-        print(f"\ncollective segments ({len(seg_counts)}):")
-        for name in sorted(seg_counts):
-            a = next(
-                (e.get("args") or {} for e in spans if e.get("name") == name),
-                {},
-            )
-            print(f"  {name}: {seg_counts[name]} span(s) "
-                  f"layers={a.get('layers')} flat={a.get('flat')} "
-                  f"buckets={a.get('buckets')}")
     slowest = meta.get("slowest") or []
     if slowest:
         print(f"\nslowest spans (top {len(slowest)}):")

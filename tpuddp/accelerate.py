@@ -1313,7 +1313,6 @@ class Accelerator:
         topk_density: float = comm_lib.DEFAULT_TOPK_DENSITY,
         guard=None,
         augment=None,
-        comm_overlap="auto",
     ):
         """``fuse_steps``: K > 1 batches per-step calls into one compiled
         lax.scan dispatch (the managed analog of the native scan fusion) —
@@ -1412,31 +1411,9 @@ class Accelerator:
         self.topk_density = float(topk_density)
         comm_lib.bucket_topk(1, self.topk_density)  # range-validate eagerly
         self.guard = guard_lib.resolve_guard(guard)
-        # comm_overlap is accepted for config parity with the explicit API,
-        # but the managed path has no collective of its own to stage: XLA's
-        # partitioner inserts the psum inside backward, so there is no seam
-        # to issue per-segment collectives through. "auto"/False record the
-        # disabled provenance; True refuses rather than silently running the
-        # barrier program under an overlap label.
-        from tpuddp.parallel.ddp import _normalize_overlap
-
-        overlap = _normalize_overlap(comm_overlap)
-        if overlap is True:
-            raise ValueError(
-                "comm_overlap=true needs the explicit API "
-                "(DistributedDataParallel / train_native.py, mode="
-                "'shard_map'): the managed path's collective is XLA-inserted "
-                "and cannot be issued per backward segment"
-            )
-        self.comm_overlap_meta = {
-            "enabled": False,
-            "segments": None,
-            "reason": (
-                "disabled" if overlap is False else
-                "managed path: the gradient collective is XLA-inserted, not "
-                "issued per segment"
-            ),
-        }
+        # run_meta's comm.overlap (schema v10): the managed path's exchange is
+        # XLA's own psum behind the backward pass, as the explicit path's is
+        self.comm_overlap_meta = dict(comm_lib.OVERLAP_META)
         self.augment = augment
         # typed event dicts from the last load_state's elastic reshard (a
         # topology_change when the restored state was written on a different

@@ -59,17 +59,6 @@ TRAINING_DEFAULTS = {
     # "local" axis, COMPRESSED inter-host exchange over "host", all-gather —
     # only the compressed shard crosses the slow link. Explicit path
     # (mode: shard_map) only; excludes weight_update_sharding.
-    "comm_overlap": "auto",  # segmented-backward execution (training/step.py):
-    # true/auto stage the backward pass as per-segment VJP closures whose
-    # segment boundaries align with bucket_cap_mb buckets, issuing each
-    # segment's gradient collective the moment its buckets materialize while
-    # the next segment's backward compute proceeds — torch DDP's ready-bucket
-    # overlap, natively in JAX. Bitwise-identical loss trajectory to the
-    # barrier step. "auto" (default) enables it only where it genuinely
-    # segments (flat topology, mode: shard_map, Sequential model, no WUS/
-    # remat/TP, and >= 2 bucket-aligned segments) and quietly keeps the
-    # barrier step elsewhere; true refuses ineligible combos loudly; false
-    # pins the barrier step.
     "topk_density": 0.1,  # comm_hook: topk_ef's keep fraction per bucket
     # (int8 values + int32 indices + per-bucket scale on the wire; 0.1 =>
     # ~87.5% fewer gradient bytes, with the unsent complement riding the

@@ -7,7 +7,7 @@ directory's artifacts:
 - ``history.jsonl``       — run_meta provenance + epoch/step_stats/serving/
                             decode windows (schema.py, v12 reader);
 - ``trace_<role>.json``   — the causal span trees (dispatch/stage/readback/
-                            collective time shares, overlap segment digests);
+                            collective time shares);
 - ``*.writer.json``       — the async snapshot writer's sidecars (backlog,
                             write seconds, skipped-queue-full counts).
 
@@ -174,7 +174,6 @@ def _span_features(traces: List[dict]) -> dict:
     if not traces:
         return {"available": False}
     by_cat: Dict[str, float] = {}
-    overlap_segments = set()
     spans = 0
     dropped = 0
     for payload in traces:
@@ -187,9 +186,6 @@ def _span_features(traces: List[dict]) -> dict:
             cat = str(e.get("cat") or "")
             dur = _num(e.get("dur"), 0.0) or 0.0
             by_cat[cat] = by_cat.get(cat, 0.0) + dur
-            name = str(e.get("name") or "")
-            if name.startswith("grad_comm.seg"):
-                overlap_segments.add(name)
     phase_total = sum(
         by_cat.get(c, 0.0)
         for c in ("dispatch", "stage", "readback", "collective")
@@ -204,7 +200,6 @@ def _span_features(traces: List[dict]) -> dict:
         "dropped": dropped,
         "time_us_by_cat": by_cat,
         "shares": shares,
-        "overlap_segment_names": sorted(overlap_segments),
     }
 
 
@@ -278,9 +273,6 @@ def extract_evidence(run: dict) -> dict:
     False`` / None members) so rules index safely."""
     run_meta = run["run_meta"]
     records = run["records"]
-    comm_block = run_meta.get("comm") if isinstance(
-        run_meta.get("comm"), dict
-    ) else None
     return {
         "run_dir": run["run_dir"],
         "run_meta": {
@@ -293,7 +285,6 @@ def extract_evidence(run: dict) -> dict:
                 run_meta.get("pipeline"), dict
             ) else None,
             "scan_steps": run_meta.get("scan_steps"),
-            "overlap": (comm_block or {}).get("overlap"),
             "grad_comm_bytes_per_update": _num(
                 run_meta.get("grad_comm_bytes_per_update")
             ),
@@ -504,29 +495,6 @@ def _rule_comm_topology(ev):
     )
 
 
-def _rule_comm_overlap_off(ev):
-    """The gradient exchange ran as one trailing barrier although the world
-    is multi-chip: segmented-backward overlap interleaves bucket collectives
-    with backward compute (run_meta.comm.overlap records enabled: false)."""
-    rm = ev["run_meta"]
-    overlap = rm["overlap"]
-    world = rm["world_size"]
-    if not isinstance(overlap, dict) or overlap.get("enabled"):
-        return None
-    if not world or world <= 1:
-        return None
-    return _rec(
-        "comm_overlap_disabled", "comm", "training", "comm_overlap",
-        {"comm_overlap": True}, "step_time_ms_p50", 5.0,
-        "gradient exchange ran as a single trailing barrier — segmented "
-        "backward overlap hides bucket collectives behind backward compute",
-        [
-            cite("history.jsonl#run_meta", "comm.overlap", overlap),
-            cite("history.jsonl#run_meta", "world_size", int(world)),
-        ],
-    )
-
-
 def _rule_snapshot_backlog(ev):
     """The async snapshot writer dropped cadence points because its inflight
     queue was full (sidecar skipped_queue_full > 0): the durability contract
@@ -684,7 +652,6 @@ RULES = (
     ("span_dispatch_share", "pipeline", "trace", _rule_span_dispatch),
     ("comm_hook_uncompressed", "comm", "history", _rule_comm_uncompressed),
     ("comm_topology_flat_multihost", "comm", "history", _rule_comm_topology),
-    ("comm_overlap_disabled", "comm", "history", _rule_comm_overlap_off),
     ("snapshot_writer_backlog", "snapshot", "history", _rule_snapshot_backlog),
     ("snapshot_cadence_hot", "snapshot", "history", _rule_snapshot_cadence),
     ("serving_low_occupancy_linger", "serving", "history",

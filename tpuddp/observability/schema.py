@@ -66,13 +66,11 @@ by every traced writer), and the ``trace_<role>.json`` sidecar artifact
 (a Chrome-trace-event file with a ``tpuddp`` provenance block,
 :func:`validate_trace_payload` — loadable in Perfetto as-is);
 v10 added the required run_meta ``comm`` block (the gradient-exchange
-execution provenance, training/step.py ``comm_overlap``): its
-``overlap`` member records whether the step ran segmented-backward
-({enabled, segments} — the bucket-aligned backward segments whose
-collectives interleave with backward compute) or the barrier step and
-why. Null for writers with no gradient exchange (serving headers), but
-the KEY must exist — a reader must distinguish "barrier because overlap
-resolved off" from "predates the overlap mode";
+execution provenance): its ``overlap`` member records whether the step
+ran segmented-backward ({enabled, segments}: histories written while
+that second step program existed) or the barrier step and why (the
+constant every writer records since; ROADMAP D12). Null for writers
+with no gradient exchange (serving headers), but the KEY must exist;
 v11 added the required run_meta ``snapshot`` field (the async
 step-checkpoint engine, training/snapshot.py): an armed block carries
 the resolved config (``every_steps``/``async``/``inflight``/
@@ -269,13 +267,11 @@ _REQUIRED_SINCE = {
     9: {
         "run_meta": ("tracing",),
     },
-    # v10: the gradient-exchange execution provenance (``comm_overlap``,
-    # training/step.py). Null for writers with no gradient exchange (serving
-    # headers) but the KEY must exist: a reader needs to distinguish
-    # "barrier step because overlap resolved off (and why)" from "this
-    # header predates segmented-backward execution". An enabled block's
-    # ``overlap.segments`` counts the bucket-aligned backward segments whose
-    # collectives interleave with backward compute.
+    # v10: the gradient-exchange execution provenance. Null for writers with
+    # no gradient exchange (serving headers) but the KEY must exist. An
+    # enabled block (a history from the segmented-backward step, since
+    # removed) counts its backward segments in ``overlap.segments``; every
+    # writer now records the barrier step's constant (ROADMAP D12).
     10: {
         "run_meta": ("comm",),
     },

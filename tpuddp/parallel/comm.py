@@ -87,7 +87,6 @@ residual) never contaminates the error-feedback state (training/step.py's
 
 from __future__ import annotations
 
-import re
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -230,75 +229,6 @@ def make_buckets(
     return tuple(buckets)
 
 
-class CommSegment(NamedTuple):
-    """One backward segment of the segmented-overlap step (``comm_overlap``,
-    training/step.py): a contiguous run of model children whose flat-vector
-    span is exactly a union of whole buckets, so the segment's collective can
-    be issued the moment its backward VJP materializes — without ever
-    splitting a bucket (the byte accounting stays per-bucket and identical
-    to barrier mode by construction)."""
-
-    layers: Tuple[int, int]  # [start, end) child indices of the Sequential
-    flat: Tuple[int, int]  # [start, end) offsets into the padded flat vector
-    buckets: Tuple[Tuple[int, int], ...]  # absolute (start, end) bucket slices
-
-
-def make_segments(
-    layer_sizes: Tuple[int, ...],
-    buckets: Tuple[Tuple[int, int], ...],
-    total: int,
-) -> Tuple[CommSegment, ...]:
-    """Derive the backward segments from the existing bucket assembly.
-
-    ``layer_sizes`` are the per-child flat element counts of a Sequential
-    model in ``tree_flatten`` order (child i's parameters occupy the
-    contiguous flat span ``[sum(sizes[:i]), sum(sizes[:i+1]))`` because the
-    params pytree is a tuple over children). A segment boundary is every
-    layer boundary that coincides with a bucket edge — buckets are never
-    split, and a bucket that straddles a layer boundary simply fuses those
-    layers into one segment (torch DDP's rule in flat-vector form). The
-    final segment extends to ``total`` so the spec's world-multiple padding
-    rides the tail bucket exactly as in barrier mode. Parameter-free
-    children (ReLU, Flatten) produce zero-width spans and attach to the
-    segment of the parameterized layer they follow."""
-    offsets = [0]
-    for n in layer_sizes:
-        offsets.append(offsets[-1] + int(n))
-    if offsets[-1] > total:
-        raise ValueError(
-            f"layer sizes sum to {offsets[-1]} > padded total {total}"
-        )
-    offsets[-1] = total  # padding rides the last layer's segment
-    edges = {s for s, _ in buckets} | {e for _, e in buckets}
-    bounds = [0]
-    for i, off in enumerate(offsets[1:-1], start=1):
-        # a boundary must advance the flat cursor (skip zero-param runs) and
-        # land on a bucket edge (never split a bucket)
-        if off > bounds[-1] and off in edges:
-            bounds.append(off)
-    if total > bounds[-1]:
-        bounds.append(total)
-    elif bounds == [0]:  # zero-parameter model: one degenerate segment
-        bounds.append(total)
-    segs = []
-    layer_cursor = 0
-    n_layers = len(layer_sizes)
-    for lo, hi in zip(bounds, bounds[1:]):
-        first = layer_cursor
-        while layer_cursor < n_layers and offsets[layer_cursor + 1] <= hi:
-            layer_cursor += 1
-        segs.append(CommSegment(
-            layers=(first, layer_cursor),
-            flat=(lo, hi),
-            buckets=tuple(b for b in buckets if lo <= b[0] and b[1] <= hi),
-        ))
-    if segs:
-        # trailing parameter-free children attach to the last segment
-        segs[-1] = segs[-1]._replace(layers=(segs[-1].layers[0], n_layers))
-    assert sum(len(s.buckets) for s in segs) == len(buckets)
-    return tuple(segs)
-
-
 class GradComm(NamedTuple):
     """Static comm plan for one (model, world, hook) triple: the flat spec the
     gradients vectorize through, the bucket partition, the hook, and the
@@ -385,27 +315,6 @@ class GradComm(NamedTuple):
         sums, keeps = [], []
         for s, e in self.buckets:
             b = lax.slice(send, (s,), (e,))
-            summed, kept = self._exchange_bucket(b, axis_name)
-            sums.append(summed)
-            keeps.append(kept)
-        return jnp.concatenate(sums), jnp.concatenate(keeps)
-
-    def exchange_segment(self, send, seg: "CommSegment", axis_name):
-        """One backward segment's slice of the bucketed exchange
-        (``comm_overlap``): ``send`` is the segment's local send vector
-        (gradient slice + residual slice, ``seg.flat`` elements long).
-        Returns ``(summed_f32, kept_f32)`` concatenated over the segment's
-        buckets — element for element the ``seg.flat`` slice of what
-        :meth:`_compressed_sum` computes over the full vector, because every
-        bucket lies whole inside exactly one segment (the
-        :func:`make_segments` invariant). Issued from inside the backward
-        walk, this is the collective that overlaps the next segment's VJP."""
-        from jax import lax
-
-        lo = seg.flat[0]
-        sums, keeps = [], []
-        for s, e in seg.buckets:
-            b = lax.slice(send, (s - lo,), (e - lo,))
             summed, kept = self._exchange_bucket(b, axis_name)
             sums.append(summed)
             keeps.append(kept)
@@ -706,39 +615,10 @@ def init_residual_tree(params):
     )
 
 
-_HLO_COLLECTIVE_RE = re.compile(
-    r"\ball[-_]reduce\b|\ball[-_]gather\b|\breduce[-_]scatter\b"
-    r"|\bcollective[-_]permute\b"
-)
-_HLO_COMPUTE_RE = re.compile(r"\bdot_general\b|\bdot\(|\bconvolution\b|\bconv\(")
-
-
-def hlo_overlap_evidence(hlo_text: str) -> dict:
-    """Positional evidence of backward/collective interleaving in a lowered
-    step's HLO/StableHLO text (the ``comm_overlap`` proof obligation, and
-    what the real-TPU latency-hiding scheduler exploits): line indices of
-    collective ops and of matmul/conv compute, plus the compute lines that
-    fall strictly BETWEEN the first and last collective. In barrier mode the
-    collectives form one trailing block (``interleaved_compute == []``); the
-    segmented step puts each earlier segment's backward compute after a later
-    segment's collective. Pure text analysis — jax-free, so bench rows and
-    the full gate can both record it."""
-    lines = hlo_text.splitlines()
-    collectives = [
-        i for i, l in enumerate(lines) if _HLO_COLLECTIVE_RE.search(l)
-    ]
-    compute = [i for i, l in enumerate(lines) if _HLO_COMPUTE_RE.search(l)]
-    inter = (
-        [i for i in compute if collectives[0] < i < collectives[-1]]
-        if collectives
-        else []
-    )
-    return {
-        "collective_lines": collectives,
-        "compute_lines": compute,
-        "interleaved_compute": inter,
-        "interleaved": bool(inter),
-    }
+# run_meta's ``comm.overlap`` (schema v10): the exchange trails the backward
+# pass in the one step program, so the record is a constant. Kept because
+# benchmark/run.py and the schema read it (ROADMAP D12).
+OVERLAP_META = {"enabled": False, "segments": None, "reason": "barrier step"}
 
 
 def redistribute_residual(mat: np.ndarray, new_world: int) -> Tuple[np.ndarray, str]:

@@ -41,26 +41,6 @@ from tpuddp.training import step as step_lib
 from tpuddp.training.train_state import TrainState, create_train_state
 
 
-def _normalize_overlap(value):
-    """Normalize the ``comm_overlap`` knob to True/False/"auto" (YAML hands
-    us bools, CLI overrides hand us strings)."""
-    if value is True or value is False:
-        return value
-    if value is None:
-        return "auto"
-    if isinstance(value, str):
-        v = value.strip().lower()
-        if v == "auto":
-            return "auto"
-        if v in ("true", "1", "on", "yes"):
-            return True
-        if v in ("false", "0", "off", "no"):
-            return False
-    raise ValueError(
-        f"comm_overlap must be true, false, or 'auto'; got {value!r}"
-    )
-
-
 class DistributedDataParallel:
     """Builds and caches the compiled DP steps for (model, optimizer, criterion).
 
@@ -88,7 +68,6 @@ class DistributedDataParallel:
         comm_topology: str = "flat",
         topk_density: float = comm_lib.DEFAULT_TOPK_DENSITY,
         guard=None,
-        comm_overlap="auto",
     ):
         """``weight_update_sharding``: shard the optimizer update + moments
         across the data axis (reduce-scatter grads, update a 1/N parameter
@@ -145,19 +124,6 @@ class DistributedDataParallel:
 
         ``topk_density``: the fraction of each bucket topk_ef keeps
         (default 0.1); ignored by the other hooks.
-
-        ``comm_overlap``: segmented-backward execution (``true``/``false``/
-        ``"auto"``, training/step.py): stage the backward pass as per-segment
-        VJP closures whose boundaries align with ``bucket_cap_mb`` buckets
-        and issue each segment's gradient collective the moment its buckets
-        materialize — torch DDP's ready-bucket overlap, bitwise-identical
-        loss trajectory to the barrier step. ``"auto"`` (default) enables it
-        only where it genuinely segments (``mode="shard_map"``, flat
-        topology, Sequential model, no WUS/remat/TP, and >= 2 bucket-aligned
-        segments) and quietly keeps the barrier step elsewhere; ``true``
-        refuses ineligible combos loudly at :meth:`init_state`; ``false``
-        pins the barrier step. :attr:`comm_overlap_meta` records the
-        resolution for run_meta provenance.
 
         ``guard``: the ``training.guard`` block (None/False/True/dict or a
         :class:`~tpuddp.resilience.guard.GuardConfig`). When enabled, the
@@ -246,9 +212,6 @@ class DistributedDataParallel:
         self.topk_density = float(topk_density)
         comm_lib.bucket_topk(1, self.topk_density)  # range-validate eagerly
         self.guard = guard_lib.resolve_guard(guard)
-        self.comm_overlap = _normalize_overlap(comm_overlap)
-        self._segments = None
-        self._overlap_meta = None
         self._comm = None
         self._grad_comm_bytes = None
         self._grad_comm_bytes_f32 = None
@@ -406,7 +369,6 @@ class DistributedDataParallel:
             "inter_host": self._grad_comm_bytes,
             "intra_host": 0,
         }
-        self._resolve_overlap(None)  # TP is overlap-ineligible; record why
         self._state_spec = tp_lib.tp_state_spec(
             self._tp_specs, self._tp_opt_specs, comm=self._comm
         )
@@ -534,7 +496,6 @@ class DistributedDataParallel:
             wus=self.weight_update_sharding,
             wire=wire,
         )
-        self._resolve_overlap(state.params)
         sharded_residual = (
             self._comm is not None
             and self._comm.needs_residual
@@ -620,105 +581,10 @@ class DistributedDataParallel:
             skipped_steps=replicate(self.mesh, state.skipped_steps),
         ))
 
-    def _resolve_overlap(self, params):
-        """Resolve the ``comm_overlap`` knob against the eligibility matrix,
-        deriving the bucket-aligned backward segments
-        (:func:`~tpuddp.parallel.comm.make_segments`) where the segmented
-        step genuinely applies. Runs inside :meth:`init_state` — segments
-        need the realized parameter layout. ``"auto"`` falls back to the
-        barrier step with a recorded reason; ``True`` refuses loudly."""
-        from tpuddp.nn.core import Sequential
-
-        want = self.comm_overlap
-        if want is False:
-            self._overlap_meta = {
-                "enabled": False, "segments": None, "reason": "disabled",
-            }
-            return
-        reason = None
-        if self.mode != "shard_map":
-            reason = (
-                "mode='auto' has no explicit collective to issue per "
-                "segment (XLA places the psum itself)"
-            )
-        elif self.comm_topology != "flat":
-            reason = (
-                "comm_topology='hierarchical': a per-segment scatter would "
-                "move the error-feedback residual's owner placement"
-            )
-        elif self.weight_update_sharding:
-            reason = (
-                "weight_update_sharding: per-segment reduce-scatter pieces "
-                "do not reassemble into the replica's canonical full-vector "
-                "shard"
-            )
-        elif self.remat:
-            reason = (
-                "remat wraps the whole forward in one jax.checkpoint body; "
-                "per-segment VJP staging would recompute outside it"
-            )
-        elif self.model_size > 1:
-            reason = "tensor parallelism (parallel.model > 1)"
-        elif not isinstance(self.model, Sequential):
-            reason = (
-                "segment boundaries are derived from Sequential children; "
-                f"{type(self.model).__name__} has no child decomposition"
-            )
-        segments = None
-        if reason is None:
-            import numpy as np
-
-            try:
-                if self._comm is not None:
-                    spec, buckets = self._comm.spec, self._comm.buckets
-                else:
-                    spec = step_lib.make_flat_param_spec(
-                        params, self.world_size
-                    )
-                    buckets = comm_lib.make_buckets(
-                        spec.sizes, spec.total, self.bucket_cap_mb
-                    )
-                layer_sizes = tuple(
-                    sum(
-                        int(np.prod(np.shape(l)))
-                        for l in jax.tree_util.tree_leaves(sub)
-                    )
-                    for sub in params
-                )
-                segments = comm_lib.make_segments(
-                    layer_sizes, buckets, spec.total
-                )
-            except ValueError as e:
-                reason, segments = f"segment derivation failed: {e}", None
-        if reason is None and want == "auto" and len(segments) < 2:
-            reason = (
-                "single bucket-aligned segment at bucket_cap_mb="
-                f"{self.bucket_cap_mb:g} — segmentation would be the barrier "
-                "step with extra staging"
-            )
-            segments = None
-        if reason is not None:
-            if want is True:
-                raise ValueError(
-                    f"comm_overlap=true refused: {reason}. Use "
-                    "comm_overlap='auto' to fall back to the barrier step "
-                    "where segmentation does not apply."
-                )
-            self._overlap_meta = {
-                "enabled": False, "segments": None, "reason": reason,
-            }
-            return
-        self._segments = segments
-        self._overlap_meta = {
-            "enabled": True, "segments": len(segments), "reason": None,
-        }
-
     @property
     def comm_overlap_meta(self):
-        """Overlap-resolution provenance for run_meta (schema v10
-        ``comm.overlap``): ``{"enabled", "segments", "reason"}`` after
-        :meth:`init_state`, None before."""
-        return self._overlap_meta
+        """What run_meta's ``comm.overlap`` (schema v10) records."""
+        return dict(comm_lib.OVERLAP_META)
 
     def _audit_at_wrap(self, state: TrainState) -> TrainState:
         """torch DDP's ``_verify_params_across_processes`` moment: under
@@ -839,7 +705,6 @@ class DistributedDataParallel:
                 comm=self._comm,
                 guard=self.guard.enabled,
                 hier=self._hier,
-                segments=self._segments,
             )
         return self._scan_step(state, stacked_batch)
 
@@ -878,7 +743,6 @@ class DistributedDataParallel:
                 comm=self._comm,
                 guard=self.guard.enabled,
                 hier=self._hier,
-                segments=self._segments,
             )
         return self._train_step(state, batch)
 

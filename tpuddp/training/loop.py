@@ -455,9 +455,8 @@ def run_training_loop(
             flight.describe() if flight is not None else False
         ),
     }
-    # v10 comm block: the gradient-exchange execution provenance — did the
-    # step run segmented-backward (comm_overlap) and over how many segments,
-    # or the barrier step and why (null on wraps predating the knob)
+    # v10 comm block: the gradient-exchange execution provenance (a constant
+    # since the step is one program; null on wraps that carry none)
     overlap_meta = getattr(ddp, "comm_overlap_meta", None)
     comm_block = (
         {"overlap": dict(overlap_meta)} if overlap_meta is not None else None
@@ -717,12 +716,9 @@ def run_training_loop(
     # dispatches carry no gradient exchange.
     epoch_span = None
     comm_attrs = None
-    _overlap_on = bool((overlap_meta or {}).get("enabled"))
-    if tracer.enabled and (
-        getattr(ddp, "comm_hook", "none") != "none" or _overlap_on
-    ):
+    if tracer.enabled and getattr(ddp, "comm_hook", "none") != "none":
         comm_attrs = {
-            "hook": getattr(ddp, "comm_hook", "none"),
+            "hook": ddp.comm_hook,
             "topology": getattr(ddp, "comm_topology", "flat"),
             "wire_bytes_per_update": getattr(
                 ddp, "grad_comm_bytes_per_step", None
@@ -734,21 +730,6 @@ def run_training_loop(
                 ddp, "grad_comm_bytes_inter_host", None
             ),
         }
-        if _overlap_on:
-            # segmented-backward overlap: one collective span per backward
-            # segment (pipeline.run_pass fans these out), each naming its
-            # layer range and bucket count so trace_breakdown.py can show
-            # the interleaving visually
-            comm_attrs["overlap"] = True
-            comm_attrs["segments"] = [
-                {
-                    "segment": i,
-                    "layers": list(seg.layers),
-                    "flat": list(seg.flat),
-                    "buckets": len(seg.buckets),
-                }
-                for i, seg in enumerate(getattr(ddp, "_segments", ()) or ())
-            ]
 
     try:
         epoch = start_epoch

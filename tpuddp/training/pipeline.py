@@ -313,26 +313,10 @@ def run_pass(
             # span (zero-length, nested in the dispatch): which hook, how
             # many wire bytes per optimizer update, how many updates this
             # dispatch carried
-            updates = max(1, n_steps // max(1, accum))
-            segs = comm_attrs.get("segments")
-            if segs:
-                # segmented overlap: one collective span per backward
-                # segment so trace_breakdown shows K interleaved issues
-                # instead of one trailing block
-                shared = {
-                    k: v for k, v in comm_attrs.items() if k != "segments"
-                }
-                for seg in segs:
-                    tracer.end_span(tracer.start_span(
-                        f"grad_comm.seg{seg['segment']}",
-                        trace_lib.KIND_COLLECTIVE, parent=dsp,
-                        attrs={**shared, **seg, "updates": updates},
-                    ))
-            else:
-                tracer.end_span(tracer.start_span(
-                    "grad_comm", trace_lib.KIND_COLLECTIVE, parent=dsp,
-                    attrs={**comm_attrs, "updates": updates},
-                ))
+            tracer.end_span(tracer.start_span(
+                "grad_comm", trace_lib.KIND_COLLECTIVE, parent=dsp,
+                attrs={**comm_attrs, "updates": max(1, n_steps // max(1, accum))},
+            ))
         tracer.end_span(dsp, inflight=drain.inflight)
         tel.post_dispatch(
             n_steps, n_samples, metrics,

@@ -29,7 +29,6 @@ fused into the forward pass by XLA.
 
 from __future__ import annotations
 
-import functools
 import math
 from typing import Any, Callable, NamedTuple, Optional, Tuple
 
@@ -243,9 +242,7 @@ def _firewall_gate(ok, do_update, params, opt_state, comm_state, skipped):
     executes only on a finite aggregated gradient; the skip branch hands
     the inputs back bitwise (the EF residual included — its NaN-poisoned
     candidate is never materialized into the carry) and bumps the
-    counters. ``consecutive`` resets on every applied update. Shared by
-    the barrier update (:func:`_make_update_fn`) and the segmented-overlap
-    tail (:func:`_make_apply_reduced`) so both lower to the same cond."""
+    counters. ``consecutive`` resets on every applied update."""
 
     def _apply():
         new_params, new_opt_state, new_comm = do_update()
@@ -295,8 +292,6 @@ def _make_update_fn(
     bad step is a bitwise no-op on params/opt-state/EF-residual and bumps
     the ``skipped_steps`` counters. ``guard=False`` is the pre-guard code
     path verbatim (identical HLO, ``skipped`` passes through untouched)."""
-
-    gate = _firewall_gate
 
     def apply_update(params, opt_state, grads, comm_state, skipped):
         if wus_spec is not None:
@@ -384,7 +379,7 @@ def _make_update_fn(
                     )
                     == 1
                 )
-                return gate(
+                return _firewall_gate(
                     ok, wus_update, params, opt_state, comm_state, skipped
                 )
 
@@ -428,8 +423,8 @@ def _make_update_fn(
 def _update_reduced(optimizer, clip_grad_norm, guard, params, opt_state,
                     agg_grads, cand_comm, comm_state, skipped, ok=None):
     """Verdict + clip + update over an ALREADY cross-replica-reduced f32
-    gradient, behind the ``lax.cond`` firewall when ``guard``: the tail the
-    barrier update and the segmented-overlap step share. ``ok`` is a verdict
+    gradient, behind the ``lax.cond`` firewall when ``guard``: the tail of
+    :func:`_make_update_fn`'s replicated path. ``ok`` is a verdict
     taken before the exchange (auto mode); without one the post-allreduce
     gradient is checked — the sum propagated any replica's NaN/Inf
     everywhere, so this replica-local check IS the global verdict, no extra
@@ -549,244 +544,6 @@ def _make_train_core(
     return core
 
 
-# -- segmented-backward execution (``comm_overlap``) ------------------------
-#
-# torch DDP's ready-bucket overlap, expressed natively in JAX: the backward
-# pass is staged as per-segment VJP closures whose boundaries align with the
-# bucket assembly (comm.make_segments), and each segment's gradient collective
-# is issued the moment its buckets materialize — in trace order BEFORE the
-# previous segment's backward compute, so the lowered HLO carries K
-# interleaved collectives instead of one trailing block and the latency-hiding
-# scheduler can overlap wire time with MXU time. Bitwise-identical to the
-# barrier step by construction: the same layer VJPs over the same operands,
-# the same per-bucket exchange over the same flat offsets, the same /world,
-# residual, guard-verdict, clip and optimizer arithmetic — only the
-# *instruction order* changes.
-
-
-def _validate_segments(segments, mode: str, wus_spec, hier):
-    """Builder-level honesty check for ``segments``: the segmented step only
-    exists where the exchange is an explicit per-bucket op (shard_map, flat
-    topology, no weight-update sharding). DDP._resolve_overlap routes
-    ineligible configs to the barrier step before we get here; this guards
-    direct builder callers."""
-    if segments is None:
-        return
-    if mode != "shard_map":
-        raise ValueError(
-            "segments= (comm_overlap) needs mode='shard_map': the auto path's "
-            "collective is inserted by XLA, not issued per segment"
-        )
-    if wus_spec is not None:
-        raise ValueError(
-            "segments= (comm_overlap) does not compose with "
-            "weight_update_sharding: per-segment reduce-scatter pieces do not "
-            "reassemble into the replica's canonical full-vector shard"
-        )
-    if hier is not None:
-        raise ValueError(
-            "segments= (comm_overlap) does not compose with "
-            "comm_topology='hierarchical': per-segment scatter would move the "
-            "error-feedback residual's owner placement"
-        )
-    if not segments:
-        raise ValueError("segments= must be a non-empty tuple of CommSegment")
-
-
-def _subtree_to_vec(tree, width: int):
-    """Concatenate a params-subtree's leaves (tree_flatten order) into a flat
-    f32 vector, zero-padded to ``width`` — the segment-sized sibling of
-    :func:`_tree_to_vec` (only the LAST segment carries the spec's tail
-    padding, so the per-segment concatenation reproduces the full padded
-    vector element for element)."""
-    leaves = jax.tree_util.tree_leaves(tree)
-    if not leaves:
-        return jnp.zeros((width,), jnp.float32)
-    flat = jnp.concatenate([jnp.ravel(l) for l in leaves])
-    pad = width - flat.shape[0]
-    if pad:
-        flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
-    return flat
-
-
-def _make_apply_reduced(optimizer, clip_grad_norm: Optional[float], guard: bool):
-    """The optimizer tail over an ALREADY cross-replica-reduced gradient:
-    verdict + clip + update behind the same ``lax.cond`` firewall as
-    :func:`_make_update_fn`. The segmented-overlap step computes the exchange
-    inside its backward walk and lands here with the aggregated f32 gradient
-    and the candidate comm_state in hand."""
-
-    return functools.partial(_update_reduced, optimizer, clip_grad_norm, guard)
-
-
-def _make_segmented_vjp(model, criterion, axis_name, sync_buffers: str,
-                        augment: Optional[Callable], segments):
-    """The forward half of the segmented step: run the model one segment at a
-    time, saving each segment's VJP closure instead of one whole-model
-    ``value_and_grad``. Returns ``seg_vjp(state, x, y, w) ->
-    (pullbacks, ct, model_state, loss, n)`` where ``ct`` is the loss
-    cotangent w.r.t. the logits — the seed for the reversed backward walk.
-
-    Parity contract: each segment applies ``model[i].apply(params[i],
-    model_state[i], x, ctx.child(i))`` at the ABSOLUTE child index ``i`` —
-    byte for byte the calls ``Sequential.apply`` makes — so the composed
-    forward and the chained per-segment pullbacks execute the same
-    primitives over the same operands as the barrier step's single VJP."""
-
-    def seg_vjp(state: TrainState, x, y, w):
-        aug_rng, dropout_rng = _split_step_rng(state, axis_name)
-        if augment is not None:
-            with _prof.scope(_prof.AUGMENT):
-                x = augment(aug_rng, x)
-        ctx = Context(
-            train=True, rng=dropout_rng, axis_name=axis_name, sample_weight=w
-        )
-        act = x
-        pullbacks = []
-        new_states = []
-        for seg in segments:
-            a, b = seg.layers
-            s_seg = tuple(state.model_state[a:b])
-
-            def seg_fwd(p, v, a=a, b=b, s_seg=s_seg):
-                out = v
-                states = []
-                with _prof.scope(_prof.FORWARD):
-                    for j, i in enumerate(range(a, b)):
-                        # the name Sequential.apply gives the same call
-                        with _prof.scope(_prof.layer_scope(i, model[i])):
-                            out, s = model[i].apply(
-                                p[j], s_seg[j], out, ctx.child(i)
-                            )
-                        states.append(s)
-                return out, tuple(states)
-
-            act, pull, st_seg = jax.vjp(
-                seg_fwd, tuple(state.params[a:b]), act, has_aux=True
-            )
-            pullbacks.append(pull)
-            new_states.extend(st_seg)
-        # loss head: criterion value + logits cotangent in one VJP — the same
-        # criterion backward the barrier step's whole-model grad begins with
-        def loss_head(logits):
-            with _prof.scope(_prof.LOSS):
-                return criterion(logits, y, w)
-
-        loss, ct = jax.value_and_grad(loss_head)(act)
-        model_state = _sync_model_state(
-            tuple(new_states), axis_name, sync_buffers
-        )
-        return pullbacks, ct, model_state, loss, jnp.sum(w)
-
-    return seg_vjp
-
-
-def _segmented_exchange(pullbacks, ct, residual, comm, segments, axis_name,
-                        grad_of_seg):
-    """The reversed backward walk WITH the eager per-segment exchange: pull
-    segment K-1's gradient, issue its collective immediately, then pull
-    segment K-2 — the collective has no data dependence on the earlier
-    segments' compute, so it interleaves. ``grad_of_seg(k, dp_seg) ->
-    f32 gradient subtree to exchange`` is the identity for the single-step
-    path and the accumulated cycle-mean fold for grad accumulation.
-
-    Returns ``(agg_grads, new_comm_state)`` — bitwise the barrier step's
-    ``comm.reduce`` (or per-leaf pmean) outputs, reassembled from the
-    per-segment slices in forward order."""
-    n_seg = len(segments)
-    red = [None] * n_seg
-    res = [None] * n_seg
-    for k in range(n_seg - 1, -1, -1):
-        dp_seg, ct = pullbacks[k](ct)
-        g_seg = grad_of_seg(k, dp_seg)
-        seg = segments[k]
-        with _prof.scope(_prof.EXCHANGE):
-            if comm is not None and comm.compressed:
-                lo, hi = seg.flat
-                g_vec = _subtree_to_vec(g_seg, hi - lo)
-                if comm.needs_residual:
-                    send = g_vec + jax.lax.slice(residual, (lo,), (hi,))
-                else:
-                    send = g_vec
-                summed, kept = comm.exchange_segment(send, seg, axis_name)
-                red[k] = summed / comm.world
-                if comm.needs_residual:
-                    res[k] = send - kept
-            else:
-                # hook "none": the segment's slice of THE DDP pmean —
-                # identical leaves to the barrier col.pmean over the whole tree
-                red[k] = col.pmean(g_seg, axis_name)
-    if comm is not None and comm.compressed:
-        with _prof.scope(_prof.EXCHANGE):
-            agg_grads = _vec_to_tree(jnp.concatenate(red), comm.spec)
-            new_comm = jnp.concatenate(res) if comm.needs_residual else residual
-    else:
-        layers = []
-        for r in red:
-            layers.extend(r)
-        agg_grads = tuple(layers)
-        new_comm = residual
-    return agg_grads, new_comm
-
-
-def _make_segmented_train_core(
-    model,
-    criterion,
-    optimizer,
-    axis_name,
-    sync_buffers: str,
-    clip_grad_norm: Optional[float],
-    augment: Optional[Callable],
-    comm,
-    segments,
-    guard: bool = False,
-):
-    """The segmented-overlap sibling of :func:`_make_train_core`: same
-    ``core(state, x, y, w) -> (new_state, metrics)`` signature and bitwise the
-    same arithmetic, with the gradient exchange issued per segment inside the
-    backward walk instead of as one trailing block."""
-    _validate_sync_buffers(model, axis_name, sync_buffers)
-    if axis_name is None:
-        raise ValueError(
-            "comm_overlap needs the explicit per-replica step "
-            "(mode='shard_map'): only there is the gradient collective an "
-            "explicit op that can be issued per backward segment"
-        )
-    seg_vjp = _make_segmented_vjp(
-        model, criterion, axis_name, sync_buffers, augment, segments
-    )
-    apply_reduced = _make_apply_reduced(optimizer, clip_grad_norm, guard)
-
-    def core(state: TrainState, x, y, w):
-        pullbacks, ct, model_state, loss, n = seg_vjp(state, x, y, w)
-        agg_grads, cand_comm = _segmented_exchange(
-            pullbacks, ct, state.comm_state, comm, segments, axis_name,
-            lambda k, dp: dp,
-        )
-        new_params, new_opt_state, new_comm, new_skipped = apply_reduced(
-            state.params, state.opt_state, agg_grads, cand_comm,
-            state.comm_state, state.skipped_steps,
-        )
-        if guard:
-            model_state = _revert_buffers_on_skip(
-                state.model_state, model_state, state.skipped_steps,
-                new_skipped,
-            )
-        metrics = _step_metrics(loss, n)
-        new_state = TrainState(
-            params=new_params,
-            model_state=model_state,
-            opt_state=new_opt_state,
-            step=state.step + 1,
-            rng=state.rng,
-            comm_state=new_comm,
-            skipped_steps=new_skipped,
-        )
-        return new_state, metrics
-
-    return core
-
-
 def _make_eval_core(model, criterion, axis_name, transform: Optional[Callable]):
     def core(state: TrainState, x, y, w):
         if transform is not None:
@@ -826,7 +583,6 @@ def build_train_step(
     comm=None,
     guard: bool = False,
     hier: Optional[Tuple[str, str]] = None,
-    segments=None,
 ):
     """Compile the DP train step over ``mesh``. Returns
     ``step(state, (x, y, w)) -> (new_state, metrics)`` with donated state.
@@ -840,27 +596,15 @@ def build_train_step(
     factored mesh (see :func:`_make_update_fn`). ``guard=True`` arms the
     non-finite gradient firewall (state must carry ``skipped_steps``
     counters; see resilience/guard.py); ``False`` lowers to the identical
-    program as before the guard existed. ``segments`` (a tuple of
-    :class:`tpuddp.parallel.comm.CommSegment` from ``comm.make_segments``)
-    selects the segmented-overlap step — mutually exclusive with
-    ``wus_spec``/``hier`` and shard_map-only (DDP._resolve_overlap enforces
-    the eligibility matrix and auto-falls back)."""
-    _validate_segments(segments, mode, wus_spec, hier)
+    program as before the guard existed."""
     if mode == "shard_map":
         axis = data_axes(mesh)
         st_spec = state_spec if state_spec is not None else P()
-        if segments is not None:
-            core = _make_segmented_train_core(
-                model, criterion, optimizer, axis, sync_buffers,
-                clip_grad_norm, augment, comm=comm, segments=segments,
-                guard=guard,
-            )
-        else:
-            core = _make_train_core(
-                model, criterion, optimizer, axis, sync_buffers,
-                clip_grad_norm, augment, remat, wus_spec=wus_spec, comm=comm,
-                guard=guard, hier=hier,
-            )
+        core = _make_train_core(
+            model, criterion, optimizer, axis, sync_buffers,
+            clip_grad_norm, augment, remat, wus_spec=wus_spec, comm=comm,
+            guard=guard, hier=hier,
+        )
         fn = shard_map(
             core,
             mesh=mesh,
@@ -888,10 +632,6 @@ def build_train_step(
         x, y, w = batch
         return jitted(state, x, y, w)
 
-    # the underlying jit-wrapped callable, exposed for HLO inspection (the
-    # overlap proof obligation: tests/bench lower the step and assert the
-    # collectives interleave with backward compute instead of trailing)
-    step.jitted = jitted
     return step
 
 
@@ -911,7 +651,6 @@ def build_train_scan_step(
     comm=None,
     guard: bool = False,
     hier: Optional[Tuple[str, str]] = None,
-    segments=None,
 ):
     """Multi-step variant: runs K train steps per jit call via ``lax.scan``.
 
@@ -947,7 +686,6 @@ def build_train_scan_step(
     if accum < 1:
         raise ValueError(f"grad_accumulation must be >= 1, got {grad_accumulation!r}")
     _validate_sync_buffers(model, axis_name, sync_buffers)
-    _validate_segments(segments, mode, wus_spec, hier)
     if wus_spec is not None and axis_name is None:
         raise ValueError(
             "weight_update_sharding needs the explicit per-replica step "
@@ -956,18 +694,11 @@ def build_train_scan_step(
         )
 
     if accum == 1:
-        if segments is not None:
-            core = _make_segmented_train_core(
-                model, criterion, optimizer, axis_name, sync_buffers,
-                clip_grad_norm, augment, comm=comm, segments=segments,
-                guard=guard,
-            )
-        else:
-            core = _make_train_core(
-                model, criterion, optimizer, axis_name, sync_buffers,
-                clip_grad_norm, augment, remat, wus_spec=wus_spec, comm=comm,
-                guard=guard, hier=hier,
-            )
+        core = _make_train_core(
+            model, criterion, optimizer, axis_name, sync_buffers,
+            clip_grad_norm, augment, remat, wus_spec=wus_spec, comm=comm,
+            guard=guard, hier=hier,
+        )
 
         def multi(state: TrainState, xs, ys, ws):
             def body(st, batch):
@@ -985,20 +716,6 @@ def build_train_scan_step(
             optimizer, axis_name, clip_grad_norm, wus_spec, comm=comm,
             guard=guard, hier=hier,
         )
-        if segments is not None:
-            # grad-accum peel: the first A-1 micro-batches scan through the
-            # barrier grad_core accumulating Σ n·g as before; the LAST micro
-            # runs segmented, folding (gacc + n·g)/denom per segment during
-            # its backward walk so the cycle's ONE exchange still overlaps
-            # that backward. Bitwise: the fold is exactly the barrier's last
-            # scan iteration + /denom, leaf for leaf.
-            seg_vjp = _make_segmented_vjp(
-                model, criterion, axis_name, sync_buffers, augment, segments
-            )
-            apply_reduced = _make_apply_reduced(
-                optimizer, clip_grad_norm, guard
-            )
-
         def multi(state: TrainState, xs, ys, ws):
             k = xs.shape[0]
             if k % accum != 0:
@@ -1041,69 +758,19 @@ def build_train_scan_step(
                     m = _step_metrics(loss, n, counters)
                     return (st, gacc, nacc + n), m
 
-                if segments is None:
-                    (st, gacc, nacc), stacked = jax.lax.scan(
-                        micro, (st, zeros, jnp.zeros((), jnp.float32)), cyc_batch
-                    )
-                    # exact weighted mean even for fractional sample weights
-                    # (guard only the all-padding nacc==0 case, like nn/loss.py)
-                    denom = jnp.where(nacc == 0, 1.0, nacc)
-                    g = jax.tree_util.tree_map(lambda a: a / denom, gacc)
-                    # the firewall (guard=True) checks THIS aggregated
-                    # cycle-mean gradient: one poisoned micro-batch skips the
-                    # whole cycle's update, bitwise
-                    new_params, new_opt_state, new_comm, new_skipped = apply_update(
-                        st.params, st.opt_state, g, st.comm_state, st.skipped_steps
-                    )
-                else:
-                    head = jax.tree_util.tree_map(
-                        lambda arr: arr[: accum - 1], cyc_batch
-                    )
-                    (st, gacc, nacc), head_stacked = jax.lax.scan(
-                        micro, (st, zeros, jnp.zeros((), jnp.float32)), head
-                    )
-                    x_l, y_l, w_l = jax.tree_util.tree_map(
-                        lambda arr: arr[accum - 1], cyc_batch
-                    )
-                    pullbacks, ct, ms_l, loss_l, n_l = seg_vjp(st, x_l, y_l, w_l)
-                    nacc = nacc + n_l
-                    denom = jnp.where(nacc == 0, 1.0, nacc)
-
-                    def grad_of_seg(k, dp):
-                        lo, hi = segments[k].layers
-                        return jax.tree_util.tree_map(
-                            lambda acc, d: (acc + n_l * d) / denom,
-                            gacc[lo:hi], dp,
-                        )
-
-                    agg, cand_comm = _segmented_exchange(
-                        pullbacks, ct, st.comm_state, comm, segments,
-                        axis_name, grad_of_seg,
-                    )
-                    st = TrainState(
-                        params=st.params,
-                        model_state=ms_l,
-                        opt_state=st.opt_state,
-                        step=st.step + 1,
-                        rng=st.rng,
-                        comm_state=st.comm_state,
-                        skipped_steps=st.skipped_steps,
-                    )
-                    m_l = _step_metrics(loss_l, n_l)
-                    # stack the peeled micro back onto the head so the metric
-                    # sum reduces over the SAME length-A array as the barrier
-                    # cycle (identical reduction order, bitwise totals)
-                    with _prof.scope(_prof.METRICS):
-                        stacked = jax.tree_util.tree_map(
-                            lambda h, last: jnp.concatenate(
-                                [h, last[None]], axis=0
-                            ),
-                            head_stacked, m_l,
-                        )
-                    new_params, new_opt_state, new_comm, new_skipped = apply_reduced(
-                        st.params, st.opt_state, agg, cand_comm,
-                        st.comm_state, st.skipped_steps,
-                    )
+                (st, gacc, nacc), stacked = jax.lax.scan(
+                    micro, (st, zeros, jnp.zeros((), jnp.float32)), cyc_batch
+                )
+                # exact weighted mean even for fractional sample weights
+                # (guard only the all-padding nacc==0 case, like nn/loss.py)
+                denom = jnp.where(nacc == 0, 1.0, nacc)
+                g = jax.tree_util.tree_map(lambda a: a / denom, gacc)
+                # the firewall (guard=True) checks THIS aggregated
+                # cycle-mean gradient: one poisoned micro-batch skips the
+                # whole cycle's update, bitwise
+                new_params, new_opt_state, new_comm, new_skipped = apply_update(
+                    st.params, st.opt_state, g, st.comm_state, st.skipped_steps
+                )
                 model_state = st.model_state
                 if guard:
                     # a skipped cycle also reverts the buffers the cycle's
@@ -1149,7 +816,6 @@ def build_train_scan_step(
         xs, ys, ws = stacked_batch
         return jitted(state, xs, ys, ws)
 
-    step.jitted = jitted  # for HLO inspection (see build_train_step)
     return step
 
 

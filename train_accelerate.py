@@ -372,8 +372,7 @@ def run_training_loop(
             flight.describe() if flight is not None else False
         ),
     }
-    # v10 comm block: the managed path always runs the barrier exchange
-    # (XLA-inserted psum); the header records that resolution explicitly
+    # v10 comm block: the exchange trails the backward pass (XLA-inserted psum)
     _overlap = getattr(accelerator, "comm_overlap_meta", None)
     metrics_writer.write(make_run_meta(
         mesh=getattr(accelerator, "mesh", None),
@@ -839,9 +838,6 @@ def basic_accelerate_training(
         comm_hook=str(training.get("comm_hook") or "none"),
         bucket_cap_mb=float(training.get("bucket_cap_mb") or 25),
         comm_topology=str(training.get("comm_topology") or "flat"),
-        # comm_overlap parity: "auto"/false record disabled provenance here
-        # (the managed collective is XLA-inserted); true refuses loudly
-        comm_overlap=training.get("comm_overlap", "auto"),
         topk_density=float(training.get("topk_density") or 0.1),
         # numerical guard (resilience/guard.py): non-finite-update firewall
         # in the fused/scan/accumulation programs + prepare-time desync audit

@@ -163,10 +163,6 @@ def basic_ddp_training_loop(
         comm_hook=str(training.get("comm_hook") or "none"),
         bucket_cap_mb=float(training.get("bucket_cap_mb") or 25),
         comm_topology=comm_topology,
-        # segmented-backward overlap (training/step.py): issue each bucket
-        # group's collective inside the backward walk instead of one trailing
-        # block; "auto" enables it only where it genuinely segments
-        comm_overlap=training.get("comm_overlap", "auto"),
         topk_density=float(training.get("topk_density") or 0.1),
         # numerical guard (resilience/guard.py): non-finite-update firewall +
         # desync auditor + rollback-to-last-good; off (exact legacy step)
