@@ -14,6 +14,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 from benchmark import cells
 from tpuddp import nn
 from tpuddp.models import QWEN3_NEXT_EP16, load_model
+from tpuddp.nn import deltanet
 from tpuddp.nn import moe as moe_lib
 from tpuddp.nn import sequence as seq
 from tpuddp.nn.core import Context
@@ -468,13 +469,26 @@ def test_the_fused_lowering_compiles_for_a_v5e(v5e, t, hq, hkv, d):
     assert "splash_mha_fwd" in text and "splash_mha_dkv" in text and "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("t,hk,hv,d", [(8192, 16, 32, 128), (512, 2, 2, 256)])
+def test_the_fused_scan_compiles_for_a_v5e(v5e, t, hk, hv, d):
+    """Forward and backward kernels of the scan's chunk-local phase (the
+    first shape is the token cell's) through Mosaic."""
+    sds = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct((1, t, *shape), dtype, sharding=v5e)
+    fused = lambda *a: deltanet._chunked_rule(*a, chunk=64, compute_dtype=jnp.bfloat16, fused=True)
+    grad = jax.grad(lambda *a: jnp.sum(jax.checkpoint(fused)(*a).astype(jnp.float32)), argnums=range(5))
+    vectors = sds(hv, dtype=jnp.float32)
+    text = jax.jit(grad).lower(sds(hk, d), sds(hk, d), sds(hv, d), vectors, vectors).compile().as_text()
+    assert "deltanet_chunk_fwd" in text and "deltanet_chunk_bwd" in text and "tpu_custom_call" in text
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("sequences,method", [(1, "train_step"), (2, "train_step_many")])
 def test_the_token_cells_step_compiles_for_a_v5e(v5e, published, system, monkeypatch, sequences, method):
     """The whole step at published widths (the check's single step at one
-    sequence, the timed 8-step program at two): inside it XLA keeps buffers of
-    its own in VMEM, and a block that compiled alone did not fit (PERF.md, PR
-    29). About a minute each."""
+    sequence, the timed 8-step program at two) with both fused lowerings,
+    attention's and the scan's: inside it XLA keeps buffers of its own in
+    VMEM, and a block that compiled alone did not fit (PERF.md, PR 29). About
+    a minute each."""
     from tpuddp.training.train_state import create_train_state
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # this process sees the CPU
@@ -491,3 +505,4 @@ def test_the_token_cells_step_compiles_for_a_v5e(v5e, published, system, monkeyp
     batch = (rows(jnp.int32), rows(jnp.int32), rows(jnp.float32))
     text = jax.jit(getattr(ddp, method)).lower(state, batch).compile().as_text()
     assert "splash_mha_fwd" in text and "splash_mha_dkv" in text
+    assert "deltanet_chunk_fwd" in text and "deltanet_chunk_bwd" in text

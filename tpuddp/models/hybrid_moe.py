@@ -19,7 +19,9 @@ loop is rolled (sequences, the DeltaNet chunks and the rows of its inverse,
 the expert rounds, the loss chunks, and attention's query blocks where
 attention runs blockwise: ``nn/sequence.py`` lowers it to one fused kernel a
 sequence on a TPU at the published widths, and to rolled query blocks on the
-CPU, at the tiny preset and under ``mode="auto"``); the layers of
+CPU, at the tiny preset and under ``mode="auto"``; ``nn/deltanet.py`` lowers
+what a DeltaNet chunk computes alone, the inverse's rows among it, to a fused
+kernel pair under the same conditions); the layers of
 the period are a Python loop, because their parameters are one tree a layer,
 as the published checkpoint has them, and each layer keeps its own scope name.
 
@@ -198,9 +200,7 @@ class HybridMoELM(Module):
             v = qkv[..., 2 * hk * dk:].reshape(b, t, hv, dv)
             q = seq.l2_normalise(q) * (dk ** -0.5)
             k = seq.l2_normalise(k)
-            # each key head serves hv / hk value heads
-            q, k = (jnp.repeat(a, hv // hk, axis=2) for a in (q, k))
-        with _prof.scope("scan"):
+        with _prof.scope("scan"):  # each key head serves hv / hk value heads: the scan's own business
             o = deltanet.chunk_gated_delta_rule(q, k, v, g, beta, chunk=self.chunk, compute_dtype=cd)
         with _prof.scope("out_proj"):
             o = seq.rms_norm(o, p["norm"], self.rms_eps, gate=z.reshape(b, t, hv, dv))

@@ -17,7 +17,13 @@ Delta Rule", 2024). With ``G`` the running sum of ``g`` inside the chunk and
 
 Only the last line and ``V'`` depend on the chunk before, so the scan over
 chunks carries ``S`` through two small products a chunk and everything else
-is batched over chunks. Every exponent is a difference of running sums with
+is batched over chunks. What a chunk computes alone (the first two lines,
+the decayed keys, queries and score block) has two lowerings, picked by
+:func:`scan_lowering` from what a call can see: on a TPU, at chunk 64 and
+widths that are multiples of 128, traced once a device, a Pallas kernel pair
+(``nn/deltanet_kernels.py``) that keeps the ``C x C`` blocks in VMEM;
+everywhere else the plain XLA below, which the kernels are tested against.
+Every exponent is a difference of running sums with
 the later one first, so none is positive. Decay sums, ``T`` and the state are
 float32; products take ``compute_dtype`` inputs and accumulate in float32.
 """
@@ -27,9 +33,11 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from tpuddp.nn.sequence import round_to
+from tpuddp.nn.sequence import _LANES, _traced_per_replica, round_to
 
 _BASE = 16  # block inverted by forward substitution; larger blocks by halves
+_FUSED_CHUNK = 64  # the chunk the kernels' blocks were laid out for
+_FUSED_BLOCK = 8  # chunks a grid step of theirs
 _EXACT = jax.lax.Precision.HIGHEST
 
 
@@ -90,14 +98,50 @@ def _invert_bwd(inv, g):
 _invert_unit_lower.defvjp(_invert_fwd, _invert_bwd)
 
 
+def scan_lowering(backend: str, chunk: int, dk: int, dv: int, t: int, *, per_replica: bool) -> str:
+    """``"fused"`` or ``"plain"``: where the chunk-local phase runs. The kernel
+    pair (``nn/deltanet_kernels.py``) is written for the TPU's tiles: chunks
+    of 64, key and value widths that fill whole 128-lane registers, a length
+    that is a whole number of its grid steps (:func:`fused_scan_block`); it is
+    a custom call, which GSPMD cannot partition, so it serves only a call that
+    is traced once a device (``per_replica``). Everything else, the CPU first,
+    takes the plain path."""
+    if backend == "tpu" and per_replica and chunk == _FUSED_CHUNK and dk % _LANES == 0 and dv % _LANES == 0:
+        if fused_scan_block(t, chunk) is not None:
+            return "fused"
+    return "plain"
+
+
+def fused_scan_block(t: int, chunk: int):
+    """Chunks a grid step of the kernels, or ``None`` for a length that is no
+    whole number of them."""
+    return _FUSED_BLOCK if t > 0 and t % (_FUSED_BLOCK * chunk) == 0 else None
+
+
 def chunk_gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64, compute_dtype=jnp.float32):
-    """``q``, ``k``: ``(B, T, H, Dk)`` (already normalised and scaled);
-    ``v``: ``(B, T, H, Dv)``; ``g`` (log decay, <= 0) and ``beta``:
-    ``(B, T, H)`` float32. Returns ``o`` of ``(B, T, H, Dv)`` in ``v``'s type.
-    ``T`` need not be a multiple of ``chunk``: the tail is padded with tokens
-    that leave the state as it is (``beta`` 0, ``g`` 0)."""
-    b, t, h, dk = q.shape
-    dv = v.shape[-1]
+    """``q``, ``k``: ``(B, T, Hk, Dk)`` (already normalised and scaled);
+    ``v``: ``(B, T, H, Dv)``, each key head serving ``H / Hk`` value heads in
+    turn; ``g`` (log decay, <= 0) and ``beta``: ``(B, T, H)`` float32. Returns
+    ``o`` of ``(B, T, H, Dv)`` in ``v``'s type. ``T`` need not be a multiple
+    of ``chunk``: the tail is padded with tokens that leave the state as it
+    is (``beta`` 0, ``g`` 0).
+
+    One contract and two lowerings of what a chunk computes alone, chosen by
+    :func:`scan_lowering` from the backend, the shapes and where the call is
+    traced: a fused kernel pair that keeps the ``C x C`` blocks in VMEM, or
+    plain XLA, which holds them in HBM. The carry over chunks and the two
+    products that read its states are the same code after either."""
+    lowering = scan_lowering(
+        jax.default_backend(), chunk, q.shape[-1], v.shape[-1], q.shape[1], per_replica=_traced_per_replica()
+    )
+    return _chunked_rule(q, k, v, g, beta, chunk=chunk, compute_dtype=compute_dtype, fused=lowering == "fused")
+
+
+def _chunked_rule(q, k, v, g, beta, *, chunk, compute_dtype, fused: bool, interpret: bool = False):
+    """``interpret`` runs the kernels in Pallas's interpreter: the CPU tests'
+    way in."""
+    b, t, h, dv = v.shape
+    dk = q.shape[-1]
     pad = -t % chunk
     if pad:
         widen = lambda a: jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
@@ -108,7 +152,6 @@ def chunk_gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64, compute_dtype=j
         a = a.reshape(b, n, chunk, *a.shape[2:])
         return jnp.moveaxis(a, 3, 1)
 
-    q, k, v = chunked(q), chunked(k), chunked(v)
     g, beta = chunked(g.astype(jnp.float32)), chunked(beta.astype(jnp.float32))
     f32 = jnp.float32
     product = lambda spec, x, y: jnp.einsum(
@@ -116,15 +159,27 @@ def chunk_gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64, compute_dtype=j
     )
 
     gsum = jnp.cumsum(g, axis=-1)  # (B, H, N, C)
-    i, j = jnp.arange(chunk)[:, None], jnp.arange(chunk)[None, :]
-    decay = jnp.exp(jnp.where(i >= j, gsum[..., :, None] - gsum[..., None, :], -jnp.inf))
-    k_beta = k.astype(f32) * beta[..., None]
-    a = jnp.where(i > j, product("bhnid,bhnjd->bhnij", k_beta, k) * decay, 0.0)
-    t_inv = _invert_unit_lower(a)
-    u = product("bhnij,bhnjd->bhnid", t_inv, v.astype(f32) * beta[..., None])
-    w = product("bhnij,bhnjd->bhnid", t_inv, k_beta * jnp.exp(gsum)[..., None])
     g_last = gsum[..., -1]  # (B, H, N)
-    k_tail = k.astype(f32) * jnp.exp(g_last[..., None] - gsum)[..., None]
+    if fused:
+        from tpuddp.nn import deltanet_kernels  # pulls in Pallas and Mosaic, which nothing else here needs
+
+        u, w, k_tail, q_grown, scores = deltanet_kernels.chunk_local(
+            q, k, v, gsum, beta, chunk, fused_scan_block(n * chunk, chunk), jnp.dtype(compute_dtype), interpret
+        )
+    else:
+        # each key head serves h / hk value heads
+        q, k = (jnp.repeat(a, h // a.shape[2], axis=2) for a in (q, k))
+        q, k, v = chunked(q), chunked(k), chunked(v)
+        i, j = jnp.arange(chunk)[:, None], jnp.arange(chunk)[None, :]
+        decay = jnp.exp(jnp.where(i >= j, gsum[..., :, None] - gsum[..., None, :], -jnp.inf))
+        k_beta = k.astype(f32) * beta[..., None]
+        a = jnp.where(i > j, product("bhnid,bhnjd->bhnij", k_beta, k) * decay, 0.0)
+        t_inv = _invert_unit_lower(a)
+        u = product("bhnij,bhnjd->bhnid", t_inv, v.astype(f32) * beta[..., None])
+        w = product("bhnij,bhnjd->bhnid", t_inv, k_beta * jnp.exp(gsum)[..., None])
+        k_tail = k.astype(f32) * jnp.exp(g_last[..., None] - gsum)[..., None]
+        q_grown = q.astype(f32) * jnp.exp(gsum)[..., None]
+        scores = product("bhnid,bhnjd->bhnij", q, k) * decay
 
     def step(state, xs):
         w_i, u_i, k_i, decay_i = xs
@@ -138,7 +193,7 @@ def chunk_gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64, compute_dtype=j
         (over_chunks(w), over_chunks(u), over_chunks(k_tail), over_chunks(jnp.exp(g_last))),
     )
     starts, v_new = jnp.moveaxis(starts, 0, 2), jnp.moveaxis(v_new, 0, 2)
-    inter = product("bhncd,bhnde->bhnce", q.astype(f32) * jnp.exp(gsum)[..., None], starts)
-    intra = product("bhnij,bhnje->bhnie", product("bhnid,bhnjd->bhnij", q, k) * decay, v_new)
+    inter = product("bhncd,bhnde->bhnce", q_grown, starts)
+    intra = product("bhnij,bhnje->bhnie", scores, v_new)
     o = jnp.moveaxis(inter + intra, 1, 3).reshape(b, n * chunk, h, dv)
     return o[:, :t].astype(v.dtype)
