@@ -4,6 +4,7 @@ nn/deltanet.py, nn/moe.py, nn/sequence.py) against its plain reference
 CPU: seeded random weights, float32 unless a test says otherwise."""
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -143,6 +144,40 @@ def test_deltanet_mixer_matches_the_reference(reference, system, tiny):
     theirs = lambda p, x: jnp.sum(jnp.sin(reference.deltanet_mixer(tiny, p, x)))
     np.testing.assert_allclose(model._deltanet(p, x), reference.deltanet_mixer(tiny, p, x), rtol=2e-4, atol=2e-5)
     _close(jax.grad(ours, argnums=(0, 1))(p, x), jax.grad(theirs, argnums=(0, 1))(p, x), 5e-4)
+
+
+def test_the_tiny_hybrid_is_bit_equal_to_the_mixers_old_expression(system, tiny, monkeypatch):
+    """``deltanet.short_conv``'s plain path is what ``_deltanet`` wrote out
+    under its ``conv`` scope before the function existed, moved and not
+    rewritten: with that expression put back in the function's place, the
+    tiny hybrid's loss and every gradient are the same to the bit, in float32
+    and with bfloat16 products."""
+    def old(qkv, taps, *, key_width, head_dim, q_scale):
+        b, t = qkv.shape[:2]
+        qkv = jax.nn.silu(seq.causal_conv1d(qkv, taps))
+        q = qkv[..., :key_width].reshape(b, t, -1, head_dim)
+        k = qkv[..., key_width: 2 * key_width].reshape(b, t, -1, head_dim)
+        v = qkv[..., 2 * key_width:]
+        q = seq.l2_normalise(q) * q_scale
+        k = seq.l2_normalise(k)
+        return q.reshape(b, t, -1), k.reshape(b, t, -1), v
+
+    tokens = jnp.asarray(np.random.RandomState(5).randint(0, VOCAB, (2, 40)), jnp.int32)
+    for compute_dtype in ("float32", "bfloat16"):
+        model = _model(system, tiny, compute_dtype=compute_dtype)
+        params = _perturbed(model.init(jax.random.key(3), tokens)[0])
+
+        def loss(params):
+            out, _ = model.apply(params, (), tokens[:, :-1], Context(train=True))
+            return nn.CrossEntropyLoss()(out, tokens[:, 1:])
+
+        ours = jax.value_and_grad(loss)(params)
+        monkeypatch.setattr(deltanet, "short_conv", old)
+        theirs = jax.value_and_grad(loss)(params)
+        monkeypatch.undo()
+        for a, b in zip(jax.tree_util.tree_leaves(ours), jax.tree_util.tree_leaves(theirs)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert float(ours[0]) > 0 and all(float(jnp.abs(g).max()) > 0 for g in jax.tree_util.tree_leaves(ours[1]["layers"][0]["mixer"]))
 
 
 def test_gated_attention_mixer_matches_the_reference(reference, system, tiny):
@@ -487,6 +522,26 @@ def test_the_fused_scan_compiles_for_a_v5e(v5e, t, hk, hv, d):
     assert "deltanet_chunk_fwd" in text and "deltanet_chunk_bwd" in text and "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("t,channels,key_width,d,taps", [(8192, 8192, 2048, 128, 4), (512, 1024, 256, 256, 8)])
+def test_the_fused_conv_compiles_for_a_v5e(v5e, t, channels, key_width, d, taps):
+    """Forward and backward kernels of the DeltaNet mixer's short convolution
+    (the first shape is the token cell's) through Mosaic, the forward once an
+    output."""
+    from tpuddp.nn import deltanet_conv_kernels
+
+    rows = jax.ShapeDtypeStruct((1, t, channels), jnp.bfloat16, sharding=v5e)
+    filt = jax.ShapeDtypeStruct((taps, channels), jnp.float32, sharding=v5e)
+    fused = lambda x, w: deltanet_conv_kernels.short_conv(x, w, key_width, d, d ** -0.5, 1e-6, False)
+
+    def loss(x, w):
+        first, again = fused(x, w), jax.checkpoint(fused)(x, w)  # the outputs are used, so the forward stays
+        return sum(jnp.sum(jnp.sin(a.astype(jnp.float32)) * b.astype(jnp.float32)) for a, b in zip(first, again))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(rows, filt).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') >= 4  # three forwards, one backward at least
+    assert "deltanet_conv_fwd" in text and "deltanet_conv_bwd" in text
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("workload,sequences,method", [
     (WORKLOAD, 1, "train_step"), (WORKLOAD, 2, "train_step_many"),
@@ -495,7 +550,8 @@ def test_the_fused_scan_compiles_for_a_v5e(v5e, t, hk, hv, d):
 def test_the_token_cells_step_compiles_for_a_v5e(v5e, monkeypatch, workload, sequences, method):
     """The whole step at published widths (the check's single step at one
     sequence, the timed 8-step program at the cell's batch) with its fused
-    lowerings, attention's (full and banded) and the scan's: inside it XLA
+    lowerings, attention's (full and banded), the scan's and the short
+    convolution's: inside it XLA
     keeps buffers of its own in VMEM, and a block that compiled alone did not
     fit (PERF.md, PR 29). About a minute each."""
     from tpuddp.training.train_state import create_train_state
@@ -517,6 +573,10 @@ def test_the_token_cells_step_compiles_for_a_v5e(v5e, monkeypatch, workload, seq
     assert "splash_mha_fwd" in text and "splash_mha_dkv" in text
     deltanet_layers = "GatedDeltaNet" in model.layer_types
     assert ("deltanet_chunk_fwd" in text and "deltanet_chunk_bwd" in text) == deltanet_layers
+    assert ("deltanet_conv_fwd" in text and "deltanet_conv_bwd" in text) == deltanet_layers  # the short convolution's
     # the grouped products: Pallas where an expert's rows are many (nn/moe.py: grouped_tiles)
     many_rows = cell.traffic["batch_per_chip"] * t * model.top_k // model.n_experts >= 1024
-    assert ("gmm" in text) == many_rows and ("tgmm" in text) == many_rows  # forward kernel, weight-gradient kernel
+    # forward kernel, weight-gradient kernel; by the instruction's name (a kernel's serialised body is
+    # base64, in which three letters turn up by chance)
+    named = lambda kernel: re.search(rf"%{kernel}(\.\d+)? = ", text) is not None
+    assert named("gmm") == many_rows and named("tgmm") == many_rows

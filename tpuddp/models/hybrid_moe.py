@@ -35,8 +35,8 @@ the expert rounds, the loss chunks, and attention's query blocks where
 attention runs blockwise: ``nn/sequence.py`` lowers it to one fused kernel a
 sequence on a TPU at the published widths, and to rolled query blocks on the
 CPU, at the tiny presets and under ``mode="auto"``; ``nn/deltanet.py`` lowers
-what a DeltaNet chunk computes alone, the inverse's rows among it, to a fused
-kernel pair under the same conditions); the layers are a Python loop, because
+what a DeltaNet chunk computes alone (the inverse's rows among it) and the short
+convolution before the scan to fused kernel pairs under the same conditions); the layers are a Python loop, because
 their parameters are one tree a layer, as the published checkpoints have
 them, and each layer keeps its own scope name.
 
@@ -237,12 +237,12 @@ class HybridMoELM(Module):
             beta = jax.nn.sigmoid(ba[..., :hv])
             g = -jnp.exp(p["A_log"]) * jax.nn.softplus(ba[..., hv:] + p["dt_bias"])
         with _prof.scope("conv"):
-            qkv = jax.nn.silu(seq.causal_conv1d(qkv, p["conv"]))
-            q = qkv[..., : hk * dk].reshape(b, t, hk, dk)
-            k = qkv[..., hk * dk: 2 * hk * dk].reshape(b, t, hk, dk)
-            v = qkv[..., 2 * hk * dk:].reshape(b, t, hv, dv)
-            q = seq.l2_normalise(q) * (dk ** -0.5)
-            k = seq.l2_normalise(k)
+            # causal convolution, SiLU and the l2 norms of queries and keys: one function with its own
+            # backward rule, a fused kernel pair where deltanet.conv_lowering takes the shapes
+            q, k, v = deltanet.short_conv(
+                qkv, p["conv"], key_width=hk * dk, head_dim=dk, q_scale=dk ** -0.5
+            )
+            q, k, v = q.reshape(b, t, hk, dk), k.reshape(b, t, hk, dk), v.reshape(b, t, hv, dv)
         with _prof.scope("scan"):  # each key head serves hv / hk value heads: the scan's own business
             o = deltanet.chunk_gated_delta_rule(q, k, v, g, beta, chunk=self.chunk, compute_dtype=cd)
         with _prof.scope("out_proj"):

@@ -1,4 +1,4 @@
-"""The gated delta rule in chunks.
+"""The gated delta rule in chunks, and the short convolution before it.
 
 Per head and token ``t``, with a state ``S`` of ``Dk x Dv`` (key by value)
 that starts at zero::
@@ -26,6 +26,18 @@ everywhere else the plain XLA below, which the kernels are tested against.
 Every exponent is a difference of running sums with
 the later one first, so none is positive. Decay sums, ``T`` and the state are
 float32; products take ``compute_dtype`` inputs and accumulate in float32.
+
+What stands between a mixer's input projection and this scan
+(:func:`short_conv`: a depthwise causal convolution over time, SiLU, the l2
+norms of queries and keys) is the family's third shape-selected lowering
+(after attention's, ``nn/sequence.py``, and the scan's): :func:`conv_lowering`
+picks, under the scan's conditions on backend and tracing, heads of whole
+lane registers, at most 8 taps and a length of whole 512-row tiles, a Pallas
+kernel pair with its own backward rule (``nn/deltanet_conv_kernels.py``) that
+reads the rows once each way, computes in float32 and rounds each output
+once; everywhere else (the CPU, the tiny preset's heads of 16, ragged
+lengths, ``mode="auto"`` over several devices) the plain XLA the mixer always
+ran, which the kernels are tested against.
 """
 
 from __future__ import annotations
@@ -33,7 +45,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from tpuddp.nn.sequence import _LANES, round_to, traced_per_replica
+from tpuddp.nn.sequence import _LANES, causal_conv1d, l2_normalise, round_to, traced_per_replica
 
 _BASE = 16  # block inverted by forward substitution; larger blocks by halves
 _FUSED_CHUNK = 64  # the chunk the kernels' blocks were laid out for
@@ -116,6 +128,66 @@ def fused_scan_block(t: int, chunk: int):
     """Chunks a grid step of the kernels, or ``None`` for a length that is no
     whole number of them."""
     return _FUSED_BLOCK if t > 0 and t % (_FUSED_BLOCK * chunk) == 0 else None
+
+
+def conv_lowering(
+    backend: str, channels: int, key_width: int, head_dim: int, taps: int, t: int, *, per_replica: bool
+) -> str:
+    """``"fused"`` or ``"plain"``: where :func:`short_conv` runs. The kernel
+    pair (``nn/deltanet_conv_kernels.py``) is written for the TPU's tiles:
+    heads that fill whole 128-lane registers, key and value columns that are
+    whole channel tiles of them, taps that reach no further back than one
+    float32 register's 8 rows, a length that is a whole number of row tiles;
+    like the scan's it is a custom call and serves only a call that is traced
+    once a device (``per_replica``). Everything else, the CPU first, takes
+    the plain path."""
+    if backend == "tpu" and per_replica:
+        kernels = _conv_kernels()
+        if (
+            0 < taps <= kernels.MAX_TAPS and kernels.row_tile(t) is not None
+            and kernels.channel_tile(channels, key_width, head_dim) is not None
+        ):
+            return "fused"
+    return "plain"
+
+
+def _conv_kernels():
+    from tpuddp.nn import deltanet_conv_kernels  # pulls in Pallas and Mosaic, which nothing else here needs
+
+    return deltanet_conv_kernels
+
+
+def short_conv(qkv, taps, *, key_width: int, head_dim: int, q_scale: float, eps: float = 1e-6):
+    """What stands between a DeltaNet mixer's input projection and its scan.
+    ``qkv``: ``(B, T, C)`` rows, queries | keys | values along ``C``, the
+    first two ``key_width`` wide in heads of ``head_dim``; ``taps``:
+    ``(K, C)``. A depthwise causal convolution over time
+    (:func:`~tpuddp.nn.sequence.causal_conv1d`), SiLU, then for queries and
+    keys the l2 norm over each head (:func:`~tpuddp.nn.sequence.l2_normalise`)
+    and for queries ``q_scale``. Returns ``q``, ``k`` of ``(B, T, key_width)``
+    and ``v`` of the rest, in ``qkv``'s type.
+
+    One contract (rows in ``qkv``'s type, arithmetic in float32) and two
+    lowerings, chosen by :func:`conv_lowering` from the backend, the shapes
+    and where the call is traced: a fused kernel pair with its own backward
+    rule that passes over the rows once each way and rounds each output once,
+    or plain XLA, which rounds after the convolution, the activation, the
+    norm and the scale, as its backward pass does after each tap."""
+    lowering = conv_lowering(
+        jax.default_backend(), qkv.shape[-1], key_width, head_dim, taps.shape[0], qkv.shape[1],
+        per_replica=traced_per_replica(),
+    )
+    if lowering == "fused":
+        return _conv_kernels().short_conv(qkv, taps, key_width, head_dim, q_scale, eps, False)
+    return _plain_short_conv(qkv, taps, key_width, head_dim, q_scale, eps)
+
+
+def _plain_short_conv(qkv, taps, key_width, head_dim, q_scale, eps):
+    qkv = jax.nn.silu(causal_conv1d(qkv, taps))
+    heads = lambda a: a.reshape(*a.shape[:-1], -1, head_dim)
+    flat = lambda a: a.reshape(*a.shape[:-2], key_width)
+    q, k, v = qkv[..., :key_width], qkv[..., key_width: 2 * key_width], qkv[..., 2 * key_width:]
+    return flat(l2_normalise(heads(q), eps) * q_scale), flat(l2_normalise(heads(k), eps)), v
 
 
 def chunk_gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64, compute_dtype=jnp.float32):
