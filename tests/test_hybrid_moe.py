@@ -195,7 +195,7 @@ def test_gated_attention_mixer_matches_the_reference(reference, system, tiny):
 
 
 def _moe_ours(model, p, x, **kw):
-    y, aux, counters = moe_lib.expert_share_moe(
+    y, aux, counters, _ = moe_lib.expert_share_moe(
         p, x.reshape(-1, x.shape[-1]), top_k=model.top_k, first_expert=model.first_expert,
         compute_dtype=jnp.float32, **kw,
     )
@@ -232,7 +232,7 @@ def test_the_shares_add_up_to_the_uncut_layer(reference, system, tiny):
     total, seen = shared, 0.0
     for share in range(n_all // held):
         mine = {**p, "experts": jax.tree_util.tree_map(lambda w: w[share * held:(share + 1) * held], p["experts"])}
-        y, _, counters = moe_lib.expert_share_moe(
+        y, _, counters, _ = moe_lib.expert_share_moe(
             mine, flat, top_k=model.top_k, first_expert=share * held, compute_dtype=jnp.float32
         )
         ref_y, _ = reference.moe({**tiny, "deployment": {**tiny["deployment"], "first_expert": share * held}}, mine, x)
@@ -496,18 +496,27 @@ def v5e():
     (8192, 16, 2, 256, None), (1536, 4, 2, 128, None), (3072, 8, 8, 256, None),
     (16384, 32, 4, 128, None), (16384, 32, 4, 128, 1024),  # the window-and-full cell's two layer types
     (1536, 4, 2, 128, 400),
+    (32768, 32, 8, 64, None),  # the convolution-and-attention cell's: heads of 64, the two-kernel backward
+    (1536, 4, 2, 64, None),  # heads of 64 under the one-kernel backward
 ])
 def test_the_fused_lowering_compiles_for_a_v5e(v5e, t, hq, hkv, d, window):
     """Forward and backward kernels at the blocks the rule picks (the first
     shape is the DeltaNet hybrid's cell's): Mosaic refuses here what it would
-    refuse on the chip, a block that does not fit VMEM first of all."""
+    refuse on the chip, a block that does not fit VMEM first of all. Past the
+    bound on the one-kernel backward's partial ``dq`` the query gradient has a
+    kernel of its own, and the program's scratch stays under 2 GB."""
     sds = lambda h: jax.ShapeDtypeStruct((1, t, h, d), jnp.bfloat16, sharding=v5e)
     fused = lambda q, k, v: seq._fused_causal_attention(
         q, k, v, scale=d ** -0.5, compute_dtype=jnp.bfloat16, window=window
     )
     grad = jax.grad(lambda q, k, v: jnp.sum(jax.checkpoint(fused)(q, k, v).astype(jnp.float32)), argnums=(0, 1, 2))
-    text = jax.jit(grad).lower(sds(hq), sds(hkv), sds(hkv)).compile().as_text()
+    compiled = jax.jit(grad).lower(sds(hq), sds(hkv), sds(hkv)).compile()
+    text = compiled.as_text()
     assert "splash_mha_fwd" in text and "splash_mha_dkv" in text and "tpu_custom_call" in text
+    assert ("splash_mha_dq" in text) == (not seq.fused_attention_blocks(t, d, window).use_fused_bwd_kernel)
+    assert ("splash_mha_dq" in text) == (t > 16384)
+    if t > 16384:
+        assert compiled.memory_analysis().temp_size_in_bytes < 2e9
 
 
 @pytest.mark.parametrize("t,hk,hv,d", [(8192, 16, 32, 128), (512, 2, 2, 256)])
@@ -546,10 +555,11 @@ def test_the_fused_conv_compiles_for_a_v5e(v5e, t, channels, key_width, d, taps)
 @pytest.mark.parametrize("workload,sequences,method", [
     (WORKLOAD, 1, "train_step"), (WORKLOAD, 2, "train_step_many"),
     ("mellum2_ep4_t16k_fused", 1, "train_step"), ("mellum2_ep4_t16k_fused", 1, "train_step_many"),
+    ("lfm2_ep4_t32k_fused", 1, "train_step"), ("lfm2_ep4_t32k_fused", 1, "train_step_many"),
 ])
 def test_the_token_cells_step_compiles_for_a_v5e(v5e, monkeypatch, workload, sequences, method):
     """The whole step at published widths (the check's single step at one
-    sequence, the timed 8-step program at the cell's batch) with its fused
+    sequence, the timed K-step program at the cell's batch) with its fused
     lowerings, attention's (full and banded), the scan's and the short
     convolution's: inside it XLA
     keeps buffers of its own in VMEM, and a block that compiled alone did not
@@ -566,11 +576,15 @@ def test_the_token_cells_step_compiles_for_a_v5e(v5e, monkeypatch, workload, seq
         lambda k: create_train_state(model, ddp.optimizer, k, jnp.zeros((1, t), jnp.int32)), jax.random.key(0)
     )
     state = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=NamedSharding(mesh, P())), state)
-    lead, spec = ((), P("data")) if method == "train_step" else ((8,), P(None, "data"))
+    lead, spec = ((), P("data")) if method == "train_step" else ((cell.traffic["scan_steps"],), P(None, "data"))
     rows = lambda dtype: jax.ShapeDtypeStruct((*lead, sequences, t), dtype, sharding=NamedSharding(mesh, spec))
     batch = (rows(jnp.int32), rows(jnp.int32), rows(jnp.float32))
-    text = jax.jit(getattr(ddp, method)).lower(state, batch).compile().as_text()
+    compiled = jax.jit(getattr(ddp, method)).lower(state, batch).compile()
+    text = compiled.as_text()
     assert "splash_mha_fwd" in text and "splash_mha_dkv" in text
+    assert ("splash_mha_dq" in text) == (t > 16384)  # the two-kernel backward past the partials' bound
+    memory = compiled.memory_analysis()  # nothing donated here: the state once as argument, once as result
+    assert memory.temp_size_in_bytes + memory.argument_size_in_bytes < 14.5e9
     deltanet_layers = "GatedDeltaNet" in model.layer_types
     assert ("deltanet_chunk_fwd" in text and "deltanet_chunk_bwd" in text) == deltanet_layers
     assert ("deltanet_conv_fwd" in text and "deltanet_conv_bwd" in text) == deltanet_layers  # the short convolution's

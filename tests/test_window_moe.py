@@ -303,7 +303,7 @@ def test_attention_mixers_match_the_reference(reference, system, tiny, index, la
 
 
 def _moe_ours(model, p, x, **kw):
-    y, aux, counters = moe_lib.expert_share_moe(
+    y, aux, counters, _ = moe_lib.expert_share_moe(
         p, x.reshape(-1, x.shape[-1]), top_k=model.top_k, first_expert=model.first_expert,
         compute_dtype=jnp.float32, **kw,
     )
@@ -345,7 +345,7 @@ def test_the_shares_add_up_to_the_uncut_layer(reference, system, tiny):
     total, seen = 0.0, 0.0
     for share in range(n_all // held):
         mine = {**p, "experts": jax.tree_util.tree_map(lambda w: w[share * held:(share + 1) * held], p["experts"])}
-        y, _, counters = moe_lib.expert_share_moe(
+        y, _, counters, _ = moe_lib.expert_share_moe(
             mine, flat, top_k=model.top_k, first_expert=share * held, compute_dtype=jnp.float32
         )
         ref_y, _ = reference.moe({**tiny, "deployment": {**tiny["deployment"], "first_expert": share * held}}, mine, x)

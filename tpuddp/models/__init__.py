@@ -4,9 +4,12 @@ adds genuinely small toy models for fast CI (per SURVEY.md scale calibration),
 ResNet-18/34 (BasicBlock) + ResNet-50/101/152 (Bottleneck), VGG-11/13/16/19, and
 CIFAR-stem variants (the ``*_s2d`` names are aliases of the plain ones); all
 torch-importable. Token models: a small GPT-2-style ``TransformerLM`` and
-``HybridMoELM``, sparse-expert decoders whose layers are listed by type
-(Gated DeltaNet, gated attention, sliding-window and full attention), built at
-published widths as one chip's share of an expert-parallel job."""
+``HybridMoELM``, expert decoders whose layers are listed by type (five mixer
+types: Gated DeltaNet, gated attention, sliding-window and full attention, a
+gated short convolution), with a dense or a sparse feed-forward a layer, a
+tied or an untied head and, where the router chooses by a bias, that bias as
+the trunk's state; built at published widths as one chip's share of an
+expert-parallel job."""
 
 from tpuddp.models.toy import ToyCNN, ToyMLP  # noqa: F401
 from tpuddp.models.alexnet import AlexNet  # noqa: F401
@@ -16,7 +19,7 @@ from tpuddp.models.resnet import (  # noqa: F401
 )
 from tpuddp.models.vgg import VGG11, VGG13, VGG16, VGG19  # noqa: F401
 from tpuddp.models.hybrid_moe import (  # noqa: F401
-    MELLUM2_EP4, MELLUM2_TINY, QWEN3_NEXT_EP16, QWEN3_NEXT_TINY, HybridMoELM,
+    LFM2_EP4, LFM2_TINY, MELLUM2_EP4, MELLUM2_TINY, QWEN3_NEXT_EP16, QWEN3_NEXT_TINY, HybridMoELM,
 )
 
 from functools import partial as _partial
@@ -54,11 +57,16 @@ _REGISTRY = {
     # DeltaNet 3:1 with gated attention, a shared expert) at the published
     # widths as one chip of a 16-way expert-parallel job holds them, Mellum 2
     # (sliding-window 3:1 with YaRN-scaled full attention, no shared expert)
-    # as one chip of a 4-way job, and a CPU-test size of each
+    # as one chip of a 4-way job, LFM2 (gated short convolutions 3:1 with
+    # full attention over heads of 64, a dense leading layer, a sigmoid router
+    # balanced by a bias, a tied head) as one chip of a 4-way job, and a
+    # CPU-test size of each
     "qwen3_next_ep16": _partial(HybridMoELM, **QWEN3_NEXT_EP16),
     "qwen3_next_tiny": _partial(HybridMoELM, **QWEN3_NEXT_TINY),
     "mellum2_ep4": _partial(HybridMoELM, **MELLUM2_EP4),
     "mellum2_tiny": _partial(HybridMoELM, **MELLUM2_TINY),
+    "lfm2_ep4": _partial(HybridMoELM, **LFM2_EP4),
+    "lfm2_tiny": _partial(HybridMoELM, **LFM2_TINY),
     # aliases of the plain names: nn.Conv2d picks the space-to-depth lowering
     # of a thin-channel strided stem from its own shapes, so these build the
     # same program (kept for settings files and checkpoints that name them)

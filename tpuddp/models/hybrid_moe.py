@@ -1,11 +1,13 @@
 """Hybrid mixture-of-experts language models on one decoder trunk, as one chip
 of an expert-parallel job holds them: layers whose mixers differ in kind
 (linear attention, softmax attention over every earlier key or over a sliding
-window) and whose feed-forward is always sparse.
+window, a gated short convolution), whose feed-forward is sparse or dense,
+whose head is the embedding's transpose or a matrix of its own, and which may
+carry state beside their parameters (a router's selection bias).
 
-Every layer is ``h = x + Mixer(RMSNorm(x))``, ``y = h + MoE(RMSNorm(h))``.
+Every layer is ``h = x + Mixer(RMSNorm(x))``, ``y = h + FF(RMSNorm(h))``.
 ``layer_types`` lists each layer's type, which names its mixer and its scope
-(``<i>_<type>``):
+(``<i>_<type>``), five of them:
 
 - ``GatedDeltaNet`` (``nn/deltanet.py``) and ``GatedAttention`` (softmax
   attention with a per-head norm on queries and keys, rotary on part of the
@@ -18,20 +20,35 @@ Every layer is ``h = x + Mixer(RMSNorm(x))``, ``y = h + MoE(RMSNorm(h))``.
   layers, plain grouped-query softmax attention with the per-head norm and
   rotary on the whole head, no gate; the first sees ``sliding_window`` keys
   (a query's own and those before it) under the plain rotary table, the
-  second every earlier key under a YaRN-scaled table (``yarn``). Plain
-  RMSNorms (scale ``w``, from 1) and no shared expert (``shared_width=0``).
+  second every earlier key under a YaRN-scaled table (``yarn``; none: the
+  plain table). Plain RMSNorms (scale ``w``, from 1) and no shared expert
+  (``shared_width=0``).
+- ``ShortConv``: the LFM2 family's operator beside its ``FullAttention``
+  layers. One projection to three streams ``b | c | u``, a depthwise causal
+  convolution of ``conv_kernel`` taps over ``b * u`` and the gate ``c`` after
+  it (``seq.gated_short_conv``), one projection back: no activation, no
+  norm, no scan.
 
-Every layer's feed-forward: a router over all ``n_experts``, the ``top_k``
-largest renormalised, of which this chip computes the ``experts_held`` it
-holds (``nn/moe.py``). The embedding and the untied head cover the
-``num_classes`` rows of the vocabulary that this chip holds.
+A layer's feed-forward is what its tree holds. ``moe``: a router over all
+``n_experts``, ``top_k`` of them a token, of which this chip computes the
+``experts_held`` it holds (``nn/moe.py``); with ``expert_bias`` the router
+scores by sigmoid and chooses by score plus a per-expert bias, which is the
+model's state (a tuple a layer; no gradient reaches it): after each training
+step ``nn/moe.py:balanced_bias`` moves it by ``bias_update_rate`` towards an
+even load, from the step's counts over all experts, summed over the data
+axis. ``mlp``: a dense SwiGLU of ``dense_width``, the first ``dense_layers``
+layers', ``mlp_chunk`` tokens at a time. The embedding covers the
+``num_classes`` rows of the vocabulary that this chip holds; the head is a
+leaf of its own or, with ``tied_head``, the embedding transposed (no ``head``
+leaf: the one leaf's gradient is the sum of both uses).
 
 The training forward returns :class:`~tpuddp.nn.sequence.DeferredLogits`
 (the criterion takes the loss from the hidden states in chunks); evaluation
-returns ``(B, T, V)`` float32 logits. Each layer's mixer and expert layer are
+returns ``(B, T, V)`` float32 logits. Each layer's mixer and feed-forward are
 recomputed in the backward pass of a training step. Inside a layer every
 loop is rolled (sequences, the DeltaNet chunks and the rows of its inverse,
-the expert rounds, the loss chunks, and attention's query blocks where
+the expert rounds, the dense feed-forward's and the loss's chunks, and
+attention's query blocks where
 attention runs blockwise: ``nn/sequence.py`` lowers it to one fused kernel a
 sequence on a TPU at the published widths, and to rolled query blocks on the
 CPU, at the tiny presets and under ``mode="auto"``; ``nn/deltanet.py`` lowers
@@ -44,7 +61,10 @@ Registry names (``models/__init__.py``): ``qwen3_next_ep16``, the published
 widths of Qwen3-Next-80B-A3B as share 0 of 16 chips that divide each layer's
 experts (one period of four layers); ``mellum2_ep4``, those of
 Mellum2-12B-A2.5B as share 0 of 4 (one period: three sliding layers and a
-full one); ``qwen3_next_tiny`` and ``mellum2_tiny`` for the CPU tests.
+full one); ``lfm2_ep4``, those of LFM2-8B-A1B as share 0 of 4 (a leading
+dense layer and one period: a full-attention layer and three convolution
+layers); ``qwen3_next_tiny``, ``mellum2_tiny`` and ``lfm2_tiny`` for the CPU
+tests.
 """
 
 from __future__ import annotations
@@ -62,7 +82,8 @@ from tpuddp.observability import profiling as _prof
 
 DELTANET, ATTENTION = "GatedDeltaNet", "GatedAttention"
 SLIDING, FULL = "SlidingAttention", "FullAttention"
-LAYER_TYPES = (DELTANET, ATTENTION, SLIDING, FULL)
+SHORT_CONV = "ShortConv"
+LAYER_TYPES = (DELTANET, ATTENTION, SLIDING, FULL, SHORT_CONV)
 
 
 class HybridMoELM(Module):
@@ -87,7 +108,7 @@ class HybridMoELM(Module):
         rope_theta: float = 1e7,
         sliding_window=None,  # keys a SlidingAttention query sees, its own among them
         yarn=None,  # FullAttention's scaling of the rotary table (seq.rotary_frequencies)
-        # Gated DeltaNet
+        # Gated DeltaNet; conv_kernel is the gated short convolution's too
         linear_k_heads: int = 16,
         linear_v_heads: int = 32,
         linear_k_dim: int = 128,
@@ -102,11 +123,19 @@ class HybridMoELM(Module):
         expert_width: int = 512,
         shared_width: int = 512,
         aux_loss_weight: float = 0.001,
+        expert_bias: bool = False,  # a sigmoid router that chooses by score plus a bias (the model's state)
+        expert_bias_std: float = 0.0,  # the bias as init draws it: 0, or a seeded normal of this scale
+        bias_update_rate: float = 1e-3,
+        # the first dense_layers layers' feed-forward: a SwiGLU of dense_width, no experts
+        dense_layers: int = 0,
+        dense_width: int = 0,
+        tied_head: bool = False,  # the head is the embedding transposed
         rms_eps: float = 1e-6,
         init_std: float = 0.02,
         compute_dtype=jnp.float32,
         attention_q_block: int = 512,
         loss_chunk: int = 2048,
+        mlp_chunk: int = 8192,
     ):
         if first_expert < 0 or first_expert + experts_held > n_experts:
             raise ValueError(
@@ -140,15 +169,22 @@ class HybridMoELM(Module):
         self.first_expert, self.top_k = int(first_expert), int(top_k)
         self.expert_width, self.shared_width = int(expert_width), int(shared_width)
         self.aux_loss_weight = float(aux_loss_weight)
+        self.expert_bias, self.expert_bias_std = bool(expert_bias), float(expert_bias_std)
+        self.bias_update_rate = float(bias_update_rate)
+        self.dense_layers, self.dense_width = int(dense_layers), int(dense_width)
+        if self.dense_layers and not self.dense_width:
+            raise ValueError("a dense feed-forward needs its dense_width")
+        self.tied_head = bool(tied_head)
         self.rms_eps, self.init_std = float(rms_eps), float(init_std)
         self.compute_dtype = jnp.dtype(compute_dtype)
         self.attention_q_block, self.loss_chunk = int(attention_q_block), int(loss_chunk)
+        self.mlp_chunk = int(mlp_chunk)
 
     def layer_kind(self, i: int) -> str:
         return self.layer_types[i]
 
     def divergent_state(self) -> bool:
-        return False  # parameters only, no buffers
+        return False  # no buffers, or selection biases moved by counts summed over the data axis
 
     # ------------------------------------------------------------------ init --
     def init(self, key, x):
@@ -190,6 +226,20 @@ class HybridMoELM(Module):
                 "o_proj": normal(ks[3], (hq * d, e)),
             }
 
+        def short_conv_mixer(k):
+            ks = jax.random.split(k, 3)
+            return {
+                "in_proj": normal(ks[0], (e, 3 * e)),  # columns: b | c | u
+                "conv": jax.random.uniform(
+                    ks[1], (self.conv_kernel, e), jnp.float32, -1.0, 1.0
+                ) / math.sqrt(self.conv_kernel),
+                "out_proj": normal(ks[2], (e, e)),
+            }
+
+        def dense(k):
+            ks = jax.random.split(k, 2)
+            return {"gate_up": normal(ks[0], (e, 2 * self.dense_width)), "down": normal(ks[1], (self.dense_width, e))}
+
         def experts(k):
             ks = jax.random.split(k, 6)
             f, s, h = self.expert_width, self.shared_width, self.experts_held
@@ -206,21 +256,33 @@ class HybridMoELM(Module):
             }
 
         k_embed, k_head, k_layers = jax.random.split(key, 3)
-        layers = []
+        layers, biases = [], []
         for i in range(self.n_layers):
             k_mixer, k_moe = jax.random.split(jax.random.fold_in(k_layers, i))
             kind = self.layer_kind(i)
-            mixer = deltanet_mixer(k_mixer) if kind == DELTANET else attention_mixer(k_mixer, kind == ATTENTION)
+            if kind == DELTANET:
+                mixer = deltanet_mixer(k_mixer)
+            elif kind == SHORT_CONV:
+                mixer = short_conv_mixer(k_mixer)
+            else:
+                mixer = attention_mixer(k_mixer, kind == ATTENTION)
+            sparse = i >= self.dense_layers
             layers.append({
-                "input_norm": norm(e), "mixer": mixer, "post_norm": norm(e), "moe": experts(k_moe),
+                "input_norm": norm(e), "mixer": mixer, "post_norm": norm(e),
+                **({"moe": experts(k_moe)} if sparse else {"mlp": dense(k_moe)}),
             })
+            if self.expert_bias:
+                biases.append({"expert_bias": self.expert_bias_std * jax.random.normal(
+                    jax.random.fold_in(k_moe, 1), (self.n_experts,), jnp.float32
+                )} if sparse else ())
         params = {
             "embed": {"weight": normal(k_embed, (self.vocab_size, e))},
             "layers": tuple(layers),
             "final_norm": norm(e),
-            "head": {"weight": normal(k_head, (e, self.vocab_size))},
         }
-        return params, ()
+        if not self.tied_head:
+            params["head"] = {"weight": normal(k_head, (e, self.vocab_size))}
+        return params, tuple(biases)  # a selection bias a sparse layer, or nothing
 
     # --------------------------------------------------------------- mixers --
     def _norm(self, x, w):
@@ -282,29 +344,67 @@ class HybridMoELM(Module):
                 o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
             return seq.matmul(o.reshape(b, t, hq * d), p["o_proj"], cd)
 
+    def _short_conv(self, p, x):
+        cd = self.compute_dtype
+        with _prof.scope("in_proj"):
+            bcu = seq.matmul(x, p["in_proj"], cd)
+        with _prof.scope("conv"):
+            z = seq.gated_short_conv(bcu, p["conv"])
+        with _prof.scope("out_proj"):
+            return seq.matmul(z, p["out_proj"], cd)
+
     def _mix(self, kind, p, x):
         """``x + Mixer(RMSNorm(x))`` for one sequence ``(T, E)``."""
-        mixer = self._deltanet if kind == DELTANET else functools.partial(self._attention, kind=kind)
+        if kind == DELTANET:
+            mixer = self._deltanet
+        elif kind == SHORT_CONV:
+            mixer = self._short_conv
+        else:
+            mixer = functools.partial(self._attention, kind=kind)
         return x + mixer(p["mixer"], self._norm(x[None], p["input_norm"]))[0]
 
-    def _experts(self, p, h):
+    def _experts(self, p, bias, h):
         with _prof.scope("moe"):
-            y, aux, counters = moe.expert_share_moe(
+            y, aux, counters, router_counts = moe.expert_share_moe(
                 p["moe"], self._norm(h, p["post_norm"]).reshape(-1, self.hidden_size),
-                top_k=self.top_k, first_expert=self.first_expert, compute_dtype=self.compute_dtype,
+                top_k=self.top_k, first_expert=self.first_expert, compute_dtype=self.compute_dtype, bias=bias,
             )
-        return h + y.reshape(h.shape), aux, counters
+        return h + y.reshape(h.shape), aux, counters, router_counts
 
-    def _layer(self, kind, p, x, remat: bool):
-        """One layer. The mixer takes the batch's sequences one at a time and
-        the expert layer all their tokens at once; with ``remat`` each of the
-        two is recomputed in the backward pass, so a step keeps the residual
-        stream at both and one sequence's mixer or one expert layer's
-        activations."""
+    def _dense(self, p, h, remat: bool):
+        """``h + SwiGLU(RMSNorm(h))``, ``mlp_chunk`` tokens at a time (with
+        ``remat`` each chunk recomputed in the backward pass): the products'
+        float32 results are ``2 dense_width`` wide a token."""
+        def chunk(rows):
+            with _prof.scope("mlp"):
+                x = self._norm(rows, p["post_norm"])
+                return rows + seq.swiglu(x, p["mlp"]["gate_up"], p["mlp"]["down"], self.compute_dtype)
+
+        if remat:
+            chunk = jax.checkpoint(chunk)
+        rows = h.reshape(-1, self.hidden_size)
+        n, size = rows.shape[0], min(self.mlp_chunk, rows.shape[0])
+        whole, out = n // size, []
+        if whole:
+            out.append(jax.lax.map(chunk, rows[:whole * size].reshape(whole, size, -1)).reshape(whole * size, -1))
+        if whole * size < n:
+            out.append(chunk(rows[whole * size:]))
+        return jnp.concatenate(out).reshape(h.shape)
+
+    def _layer(self, kind, p, bias, x, remat: bool):
+        """One layer: ``(y, aux_loss, counters, router_counts)``, the last
+        three ``None`` for a dense feed-forward. The mixer takes the batch's
+        sequences one at a time and the feed-forward all their tokens at once
+        (a dense one in chunks of them); with ``remat`` each of the two is
+        recomputed in the backward pass, so a step keeps the residual stream
+        at both and one sequence's mixer or one expert layer's activations."""
         mix, experts = functools.partial(self._mix, kind), self._experts
         if remat:
             mix, experts = jax.checkpoint(mix), jax.checkpoint(experts)
-        return experts(p, jax.lax.map(lambda sequence: mix(p, sequence), x))
+        h = jax.lax.map(lambda sequence: mix(p, sequence), x)
+        if "moe" not in p:  # the tree says which feed-forward a layer has
+            return self._dense(p, h, remat), None, None, None
+        return experts(p, bias, h)
 
     # -------------------------------------------------------------- forward --
     def apply(self, params, state, x, ctx: Context):
@@ -314,18 +414,27 @@ class HybridMoELM(Module):
         h = seq.round_to(jnp.take(params["embed"]["weight"], tokens, axis=0), self.compute_dtype)
         aux_total = jnp.zeros((), jnp.float32)
         totals = {name: jnp.zeros((), jnp.float32) for name in self.counter_names}
+        new_state = list(state)  # a selection bias a sparse layer, where the model has them
         for i, p in enumerate(params["layers"]):
             kind = self.layer_kind(i)
+            bias = state[i]["expert_bias"] if state and state[i] else None
             with _prof.scope(f"{i}_{kind}"):
-                h, aux, counters = self._layer(kind, p, h, ctx.train)
-            aux_total = aux_total + aux
-            totals = {name: totals[name] + counters[name] for name in totals}
+                h, aux, counters, router_counts = self._layer(kind, p, bias, h, ctx.train)
+                if bias is not None and ctx.train:
+                    with _prof.scope("moe"), _prof.scope("router"):
+                        new_state[i] = {"expert_bias": moe.balanced_bias(
+                            bias, router_counts, self.bias_update_rate, ctx.axis_name
+                        )}
+            if counters is not None:
+                aux_total = aux_total + aux
+                totals = {name: totals[name] + counters[name] for name in totals}
         h = self._norm(h, params["final_norm"])
+        head = params["head"]["weight"] if "head" in params else params["embed"]["weight"].T
         out = seq.DeferredLogits(
-            h, params["head"]["weight"], self.aux_loss_weight * aux_total, totals,
+            h, head, self.aux_loss_weight * aux_total, totals,
             compute_dtype=self.compute_dtype, chunk=self.loss_chunk,
         )
-        return (out if ctx.train else out.logits()), state
+        return (out if ctx.train else out.logits()), tuple(new_state)
 
 
 QWEN3_NEXT_EP16 = dict(  # Qwen3-Next-80B-A3B's widths; depth, experts held and vocabulary cut
@@ -341,6 +450,13 @@ MELLUM2_EP4 = dict(  # Mellum2-12B-A2.5B's widths; depth, experts held and vocab
               attention_factor=1.2772588722239782),
     n_experts=64, experts_held=16, first_expert=0, top_k=8, expert_width=896, shared_width=0,
 )
+LFM2_EP4 = dict(  # LFM2-8B-A1B's widths; depth, dense layers, experts held and vocabulary cut
+    hidden_size=2048, n_layers=5, layer_types=(SHORT_CONV, FULL, SHORT_CONV, SHORT_CONV, SHORT_CONV),
+    zero_centred_norms=False, n_heads=32, n_kv_heads=8, head_dim=64, partial_rotary_factor=1.0, rope_theta=1e6,
+    conv_kernel=3, dense_layers=1, dense_width=7168, tied_head=True, rms_eps=1e-5,
+    n_experts=32, experts_held=8, first_expert=0, top_k=4, expert_width=1792, shared_width=0,
+    expert_bias=True, bias_update_rate=1e-3, aux_loss_weight=0.0,
+)
 QWEN3_NEXT_TINY = dict(
     hidden_size=64, n_layers=4, full_attention_interval=4,
     n_heads=4, n_kv_heads=2, head_dim=16, partial_rotary_factor=0.25, rope_theta=1e7,
@@ -355,4 +471,12 @@ MELLUM2_TINY = dict(  # a window that is shorter than the tests' sequences and n
               attention_factor=1.1386294361119891),
     n_experts=8, experts_held=2, first_expert=0, top_k=2, expert_width=32, shared_width=0,
     attention_q_block=16, loss_chunk=64,
+)
+LFM2_TINY = dict(  # the dense feed-forward in chunks of 32 tokens and what is left of the tests' sequences
+    hidden_size=64, n_layers=5, layer_types=(SHORT_CONV, FULL, SHORT_CONV, SHORT_CONV, SHORT_CONV),
+    zero_centred_norms=False, n_heads=4, n_kv_heads=2, head_dim=16, partial_rotary_factor=1.0, rope_theta=1e4,
+    conv_kernel=3, dense_layers=1, dense_width=96, tied_head=True, rms_eps=1e-5,
+    n_experts=8, experts_held=2, first_expert=0, top_k=2, expert_width=32, shared_width=0,
+    expert_bias=True, bias_update_rate=1e-3, aux_loss_weight=0.0,
+    attention_q_block=16, loss_chunk=64, mlp_chunk=32,
 )

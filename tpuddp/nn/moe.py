@@ -3,7 +3,9 @@
 Under expert parallelism every chip routes its tokens over all ``E`` experts
 and computes the part of the result that its own experts give; an exchange
 (not in this repo yet) would bring in the rest. :func:`expert_share_moe` is
-that chip's part: the router over all ``E``, the ``k`` largest renormalised,
+that chip's part: the router over all ``E`` (:func:`route`: a softmax whose
+``k`` largest are renormalised, or, where the layer has a selection bias,
+sigmoid scores chosen by score plus bias and weighted by the scores alone),
 the held experts ``[first, first + H)`` through a grouped matrix product
 (:func:`grouped_product`) over the assignments that chose them, and the shared
 expert once where the layer has one (its parameters say: a tree without a
@@ -38,6 +40,7 @@ COUNTERS = (  # additive: summed over layers here and over steps by whoever read
     "moe_expert_tokens_held",  # assignments to held experts, summed over layers
     "moe_absent_assignments",  # assignments to experts this chip does not hold
     "moe_dropped_assignments",  # held assignments that went through no expert: always 0
+    "moe_router_tokens_max",  # largest count of tokens at one of ALL the router's experts, summed over layers
 )
 
 
@@ -222,15 +225,44 @@ def _routed_bwd(rows, compute_dtype, saved, cotangent):
 _routed_experts.defvjp(_routed_fwd, _routed_bwd)
 
 
-def route(x, router, *, top_k: int):
-    """``(weights, experts, probs)``: the softmax over all experts in
-    float32, its ``top_k`` largest renormalised to sum 1, and their ids."""
+def route(x, router, *, top_k: int, bias=None):
+    """``(weights, experts, scores)`` over all experts in float32. Without a
+    ``bias``: the softmax, its ``top_k`` largest renormalised to sum 1, and
+    their ids. With one (``(n_experts,)``, the layer's state: no gradient
+    reaches it): sigmoid scores ``s``; the experts are the ``top_k`` largest
+    of ``s + bias`` and their weights ``s / (sum of the chosen s + 1e-6)``,
+    the bias in the choice and in no weight (auxiliary-loss-free balancing,
+    arXiv:2408.15664)."""
     logits = jnp.matmul(
         x.astype(jnp.float32), router.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST
     )
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_w, top_e = jax.lax.top_k(probs, top_k)
-    return top_w / jnp.sum(top_w, axis=-1, keepdims=True), top_e, probs
+    if bias is None:
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_w, top_e = jax.lax.top_k(probs, top_k)
+        return top_w / jnp.sum(top_w, axis=-1, keepdims=True), top_e, probs
+    scores = jax.nn.sigmoid(logits)
+    _, top_e = jax.lax.top_k(scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
+    top_w = jnp.take_along_axis(scores, top_e, axis=-1)
+    return top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-6), top_e, scores
+
+
+def balanced_bias(bias, counts, rate: float, axis_name=None):
+    """The selection bias after a step whose tokens chose expert ``e``
+    ``counts[e]`` times: ``bias + rate * sign(mean(counts) - counts)``, an
+    expert with fewer than its share up and one with more down, by a fixed
+    step whatever the gap. ``axis_name``: the data axis the step's tokens are
+    divided over; the counts are summed over it first (as ``nn/norm.py``'s
+    synchronised BatchNorm sums its statistics), so every replica moves the
+    bias alike."""
+    counts = counts.astype(jnp.float32)
+    if axis_name is not None:
+        counts = jax.lax.psum(counts, axis_name)
+    return bias + rate * jnp.sign(jnp.mean(counts) - counts)
+
+
+def chosen_counts(experts, n_experts: int):
+    """How many assignments chose each of ``n_experts`` (float32)."""
+    return jnp.sum(experts.reshape(-1, 1) == jnp.arange(n_experts), axis=0, dtype=jnp.float32)
 
 
 def load_balance_loss(probs, experts):
@@ -238,14 +270,17 @@ def load_balance_loss(probs, experts):
     expert ``e`` and ``P_e`` its mean router probability (Switch
     Transformers, eq. 4, over all ``E`` router outputs)."""
     n_experts = probs.shape[-1]
-    chosen = jnp.sum(experts.reshape(-1, 1) == jnp.arange(n_experts), axis=0, dtype=jnp.float32)
-    return n_experts * jnp.sum(chosen / experts.size * jnp.mean(probs, axis=0))
+    return n_experts * jnp.sum(chosen_counts(experts, n_experts) / experts.size * jnp.mean(probs, axis=0))
 
 
 def expert_share_moe(params, x, *, top_k: int, first_expert: int, compute_dtype,
-                     round_rows=None):
+                     round_rows=None, bias=None):
     """This chip's part of the layer for tokens ``x`` of ``(N, E)``. Returns
-    ``(y, aux_loss, counters)``. ``params``: ``router (E, n_experts)``,
+    ``(y, aux_loss, counters, router_counts)``: ``router_counts`` is how many
+    of the tokens chose each of all ``n_experts`` (float32; what
+    :func:`balanced_bias` balances). ``bias``: the layer's selection bias
+    where it has one (:func:`route`; such a layer has no load-balancing loss,
+    ``aux_loss`` is 0). ``params``: ``router (E, n_experts)``,
     ``experts.gate_up (H, E, 2F)`` and ``experts.down (H, F, E)`` for the
     held experts ``first_expert .. first_expert + H - 1`` and, where the layer
     has a shared expert, ``shared.gate_up``, ``shared.down`` and
@@ -253,8 +288,9 @@ def expert_share_moe(params, x, *, top_k: int, first_expert: int, compute_dtype,
     n, n_experts = x.shape[0], params["router"].shape[-1]
     held = params["experts"]["gate_up"].shape[0]
     with _prof.scope("router"):
-        top_w, top_e, probs = route(x, params["router"], top_k=top_k)
-        aux = load_balance_loss(probs, top_e)
+        top_w, top_e, probs = route(x, params["router"], top_k=top_k, bias=bias)
+        aux = load_balance_loss(probs, top_e) if bias is None else jnp.zeros((), jnp.float32)
+        router_counts = chosen_counts(top_e, n_experts)
     with _prof.scope("dispatch"):
         local = top_e.reshape(-1) - first_expert
         key = jnp.where((local >= 0) & (local < held), local, held).astype(jnp.int32)
@@ -283,6 +319,7 @@ def expert_share_moe(params, x, *, top_k: int, first_expert: int, compute_dtype,
         "moe_expert_tokens_held": total,
         "moe_absent_assignments": n * top_k - total,
         "moe_dropped_assignments": dropped,
+        "moe_router_tokens_max": jnp.max(router_counts),
     }
     counters = {k: jax.lax.stop_gradient(v.astype(jnp.float32)) for k, v in counters.items()}
-    return y.astype(x.dtype), aux, counters
+    return y.astype(x.dtype), aux, counters, jax.lax.stop_gradient(router_counts)

@@ -1,6 +1,7 @@
 """Layers of a token model: RMSNorm (plain, zero-centred and gated), rotary
 positions over part or all of a head from a plain or a YaRN-scaled frequency
-table, a causal depthwise 1-D convolution, SwiGLU, causal attention over
+table, a causal depthwise 1-D convolution, plain and between two gates, SwiGLU,
+causal attention over
 grouped key/value heads, full or within a sliding window, that never holds a
 ``T x T`` score block, and a head-plus-cross-entropy that never holds
 ``(B, T, V)`` logits.
@@ -8,7 +9,7 @@ grouped key/value heads, full or within a sliding window, that never holds a
 Attention has one mask with one parameter (a query sees itself and the keys
 before it, all of them or the ``window - 1`` nearest) and two lowerings, and
 picks one from what a call can see (:func:`attention_lowering`): on a TPU,
-for heads of 128 or 256, sequences that are a multiple of 512 and a window
+for heads of 64, 128 or 256, sequences that are a multiple of 512 and a window
 whose chunks divide them, traced once a device (the default
 ``mode="shard_map"`` step, a one-device process), the library's fused kernel,
 which keeps score blocks, softmax statistics and the accumulator in VMEM,
@@ -128,6 +129,16 @@ def causal_conv1d(x, kernel):
     w = kernel.astype(jnp.float32)
     y = sum(padded[:, j:j + t].astype(jnp.float32) * w[j] for j in range(k))
     return y.astype(x.dtype)
+
+
+def gated_short_conv(bcu, kernel):
+    """A short convolution between two gates: ``bcu`` ``(B, T, 3C)`` holds
+    three streams side by side, ``b | c | u``, and the result is ``c *
+    causal_conv1d(b * u)``: no activation, no norm, no scan. The gates and the
+    taps are float32 elementwise work on rows that are read once and written
+    once (the compiler fuses the chain); the result is in ``bcu``'s type."""
+    b, c, u = jnp.split(bcu.astype(jnp.float32), 3, axis=-1)
+    return (c * causal_conv1d(b * u, kernel)).astype(bcu.dtype)
 
 
 def swiglu(x, gate_up, down, compute_dtype):
@@ -280,13 +291,16 @@ def _banded_blocks(block, q, k, v, q_block: int, window: int):
 
 _LANES = 128  # a vector register's minor width
 _WIDEST_HEAD = 256  # the blocks below fill VMEM at this width; a wider head does not compile with them
+_MOST_DQ_PARTIALS = 16 * 16384  # rows a head of partial ``dq`` that the one-kernel backward may write
 
 
 def fused_attention_blocks(t: int, head_dim: int, window=None):
     """The fused kernels' block sizes for sequences of ``t`` tokens and heads
     of ``head_dim``, or ``None`` where the kernel is not for the shapes: a
-    head that is no whole number of lane registers or wider than the blocks
-    were sized for, a sequence that is no multiple of 512 (at blocks of
+    head that is neither half a lane register (64: the library's kernel takes
+    it as it is, and zeros to fill the register would double q, k and v in
+    HBM for the same products) nor a whole number of them, or wider than the
+    blocks were sized for, a sequence that is no multiple of 512 (at blocks of
     256 and 128 the kernel is slower than the blockwise path: 6.3 and 18.8 ms
     a forward against 12.3 at 8,192 tokens; PERF.md, PR 29), or a ``window``
     whose chunks (:func:`_banded_chunks`) do not divide the sequence.
@@ -297,16 +311,25 @@ def fused_attention_blocks(t: int, head_dim: int, window=None):
     library's two kernels make 7), which writes a partial ``dq`` a key block
     and sums them. Its key block stays 1024: 2048 read 0.3 ms a sequence
     faster alone and then did not fit VMEM inside the whole step, where XLA
-    keeps buffers of its own there."""
-    if head_dim % _LANES or head_dim > _WIDEST_HEAD or t % 512:
+    keeps buffers of its own there. Those partials are a copy of ``dq`` a key block of a
+    call (``t / block`` of them without a window) in the inputs' type, lanes filled: 64 MiB a head at 16,384 tokens
+    (2 GiB over 32 heads) and 256 MiB a head at 32,768 (8.6 GB, compiled for a
+    described v5e; PERF.md, PR 37), so past 16,384 the backward pass is the
+    library's two kernels, 7 products and no partials (1.7 GB of scratch
+    there)."""
+    if (head_dim % _LANES and head_dim != _LANES // 2) or head_dim > _WIDEST_HEAD or t % 512:
         return None
     block = 1024 if t % 1024 == 0 else 512
     if window is not None and t % _band_chunk(window, block):
         return None
+    keys = t if window is None else 2 * _band_chunk(window, block)  # a kernel call's, at most
+    if (keys // block) * t <= _MOST_DQ_PARTIALS:
+        backward = dict(use_fused_bwd_kernel=True)
+    else:
+        backward = dict(use_fused_bwd_kernel=False, block_q_dq=block, block_kv_dq=block)
     return _splash()[0].BlockSizes(
         block_q=block, block_kv=block, block_kv_compute=256,
-        block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=512,
-        use_fused_bwd_kernel=True,
+        block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=512, **backward,
     )
 
 
