@@ -521,14 +521,16 @@ def test_the_fused_lowering_compiles_for_a_v5e(v5e, t, hq, hkv, d, window):
 
 @pytest.mark.parametrize("t,hk,hv,d", [(8192, 16, 32, 128), (512, 2, 2, 256)])
 def test_the_fused_scan_compiles_for_a_v5e(v5e, t, hk, hv, d):
-    """Forward and backward kernels of the scan's chunk-local phase (the
-    first shape is the token cell's) through Mosaic."""
+    """Forward and backward kernels of the scan's chunk-local phase and of
+    its carry over chunks (the first shape is the token cell's) through
+    Mosaic."""
     sds = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct((1, t, *shape), dtype, sharding=v5e)
     fused = lambda *a: deltanet._chunked_rule(*a, chunk=64, compute_dtype=jnp.bfloat16, fused=True)
     grad = jax.grad(lambda *a: jnp.sum(jax.checkpoint(fused)(*a).astype(jnp.float32)), argnums=range(5))
     vectors = sds(hv, dtype=jnp.float32)
     text = jax.jit(grad).lower(sds(hk, d), sds(hk, d), sds(hv, d), vectors, vectors).compile().as_text()
     assert "deltanet_chunk_fwd" in text and "deltanet_chunk_bwd" in text and "tpu_custom_call" in text
+    assert "deltanet_carry_fwd" in text and "deltanet_carry_bwd" in text
 
 
 @pytest.mark.parametrize("t,channels,key_width,d,taps", [(8192, 8192, 2048, 128, 4), (512, 1024, 256, 256, 8)])
@@ -560,7 +562,8 @@ def test_the_fused_conv_compiles_for_a_v5e(v5e, t, channels, key_width, d, taps)
 def test_the_token_cells_step_compiles_for_a_v5e(v5e, monkeypatch, workload, sequences, method):
     """The whole step at published widths (the check's single step at one
     sequence, the timed K-step program at the cell's batch) with its fused
-    lowerings, attention's (full and banded), the scan's and the short
+    lowerings, attention's (full and banded), the scan's two pairs (what a
+    chunk computes alone, and the carry over chunks) and the short
     convolution's: inside it XLA
     keeps buffers of its own in VMEM, and a block that compiled alone did not
     fit (PERF.md, PR 29). About a minute each."""
@@ -587,6 +590,7 @@ def test_the_token_cells_step_compiles_for_a_v5e(v5e, monkeypatch, workload, seq
     assert memory.temp_size_in_bytes + memory.argument_size_in_bytes < 14.5e9
     deltanet_layers = "GatedDeltaNet" in model.layer_types
     assert ("deltanet_chunk_fwd" in text and "deltanet_chunk_bwd" in text) == deltanet_layers
+    assert ("deltanet_carry_fwd" in text) == deltanet_layers and ("deltanet_carry_bwd" in text) == deltanet_layers
     assert ("deltanet_conv_fwd" in text and "deltanet_conv_bwd" in text) == deltanet_layers  # the short convolution's
     # the grouped products: Pallas where an expert's rows are many (nn/moe.py: grouped_tiles)
     many_rows = cell.traffic["batch_per_chip"] * t * model.top_k // model.n_experts >= 1024

@@ -17,12 +17,17 @@ Delta Rule", 2024). With ``G`` the running sum of ``g`` inside the chunk and
 
 Only the last line and ``V'`` depend on the chunk before, so the scan over
 chunks carries ``S`` through two small products a chunk and everything else
-is batched over chunks. What a chunk computes alone (the first two lines,
-the decayed keys, queries and score block) has two lowerings, picked by
-:func:`scan_lowering` from what a call can see: on a TPU, at chunk 64 and
-widths that are multiples of 128, traced once a device, a Pallas kernel pair
-(``nn/deltanet_kernels.py``) that keeps the ``C x C`` blocks in VMEM;
-everywhere else the plain XLA below, which the kernels are tested against.
+is batched over chunks. Both phases have two lowerings, picked together by
+:func:`scan_lowering` from what a call can see. On a TPU, at chunk 64 and
+widths that are multiples of 128, traced once a device: one Pallas kernel
+pair (``nn/deltanet_kernels.py``) for what a chunk computes alone (the first
+two lines, the decayed keys, queries and score block), which keeps the ``C x
+C`` blocks in VMEM, and a second (``nn/deltanet_carry_kernels.py``) for the
+carry, which walks a head's chunks with ``S`` in VMEM, rounds where the loop
+below rounds and writes the output rows as the mixer's projection reads them.
+Everywhere else the plain XLA below (the carry a ``lax.scan`` that stacks
+every chunk's start state, then two batched products that read them), which
+the kernels are tested against.
 Every exponent is a difference of running sums with
 the later one first, so none is positive. Decay sums, ``T`` and the state are
 float32; products take ``compute_dtype`` inputs and accumulate in float32.
@@ -111,17 +116,27 @@ _invert_unit_lower.defvjp(_invert_fwd, _invert_bwd)
 
 
 def scan_lowering(backend: str, chunk: int, dk: int, dv: int, t: int, *, per_replica: bool) -> str:
-    """``"fused"`` or ``"plain"``: where the chunk-local phase runs. The kernel
-    pair (``nn/deltanet_kernels.py``) is written for the TPU's tiles: chunks
+    """``"fused"`` or ``"plain"``: where the scan runs, its chunk-local phase
+    and its carry alike. The two kernel pairs (``nn/deltanet_kernels.py``,
+    ``nn/deltanet_carry_kernels.py``) are written for the TPU's tiles: chunks
     of 64, key and value widths that fill whole 128-lane registers, a length
-    that is a whole number of its grid steps (:func:`fused_scan_block`); it is
-    a custom call, which GSPMD cannot partition, so it serves only a call that
-    is traced once a device (``per_replica``). Everything else, the CPU first,
-    takes the plain path."""
+    that is a whole number of their grid steps (:func:`fused_scan_block`), a
+    state narrow enough that one chunk's blocks fit VMEM beside it
+    (``deltanet_carry_kernels.tile``: 512 x 512 does, 1,024 x 1,024 does not);
+    they are custom calls, which GSPMD cannot partition, so they serve only a
+    call that is traced once a device (``per_replica``). Everything else, the
+    CPU first, takes the plain path."""
     if backend == "tpu" and per_replica and chunk == _FUSED_CHUNK and dk % _LANES == 0 and dv % _LANES == 0:
-        if fused_scan_block(t, chunk) is not None:
+        block = fused_scan_block(t, chunk)
+        if block is not None and _carry_kernels().tile(chunk, dk, dv, 1, block) is not None:
             return "fused"
     return "plain"
+
+
+def _carry_kernels():
+    from tpuddp.nn import deltanet_carry_kernels  # pulls in Pallas and Mosaic, which nothing else here needs
+
+    return deltanet_carry_kernels
 
 
 def fused_scan_block(t: int, chunk: int):
@@ -198,11 +213,13 @@ def chunk_gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64, compute_dtype=j
     of ``chunk``: the tail is padded with tokens that leave the state as it
     is (``beta`` 0, ``g`` 0).
 
-    One contract and two lowerings of what a chunk computes alone, chosen by
-    :func:`scan_lowering` from the backend, the shapes and where the call is
-    traced: a fused kernel pair that keeps the ``C x C`` blocks in VMEM, or
-    plain XLA, which holds them in HBM. The carry over chunks and the two
-    products that read its states are the same code after either."""
+    One contract and two lowerings, chosen by :func:`scan_lowering` from the
+    backend, the shapes and where the call is traced: two fused kernel pairs,
+    one that keeps a chunk's ``C x C`` blocks in VMEM and one that keeps the
+    state there while it walks the chunks, or plain XLA, which holds the
+    blocks in HBM, carries the state through a loop of ``T / chunk`` steps and
+    reads the stacked states back. Both round the same values at the same
+    places."""
     lowering = scan_lowering(
         jax.default_backend(), chunk, q.shape[-1], v.shape[-1], q.shape[1], per_replica=traced_per_replica()
     )
@@ -235,9 +252,10 @@ def _chunked_rule(q, k, v, g, beta, *, chunk, compute_dtype, fused: bool, interp
     if fused:
         from tpuddp.nn import deltanet_kernels  # pulls in Pallas and Mosaic, which nothing else here needs
 
-        u, w, k_tail, q_grown, scores = deltanet_kernels.chunk_local(
-            q, k, v, gsum, beta, chunk, fused_scan_block(n * chunk, chunk), jnp.dtype(compute_dtype), interpret
-        )
+        block, dtype = fused_scan_block(n * chunk, chunk), jnp.dtype(compute_dtype)
+        local = deltanet_kernels.chunk_local(q, k, v, gsum, beta, chunk, block, dtype, interpret)
+        o = _carry_kernels().carry(*local, gsum, block, dtype, v.dtype, interpret)  # (B, N C, H Dv)
+        return o.reshape(b, n * chunk, h, dv)[:, :t]
     else:
         # each key head serves h / hk value heads
         q, k = (jnp.repeat(a, h // a.shape[2], axis=2) for a in (q, k))

@@ -14,7 +14,8 @@ walks the value heads of one key head, so its rows are fetched once) and
 ``v`` straight from the ``(B, T, heads * width)`` arrays, keeps ``D``, ``A``,
 the inverse's levels and ``q k^T`` in VMEM, and writes ``U``, ``W``, ``K~``,
 ``Q~``, ``P`` rounded to the products' input type, which is where the plain
-path rounds them, laid out chunks first, as the carry reads them. ``W`` and
+path rounds them, laid out chunks first, as the carry's kernel
+(``nn/deltanet_carry_kernels.py``) reads them. ``W`` and
 ``P`` are stored in that type: their cotangents only ever enter products,
 which round them anyway. ``U``, ``K~`` and ``Q~`` are stored in float32,
 because their cotangents are used elementwise and a cotangent takes its
@@ -216,11 +217,12 @@ def _layout(q, k, v, gsum, beta, block, chunk):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
 def chunk_local(q, k, v, gsum, beta, chunk, block, dtype, interpret):
-    """``U, W, K~, Q~, P`` of ``(B, Hv, N, C, .)`` from ``q``, ``k`` of ``(B,
-    T, Hk, Dk)``, ``v`` of ``(B, T, Hv, Dv)`` and ``gsum`` (the decay's running
-    sum inside each chunk), ``beta`` of ``(B, Hv, N, C)`` float32. ``T`` is
-    ``N * chunk`` and ``N`` a multiple of ``block``; ``dtype`` is the products'
-    input type."""
+    """``U, W, K~, Q~, P`` of ``(B, N, Hv, C, .)``, chunks first as the
+    carry's kernel (``nn/deltanet_carry_kernels.py``) reads them, from ``q``,
+    ``k`` of ``(B, T, Hk, Dk)``, ``v`` of ``(B, T, Hv, Dv)`` and ``gsum`` (the
+    decay's running sum inside each chunk), ``beta`` of ``(B, Hv, N, C)``
+    float32. ``T`` is ``N * chunk`` and ``N`` a multiple of ``block``;
+    ``dtype`` is the products' input type."""
     return _forward(q, k, v, gsum, beta, chunk, block, dtype, interpret)[0]
 
 
@@ -237,7 +239,7 @@ def _forward(q, k, v, gsum, beta, chunk, block, dtype, interpret):
         [out(dv, _F32), out(dk, rounded), out(dk, _F32), out(dk, _F32), out(chunk, rounded), out(chunk, _F32)],
         interpret,
     )(*arrays)
-    return tuple(jnp.moveaxis(o, 1, 2) for o in outs), (q, k, v, gsum, beta, t_inv)
+    return tuple(outs), (q, k, v, gsum, beta, t_inv)
 
 
 def _backward(chunk, block, dtype, interpret, saved, cotangents):
@@ -254,7 +256,7 @@ def _backward(chunk, block, dtype, interpret, saved, cotangents):
         [jax.ShapeDtypeStruct((b, t, hk * dk), _F32), jax.ShapeDtypeStruct((b, t, hk * dk), _F32),
          jax.ShapeDtypeStruct((b, t, hv * dv), v.dtype), vector, vector],
         interpret,
-    )(*arrays, t_inv, *(jnp.moveaxis(c, 2, 1) for c in cotangents))
+    )(*arrays, t_inv, *cotangents)
     return (
         dq.reshape(q.shape).astype(q.dtype), dk_.reshape(k.shape).astype(k.dtype), dv_.reshape(v.shape),
         dg.reshape(gsum.shape).astype(gsum.dtype), db.reshape(beta.shape).astype(beta.dtype),
