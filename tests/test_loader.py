@@ -2,6 +2,7 @@
 assembly in mesh order (SURVEY.md §2b #12/#14 consequences)."""
 
 import numpy as np
+import pytest
 
 from tpuddp.data import DataLoader, ShardedDataLoader, SyntheticClassification
 from tpuddp.parallel import DistributedSampler, make_mesh
@@ -97,3 +98,43 @@ def test_probe_fingerprint_mentions_each_replica(cpu_devices):
     x, _, _ = next(iter(loader))
     s = loader.probe_fingerprint(x)
     assert "replica 0" in s and "replica 1" in s
+
+
+def _loader_under_test(kind, cpu_devices):
+    ds = SyntheticClassification(n=37, shape=(4, 4, 3), seed=2)
+    if kind == "single":
+        return DataLoader(ds, batch_size=8, shuffle=True, seed=3)
+    return ShardedDataLoader(ds, 4, make_mesh(cpu_devices[:2]), shuffle=True, seed=3)
+
+
+@pytest.mark.parametrize("kind", ["single", "sharded"])
+def test_loader_opens_its_own_spans_under_a_handed_tracer(kind, cpu_devices):
+    """Handed a tracer and a parent, a loader brackets what it does where it
+    does it: the epoch's order once an iteration, the row gather and the
+    padding once a batch, all of kind ``load`` under the parent; the batches
+    are bitwise the untraced ones; handed None again it opens nothing."""
+    from tpuddp.observability import trace as trace_lib
+
+    loader = _loader_under_test(kind, cpu_devices)
+    loader.set_epoch(1)
+    want = list(loader)
+    tracer = trace_lib.Tracer("train", process_index=0)
+    parent = tracer.start_span("epoch 1", trace_lib.KIND_EPOCH)
+    loader.set_tracer(tracer, parent)
+    got = list(loader)
+    loader.set_tracer(None)
+    tracer.end_span(parent)
+    assert len(got) == len(want) == len(loader)
+    for a, b in zip(got, want):
+        for x, y in zip(a, b):
+            assert x.dtype == y.dtype
+            np.testing.assert_array_equal(x, y)
+    spans = [s for s in tracer.endpoint_payload(limit=None)["spans"] if s is not None]
+    names = [s["name"] for s in spans if s["name"] != "epoch 1"]
+    assert names == ["loader_order"] + ["loader_gather", "loader_pad"] * len(loader)
+    assert {(s["kind"], s["parent_id"]) for s in spans if s["name"] != "epoch 1"} == {
+        ("load", parent.span_id)
+    }
+    assert {s["tid"] for s in spans} == {"train"}  # this thread: the parent's row
+    done = tracer.completed
+    assert len(list(loader)) == len(want) and tracer.completed == done

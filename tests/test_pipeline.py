@@ -22,6 +22,7 @@ from tpuddp.data import (
 from tpuddp.models import ToyMLP
 from tpuddp.nn import CrossEntropyLoss
 from tpuddp.observability import schema as schema_mod
+from tpuddp.observability import trace as trace_lib
 from tpuddp.parallel import make_mesh
 from tpuddp.parallel.ddp import DistributedDataParallel
 from tpuddp.resilience import guard as guard_lib
@@ -458,6 +459,228 @@ def test_prefetch_effective_depth_byte_capped():
     assert PrefetchLoader(Small(), depth=8).effective_depth() == 8
     # unknowable batch bytes -> the configured depth survives
     assert PrefetchLoader(NoBytes(), depth=3).effective_depth() == 3
+
+
+# ------------------------------------------- the pass's own spans (ISSUE 39) --
+
+
+class _Tel:
+    """``run_pass``'s ``tel=`` interface, keeping what it is given."""
+
+    def __init__(self):
+        self.host_stall_s = 0.0
+
+    def offer_batch(self, batch):
+        pass
+
+    def pre_dispatch(self, n_steps):
+        pass
+
+    def post_dispatch(self, n_steps, n_samples, metrics=None, host_stall_s=0.0, **_):
+        self.host_stall_s += host_stall_s
+
+
+def _traced_pass(mesh, workers, scan_k, tracer, **kw):
+    """One pass over 10 batches (8 a replica) under ``tracer`` (a Tracer,
+    NULL or None), as the epoch driver runs it: the spans' parent is an
+    epoch span."""
+    ddp, state = _make_ddp(mesh)
+    loader = _loader(mesh, workers=workers)
+    live = isinstance(tracer, trace_lib.Tracer)
+    epoch = tracer.start_span("epoch 0", trace_lib.KIND_EPOCH, tid="train") if live else None
+    out = pipe.run_pass(
+        ddp, state, loader, scan_k, ddp.train_step, ddp.train_step_many,
+        cfg=pipe.PipelineConfig(depth=2, host_workers=workers),
+        tracer=tracer, trace_parent=epoch, **kw,
+    )
+    if live:
+        tracer.end_span(epoch)
+    return loader, epoch, out
+
+
+def _spans_by_name(tracer):
+    by = {}
+    for span in tracer.endpoint_payload(limit=None)["spans"]:
+        by.setdefault(span["name"], []).append(span)
+    return by
+
+
+@pytest.mark.parametrize("scan_k", [4, 1])
+@pytest.mark.parametrize("workers", [0, 1, 2])
+def test_traced_pass_span_tree(mesh, workers, scan_k):
+    """Names, kinds and parents of what one pass opens: everything under the
+    epoch span but the two halves of a ``stage``; one ``input_wait`` a
+    ``next``; one ``loader_gather`` and one ``loader_pad`` a batch and one
+    ``loader_order`` a pass, opened by the thread that assembles."""
+    tracer = trace_lib.Tracer("train", process_index=0)
+    loader, epoch, (_, _, interrupted) = _traced_pass(mesh, workers, scan_k, tracer)
+    assert not interrupted
+    n_batches = len(loader)
+    assert n_batches == 10  # at scan_k 4: two chunks and two single steps
+    by = _spans_by_name(tracer)
+    kinds = {name: {s["kind"] for s in spans} for name, spans in by.items()}
+    assert kinds == {
+        "epoch 0": {"epoch"}, "input_wait": {"queue_wait"}, "stage": {"stage"},
+        "stage_put": {"load"}, "dispatch": {"dispatch"}, "readback": {"readback"},
+        "loader_order": {"load"}, "loader_gather": {"load"}, "loader_pad": {"load"},
+        **({"stage_stack": {"load"}} if scan_k > 1 else {}),
+    }
+    assert len({s["trace_id"] for spans in by.values() for s in spans}) == 1
+    for name in ("input_wait", "stage", "dispatch", "readback",
+                 "loader_order", "loader_gather", "loader_pad"):
+        assert {s["parent_id"] for s in by[name]} == {epoch.span_id}, name
+    # a stage's halves lie inside it
+    stages = {s["span_id"]: s for s in by["stage"]}
+    stacked = n_batches // scan_k if scan_k > 1 else 0
+    assert len(stages) == len(by["stage_put"]) == stacked + n_batches - stacked * scan_k
+    assert len(by.get("stage_stack", [])) == stacked
+    halves = by["stage_put"] + by.get("stage_stack", [])
+    for half in halves:
+        outer = stages[half["parent_id"]]
+        assert outer["t_start_ns"] <= half["t_start_ns"]
+        assert half["duration_ms"] <= outer["duration_ms"]
+    # one wait a next: every batch, and the next that found the loader empty
+    waits = by["input_wait"]
+    assert len(waits) == n_batches + 1
+    assert [bool(w["attrs"].get("exhausted")) for w in waits] == [False] * n_batches + [True]
+    # the loader's own: once a pass, once a batch, on the assembling thread
+    assert len(by["loader_order"]) == 1
+    assert len(by["loader_gather"]) == len(by["loader_pad"]) == n_batches
+    rows = {s["tid"] for s in by["loader_gather"] + by["loader_pad"]}
+    if workers == 0:
+        assert rows == {"train"}  # inline: the pass's own thread, its parent's row
+    else:
+        assert rows and all(r.startswith("tpuddp-prefetch") for r in rows)
+        assert len(rows) <= workers
+    assert tracer.open_span_summaries() == [] and tracer.dropped == 0
+
+
+@pytest.mark.parametrize("workers", [0, 2])
+def test_traced_pass_is_bitwise_the_untraced_one(mesh, workers):
+    """Same batches in the same order and the same final state under a live
+    tracer, under NULL and under none."""
+    seen, states = {}, {}
+    for label, tracer in (
+        ("none", None), ("null", trace_lib.NULL),
+        ("live", trace_lib.Tracer("train", process_index=0)),
+    ):
+        batches = seen[label] = []
+        _, _, (state, acc, _) = _traced_pass(
+            mesh, workers, 4, tracer,
+            probe_cb=lambda i, b: batches.append([np.array(a) for a in b]),
+        )
+        states[label] = jax.device_get((state, acc))
+    for label in ("null", "live"):
+        assert len(seen[label]) == len(seen["none"]) == 10
+        for got, want in zip(seen[label], seen["none"]):
+            for a, b in zip(got, want):
+                np.testing.assert_array_equal(a, b)
+        assert_states_bitwise_equal(states[label], states["none"])
+
+
+def test_input_wait_spans_are_the_stall_clocks_intervals(mesh, monkeypatch):
+    """The ``input_wait`` spans bracket exactly what ``StallClock.add`` is
+    given: on a clock that ticks once a reading, each span that handed a
+    batch over is its stall plus the two readings that bracket it, whatever
+    the (inline, traced) loader read in between."""
+    ticks = {"n": 0}
+
+    def tick():
+        ticks["n"] += 1
+        return ticks["n"]
+
+    tracer = trace_lib.Tracer("train", process_index=0)
+    tel = _Tel()
+    with monkeypatch.context() as patched:
+        patched.setattr(time, "perf_counter", lambda: tick() * 1e-3)
+        patched.setattr(time, "perf_counter_ns", lambda: tick() * 1_000_000)
+        _traced_pass(mesh, 0, 4, tracer, tel=tel)
+    waits = [w for w in _spans_by_name(tracer)["input_wait"] if not w["attrs"].get("exhausted")]
+    assert len(waits) == 10
+    total_ms = sum(w["duration_ms"] for w in waits)
+    assert total_ms == pytest.approx(1e3 * tel.host_stall_s + 2 * len(waits))
+    assert tel.host_stall_s >= 10 * 4e-3  # the loader's own readings lie inside
+
+
+class _Boom(RuntimeError):
+    pass
+
+
+@pytest.mark.parametrize("ending", ["whole", "interrupted", "raised"])
+@pytest.mark.parametrize("workers", [0, 1, 2])
+def test_loader_holds_no_tracer_after_the_pass(mesh, workers, ending):
+    """However a traced pass ends, the loader gives the tracer back
+    (``finally``), its workers are reaped before ``run_pass`` returns, every
+    span the pass and the loader opened is closed, and iterating the loader
+    afterwards opens nothing."""
+    from tpuddp.data import loader as loader_mod
+
+    tracer = trace_lib.Tracer("train", process_index=0)
+    seen = {"n": 0}
+    handed = []
+    real_set = loader_mod._Traced.set_tracer
+
+    def probe(i, batch):
+        seen["n"] = i + 1
+        if ending == "raised" and i == 6:
+            raise _Boom()
+
+    kw = {"probe_cb": probe}
+    if ending == "interrupted":
+        kw["poll"] = lambda: seen["n"] >= 7
+    with pytest.MonkeyPatch.context() as patched:
+        patched.setattr(
+            loader_mod._Traced, "set_tracer",
+            lambda self, tracer, parent=None: (
+                handed.append((self, tracer)), real_set(self, tracer, parent)
+            ) and None,
+        )
+        if ending == "raised":
+            with pytest.raises(_Boom):
+                _traced_pass(mesh, workers, 2, tracer, **kw)
+        else:
+            _, _, (_, _, interrupted) = _traced_pass(mesh, workers, 2, tracer, **kw)
+            assert interrupted == (ending == "interrupted")
+    assert _prefetch_threads() == []
+    # the driver's own span is open where the pass raised; nothing else is
+    assert {s["name"] for s in tracer.open_span_summaries()} <= {"epoch 0"}
+    (loader, first), (same, last) = handed
+    assert loader is same and first is tracer and last is None
+    assert loader._trace is loader_mod._UNTRACED
+    before = tracer.completed
+    assert len(list(loader)) == 10
+    assert tracer.completed == before
+
+
+@pytest.mark.parametrize("tracer", [None, trace_lib.NULL], ids=["none", "null"])
+def test_untraced_pass_hands_the_loader_nothing(mesh, tracer, monkeypatch):
+    """With no tracer the loader is never handed one, what its plan brackets
+    its calls with is the NULL tracer's one shared span (nothing is made per
+    batch), and neither the runner nor the loader opens a span on any live
+    tracer."""
+    from tpuddp.data import loader as loader_mod
+
+    handed, opened, live = [], [], []
+    monkeypatch.setattr(
+        loader_mod._Traced, "set_tracer", lambda self, *a, **k: handed.append(a)
+    )
+    real_open = loader_mod._open
+
+    def spy_open(trace, name):
+        opened.append(real_open(trace, name))
+        return opened[-1]
+
+    monkeypatch.setattr(loader_mod, "_open", spy_open)
+    real_start = trace_lib.Tracer.start_span
+    monkeypatch.setattr(
+        trace_lib.Tracer, "start_span",
+        lambda self, *a, **k: live.append(a) or real_start(self, *a, **k),
+    )
+    loader, _, _ = _traced_pass(mesh, 0, 4, tracer)
+    assert handed == [] and live == []
+    assert len(opened) == 1 + 2 * len(loader)
+    assert all(span is trace_lib.NULL_SPAN for span in opened)
+    assert loader._trace is loader_mod._UNTRACED
 
 
 # -------------------------------------------------- FusedEvaluator staging --

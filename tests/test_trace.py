@@ -225,6 +225,22 @@ def test_traced_training_bitwise_and_artifact(mesh, tmp_path):
     spans = [e for e in payload["traceEvents"] if e.get("ph") == "X"]
     kinds = {e["cat"] for e in spans}
     assert {"epoch", "stage", "dispatch", "readback", "collective"} <= kinds
+    # the pass boundary from inside: the runner's wait and the halves of a
+    # stage, the loader's order / gather / pad (ISSUE 39)
+    by_name = {}
+    for e in spans:
+        by_name.setdefault(e["name"], []).append(e)
+    assert {e["cat"] for e in by_name["input_wait"]} == {"queue_wait"}
+    for name in ("stage_put", "loader_order", "loader_gather", "loader_pad"):
+        assert {e["cat"] for e in by_name[name]} == {"load"}, name
+    assert "stage_stack" not in by_name  # one batch a pass: nothing to stack
+    stage_ids = {e["args"]["span_id"] for e in by_name["stage"]}
+    assert len(by_name["stage_put"]) == len(stage_ids) == 4
+    assert all(e["args"]["parent_id"] in stage_ids for e in by_name["stage_put"])
+    # 2 epochs x (train + eval) passes of 1 batch (8 a replica, 64 samples)
+    assert len(by_name["loader_order"]) == 4
+    assert len(by_name["loader_gather"]) == len(by_name["loader_pad"]) == 4
+    assert len(by_name["input_wait"]) == 8  # a batch and the empty next, a pass
     # the collective annotation carries the hook's wire accounting
     coll = next(e for e in spans if e["cat"] == "collective")
     assert coll["args"]["hook"] == "bf16_ef"
@@ -704,6 +720,53 @@ def test_flight_dump_embeds_open_spans(tmp_path):
     assert "failed" in json.load(open(path2))["notes"]["boom"]
 
 
+@pytest.mark.parametrize("workers", [0, 2])
+@pytest.mark.parametrize("capacity", [4, 24])
+def test_a_pass_that_overflows_the_ring_still_exports_a_valid_payload(
+    mesh, capacity, workers
+):
+    """One pass opens some four spans a batch and three a chunk, so a long
+    pass outruns any ring: the oldest spans drop, counted, and what is left
+    is still a payload the validator takes, mid-pass (the epoch span open)
+    and after it: orphans are allowed exactly when ``dropped`` > 0, and a
+    parent here ends after its children, so it is never dropped before them."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpuddp import optim
+    from tpuddp.data import PrefetchLoader, ShardedDataLoader, SyntheticClassification
+    from tpuddp.models import ToyMLP
+    from tpuddp.nn import CrossEntropyLoss
+    from tpuddp.parallel.ddp import DistributedDataParallel
+    from tpuddp.training import pipeline as pipe
+
+    ds = SyntheticClassification(n=640, shape=(8, 8, 3), seed=0)
+    loader = ShardedDataLoader(ds, 8, mesh, shuffle=True)
+    if workers:
+        loader = PrefetchLoader(loader, workers=workers)
+    ddp = DistributedDataParallel(
+        ToyMLP(hidden=(16,)), optim.Adam(1e-2), CrossEntropyLoss(), mesh=mesh
+    )
+    state = ddp.init_state(jax.random.key(0), jnp.zeros((1, 8, 8, 3)))
+    tracer = Tracer("train", capacity=capacity, process_index=0)
+    epoch = tracer.start_span("epoch 0", trace_mod.KIND_EPOCH, tid="train")
+    pipe.run_pass(
+        ddp, state, loader, 4, ddp.train_step, ddp.train_step_many,
+        tracer=tracer, trace_parent=epoch,
+    )
+    # 10 batches: 11 waits, 1 order, 20 gather + pad, 4 stages with 6 halves,
+    # 4 dispatches, 1 readback
+    assert tracer.completed == 47 and tracer.dropped == 47 - capacity
+    payload = tracer.chrome_payload()  # the epoch span still open: the crash view
+    assert schema_mod.validate_trace_payload(payload) == []
+    tracer.end_span(epoch)
+    payload = tracer.chrome_payload()
+    assert payload["tpuddp"]["dropped"] == 48 - capacity
+    assert schema_mod.validate_trace_payload(payload) == []
+    kept = [e for e in payload["traceEvents"] if e["ph"] == "X"]
+    assert len(kept) == capacity and kept[-1]["name"] == "epoch 0"
+
+
 # ------------------------------------------------------------ CLI satellites --
 
 
@@ -722,6 +785,7 @@ def test_inspect_trace_subcommand(tmp_path):
     )
     assert out.returncode == 0, out.stderr
     assert "role=train" in out.stdout and "slowest spans" in out.stdout
+    assert "per-name host time" in out.stdout and "ms/span" in out.stdout
     # --validate through content detection too
     assert subprocess.run(
         [sys.executable, inspect, "--validate", art]

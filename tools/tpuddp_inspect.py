@@ -545,6 +545,9 @@ def summarize_flight(path: str) -> None:
             print(f"    [{ev.get('epoch', '-')}] {ev.get('event')}: {fields}")
 
 
+_TRACE_NAMES = 16  # rows of the trace summary's per-name table
+
+
 def summarize_trace(path: str) -> None:
     """Pretty-print a trace_<role>.json artifact: provenance, per-kind time
     share, and the slowest-span table (the ``trace`` subcommand's summary —
@@ -583,6 +586,22 @@ def summarize_trace(path: str) -> None:
             for k, d in by_kind.most_common()
         ]
         _print_table(rows, ["kind", "spans", "ms", "share"])
+        # the same time by span name: a kind holds several (load: the
+        # loader's order / gather / pad and a stage's stack / put), and which
+        # of them is the fat one is what points at a fix
+        by_name = collections.Counter()
+        name_counts = collections.Counter()
+        for e in spans:
+            key = (e.get("cat") or "?", e.get("name") or "?")
+            by_name[key] += float(e.get("dur") or 0.0)
+            name_counts[key] += 1
+        print(f"\nper-name host time (top {min(len(by_name), _TRACE_NAMES)}):")
+        rows = [
+            [n, k, str(name_counts[k, n]), f"{d / 1e3:.1f}",
+             f"{d / 1e3 / name_counts[k, n]:.3f}"]
+            for (k, n), d in by_name.most_common(_TRACE_NAMES)
+        ]
+        _print_table(rows, ["name", "kind", "spans", "ms", "ms/span"])
     slowest = meta.get("slowest") or []
     if slowest:
         print(f"\nslowest spans (top {len(slowest)}):")

@@ -32,6 +32,7 @@ import threading
 import jax
 import numpy as np
 
+from tpuddp.observability import trace as trace_lib
 from tpuddp.parallel.sampler import DistributedSampler
 from tpuddp.utils import batching
 
@@ -59,24 +60,72 @@ def _pad_batch(x: np.ndarray, y: np.ndarray, batch_size: int):
     return batching.pad_batch(x, y, batch_size)
 
 
-def _fetch_padded(dataset, indices: np.ndarray, batch_size: int):
-    """Fetch + pad in one step. Datasets exposing contiguous ``.images`` /
-    ``.labels`` arrays (CIFAR10, SyntheticClassification) take the native C++
-    multi-threaded row-gather fast path (tpuddp/data/_native); everything else
-    falls back to numpy with identical results."""
-    n = len(indices)
+def _gather(dataset, indices: np.ndarray, batch_size: int):
+    """The rows of one microbatch: ``(x, y)``. Datasets exposing contiguous
+    ``.images`` / ``.labels`` arrays (CIFAR10, SyntheticClassification) take
+    the native C++ multi-threaded row-gather fast path (tpuddp/data/_native),
+    which pads as it gathers and leaves the labels to :func:`_pad`
+    (``y is None``); everything else falls back to numpy with identical
+    results."""
     images = getattr(dataset, "images", None)
-    labels = getattr(dataset, "labels", None)
-    if images is not None and labels is not None:
+    if images is not None and getattr(dataset, "labels", None) is not None:
         x = _native.gather_rows(images, indices, pad_rows=batch_size)
         if x is not None:
-            w = np.ones(batch_size, np.float32)
-            w[n:] = 0.0
-            y = np.zeros((batch_size, *labels.shape[1:]), labels.dtype)  # a token set's labels are rows
-            y[:n] = labels[np.asarray(indices)]
-            return x, y, w
-    x, y = _fetch(dataset, indices)
-    return _pad_batch(x, y, batch_size)
+            return x, None
+    return _fetch(dataset, indices)
+
+
+def _pad(dataset, indices: np.ndarray, batch_size: int, x, y):
+    """``(x, y, w)`` at the static batch size from what :func:`_gather`
+    returned: labels and weights beside natively gathered rows, else
+    :func:`_pad_batch`."""
+    if y is not None:
+        return _pad_batch(x, y, batch_size)
+    n = len(indices)
+    labels = dataset.labels
+    w = np.ones(batch_size, np.float32)
+    w[n:] = 0.0
+    y = np.zeros((batch_size, *labels.shape[1:]), labels.dtype)  # a token set's labels are rows
+    y[:n] = labels[np.asarray(indices)]
+    return x, y, w
+
+
+def _fetch_padded(dataset, indices: np.ndarray, batch_size: int):
+    """Fetch + pad in one step."""
+    return _pad(dataset, indices, batch_size, *_gather(dataset, indices, batch_size))
+
+
+# (tracer, parent span, the thread that handed them over)
+_UNTRACED = (trace_lib.NULL, None, None)
+
+
+class _Traced:
+    """What a loader needs to open its own spans (``loader_order``,
+    ``loader_gather``, ``loader_pad``, kind ``load``) where its work happens:
+    :meth:`set_tracer` for the length of a pass (``pipeline.run_pass`` hands
+    over its tracer and epoch span and takes them back in a ``finally``), and
+    the pair a batch plan brackets its calls with. A plan keeps the tracer it
+    was made under, so an abandoned pass's workers end their spans where they
+    opened them. Without a tracer the two calls are the NULL tracer's no-ops.
+    A span opened on another thread than the one that handed the tracer over
+    (a ``PrefetchLoader`` worker) lands on a timeline row of that thread's
+    name, not its parent's."""
+
+    _trace = _UNTRACED
+
+    def set_tracer(self, tracer, parent=None) -> None:
+        self._trace = (
+            _UNTRACED if tracer is None
+            else (tracer, parent, threading.get_ident())
+        )
+
+
+def _open(trace, name: str):
+    tracer, parent, owner = trace
+    tid = None
+    if owner is not None and threading.get_ident() != owner:
+        tid = threading.current_thread().name
+    return tracer.start_span(name, trace_lib.KIND_LOAD, parent=parent, tid=tid)
 
 
 def _per_sample_nbytes(dataset):
@@ -89,7 +138,7 @@ def _per_sample_nbytes(dataset):
     return int(np.prod(images.shape[1:])) * images.itemsize
 
 
-class DataLoader:
+class DataLoader(_Traced):
     """Single-stream host loader yielding ``(x, y, w)`` numpy batches.
 
     ``sampler``: optional index source with the DistributedSampler protocol
@@ -148,14 +197,24 @@ class DataLoader:
         the random-access protocol PrefetchLoader's worker pool parallelizes
         over. One plan per epoch; ``__iter__`` is defined in terms of it so
         the two can never drift."""
+        trace = self._trace
+        tracer = trace[0]
+        span = _open(trace, "loader_order")
         indices = self._indices()
+        tracer.end_span(span)
         steps = len(self)
         batch_size = self.batch_size
         dataset = self.dataset
 
         def fetch(s: int):
             chunk = indices[s * batch_size : (s + 1) * batch_size]
-            return _fetch_padded(dataset, chunk, batch_size)
+            span = _open(trace, "loader_gather")
+            x, y = _gather(dataset, chunk, batch_size)
+            tracer.end_span(span)
+            span = _open(trace, "loader_pad")
+            batch = _pad(dataset, chunk, batch_size, x, y)
+            tracer.end_span(span)
+            return batch
 
         return steps, fetch
 
@@ -211,7 +270,7 @@ class _EpochMemoizedOrder:
         return iter(self._materialize())
 
 
-class ShardedDataLoader:
+class ShardedDataLoader(_Traced):
     """Global-batch DP loader: one instance per process, one sampler per local
     replica. Yields the process-local ``(x, y, w)`` slice of the global batch
     (concat over local replicas in mesh order); pair with
@@ -313,20 +372,28 @@ class ShardedDataLoader:
         ``(n_batches, fetch)`` — the random-access protocol PrefetchLoader's
         worker pool parallelizes over (see :meth:`DataLoader.make_batch_plan`).
         """
+        trace = self._trace
+        tracer = trace[0]
+        span = _open(trace, "loader_order")
         per_replica = [s.local_indices() for s in self.samplers]
+        tracer.end_span(span)
         steps = len(self)
         batch_size = self.batch_size
         dataset = self.dataset
 
         def fetch(s: int):
-            xs, ys, ws = [], [], []
-            for shard in per_replica:
-                chunk = shard[s * batch_size : (s + 1) * batch_size]
-                x, y, w = _fetch_padded(dataset, chunk, batch_size)
-                xs.append(x)
-                ys.append(y)
-                ws.append(w)
-            return np.concatenate(xs), np.concatenate(ys), np.concatenate(ws)
+            chunks = [shard[s * batch_size : (s + 1) * batch_size] for shard in per_replica]
+            span = _open(trace, "loader_gather")
+            rows = [_gather(dataset, chunk, batch_size) for chunk in chunks]
+            tracer.end_span(span)
+            span = _open(trace, "loader_pad")
+            xs, ys, ws = zip(*(
+                _pad(dataset, chunk, batch_size, x, y)
+                for chunk, (x, y) in zip(chunks, rows)
+            ))
+            batch = np.concatenate(xs), np.concatenate(ys), np.concatenate(ws)
+            tracer.end_span(span)
+            return batch
 
         return steps, fetch
 
@@ -354,6 +421,15 @@ class PrefetchLoader:
     multi-GPU-training-torch.py:90-98): batch assembly (sampler slicing,
     native gather, padding) overlaps with device compute through a bounded
     queue. Semantics are unchanged — same batches, same order.
+
+    Tracing: ``set_tracer`` reaches the inner loader (unknown attributes
+    delegate), whose batch plan opens the assembly spans on the thread that
+    runs it: here ``loader_gather`` and ``loader_pad`` come from the producer
+    side (``produce``, or a ``work`` of the pool), never from the consumer,
+    and ``loader_order`` from where the plan is made (the producer thread; the
+    consumer's first ``next`` with a pool). A consumer that waits while no
+    such span is open waited on an empty queue or on the hand-over, not on
+    assembly.
 
     ``workers > 1`` parallelizes batch *assembly* across a thread pool when
     the inner loader exposes the random-access ``make_batch_plan`` protocol

@@ -67,7 +67,12 @@ ENDPOINT_SPANS_DEFAULT = 256
 # batch -> device placement), dispatch (the jitted call's issue window),
 # collective (the comm hook's bucketed exchange, annotated with wire bytes —
 # an annotation span: the exchange itself runs inside the compiled program),
-# and readback (deferred metric drain / explicit sync) children. Serving:
+# readback (deferred metric drain / explicit sync) and queue_wait (the runner
+# blocked on the loader's next batch) children; load is the host moving a
+# batch's bytes: the loader's order / gather / pad, from whichever thread
+# assembles, and the two halves of a stage (stack, put). The halves are not
+# stage spans themselves because readers that sum by kind (the advisor's
+# phase shares, the inspect tool) would count a stage's interval twice. Serving:
 # one request span per admitted request with admission / queue_wait /
 # prefill / serve children; decode_step spans are the engine-side step
 # timeline; failover and probation mark survivability episodes. Fleet: one
@@ -77,6 +82,7 @@ KIND_STAGE = "stage"
 KIND_DISPATCH = "dispatch"
 KIND_COLLECTIVE = "collective"
 KIND_READBACK = "readback"
+KIND_LOAD = "load"
 KIND_REQUEST = "request"
 KIND_ADMISSION = "admission"
 KIND_QUEUE_WAIT = "queue_wait"
@@ -90,8 +96,9 @@ KIND_ACTION = "action"
 
 SPAN_KINDS = (
     KIND_EPOCH, KIND_STAGE, KIND_DISPATCH, KIND_COLLECTIVE, KIND_READBACK,
-    KIND_REQUEST, KIND_ADMISSION, KIND_QUEUE_WAIT, KIND_PREFILL, KIND_SERVE,
-    KIND_DECODE_STEP, KIND_FAILOVER, KIND_PROBATION, KIND_JOB, KIND_ACTION,
+    KIND_LOAD, KIND_REQUEST, KIND_ADMISSION, KIND_QUEUE_WAIT, KIND_PREFILL,
+    KIND_SERVE, KIND_DECODE_STEP, KIND_FAILOVER, KIND_PROBATION, KIND_JOB,
+    KIND_ACTION,
 )
 
 
@@ -182,11 +189,6 @@ class _NullTracer:
 
     def end_span(self, span, **attrs) -> None:
         pass
-
-    def span(self, *a, **kw):
-        import contextlib
-
-        return contextlib.nullcontext(NULL_SPAN)
 
     def open_span_summaries(self) -> list:
         return []
@@ -335,20 +337,6 @@ class Tracer:
                     key=lambda r: r["duration_ms"], reverse=True
                 )
                 del self._slowest[_SLOWEST_TABLE:]
-
-    def span(self, name: str, kind: str, **kw):
-        """Context-manager sugar over start/end for non-hot-path callers."""
-        import contextlib
-
-        @contextlib.contextmanager
-        def _cm():
-            s = self.start_span(name, kind, **kw)
-            try:
-                yield s
-            finally:
-                self.end_span(s)
-
-        return _cm()
 
     # ------------------------------------------------------------ live views --
     def open_span_summaries(self) -> List[dict]:

@@ -193,16 +193,31 @@ class StallClock:
         return dt
 
 
-def stalled_iter(loader, stall: StallClock):
+def stalled_iter(loader, stall: StallClock, tracer=trace_lib.NULL, trace_parent=None):
+    """``loader``'s batches, each ``next`` timed into ``stall`` and bracketed
+    by an ``input_wait`` span round the same interval (the ``next`` that
+    finds the loader exhausted has its span, marked so, and no stall: it
+    hands no batch to a dispatch). Closing the generator closes the loader's
+    iterator, which is what reaps a ``PrefetchLoader``'s workers."""
     it = iter(loader)
-    while True:
-        t0 = time.perf_counter()
-        try:
-            batch = next(it)
-        except StopIteration:
-            return
-        stall.add(time.perf_counter() - t0)
-        yield batch
+    try:
+        while True:
+            wait = tracer.start_span(
+                "input_wait", trace_lib.KIND_QUEUE_WAIT, parent=trace_parent,
+            )
+            t0 = time.perf_counter()
+            try:
+                batch = next(it)
+            except StopIteration:
+                tracer.end_span(wait, exhausted=True)
+                return
+            stall.add(time.perf_counter() - t0)
+            tracer.end_span(wait)
+            yield batch
+    finally:
+        close = getattr(it, "close", None)
+        if close is not None:
+            close()
 
 
 def _pad_to_cycles(chunk, accum: int):
@@ -248,15 +263,25 @@ def run_pass(
     (host stall, staged queue depth, in-flight depth).
 
     Tracing (``tracer``, an :mod:`~tpuddp.observability.trace` Tracer; None
-    -> inert): each staged placement lands a ``stage`` span, each jitted
-    call a ``dispatch`` span (issue-time window — dispatch is async, so the
-    span measures what the HOST paid, matching the recorder's lap
-    semantics), the deferred metric drain a ``readback`` span, and — when
-    ``comm_attrs`` names a live comm hook — a zero-length ``collective``
-    annotation span per dispatch carrying the wire-byte accounting. All
-    children of ``trace_parent`` (the driver's epoch span). Pure host
-    bracketing of calls this pass already makes: no new fences, bitwise
-    identity untouched.
+    -> inert). On the thread that runs the pass, all children of
+    ``trace_parent`` (the driver's epoch span): each ``next`` on the loader an
+    ``input_wait`` span (kind ``queue_wait``; the interval the stall clock is
+    given, so its total is ``host_stall_s``), each staged placement a
+    ``stage`` span with two children of kind ``load``, ``stage_stack``
+    (``stack_batches``; absent on the single-batch path) and ``stage_put``
+    (``ddp.shard_stacked`` / ``ddp.shard``: the host-side re-tile and the
+    transfer's issue), each jitted call a ``dispatch`` span (issue-time
+    window — dispatch is async, so the span measures what the HOST paid,
+    matching the recorder's lap semantics), the deferred metric drain a
+    ``readback`` span, and — when ``comm_attrs`` names a live comm hook — a
+    zero-length ``collective`` annotation span per dispatch carrying the
+    wire-byte accounting. A loader with ``set_tracer`` is handed the tracer
+    and the parent for the length of the pass (taken back in a ``finally``)
+    and opens ``loader_order``, ``loader_gather`` and ``loader_pad`` (kind
+    ``load``) on whichever thread assembles: this one with inline loading,
+    inside ``input_wait``; a ``PrefetchLoader`` producer or pool worker
+    otherwise. Pure host bracketing of calls this pass already makes: no new
+    fences, bitwise identity untouched.
 
     Step snapshots (``snap_cb``, the async checkpoint engine's hook): called
     between dispatches — AFTER dispatch N's telemetry posts and BEFORE
@@ -330,12 +355,21 @@ def run_pass(
             # before the next dispatch — never blocking (see docstring)
             snap_cb(state, dispatched_real, drain)
 
-    def stage(chunk_value, n_steps, n_real, n_samples, use_many):
+    def stage(host, n_steps, n_real, n_samples, use_many):
+        """Place ``host`` on the mesh and queue it: a list of batches to
+        stack into one scan chunk (``use_many``), or one batch."""
         ssp = tracer.start_span(
             "stage", trace_lib.KIND_STAGE, parent=trace_parent,
             attrs={"steps": n_steps},
         )
-        staged.append((chunk_value(), n_steps, n_real, n_samples, use_many))
+        if use_many:
+            csp = tracer.start_span("stage_stack", trace_lib.KIND_LOAD, parent=ssp)
+            host = stack_batches(host)
+            tracer.end_span(csp)
+        csp = tracer.start_span("stage_put", trace_lib.KIND_LOAD, parent=ssp)
+        placed = ddp.shard_stacked(host) if use_many else ddp.shard(host)
+        tracer.end_span(csp)
+        staged.append((placed, n_steps, n_real, n_samples, use_many))
         tracer.end_span(ssp)
 
     def drain_all():
@@ -347,57 +381,59 @@ def run_pass(
         tracer.end_span(rsp)
         return acc
 
-    chunk = []
-    for batch_idx, host_batch in enumerate(stalled_iter(loader, stall)):
-        if inject_cb is not None:
-            host_batch = inject_cb(host_batch)
-        if probe_cb is not None:
-            probe_cb(batch_idx, host_batch)
-        tel.offer_batch(host_batch)
+    hand = None if tracer is trace_lib.NULL else getattr(loader, "set_tracer", None)
+    if hand is not None:
+        hand(tracer, trace_parent)
+    batches = stalled_iter(loader, stall, tracer, trace_parent)
+    try:
+        chunk = []
+        for batch_idx, host_batch in enumerate(batches):
+            if inject_cb is not None:
+                host_batch = inject_cb(host_batch)
+            if probe_cb is not None:
+                probe_cb(batch_idx, host_batch)
+            tel.offer_batch(host_batch)
+            if poll():
+                return state, drain_all(), True
+            if scan_k <= 1 and accum <= 1:
+                # per-batch cadence: the staging queue still overlaps batch N+1's
+                # placement with batch N's dispatch (the pre-pipeline path staged
+                # nothing ahead here and paid the transfer serially). Same depth
+                # semantics as the scan path: `depth` batches held staged ahead.
+                stage(host_batch, 1, 1, len(host_batch[1]), False)
+                while len(staged) > depth or (staged and cfg.sync_readback):
+                    dispatch_oldest()
+                continue
+            chunk.append(host_batch)
+            if len(chunk) == scan_k:
+                stage(chunk, scan_k, scan_k, sum(len(b[1]) for b in chunk), True)
+                chunk = []
+                # keep at most `depth` chunks staged ahead; dispatch the oldest
+                # beyond that (dispatch is async — the device is already busy)
+                while len(staged) > depth or (staged and cfg.sync_readback):
+                    dispatch_oldest()
         if poll():
             return state, drain_all(), True
-        if scan_k <= 1 and accum <= 1:
-            # per-batch cadence: the staging queue still overlaps batch N+1's
-            # placement with batch N's dispatch (the pre-pipeline path staged
-            # nothing ahead here and paid the transfer serially). Same depth
-            # semantics as the scan path: `depth` batches held staged ahead.
-            stage(lambda: ddp.shard(host_batch), 1, 1, len(host_batch[1]), False)
-            while len(staged) > depth or (staged and cfg.sync_readback):
-                dispatch_oldest()
-            continue
-        chunk.append(host_batch)
-        if len(chunk) == scan_k:
-            stage(
-                lambda c=chunk: ddp.shard_stacked(stack_batches(c)),
-                scan_k,
-                scan_k,
-                sum(len(b[1]) for b in chunk),
-                True,
-            )
-            chunk = []
-            # keep at most `depth` chunks staged ahead; dispatch the oldest
-            # beyond that (dispatch is async — the device is already busy)
-            while len(staged) > depth or (staged and cfg.sync_readback):
-                dispatch_oldest()
-    if poll():
-        return state, drain_all(), True
-    while staged:
-        dispatch_oldest()
-    if chunk and accum > 1:
-        # tail under accumulation: pad to whole cycles, one scan dispatch
-        # (a per-batch step would fire a full-scale update per micro-batch)
-        tail_samples = sum(len(b[1]) for b in chunk)
-        n_real_tail = len(chunk)  # padding batches are not loader positions
-        tail = _pad_to_cycles(chunk, accum)
-        stage(
-            lambda: ddp.shard_stacked(stack_batches(tail)),
-            len(tail), n_real_tail, tail_samples, True,
-        )
-        dispatch_oldest()
+        while staged:
+            dispatch_oldest()
+        if chunk and accum > 1:
+            # tail under accumulation: pad to whole cycles, one scan dispatch
+            # (a per-batch step would fire a full-scale update per micro-batch)
+            tail_samples = sum(len(b[1]) for b in chunk)
+            n_real_tail = len(chunk)  # padding batches are not loader positions
+            tail = _pad_to_cycles(chunk, accum)
+            stage(tail, len(tail), n_real_tail, tail_samples, True)
+            dispatch_oldest()
+            return state, drain_all(), poll()
+        for host_batch in chunk:  # remainder: single steps, same semantics
+            if poll():
+                return state, drain_all(), True
+            stage(host_batch, 1, 1, len(host_batch[1]), False)
+            dispatch_oldest()
         return state, drain_all(), poll()
-    for host_batch in chunk:  # remainder: single steps, same semantics
-        if poll():
-            return state, drain_all(), True
-        stage(lambda: ddp.shard(host_batch), 1, 1, len(host_batch[1]), False)
-        dispatch_oldest()
-    return state, drain_all(), poll()
+    finally:
+        # an interrupted pass abandons the loader mid-epoch: its workers are
+        # reaped here, before the tracer they open spans on is taken back
+        batches.close()
+        if hand is not None:
+            hand(None)
