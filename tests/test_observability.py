@@ -473,8 +473,11 @@ def test_epoch_rows_carry_step_fields_and_no_recompilation(mesh, tmp_path):
     """ISSUE 4 acceptance: telemetry-on epoch rows carry step-time
     percentiles + MFU fields, the step program is HLO-identical with
     telemetry on or off, and no recompilation happens across epochs."""
+    # scan_steps pinned: "auto" runs a pass of four batches batch by batch
+    # (a quarter of the pass a dispatch), and this test wants the scan step
     ddp, (state, history) = small_run(
-        mesh, str(tmp_path), num_epochs=2, step_stats_every=2, n=256
+        mesh, str(tmp_path), num_epochs=2, step_stats_every=2, n=256,
+        scan_steps=4,
     )
     for row in history:
         assert row["type"] == "epoch"

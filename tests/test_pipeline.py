@@ -301,6 +301,85 @@ def test_hlo_identity_pipeline_on_off(mesh):
     assert on == off
 
 
+# ------------------------------------ when a staged chunk is released (ISSUE 40) --
+
+
+class _CountingLoader:
+    """``loader``'s batches, counting how many it has handed over."""
+
+    def __init__(self, loader):
+        self.loader, self.drawn = loader, 0
+
+    def __len__(self):
+        return len(self.loader)
+
+    def __iter__(self):
+        for batch in self.loader:
+            self.drawn += 1
+            yield batch
+
+
+@pytest.mark.parametrize("scan_k", [4, 1])
+@pytest.mark.parametrize("depth", [1, 2, 4])
+def test_first_chunk_is_dispatched_as_soon_as_it_is_staged(mesh, depth, scan_k):
+    """The first dispatch of a pass is issued when exactly K batches (one on
+    the per-batch cadence) were drawn from the loader, whatever ``depth``;
+    dispatch i after it is released by the staging of chunk i + depth (or by
+    the end of the loader), so at most ``depth`` chunks are ever left staged
+    behind a dispatch; and the pass is still the synchronous one, bitwise."""
+    ddp, state0 = _make_ddp(mesh)
+    loader = _CountingLoader(_loader(mesh, n=8 * 8 * 21))
+    n_batches = len(loader)
+    assert n_batches == 21  # at scan_k 4: five chunks and one single step
+    drawn_at, steps_of = [], []
+
+    def rec_one(s, b):
+        drawn_at.append(loader.drawn)
+        steps_of.append(1)
+        return ddp.train_step(s, b)
+
+    def rec_many(s, b):
+        drawn_at.append(loader.drawn)
+        steps_of.append(scan_k)
+        return ddp.train_step_many(s, b)
+
+    tel = _Tel()
+    state, acc, interrupted = pipe.run_pass(
+        ddp, state0, loader, scan_k, rec_one, rec_many,
+        cfg=pipe.PipelineConfig(depth=depth, host_workers=0), tel=tel,
+    )
+    assert not interrupted
+    whole = n_batches // scan_k
+    assert steps_of == [scan_k] * whole + [1] * (n_batches - whole * scan_k)
+    assert len(steps_of) == pipe.dispatches_per_pass(n_batches, scan_k)
+    assert drawn_at[0] == scan_k
+    assert drawn_at[1:whole] == [
+        min(scan_k * (i + depth + 1), n_batches) for i in range(1, whole)
+    ]
+    assert drawn_at[whole:] == [n_batches] * (n_batches - whole * scan_k)
+    assert tel.staged_behind[0] == 0 and max(tel.staged_behind) <= depth
+    # the queue does fill behind the first dispatch: a dispatch released by
+    # a newly staged chunk leaves `depth` behind it, the end's flush fewer
+    assert max(tel.staged_behind) == (depth if whole - 1 > depth else whole - 2)
+
+    ddp_ref, ref0 = _make_ddp(mesh)
+    ref, ref_acc, _ = pipe.run_pass(
+        ddp_ref, ref0, _loader(mesh, n=8 * 8 * 21), scan_k,
+        ddp_ref.train_step, ddp_ref.train_step_many, cfg=pipe.SYNCHRONOUS,
+    )
+    assert_states_bitwise_equal(jax.device_get(ref), jax.device_get(state))
+    assert_states_bitwise_equal(jax.device_get(ref_acc), jax.device_get(acc))
+
+
+@pytest.mark.parametrize(
+    "n_batches, scan_k, accum, want",
+    [(25, 5, 1, 5), (25, 25, 1, 1), (10, 4, 1, 4), (10, 4, 2, 3), (8, 4, 2, 2),
+     (21, 1, 1, 21), (3, 4, 1, 3), (0, 4, 1, 0)],
+)
+def test_dispatches_per_pass_counts_chunks_and_the_tail(n_batches, scan_k, accum, want):
+    assert pipe.dispatches_per_pass(n_batches, scan_k, accum) == want
+
+
 # ------------------------------------------------------ deferred readback --
 
 
@@ -469,6 +548,7 @@ class _Tel:
 
     def __init__(self):
         self.host_stall_s = 0.0
+        self.staged_behind = []  # the staged queue's length after each dispatch left it
 
     def offer_batch(self, batch):
         pass
@@ -476,8 +556,10 @@ class _Tel:
     def pre_dispatch(self, n_steps):
         pass
 
-    def post_dispatch(self, n_steps, n_samples, metrics=None, host_stall_s=0.0, **_):
+    def post_dispatch(self, n_steps, n_samples, metrics=None, host_stall_s=0.0,
+                      staging_depth=0, **_):
         self.host_stall_s += host_stall_s
+        self.staged_behind.append(staging_depth)
 
 
 def _traced_pass(mesh, workers, scan_k, tracer, **kw):
@@ -553,6 +635,20 @@ def test_traced_pass_span_tree(mesh, workers, scan_k):
         assert rows and all(r.startswith("tpuddp-prefetch") for r in rows)
         assert len(rows) <= workers
     assert tracer.open_span_summaries() == [] and tracer.dropped == 0
+    # every dispatch says which of its pass it is; the first says how long
+    # the pass took to reach it, which lies inside the epoch span's head
+    dispatches = sorted(by["dispatch"], key=lambda s: s["t_start_ns"])
+    assert len(dispatches) == pipe.dispatches_per_pass(n_batches, scan_k)
+    assert [d["attrs"]["index"] for d in dispatches] == list(range(len(dispatches)))
+    assert ["head_s" in d["attrs"] for d in dispatches] == (
+        [True] + [False] * (len(dispatches) - 1)
+    )
+    (opened,) = by["epoch 0"]
+    reach_s = (dispatches[0]["t_start_ns"] - opened["t_start_ns"]) / 1e9
+    assert 0 < dispatches[0]["attrs"]["head_s"] <= reach_s
+    # and it was issued before the second chunk's batches were waited for
+    first_waits = sorted(w["t_start_ns"] for w in by["input_wait"])
+    assert first_waits[max(scan_k, 1)] > dispatches[0]["t_start_ns"]
 
 
 @pytest.mark.parametrize("workers", [0, 2])

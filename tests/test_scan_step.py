@@ -17,20 +17,73 @@ from tpuddp.training.step import stack_batches
 KEY = jax.random.key(7)
 
 
-def test_resolve_scan_steps_auto_caps_by_model_size():
-    from tpuddp.training.loop import resolve_scan_steps
+MB = 1024 * 1024
 
-    mb = 1024 * 1024
-    assert resolve_scan_steps("auto", 1000) == 32  # unknown batch size: conservative
-    assert resolve_scan_steps("auto", 1000, param_bytes=100 * mb) == 32
-    # known batch bytes: deep cap, bounded by the ~256MB staging budget
-    assert resolve_scan_steps("auto", 1000, param_bytes=100 * mb, batch_nbytes=mb) == 64
-    assert resolve_scan_steps("auto", 1000, param_bytes=100 * mb, batch_nbytes=16 * mb) == 16
-    assert resolve_scan_steps("auto", 1000, param_bytes=100 * mb, batch_nbytes=10_000 * mb) == 1
-    # dispatch-bound small models get the deep cap (BASELINE.md K-sweep)
-    assert resolve_scan_steps("auto", 1000, param_bytes=2 * mb) == 64
-    assert resolve_scan_steps("auto", 5, param_bytes=2 * mb) == 5  # epoch-bound
-    assert resolve_scan_steps(16, 1000, param_bytes=2 * mb) == 16  # explicit wins
+
+@pytest.mark.parametrize(
+    "scan_steps, n_batches, param_mb, batch_nbytes, want",
+    [
+        # a long epoch never meets the share-of-the-pass cap: as before PR 40
+        pytest.param("auto", 1000, None, None, 32, id="unknown-batch-size-conservative"),
+        pytest.param("auto", 1000, 100, None, 32, id="large-model-unknown-batch"),
+        # known batch bytes: deep cap, bounded by the ~256MB staging budget
+        pytest.param("auto", 1000, 100, MB, 64, id="1MB-batches-deep-cap"),
+        pytest.param("auto", 1000, 100, 16 * MB, 16, id="16MB-batches-budget"),
+        pytest.param("auto", 1000, 100, 10_000 * MB, 1, id="batch-over-budget"),
+        # dispatch-bound small models get the deep cap (BASELINE.md K-sweep)
+        pytest.param("auto", 1000, 2, None, 64, id="small-model-deep-cap"),
+        # a short pass is cut into at least four dispatches so that the runner
+        # has a next chunk to stage while one runs: 5 // 4 = 1, batch by batch
+        # (was 5, the whole pass one dispatch, until PR 40)
+        pytest.param("auto", 5, 2, None, 1, id="short-epoch-batch-by-batch"),
+        pytest.param(16, 1000, 2, None, 16, id="explicit-wins"),
+        pytest.param(25, 25, 2, 6_291_456, 25, id="explicit-wins-over-the-share"),
+        # the benchmark's loader cell (2048 x 32x32x3 uint8 a batch, 25 a
+        # pass): the share is 6, 5 divides 25, so five dispatches and no tail
+        pytest.param("auto", 25, 228, 6_291_456, 5, id="loader-cell-25-batches"),
+        pytest.param("auto", 24, 228, 6_291_456, 6, id="share-divides"),
+        pytest.param("auto", 23, 228, 6_291_456, 5, id="prime-pass-keeps-the-share"),
+        pytest.param("auto", 8, 228, 6_291_456, 2, id="eight-batches-four-chunks"),
+        pytest.param("auto", 3, 228, 6_291_456, 1, id="fewer-than-four"),
+        pytest.param("auto", 150, 228, 6_291_456, 30, id="divisor-in-the-upper-half"),
+        pytest.param("auto", 160, 228, 6_291_456, 40, id="budget-binds-first"),
+        pytest.param("auto", 200, 2, None, 50, id="small-model-200-batches"),
+    ],
+)
+def test_resolve_scan_steps_table(scan_steps, n_batches, param_mb, batch_nbytes, want):
+    from tpuddp.training.loop import resolve_scan_steps
+    from tpuddp.training.pipeline import dispatches_per_pass
+
+    k = resolve_scan_steps(
+        scan_steps, n_batches,
+        param_bytes=None if param_mb is None else param_mb * MB,
+        batch_nbytes=batch_nbytes,
+    )
+    assert k == want
+    if scan_steps == "auto" and n_batches >= 4:
+        assert dispatches_per_pass(n_batches, k) >= 4
+
+
+def test_auto_cuts_every_short_pass_into_four_and_never_deepens():
+    """Whatever the pass's length: at least four dispatches once there are
+    four batches, never a K over what the caps gave before the share, and the
+    divisor preference gives up less than half of the share."""
+    from tpuddp.training.loop import resolve_scan_steps
+    from tpuddp.training.pipeline import dispatches_per_pass
+
+    budget_cap = 256 * MB // 6_291_456  # 40
+    for n_batches in range(1, 400):
+        k = resolve_scan_steps("auto", n_batches, 228 * MB, 6_291_456)
+        assert 1 <= k <= min(budget_cap, n_batches), n_batches
+        assert dispatches_per_pass(n_batches, k) >= min(4, n_batches), n_batches
+        share = max(1, n_batches // 4)
+        if share >= budget_cap:
+            assert k == budget_cap, n_batches
+            continue
+        assert share // 2 < k <= share, n_batches
+        if n_batches % k:
+            assert k == share, n_batches
+            assert all(n_batches % j for j in range(share // 2 + 1, share)), n_batches
 
 
 def make_batches(k, n=32, shape=(8, 8, 3), seed=0):

@@ -235,7 +235,8 @@ def summarize_history(path: str) -> None:
             "mesh",
             "world_size", "process_count", "device_kind", "jax_version",
             "tpuddp_version", "comm_hook", "comm_topology", "comm_density",
-            "scan_steps", "grad_accumulation", "step_stats_every",
+            "scan_steps", "dispatches_per_pass", "grad_accumulation",
+            "step_stats_every",
             # serving run_meta fields (api == "serving")
             "num_replicas", "max_batch_size", "max_queue_depth",
             "per_tenant_quota", "batch_timeout_ms", "buckets", "input_shape",

@@ -55,12 +55,16 @@ logger = logging.getLogger("tpuddp")
 
 _AUTO_SCAN_CAP = 64  # fusing amortizes the per-dispatch latency at no
 # semantic cost. This is the depth the bench's CNN rows publish — the product
-# default and the bench agree. Not re-measured on today's chip (PERF.md).
+# default and the bench agree.
 _AUTO_SCAN_FALLBACK_CAP = 32  # when the staged-chunk size cannot be known
 # bound on one staged (K, batch) chunk — the shared budget every auto depth
 # policy (native scan, managed fuse, eval fusion, serving) caps against
 _STAGE_BYTES_BUDGET = batching.STAGE_BYTES_BUDGET
 _SMALL_PARAM_BYTES = 4 * 1024 * 1024
+_AUTO_MIN_DISPATCHES = 4  # a pass is cut into at least this many dispatches
+# where it has the batches: the runner's shipped two staged chunks
+# (pipeline.PIPELINE_DEFAULTS["depth"]), one running and one being filled.
+# One dispatch a pass leaves the runner nothing to overlap (PERF.md, PR 40)
 
 
 def resolve_scan_steps(
@@ -79,9 +83,22 @@ def resolve_scan_steps(
     the starting cap when batch bytes are unknowable: small models (whole
     parameter set under ~4 MB) start from 64 — dispatch latency dominates
     them even deeper (the bench's toy-MLP K-sweep) — while unknown-size
-    batches on non-small models fall back to a conservative 32. Any integer
-    pins K explicitly; 1 disables fusion (one dispatch per batch, the
-    reference's cadence)."""
+    batches on non-small models fall back to a conservative 32.
+
+    K is also at most a quarter of the pass (``n_batches // 4``, at least
+    1): the async runner overlaps the loader, ``np.stack`` and the transfer
+    of chunk N+1 with the device's run of chunk N, and a pass that is one
+    dispatch gives it no chunk N+1. Measured on a v5e (PERF.md, PR 39-40):
+    25 batches of 6.29 MB resolved to K=25, the device then waited out the
+    whole pass's assembly and staging, 0.39 s of every 2.26 s; at K=5 it
+    waits for the first chunk alone. Where that share binds, the largest K
+    in its upper half that divides ``n_batches`` is taken (5 for 25, where
+    the share is 6): no single-step remainder, so the pass compiles one
+    program and its tail is not a row of one-batch transfers. A long epoch
+    never meets the share (1,000 batches of 1 MB still resolve to 64, with
+    today's remainder), and a pass of fewer than 8 batches runs batch by
+    batch. Any integer pins K explicitly; 1 disables fusion (one dispatch
+    per batch, the reference's cadence)."""
     if scan_steps in (None, "auto"):
         small = param_bytes is not None and param_bytes < _SMALL_PARAM_BYTES
         cap = _AUTO_SCAN_CAP if (small or batch_nbytes) else _AUTO_SCAN_FALLBACK_CAP
@@ -89,7 +106,12 @@ def resolve_scan_steps(
         # on large inputs still stages K x batch bytes (shared cap policy,
         # tpuddp/utils/batching.py)
         cap = batching.resolve_fuse(batch_nbytes, cap=cap)
-        return max(1, min(cap, n_batches))
+        share = max(1, n_batches // _AUTO_MIN_DISPATCHES)
+        if share >= cap:
+            return cap
+        return next(
+            (k for k in range(share, share // 2, -1) if n_batches % k == 0), share
+        )
     k = int(scan_steps)
     if k < 1:
         raise ValueError(f"scan_steps must be >= 1 or 'auto', got {scan_steps!r}")
@@ -265,6 +287,9 @@ def run_training_loop(
                     "the host/device cannot hold it",
                     accum, scan_steps * bnb / 1e6, _STAGE_BYTES_BUDGET // 2**20,
                 )
+    train_dispatches = pipeline_lib.dispatches_per_pass(
+        len(train_loader), scan_steps, accum
+    )
     want_resume = auto_resume or auto_resume_requested()
     if want_resume and save_dir is None and is_main:
         log("Auto-resume requested but no save_dir configured; starting fresh.")
@@ -405,6 +430,7 @@ def run_training_loop(
     meta_extra = {
         "api": "native",
         "scan_steps": scan_steps,
+        "dispatches_per_pass": train_dispatches,
         "grad_accumulation": accum,
         "start_epoch": start_epoch,
         "num_epochs": num_epochs,
@@ -707,6 +733,11 @@ def run_training_loop(
     if is_main:
         log(
             f"Training on {len(train_loader)} batches, test on {len(test_loader)} batches"
+        )
+        log(
+            f"Dispatch: {scan_steps} train steps fused ({train_dispatches} "
+            f"dispatches a pass), {eval_scan_steps} eval steps "
+            f"({pipeline_lib.dispatches_per_pass(len(test_loader), eval_scan_steps)})"
         )
 
     # the whole run is ONE trace: every epoch span (and its stage/dispatch/

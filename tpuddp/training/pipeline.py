@@ -10,7 +10,13 @@ module owns the overlap:
   async, so chunk N+1's host->HBM transfer rides the runtime's stream while
   chunk N's compute runs). The staged queue is byte-capped against the shared
   ~256 MB staging budget (``tpuddp/utils/batching.py``) — depth x chunk bytes
-  is real HBM.
+  is real HBM. ``depth`` is a lookahead, not a start-up threshold: the first
+  chunk of a pass is dispatched the moment it is staged (the device has
+  nothing to run until then, and a staged chunk is in HBM on either side of
+  the queue), and the queue fills behind it. There is something to overlap
+  only when a pass is several chunks, which is what ``scan_steps: auto``
+  sees to (``training/loop.py:resolve_scan_steps``: at least four dispatches
+  a pass).
 - **dispatch pipelining**: dispatch N+1 is enqueued before N's results land
   (JAX dispatch is asynchronous; the state dependency chains on device), and
   per-dispatch metric pytrees are harvested by a *deferred readback drain* —
@@ -51,7 +57,8 @@ from tpuddp.utils import batching
 # training-block contract, tpuddp/config.py::_merge_refusing_unknown).
 PIPELINE_DEFAULTS = {
     "depth": 2,  # staged device chunks held ahead of the dispatch cursor
-    # (byte-capped by the ~256 MB staging budget; 1 = single-chunk lookahead)
+    # once the pass's first dispatch is out (byte-capped by the ~256 MB
+    # staging budget; 1 = single-chunk lookahead)
     "host_workers": 2,  # PrefetchLoader worker threads assembling host
     # batches (0 = inline loading on the dispatch thread)
     "device_augment": True,  # fold normalize/flip/resize into the compiled
@@ -124,6 +131,14 @@ def staging_depth_for(depth: int, chunk_nbytes) -> int:
     depth — the chunker upstream already bounded one chunk by the same
     budget."""
     return batching.resolve_fuse(chunk_nbytes, cap=max(1, int(depth)))
+
+
+def dispatches_per_pass(n_batches: int, scan_k: int, accum: int = 1) -> int:
+    """How many dispatches :func:`run_pass` cuts a pass of ``n_batches`` into:
+    the whole ``scan_k``-chunks, then the remainder as single steps (one
+    padded scan under ``accum > 1``)."""
+    whole, rest = divmod(n_batches, max(1, scan_k))
+    return whole + (min(rest, 1) if accum > 1 else rest)
 
 
 def _leaf_ready(metrics) -> bool:
@@ -252,11 +267,16 @@ def run_pass(
     Semantics are the synchronous pass's, exactly: same batches, same order,
     same dispatch granularity (``scan_k``-chunks, a padded tail under
     ``accum > 1``, single steps for the remainder), so the result is bitwise
-    identical at every depth. ``poll`` (the preemption flag) is checked at
-    every batch boundary; an interrupted pass returns early with the state as
-    of the last issued dispatch — staged-but-undispatched chunks are dropped
-    (the redone epoch re-derives them), and the emergency checkpoint's device
-    fetch flushes the in-flight dispatches before anything is written.
+    identical at every depth. The first chunk of the pass (the first batch
+    on the per-batch cadence) is dispatched as soon as it is staged: until
+    then the device has nothing of this pass to run. After it, up to
+    ``cfg.depth`` staged chunks are held ahead of the dispatch cursor and
+    each newly staged one releases the oldest. ``poll`` (the preemption
+    flag) is checked at every batch boundary; an interrupted pass returns
+    early with the state as of the last issued dispatch —
+    staged-but-undispatched chunks are dropped (the redone epoch re-derives
+    them), and the emergency checkpoint's device fetch flushes the in-flight
+    dispatches before anything is written.
     ``inject_cb`` (the ``nan@step=N`` chaos hook) may rewrite each host batch
     before staging. ``tel`` (a :class:`~tpuddp.observability.RunTelemetry`;
     None -> inert) brackets each dispatch and receives the occupancy fields
@@ -272,7 +292,10 @@ def run_pass(
     (``ddp.shard_stacked`` / ``ddp.shard``: the host-side re-tile and the
     transfer's issue), each jitted call a ``dispatch`` span (issue-time
     window — dispatch is async, so the span measures what the HOST paid,
-    matching the recorder's lap semantics), the deferred metric drain a
+    matching the recorder's lap semantics; ``index`` says which dispatch of
+    the pass it is, and the first carries ``head_s``, the time from the
+    start of ``run_pass`` to its issue: what the device waited at the head
+    of the pass), the deferred metric drain a
     ``readback`` span, and — when ``comm_attrs`` names a live comm hook — a
     zero-length ``collective`` annotation span per dispatch carrying the
     wire-byte accounting. A loader with ``set_tracer`` is handed the tracer
@@ -311,14 +334,19 @@ def run_pass(
     stall = StallClock()
     staged = deque()  # (staged_chunk, n_steps, n_real, n_samples, use_many)
     dispatched_real = 0  # real (non-padding) micro-batches dispatched so far
+    n_dispatched = 0  # dispatches issued by this pass
+    t_pass = time.perf_counter()
 
     def dispatch_oldest():
-        nonlocal state, dispatched_real
+        nonlocal state, dispatched_real, n_dispatched
         chunk, n_steps, n_real, n_samples, use_many = staged.popleft()
         tel.pre_dispatch(n_steps)
+        attrs = {"steps": n_steps, "samples": n_samples, "index": n_dispatched}
+        if n_dispatched == 0:
+            attrs["head_s"] = time.perf_counter() - t_pass
+        n_dispatched += 1
         dsp = tracer.start_span(
-            "dispatch", trace_lib.KIND_DISPATCH, parent=trace_parent,
-            attrs={"steps": n_steps, "samples": n_samples},
+            "dispatch", trace_lib.KIND_DISPATCH, parent=trace_parent, attrs=attrs,
         )
         if use_many:
             state, metrics = step_many(state, chunk)
@@ -372,6 +400,15 @@ def run_pass(
         staged.append((placed, n_steps, n_real, n_samples, use_many))
         tracer.end_span(ssp)
 
+    def release():
+        """Dispatch what the queue may not hold: everything beyond ``depth``
+        staged chunks, and whatever is staged while this pass has issued
+        nothing (or, on the serial cadence, at all)."""
+        while len(staged) > depth or (
+            staged and (n_dispatched == 0 or cfg.sync_readback)
+        ):
+            dispatch_oldest()
+
     def drain_all():
         rsp = tracer.start_span(
             "readback", trace_lib.KIND_READBACK, parent=trace_parent,
@@ -399,19 +436,19 @@ def run_pass(
                 # per-batch cadence: the staging queue still overlaps batch N+1's
                 # placement with batch N's dispatch (the pre-pipeline path staged
                 # nothing ahead here and paid the transfer serially). Same depth
-                # semantics as the scan path: `depth` batches held staged ahead.
+                # semantics as the scan path: the first batch goes out at
+                # once, then `depth` batches are held staged ahead.
                 stage(host_batch, 1, 1, len(host_batch[1]), False)
-                while len(staged) > depth or (staged and cfg.sync_readback):
-                    dispatch_oldest()
+                release()
                 continue
             chunk.append(host_batch)
             if len(chunk) == scan_k:
                 stage(chunk, scan_k, scan_k, sum(len(b[1]) for b in chunk), True)
                 chunk = []
-                # keep at most `depth` chunks staged ahead; dispatch the oldest
-                # beyond that (dispatch is async — the device is already busy)
-                while len(staged) > depth or (staged and cfg.sync_readback):
-                    dispatch_oldest()
+                # the first chunk goes out at once; after it keep at most
+                # `depth` chunks staged ahead and dispatch the oldest beyond
+                # that (dispatch is async — the device is already busy)
+                release()
         if poll():
             return state, drain_all(), True
         while staged:
