@@ -558,6 +558,7 @@ def test_the_fused_conv_compiles_for_a_v5e(v5e, t, channels, key_width, d, taps)
     (WORKLOAD, 1, "train_step"), (WORKLOAD, 2, "train_step_many"),
     ("mellum2_ep4_t16k_fused", 1, "train_step"), ("mellum2_ep4_t16k_fused", 1, "train_step_many"),
     ("lfm2_ep4_t32k_fused", 1, "train_step"), ("lfm2_ep4_t32k_fused", 1, "train_step_many"),
+    ("ouro_loop4_t16k_fused", 1, "train_step"), ("ouro_loop4_t16k_fused", 1, "train_step_many"),  # four rolled passes
 ])
 def test_the_token_cells_step_compiles_for_a_v5e(v5e, monkeypatch, workload, sequences, method):
     """The whole step at published widths (the check's single step at one

@@ -9,7 +9,8 @@ types: Gated DeltaNet, gated attention, sliding-window and full attention, a
 gated short convolution), with a dense or a sparse feed-forward a layer, a
 tied or an untied head and, where the router chooses by a bias, that bias as
 the trunk's state; built at published widths as one chip's share of an
-expert-parallel job."""
+expert-parallel job. The same trunk walks a dense stack several times over the
+same weights, every pass an exit that a learned gate weighs."""
 
 from tpuddp.models.toy import ToyCNN, ToyMLP  # noqa: F401
 from tpuddp.models.alexnet import AlexNet  # noqa: F401
@@ -19,7 +20,8 @@ from tpuddp.models.resnet import (  # noqa: F401
 )
 from tpuddp.models.vgg import VGG11, VGG13, VGG16, VGG19  # noqa: F401
 from tpuddp.models.hybrid_moe import (  # noqa: F401
-    LFM2_EP4, LFM2_TINY, MELLUM2_EP4, MELLUM2_TINY, QWEN3_NEXT_EP16, QWEN3_NEXT_TINY, HybridMoELM,
+    LFM2_EP4, LFM2_TINY, MELLUM2_EP4, MELLUM2_TINY, OURO_2_6B_L6, OURO_TINY, QWEN3_NEXT_EP16, QWEN3_NEXT_TINY,
+    HybridMoELM,
 )
 
 from functools import partial as _partial
@@ -67,6 +69,13 @@ _REGISTRY = {
     "mellum2_tiny": _partial(HybridMoELM, **MELLUM2_TINY),
     "lfm2_ep4": _partial(HybridMoELM, **LFM2_EP4),
     "lfm2_tiny": _partial(HybridMoELM, **LFM2_TINY),
+    # the same trunk as a looped dense decoder: Ouro-2.6B (full attention over
+    # 16 heads of 128 with no per-head norm, a norm before and after each half
+    # of a layer, a 5,632-wide SwiGLU, an untied head) with six of its layers
+    # whole on the chip, walked four times over the same leaves, every pass an
+    # exit weighted by a learned gate; and a CPU-test size of it
+    "ouro_2_6b_l6": _partial(HybridMoELM, **OURO_2_6B_L6),
+    "ouro_tiny": _partial(HybridMoELM, **OURO_TINY),
     # aliases of the plain names: nn.Conv2d picks the space-to-depth lowering
     # of a thin-channel strided stem from its own shapes, so these build the
     # same program (kept for settings files and checkpoints that name them)

@@ -5,7 +5,9 @@ window, a gated short convolution), whose feed-forward is sparse or dense,
 whose head is the embedding's transpose or a matrix of its own, and which may
 carry state beside their parameters (a router's selection bias).
 
-Every layer is ``h = x + Mixer(RMSNorm(x))``, ``y = h + FF(RMSNorm(h))``.
+Every layer is ``h = x + Mixer(RMSNorm(x))``, ``y = h + FF(RMSNorm(h))``;
+where its tree has ``mixer_out_norm`` and ``ff_out_norm`` (``sandwich_norms``)
+a second norm stands on each half's result before the add.
 ``layer_types`` lists each layer's type, which names its mixer and its scope
 (``<i>_<type>``), five of them:
 
@@ -17,7 +19,8 @@ Every layer is ``h = x + Mixer(RMSNorm(x))``, ``y = h + FF(RMSNorm(h))``.
   zero-centred RMSNorms (scale ``1 + w``) and one shared expert behind a
   sigmoid gate beside the routed ones.
 - ``SlidingAttention`` and ``FullAttention``: the Mellum 2 family's two
-  layers, plain grouped-query softmax attention with the per-head norm and
+  layers, plain grouped-query softmax attention with the per-head norm (where
+  the mixer's tree has ``q_norm``: ``qk_norm``) and
   rotary on the whole head, no gate; the first sees ``sliding_window`` keys
   (a query's own and those before it) under the plain rotary table, the
   second every earlier key under a YaRN-scaled table (``yarn``; none: the
@@ -42,6 +45,19 @@ layers', ``mlp_chunk`` tokens at a time. The embedding covers the
 leaf of its own or, with ``tied_head``, the embedding transposed (no ``head``
 leaf: the one leaf's gradient is the sum of both uses).
 
+A layer runs once a token unless ``loop_steps`` is more than 1: then the whole
+stack (dense layers, no state) is walked that many times over the SAME leaves
+in one rolled loop, so a leaf's gradient is the sum over its uses and the
+program holds the stack once; the state is normed after every pass
+(``final_norm``, shared) and that normed state is what the next pass takes in.
+With an ``exit_gate`` leaf every pass's normed state is an exit: training
+returns :class:`~tpuddp.nn.sequence.DeferredExits` (one head for all exits, a
+token's loss the exits' losses weighted by the gate's distribution, its
+entropy in the gradient alone; the exits' counters in place of an expert
+layer's) and evaluation the last pass's logits. Without the leaf the last
+pass's state alone goes to the head. In training a pass keeps its input and
+is walked again in the backward pass (``_pass``).
+
 The training forward returns :class:`~tpuddp.nn.sequence.DeferredLogits`
 (the criterion takes the loss from the hidden states in chunks); evaluation
 returns ``(B, T, V)`` float32 logits. Each layer's mixer and feed-forward are
@@ -63,8 +79,10 @@ experts (one period of four layers); ``mellum2_ep4``, those of
 Mellum2-12B-A2.5B as share 0 of 4 (one period: three sliding layers and a
 full one); ``lfm2_ep4``, those of LFM2-8B-A1B as share 0 of 4 (a leading
 dense layer and one period: a full-attention layer and three convolution
-layers); ``qwen3_next_tiny``, ``mellum2_tiny`` and ``lfm2_tiny`` for the CPU
-tests.
+layers); ``ouro_2_6b_l6``, those of Ouro-2.6B with every layer whole on the
+chip (six dense full-attention layers with sandwich norms and no per-head
+norm, walked four times, every pass an exit); ``qwen3_next_tiny``,
+``mellum2_tiny``, ``lfm2_tiny`` and ``ouro_tiny`` for the CPU tests.
 """
 
 from __future__ import annotations
@@ -130,6 +148,12 @@ class HybridMoELM(Module):
         dense_layers: int = 0,
         dense_width: int = 0,
         tied_head: bool = False,  # the head is the embedding transposed
+        # a stack that runs loop_steps times over the same leaves, the state normed after every pass
+        loop_steps: int = 1,
+        sandwich_norms: bool = False,  # a norm after the mixer and after the feed-forward too, before each residual add
+        qk_norm: bool = True,  # softmax attention's per-head norm on queries and keys
+        exit_gate: bool = False,  # every pass is an exit and a learned gate spreads a token's loss over them
+        exit_entropy_weight: float = 0.05,  # what the exit distribution's entropy weighs in the gradient
         rms_eps: float = 1e-6,
         init_std: float = 0.02,
         compute_dtype=jnp.float32,
@@ -175,6 +199,14 @@ class HybridMoELM(Module):
         if self.dense_layers and not self.dense_width:
             raise ValueError("a dense feed-forward needs its dense_width")
         self.tied_head = bool(tied_head)
+        self.loop_steps, self.sandwich_norms, self.qk_norm = int(loop_steps), bool(sandwich_norms), bool(qk_norm)
+        self.exit_gate, self.exit_entropy_weight = bool(exit_gate), float(exit_entropy_weight)
+        if self.loop_steps < 1 or (self.exit_gate and self.loop_steps < 2):
+            raise ValueError(f"an exit gate chooses among two passes or more, not {loop_steps}")
+        if self.loop_steps > 1 and (self.dense_layers < self.n_layers or self.expert_bias):
+            raise ValueError("a looped stack's layers are dense: a pass carries neither counters nor state")
+        if self.exit_gate:  # what the exits' loss counts (nn/sequence.py); an expert layer's otherwise
+            self.counter_names = seq.exit_counter_names(self.loop_steps)
         self.rms_eps, self.init_std = float(rms_eps), float(init_std)
         self.compute_dtype = jnp.dtype(compute_dtype)
         self.attention_q_block, self.loss_chunk = int(attention_q_block), int(loss_chunk)
@@ -222,7 +254,7 @@ class HybridMoELM(Module):
                 "q_proj": normal(ks[0], (e, hq * (2 if gated else 1) * d)),
                 "k_proj": normal(ks[1], (e, hkv * d)),
                 "v_proj": normal(ks[2], (e, hkv * d)),
-                "q_norm": norm(d), "k_norm": norm(d),
+                **({"q_norm": norm(d), "k_norm": norm(d)} if self.qk_norm else {}),
                 "o_proj": normal(ks[3], (hq * d, e)),
             }
 
@@ -269,6 +301,7 @@ class HybridMoELM(Module):
             sparse = i >= self.dense_layers
             layers.append({
                 "input_norm": norm(e), "mixer": mixer, "post_norm": norm(e),
+                **({"mixer_out_norm": norm(e), "ff_out_norm": norm(e)} if self.sandwich_norms else {}),
                 **({"moe": experts(k_moe)} if sparse else {"mlp": dense(k_moe)}),
             })
             if self.expert_bias:
@@ -282,6 +315,8 @@ class HybridMoELM(Module):
         }
         if not self.tied_head:
             params["head"] = {"weight": normal(k_head, (e, self.vocab_size))}
+        if self.exit_gate:  # from 0: a fresh gate halves what is left at every exit
+            params["exit_gate"] = {"weight": jnp.zeros((e, 1), jnp.float32), "bias": jnp.zeros((1,), jnp.float32)}
         return params, tuple(biases)  # a selection bias a sparse layer, or nothing
 
     # --------------------------------------------------------------- mixers --
@@ -333,7 +368,9 @@ class HybridMoELM(Module):
                 seq.rotary, positions=positions, rotary_dim=self.rotary_dim, theta=self.rope_theta,
                 yarn=self.yarn if kind == FULL else None,
             )
-            q, k = rope(self._norm(q, p["q_norm"])), rope(self._norm(k, p["k_norm"]))
+            # the tree says whether queries and keys have a norm a head
+            head_norm = (lambda a, w: self._norm(a, p[w])) if "q_norm" in p else (lambda a, w: a)
+            q, k = rope(head_norm(q, "q_norm")), rope(head_norm(k, "k_norm"))
         with _prof.scope("attention"):
             o = seq.causal_attention(
                 q, k, v, scale=d ** -0.5, compute_dtype=cd, q_block=self.attention_q_block,
@@ -354,14 +391,19 @@ class HybridMoELM(Module):
             return seq.matmul(z, p["out_proj"], cd)
 
     def _mix(self, kind, p, x):
-        """``x + Mixer(RMSNorm(x))`` for one sequence ``(T, E)``."""
+        """``x + Mixer(RMSNorm(x))`` for one sequence ``(T, E)``; where the
+        layer's tree has ``mixer_out_norm``, that norm on the mixer's result
+        before the add."""
         if kind == DELTANET:
             mixer = self._deltanet
         elif kind == SHORT_CONV:
             mixer = self._short_conv
         else:
             mixer = functools.partial(self._attention, kind=kind)
-        return x + mixer(p["mixer"], self._norm(x[None], p["input_norm"]))[0]
+        y = mixer(p["mixer"], self._norm(x[None], p["input_norm"]))[0]
+        if "mixer_out_norm" in p:
+            y = self._norm(y, p["mixer_out_norm"])
+        return x + y
 
     def _experts(self, p, bias, h):
         with _prof.scope("moe"):
@@ -369,16 +411,24 @@ class HybridMoELM(Module):
                 p["moe"], self._norm(h, p["post_norm"]).reshape(-1, self.hidden_size),
                 top_k=self.top_k, first_expert=self.first_expert, compute_dtype=self.compute_dtype, bias=bias,
             )
-        return h + y.reshape(h.shape), aux, counters, router_counts
+        y = y.reshape(h.shape)
+        if "ff_out_norm" in p:
+            y = self._norm(y, p["ff_out_norm"])
+        return h + y, aux, counters, router_counts
 
     def _dense(self, p, h, remat: bool):
         """``h + SwiGLU(RMSNorm(h))``, ``mlp_chunk`` tokens at a time (with
         ``remat`` each chunk recomputed in the backward pass): the products'
-        float32 results are ``2 dense_width`` wide a token."""
+        float32 results are ``2 dense_width`` wide a token. Where the layer's
+        tree has ``ff_out_norm``, that norm on the SwiGLU's result before the
+        add."""
         def chunk(rows):
             with _prof.scope("mlp"):
                 x = self._norm(rows, p["post_norm"])
-                return rows + seq.swiglu(x, p["mlp"]["gate_up"], p["mlp"]["down"], self.compute_dtype)
+                y = seq.swiglu(x, p["mlp"]["gate_up"], p["mlp"]["down"], self.compute_dtype)
+                if "ff_out_norm" in p:
+                    y = self._norm(y, p["ff_out_norm"])
+                return rows + y
 
         if remat:
             chunk = jax.checkpoint(chunk)
@@ -407,11 +457,8 @@ class HybridMoELM(Module):
         return experts(p, bias, h)
 
     # -------------------------------------------------------------- forward --
-    def apply(self, params, state, x, ctx: Context):
-        tokens = jnp.asarray(x).astype(jnp.int32)
-        # the residual stream is kept in the products' input type (an 8-bit
-        # type only rounds the products' inputs: round_to)
-        h = seq.round_to(jnp.take(params["embed"]["weight"], tokens, axis=0), self.compute_dtype)
+    def _layers(self, params, state, h, ctx: Context):
+        """One walk over the layers: ``(h, aux_loss, counters, new_state)``."""
         aux_total = jnp.zeros((), jnp.float32)
         totals = {name: jnp.zeros((), jnp.float32) for name in self.counter_names}
         new_state = list(state)  # a selection bias a sparse layer, where the model has them
@@ -428,13 +475,52 @@ class HybridMoELM(Module):
             if counters is not None:
                 aux_total = aux_total + aux
                 totals = {name: totals[name] + counters[name] for name in totals}
-        h = self._norm(h, params["final_norm"])
+        return h, aux_total, totals, tuple(new_state)
+
+    def _pass(self, params, state, h, ctx: Context):
+        """One pass of a looped stack: ``(what the next pass takes in, this
+        pass's exit)``, one and the same normed state. In training a pass
+        keeps its input alone and is walked again in the backward pass, where
+        each layer then keeps the residual stream twice as in one walk: kept
+        for every pass, those rows are ``2 * loop_steps * n_layers`` copies of
+        the stream and do not fit beside the optimizer's state at the
+        published widths (PERF.md, PR 42)."""
+        def walk(params, h):
+            h = self._norm(self._layers(params, state, h, ctx)[0], params["final_norm"])
+            return h, h
+
+        return (jax.checkpoint(walk) if ctx.train else walk)(params, h)
+
+    def apply(self, params, state, x, ctx: Context):
+        tokens = jnp.asarray(x).astype(jnp.int32)
+        # the residual stream is kept in the products' input type (an 8-bit
+        # type only rounds the products' inputs: round_to)
+        h = seq.round_to(jnp.take(params["embed"]["weight"], tokens, axis=0), self.compute_dtype)
+        exits = None
+        if self.loop_steps == 1:
+            h, aux_total, totals, new_state = self._layers(params, state, h, ctx)
+            h = self._norm(h, params["final_norm"])
+        else:
+            # one rolled loop over the same leaves: the program holds the
+            # stack once, whatever loop_steps is
+            with _prof.scope("passes"):
+                h, exits = jax.lax.scan(
+                    lambda h, _: self._pass(params, state, h, ctx), h, None, length=self.loop_steps
+                )
+            # dense layers, no state (the constructor holds that): nothing to add up over the passes
+            aux_total, new_state = None, tuple(state)
+            totals = {name: jnp.zeros((), jnp.float32) for name in self.counter_names}
         head = params["head"]["weight"] if "head" in params else params["embed"]["weight"].T
+        if exits is not None and "exit_gate" in params and ctx.train:  # the tree says whether the passes are exits
+            return seq.DeferredExits(
+                exits, head, params["exit_gate"], entropy_weight=self.exit_entropy_weight,
+                compute_dtype=self.compute_dtype, chunk=self.loss_chunk,
+            ), new_state
         out = seq.DeferredLogits(
-            h, head, self.aux_loss_weight * aux_total, totals,
+            h, head, None if aux_total is None else self.aux_loss_weight * aux_total, totals,
             compute_dtype=self.compute_dtype, chunk=self.loss_chunk,
         )
-        return (out if ctx.train else out.logits()), tuple(new_state)
+        return (out if ctx.train else out.logits()), new_state
 
 
 QWEN3_NEXT_EP16 = dict(  # Qwen3-Next-80B-A3B's widths; depth, experts held and vocabulary cut
@@ -457,6 +543,11 @@ LFM2_EP4 = dict(  # LFM2-8B-A1B's widths; depth, dense layers, experts held and 
     n_experts=32, experts_held=8, first_expert=0, top_k=4, expert_width=1792, shared_width=0,
     expert_bias=True, bias_update_rate=1e-3, aux_loss_weight=0.0,
 )
+OURO_2_6B_L6 = dict(  # Ouro-2.6B's widths, heads and passes; depth cut. Every layer whole on the chip
+    hidden_size=2048, n_layers=6, layer_types=(FULL,) * 6, zero_centred_norms=False,
+    n_heads=16, n_kv_heads=16, head_dim=128, partial_rotary_factor=1.0, rope_theta=1e6, qk_norm=False,
+    dense_layers=6, dense_width=5632, sandwich_norms=True, loop_steps=4, exit_gate=True, exit_entropy_weight=0.05,
+)
 QWEN3_NEXT_TINY = dict(
     hidden_size=64, n_layers=4, full_attention_interval=4,
     n_heads=4, n_kv_heads=2, head_dim=16, partial_rotary_factor=0.25, rope_theta=1e7,
@@ -478,5 +569,11 @@ LFM2_TINY = dict(  # the dense feed-forward in chunks of 32 tokens and what is l
     conv_kernel=3, dense_layers=1, dense_width=96, tied_head=True, rms_eps=1e-5,
     n_experts=8, experts_held=2, first_expert=0, top_k=2, expert_width=32, shared_width=0,
     expert_bias=True, bias_update_rate=1e-3, aux_loss_weight=0.0,
+    attention_q_block=16, loss_chunk=64, mlp_chunk=32,
+)
+OURO_TINY = dict(  # three layers four times over; the dense feed-forward and the exits' loss in chunks with a rest
+    hidden_size=64, n_layers=3, layer_types=(FULL,) * 3, zero_centred_norms=False,
+    n_heads=4, n_kv_heads=4, head_dim=16, partial_rotary_factor=1.0, rope_theta=1e4, qk_norm=False,
+    dense_layers=3, dense_width=96, sandwich_norms=True, loop_steps=4, exit_gate=True, exit_entropy_weight=0.05,
     attention_q_block=16, loss_chunk=64, mlp_chunk=32,
 )

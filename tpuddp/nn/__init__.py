@@ -27,6 +27,7 @@ from tpuddp.nn.norm import (  # noqa: F401
 )
 from tpuddp.nn.loss import CrossEntropyLoss, cross_entropy  # noqa: F401
 from tpuddp.nn.sequence import (  # noqa: F401
+    DeferredExits,
     DeferredLogits,
     causal_attention,
     causal_conv1d,
@@ -58,6 +59,7 @@ __all__ = [
     "convert_sync_batchnorm",
     "CrossEntropyLoss",
     "cross_entropy",
+    "DeferredExits",
     "DeferredLogits",
     "causal_attention",
     "causal_conv1d",

@@ -4,7 +4,7 @@ table, a causal depthwise 1-D convolution, plain and between two gates, SwiGLU,
 causal attention over
 grouped key/value heads, full or within a sliding window, that never holds a
 ``T x T`` score block, and a head-plus-cross-entropy that never holds
-``(B, T, V)`` logits.
+``(B, T, V)`` logits, over one final state or over the exits of a looped stack.
 
 Attention has one mask with one parameter (a query sees itself and the keys
 before it, all of them or the ``window - 1`` nearest) and two lowerings, and
@@ -32,6 +32,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+
+from tpuddp.observability import profiling as _prof
 
 _NEG_INF = -1e30  # masked-score fill: finite, so a fully masked row stays NaN-free
 
@@ -409,6 +411,15 @@ def _banded_chunks(attend, band, q, k, v, *, chunk: int):
 
 # -- the head and its loss ------------------------------------------------------
 
+def _chunk_cross_entropies(h, y, head, compute_dtype):
+    """A token's cross-entropy for one chunk of rows ``h`` against the rounded
+    ``head``: the chunk's logits live in float32 for their own logsumexp only."""
+    logits = jnp.matmul(round_to(h, compute_dtype), head, preferred_element_type=jnp.float32)
+    logz = jax.scipy.special.logsumexp(logits, axis=-1)
+    true = jnp.take_along_axis(logits, y[:, None], axis=-1)[:, 0]
+    return logz - true
+
+
 def linear_cross_entropy(hidden, head, labels, weights, *, compute_dtype, chunk: int = 2048):
     """Sum over tokens of ``weights * cross_entropy(hidden @ head, labels)``
     without the ``(N, V)`` logits: tokens go ``chunk`` at a time, each chunk's
@@ -424,10 +435,7 @@ def linear_cross_entropy(hidden, head, labels, weights, *, compute_dtype, chunk:
 
     @jax.checkpoint
     def chunk_loss(h, y, w):
-        logits = jnp.matmul(round_to(h, compute_dtype), head, preferred_element_type=jnp.float32)
-        logz = jax.scipy.special.logsumexp(logits, axis=-1)
-        true = jnp.take_along_axis(logits, y[:, None], axis=-1)[:, 0]
-        return jnp.sum((logz - true) * w)
+        return jnp.sum(_chunk_cross_entropies(h, y, head, compute_dtype) * w)
 
     def body(total, args):
         return total + chunk_loss(*args), None
@@ -491,6 +499,105 @@ class DeferredLogits:
             # value of the cross-entropy alone, gradient of the sum
             loss = loss + (self.aux_loss - jax.lax.stop_gradient(self.aux_loss))
         return loss
+
+
+def token_cross_entropies(hidden, head, labels, *, compute_dtype, chunk: int = 2048):
+    """``cross_entropy(hidden @ head, labels)`` a token, ``(N,)`` float32,
+    without the ``(N, V)`` logits: :func:`linear_cross_entropy`'s chunks (each
+    chunk's logits live in float32 for its own logsumexp only and are
+    recomputed in the backward pass), kept apart a token for a caller whose
+    weights depend on the losses' own inputs."""
+    n = hidden.shape[0]
+    chunk = min(chunk, n)
+    pad = -n % chunk
+    if pad:
+        hidden, labels = jnp.pad(hidden, ((0, pad), (0, 0))), jnp.pad(labels, (0, pad))
+    head = round_to(head, compute_dtype)
+
+    chunk_losses = jax.checkpoint(lambda rows: _chunk_cross_entropies(*rows, head, compute_dtype))
+    split = lambda a: a.reshape(-1, chunk, *a.shape[1:])
+    return jax.lax.map(chunk_losses, (split(hidden), split(labels))).reshape(-1)[:n]
+
+
+def exit_distribution(gate_logits):
+    """``(log p, p)`` over the ``R`` exits of a looped stack from the gate's
+    logits ``(R, N)`` (the last row is not read): exit ``t`` takes
+    ``sigmoid(z_t)`` of what the exits before it left, the last exit all that
+    is left, so a token's ``p`` sums to 1. In logarithms, so that a gate that
+    has saturated still has an entropy."""
+    stay = jax.nn.log_sigmoid(-gate_logits[:-1])  # log(1 - lambda_t)
+    left = jnp.concatenate([jnp.zeros_like(gate_logits[:1]), jnp.cumsum(stay, axis=0)])  # log of what reaches exit t
+    log_p = left + jnp.concatenate([jax.nn.log_sigmoid(gate_logits[:-1]), jnp.zeros_like(gate_logits[:1])])
+    return log_p, jnp.exp(log_p)
+
+
+def exit_counter_names(exits: int) -> tuple:
+    """The counters :class:`DeferredExits` carries out: over a step's tokens
+    the sum of ``p_t`` and the sum of the exit's own loss, an exit each."""
+    return tuple(f"loop_exit_{what}_{t}" for what in ("mass", "loss") for t in range(1, exits + 1))
+
+
+@jax.tree_util.register_pytree_node_class
+class DeferredExits:
+    """What a looped stack with an exit gate returns in training: the
+    ``(R, B, T, E)`` normalised states after each of its ``R`` passes, the one
+    head they share and the gate ``{"weight": (E, 1), "bias": (1,)}``. The
+    criterion binds itself as to :class:`DeferredLogits`. Per token: exit
+    ``t``'s cross-entropy ``l_t`` (chunked: no exit's logits exist whole),
+    ``lambda_t = sigmoid(h_t . w + b)`` in float32, ``p`` as
+    :func:`exit_distribution` has it, and the loss ``sum_t p_t l_t``; the
+    entropy term ``-entropy_weight * H(p)`` enters the gradient and not the
+    reported loss, as ``aux_loss`` does. ``counters`` is filled when the loss
+    is taken (:func:`exit_counter_names`), which is before the step reads it."""
+
+    def __init__(self, hidden, head, gate, *, entropy_weight, compute_dtype, chunk=2048):
+        self.hidden, self.head, self.gate = hidden, head, gate
+        self.counters = {}
+        self.entropy_weight = float(entropy_weight)
+        self.compute_dtype, self.chunk = jnp.dtype(compute_dtype), chunk
+
+    def tree_flatten(self):
+        return (self.hidden, self.head, self.gate), (self.entropy_weight, self.compute_dtype, self.chunk)
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(*children, entropy_weight=aux[0], compute_dtype=aux[1], chunk=aux[2])
+
+    def _tpuddp_bind_loss(self, criterion, labels, weights=None):
+        return self.cross_entropy(labels, weights, criterion.reduction)
+
+    def cross_entropy(self, labels, weights: Optional[jax.Array], reduction: str = "mean"):
+        if reduction not in ("mean", "sum"):
+            raise ValueError(f"deferred exits reduce to 'mean' or 'sum', not {reduction!r}")
+        exits, width = self.hidden.shape[0], self.hidden.shape[-1]
+        labels = labels.reshape(-1)
+        if weights is None:
+            weights = jnp.ones(labels.shape, jnp.float32)
+        else:
+            weights = per_token_weights(weights, self.hidden.shape[1:-1]).reshape(-1)
+        rows = self.hidden.reshape(exits, -1, width)
+        with _prof.scope("exits"):
+            losses = token_cross_entropies(
+                rows.reshape(-1, width), self.head, jnp.tile(labels, exits),
+                compute_dtype=self.compute_dtype, chunk=self.chunk,
+            ).reshape(exits, -1)
+            with _prof.scope("gate"):
+                # an elementwise sum: float32 whatever a backend makes of a float32 product
+                logits = jnp.sum(
+                    rows.astype(jnp.float32) * self.gate["weight"][:, 0].astype(jnp.float32), axis=-1
+                ) + self.gate["bias"].astype(jnp.float32)
+                log_p, p = exit_distribution(logits)
+            total = jnp.sum(weights * jnp.sum(p * losses, axis=0))
+            entropy = -jnp.sum(weights * jnp.sum(p * log_p, axis=0))
+            masses, sums = jnp.sum(weights * p, axis=1), jnp.sum(weights * losses, axis=1)
+        self.counters = dict(zip(exit_counter_names(exits), jax.lax.stop_gradient(jnp.concatenate([masses, sums]))))
+        denom = 1.0
+        if reduction == "mean":
+            denom = jnp.sum(weights)
+            denom = jnp.where(denom == 0, 1.0, denom)
+        aux = -self.entropy_weight * entropy / denom
+        # value of the expected cross-entropy alone, gradient of the sum
+        return total / denom + (aux - jax.lax.stop_gradient(aux))
 
 
 def per_token_weights(weights, token_shape):
