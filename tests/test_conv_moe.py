@@ -7,6 +7,7 @@ nn/moe.py's sigmoid router) against its plain reference
 random weights, float32 unless a test says otherwise. Whole training steps are
 in tests/test_conv_moe_training.py."""
 
+import functools
 import re
 
 import jax
@@ -324,70 +325,159 @@ def test_the_lowering_rule_for_heads_of_64(backend, head_dim, t, per_replica, wa
     assert seq.attention_lowering(backend, head_dim, t, per_replica=per_replica) == want
 
 
-@pytest.mark.parametrize("t,head_dim,window,one_kernel", [
-    (32768, 64, None, False),   # 32 partial dq of 32,768 rows a head: the library's two kernels
-    (32768, 128, None, False),
-    (16384, 128, None, True),   # the window-and-full cell's full layer: as it was
-    (16384, 128, 1024, True),   # and its band
-    (16384, 64, None, True),
-    (8192, 256, None, True),    # the DeltaNet hybrid's cell
-    (32768, 128, 1024, True),   # a band's calls see 2,048 keys whatever the sequence
-    (17408, 64, None, False),   # the first length past the bound
+@pytest.mark.parametrize("t,head_dim,window,heads,sequences,groups", [
+    (32768, 64, None, (32, 8), 1, 8),     # this cell: 32 partial dq of 32,768 rows a head, one key/value head a call
+    (32768, 128, None, (32, 4), 1, 0),    # 8 query heads a key/value head: one group is past the bound a call
+    (32768, 64, None, (32, 8), 2, 0),     # two sequences a call, likewise
+    (32768, 64, None, (8, 8), 1, 2),      # ungrouped heads: 4 a call
+    (32768, 256, None, (16, 2), 1, 0),    # a row of 256 fills two lane registers
+    (65536, 64, None, (32, 8), 1, 0),     # the library's two kernels
+    (16384, 128, None, (32, 4), 1, 1),    # the window-and-full cell's full layer: as it was
+    (16384, 128, 1024, (32, 4), 1, 1),    # and its band
+    (16384, 128, None, (16, 16), 1, 1),   # the looped cell
+    (16384, 64, None, (32, 8), 1, 1),
+    (8192, 256, None, (16, 2), 2, 1),     # the DeltaNet hybrid's cell
+    (32768, 128, 1024, (32, 4), 1, 1),    # a band's calls see 2,048 keys whatever the sequence
+    (17408, 64, None, (32, 8), 1, 4),     # the first length past the bound a head: two key/value heads a call
+    (17408, 64, None, (6, 3), 1, 1),      # past it and few heads: one call still
+    (17408, 64, None, (36, 3), 1, 3),     # groups divide the key/value heads: 3, not 2
 ])
-def test_the_backward_pass_is_one_kernel_up_to_its_partials_bound(t, head_dim, window, one_kernel):
-    blocks = seq.fused_attention_blocks(t, head_dim, window)
-    assert blocks.use_fused_bwd_kernel is one_kernel and blocks.has_backward_blocks
+def test_the_backward_pass_is_one_kernel_up_to_its_partials_bound(t, head_dim, window, heads, sequences, groups):
+    """``groups``: the calls the one kernel's heads go in, 0 where the
+    backward pass is the library's two kernels."""
+    shapes = dict(hq=heads[0], hkv=heads[1], sequences=sequences)
+    assert seq.fused_attention_head_groups(t, head_dim, window, **shapes) == groups
+    blocks = seq.fused_attention_blocks(t, head_dim, window, **shapes)
+    assert blocks.use_fused_bwd_kernel is (groups > 0) and blocks.has_backward_blocks
     assert blocks.block_q == blocks.block_kv == blocks.block_q_dkv == blocks.block_kv_dkv == 1024
-    if not one_kernel:
+    if not groups:
         assert blocks.block_q_dq == blocks.block_kv_dq == 1024
+
+
+def test_the_bound_a_call_is_one_key_value_head_of_this_cell():
+    """Stated in rows, beside the bound a head: what one key/value head's
+    four query heads write at 32,768 tokens, and less than two write."""
+    a_head = 4 * (32768 // 1024) * 32768
+    assert a_head <= seq._MOST_DQ_PARTIALS_A_CALL < 2 * a_head
+    assert seq._MOST_DQ_PARTIALS == 16 * 16384  # the bound a head, as it was
+
+
+@pytest.mark.parametrize("sequences,t,heads,head_dim,window,loops", [
+    (2, 8192, (16, 2), 256, None, 0),     # the DeltaNet hybrid's cell
+    (1, 16384, (32, 4), 128, None, 0),    # the window-and-full cell's full layer
+    (1, 16384, (32, 4), 128, 1024, 0),    # and its band
+    (1, 16384, (16, 16), 128, None, 0),   # the looped cell
+    (1, 32768, (32, 8), 64, None, 1),     # this cell: the groups' loop
+    (1, 65536, (8, 2), 64, None, 0),      # the two kernels take every head at once
+])
+def test_only_heads_that_go_in_groups_are_walked_by_a_loop(sequences, t, heads, head_dim, window, loops):
+    """Traced, not run: within the bound a head the kernel is called once
+    over all heads, the program the other token cells had before there were
+    groups; past it the one call stands in a loop over the groups."""
+    q, k = (jax.ShapeDtypeStruct((sequences, t, h, head_dim), jnp.bfloat16) for h in heads)
+    jaxpr = jax.make_jaxpr(functools.partial(
+        seq._fused_causal_attention, scale=head_dim ** -0.5, compute_dtype=jnp.bfloat16, window=window
+    ))(q, k, k)
+    assert [e.primitive.name for e in jaxpr.jaxpr.eqns].count("scan") == loops
 
 
 # kernel-eligible and small: three blocks of 512, heads of 64, two query heads a key/value head
 _T, _D = 1536, 64
 
 
-def _qkvw(dtype=jnp.float32):
+def _qkvw(hq=4, hkv=2):
     keys = jax.random.split(jax.random.key(11), 4)
-    shapes = ((1, _T, 4, _D), (1, _T, 2, _D), (1, _T, 2, _D), (1, _T, 4, _D))
-    return [jax.random.normal(k, s, jnp.float32).astype(dtype) for k, s in zip(keys, shapes)]
+    shapes = ((1, _T, hq, _D), (1, _T, hkv, _D), (1, _T, hkv, _D), (1, _T, hq, _D))
+    return [jax.random.normal(k, s, jnp.float32) for k, s in zip(keys, shapes)]
 
 
-@pytest.fixture(scope="module", params=["one_kernel", "two_kernels"])
-def narrow(request, ):
-    """Output and gradients at heads of 64 of the kernel in interpret mode,
-    with the backward pass it has at this length (one kernel) and the one it
-    has past the partials' bound (the library's two), and of the blockwise
-    path."""
-    q, k, v, w = _qkvw()
+def _value_and_grads(f, q, k, v, w):
+    """``(out, (dq, dk, dv))`` of ``sum(f(q, k, v) * w)``."""
+    return f(q, k, v), jax.grad(lambda *a: jnp.sum(f(*a) * w), argnums=(0, 1, 2))(q, k, v)
+
+
+def _fused(qkvw, **patched):
+    """Through the kernel in interpret mode, with the names of ``nn.sequence``
+    in ``patched`` set while it is traced."""
     kw = dict(scale=_D ** -0.5, compute_dtype=jnp.float32)
-    real = seq.fused_attention_blocks
+    with pytest.MonkeyPatch.context() as patch:
+        for name, value in patched.items():
+            patch.setattr(seq, name, value)
+        return _value_and_grads(lambda q, k, v: seq._fused_causal_attention(q, k, v, interpret=True, **kw), *qkvw)
 
-    def two_kernels(t, head_dim, window=None):
-        blocks = real(t, head_dim, window)
-        return type(blocks)(**{
-            **{f: getattr(blocks, f) for f in blocks.__dataclass_fields__},
-            "use_fused_bwd_kernel": False, "block_q_dq": blocks.block_q, "block_kv_dq": blocks.block_kv,
-        })
 
-    def value_and_grads(f):
-        return f(q, k, v), jax.grad(lambda *a: jnp.sum(f(*a) * w), argnums=(0, 1, 2))(q, k, v)
+def _blockwise(qkvw):
+    kw = dict(scale=_D ** -0.5, compute_dtype=jnp.float32)
+    return _value_and_grads(lambda q, k, v: seq._blockwise_causal_attention(q, k, v, q_block=512, **kw), *qkvw)
 
-    if request.param == "two_kernels":
-        seq.fused_attention_blocks = two_kernels
-    try:
-        fused = value_and_grads(lambda q, k, v: seq._fused_causal_attention(q, k, v, interpret=True, **kw))
-    finally:
-        seq.fused_attention_blocks = real
-    return fused, value_and_grads(lambda q, k, v: seq._blockwise_causal_attention(q, k, v, q_block=512, **kw))
+
+def _two_kernels(t, head_dim, window=None, *, real=seq.fused_attention_blocks, **shapes):
+    blocks = real(t, head_dim, window, **shapes)
+    return type(blocks)(**{
+        **{f: getattr(blocks, f) for f in blocks.__dataclass_fields__},
+        "use_fused_bwd_kernel": False, "block_q_dq": blocks.block_q, "block_kv_dq": blocks.block_kv,
+    })
+
+
+def _a_kv_head_a_call(hq, hkv):
+    """The bounds at which ``_T`` tokens are past the bound a head and one
+    key/value head's query heads fill the bound a call."""
+    return dict(_MOST_DQ_PARTIALS=0, _MOST_DQ_PARTIALS_A_CALL=(hq // hkv) * (_T // 512) * _T)
+
+
+@pytest.fixture(scope="module", params=["one_kernel", "two_kernels", "grouped"])
+def narrow(request):
+    """Output and gradients at heads of 64 of the kernel in interpret mode,
+    with the backward pass it has at this length (one kernel, every head in
+    one call), the one it has past the partials' bound a head (one kernel, a
+    key/value head's group a call: the bounds patched so that two groups
+    form) and the one it has where a group is past the bound a call (the
+    library's two), and of the blockwise path."""
+    qkvw = _qkvw()
+    patched = {
+        "one_kernel": {}, "two_kernels": dict(fused_attention_blocks=_two_kernels), "grouped": _a_kv_head_a_call(4, 2),
+    }[request.param]
+    return _fused(qkvw, **patched), _blockwise(qkvw)
+
+
+def _pick(got, what):
+    return np.asarray(got[0] if what == "out" else got[1]["qkv".index(what[1])])
 
 
 @pytest.mark.parametrize("what", ["out", "dq", "dk", "dv"])
 def test_heads_of_64_through_the_kernel_agree_with_the_blockwise_path(narrow, what):
     fused, blockwise = narrow
-    pick = lambda got: got[0] if what == "out" else got[1]["qkv".index(what[1])]
-    a, b = np.asarray(pick(fused)), np.asarray(pick(blockwise))
+    a, b = _pick(fused, what), _pick(blockwise, what)
     assert a.shape == b.shape and a.shape[-1] == 64
     np.testing.assert_allclose(a, b, rtol=0, atol=2e-5 * np.abs(b).max())
+
+
+@pytest.fixture(scope="module")
+def three_a_group():
+    """Six query heads over two key/value heads, three a group: a cut that is
+    not at a key/value head gives a query head another head's keys. All heads
+    in one call, a key/value head's group a call, and the blockwise path."""
+    qkvw = _qkvw(6, 2)
+    with pytest.MonkeyPatch.context() as patch:
+        for name, value in _a_kv_head_a_call(6, 2).items():
+            patch.setattr(seq, name, value)
+        assert seq.fused_attention_head_groups(_T, _D, hq=6, hkv=2) == 2
+        assert seq.fused_attention_head_groups(_T, _D, hq=6, hkv=2, sequences=2) == 0
+    assert seq.fused_attention_head_groups(_T, _D, hq=6, hkv=2) == 1
+    return _fused(qkvw), _fused(qkvw, **_a_kv_head_a_call(6, 2)), _blockwise(qkvw)
+
+
+@pytest.mark.parametrize("what", ["out", "dq", "dk", "dv"])
+def test_a_group_is_a_key_value_head_and_its_query_heads(three_a_group, what):
+    """Grouped, the kernel computes a head as it does with every head in one
+    call (the same blocks in the same order: to rounding, and the output to
+    the bit), and both agree with the blockwise path."""
+    one_call, grouped, blockwise = (_pick(got, what) for got in three_a_group)
+    assert grouped.shape == blockwise.shape == (1, _T, 6 if what in ("out", "dq") else 2, 64)
+    np.testing.assert_allclose(grouped, blockwise, rtol=0, atol=2e-5 * np.abs(blockwise).max())
+    np.testing.assert_allclose(grouped, one_call, rtol=0, atol=1e-6 * np.abs(one_call).max())
+    if what == "out":
+        np.testing.assert_array_equal(grouped, one_call)
 
 
 # -- the model -------------------------------------------------------------------------

@@ -293,19 +293,24 @@ def _banded_blocks(block, q, k, v, q_block: int, window: int):
 
 _LANES = 128  # a vector register's minor width
 _WIDEST_HEAD = 256  # the blocks below fill VMEM at this width; a wider head does not compile with them
-_MOST_DQ_PARTIALS = 16 * 16384  # rows a head of partial ``dq`` that the one-kernel backward may write
+# The one-kernel backward writes a copy of ``dq`` a key block, and what bounds it is rows of that copy
+# (a row: a head's ``dq`` at one query for one key block, in the inputs' type):
+_MOST_DQ_PARTIALS = 16 * 16384  # a head: up to it every head goes in one call
+_MOST_DQ_PARTIALS_A_CALL = 4 * 32 * 32768  # past it, a call over all its heads and sequences, lanes filled
 
 
-def fused_attention_blocks(t: int, head_dim: int, window=None):
-    """The fused kernels' block sizes for sequences of ``t`` tokens and heads
-    of ``head_dim``, or ``None`` where the kernel is not for the shapes: a
+def fused_attention_blocks(t: int, head_dim: int, window=None, *, hq: int = 1, hkv: int = 1, sequences: int = 1):
+    """The fused kernels' block sizes for ``sequences`` sequences of ``t``
+    tokens and ``hq`` query heads of ``head_dim`` over ``hkv`` key/value
+    heads, or ``None`` where the kernel is not for the shapes: a
     head that is neither half a lane register (64: the library's kernel takes
     it as it is, and zeros to fill the register would double q, k and v in
     HBM for the same products) nor a whole number of them, or wider than the
     blocks were sized for, a sequence that is no multiple of 512 (at blocks of
     256 and 128 the kernel is slower than the blockwise path: 6.3 and 18.8 ms
     a forward against 12.3 at 8,192 tokens; PERF.md, PR 29), or a ``window``
-    whose chunks (:func:`_banded_chunks`) do not divide the sequence.
+    whose chunks (:func:`_banded_chunks`) do not divide the sequence. The
+    heads and the sequences decide the backward pass only.
 
     Constants from that sweep, on a v5e at ``(8192, 256)``: blocks of 1024
     queries and keys (512 where 1024 does not divide ``t``), the forward's
@@ -315,17 +320,18 @@ def fused_attention_blocks(t: int, head_dim: int, window=None):
     faster alone and then did not fit VMEM inside the whole step, where XLA
     keeps buffers of its own there. Those partials are a copy of ``dq`` a key block of a
     call (``t / block`` of them without a window) in the inputs' type, lanes filled: 64 MiB a head at 16,384 tokens
-    (2 GiB over 32 heads) and 256 MiB a head at 32,768 (8.6 GB, compiled for a
-    described v5e; PERF.md, PR 37), so past 16,384 the backward pass is the
-    library's two kernels, 7 products and no partials (1.7 GB of scratch
-    there)."""
+    (2 GiB over 32 heads) and 256 MiB a head at 32,768, so what a call writes
+    is bounded (:func:`fused_attention_head_groups`): past 16,384 tokens the
+    one kernel goes a group of whole key/value heads at a time, and where one
+    key/value head's query heads are past the bound a call (65,536 tokens at
+    32/8 heads) the backward pass is the library's two kernels, 7 products
+    and no partials."""
     if (head_dim % _LANES and head_dim != _LANES // 2) or head_dim > _WIDEST_HEAD or t % 512:
         return None
-    block = 1024 if t % 1024 == 0 else 512
+    block = _fused_block(t)
     if window is not None and t % _band_chunk(window, block):
         return None
-    keys = t if window is None else 2 * _band_chunk(window, block)  # a kernel call's, at most
-    if (keys // block) * t <= _MOST_DQ_PARTIALS:
+    if fused_attention_head_groups(t, head_dim, window, hq=hq, hkv=hkv, sequences=sequences):
         backward = dict(use_fused_bwd_kernel=True)
     else:
         backward = dict(use_fused_bwd_kernel=False, block_q_dq=block, block_kv_dq=block)
@@ -333,6 +339,38 @@ def fused_attention_blocks(t: int, head_dim: int, window=None):
         block_q=block, block_kv=block, block_kv_compute=256,
         block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=512, **backward,
     )
+
+
+def _fused_block(t: int) -> int:
+    """Queries and keys a block of the fused kernels, forward and backward."""
+    return 1024 if t % 1024 == 0 else 512
+
+
+def fused_attention_head_groups(t: int, head_dim: int, window=None, *, hq: int, hkv: int, sequences: int = 1) -> int:
+    """How many calls the one-kernel backward's heads go in, one after
+    another, at shapes :func:`fused_attention_blocks` has blocks for: 1 where a
+    head's partial ``dq`` is within ``_MOST_DQ_PARTIALS`` rows (every head and
+    sequence in one call: 2 GiB at 16,384 tokens and 32 heads); past it the
+    fewest groups of whole key/value heads, each with its ``hq / hkv`` query
+    heads, that divide the heads and keep what a call writes (the group's
+    query heads over all sequences, a row counted once a lane register it
+    fills) within ``_MOST_DQ_PARTIALS_A_CALL``; 0 where one key/value head's
+    group is past that, and the backward pass is the library's two kernels.
+    At 32,768 tokens and 32/8 heads of 64: 8 calls of 4 query heads, 1.07 GB
+    of partials alive at a time where one call's 8.6 GB did not fit; the
+    whole step's scratch, compiled for a described v5e, is 7.03 GB with the
+    two kernels, 6.88 at one key/value head a call and 8.00 at two (PERF.md,
+    PR 43), so the bound a call is one head's group there and no more."""
+    block = _fused_block(t)
+    keys = t if window is None else 2 * _band_chunk(window, block)  # a kernel call's, at most
+    rows_a_head = (keys // block) * t
+    if rows_a_head <= _MOST_DQ_PARTIALS:
+        return 1
+    rows_a_kv_head = sequences * (hq // hkv) * rows_a_head * -(-head_dim // _LANES)
+    for groups in range(1, hkv + 1):
+        if hkv % groups == 0 and (hkv // groups) * rows_a_kv_head <= _MOST_DQ_PARTIALS_A_CALL:
+            return groups
+    return 0
 
 
 def _splash():
@@ -353,25 +391,34 @@ def _fused_causal_attention(q, k, v, *, scale, compute_dtype, window=None, inter
     in the forward and the backward pass alike, and which brings its own
     backward kernels (they recompute the scores from the saved log-sum-exp).
     Grouped heads are the kernel's own: query head ``h`` reads key/value head
-    ``h // (Hq / Hkv)``. The kernel applies no scale, so ``q`` carries it in.
+    ``h // (Hq / Hkv)``. Where the rule cuts the heads into groups
+    (:func:`fused_attention_head_groups`: past 16,384 tokens) the kernel is
+    called a group of whole key/value heads at a time, one after another
+    (``lax.map``: one kernel instance, one group's partial ``dq`` alive at a
+    time), and within a group the heads are the kernel's own still. The
+    kernel applies no scale, so ``q`` carries it in.
     ``interpret`` runs the kernels in Pallas's interpreter: the CPU tests'
     way in."""
     kernels, masks = _splash()
-    t, hq, d = q.shape[1:]
-    blocks = fused_attention_blocks(t, d, window)
+    (b, t, hq, d), hkv = q.shape, k.shape[2]
+    shapes = dict(hq=hq, hkv=hkv, sequences=b)
+    blocks = fused_attention_blocks(t, d, window, **shapes)
+    groups = fused_attention_head_groups(t, d, window, **shapes) or 1  # the two kernels take every head at once
 
     def attend(mask, q, k, v):
         """``q (N, Tq, Hq, D)`` against ``k``, ``v (N, Tk, Hkv, D)`` under ``mask (Tq, Tk)``."""
-        kernel = kernels.make_splash_mha(
-            masks.MultiHeadMask([mask] * hq), block_sizes=blocks, head_shards=1, q_seq_shards=1,
+        kernel = jax.vmap(kernels.make_splash_mha(
+            masks.MultiHeadMask([mask] * (hq // groups)), block_sizes=blocks, head_shards=1, q_seq_shards=1,
             interpret=interpret,
-        )
-        heads_first = lambda a: jnp.swapaxes(a, 1, 2)  # (N, H, T, D)
-        out = jax.vmap(kernel)(
-            heads_first(round_to(q, compute_dtype) * scale),
-            heads_first(round_to(k, compute_dtype)), heads_first(round_to(v, compute_dtype)),
-        )
-        return jnp.swapaxes(out, 1, 2).astype(q.dtype)
+        ))
+        qkv = (round_to(q, compute_dtype) * scale, round_to(k, compute_dtype), round_to(v, compute_dtype))
+        if groups == 1:
+            heads_first = lambda a: jnp.swapaxes(a, 1, 2)  # (N, H, T, D)
+            return heads_first(kernel(*map(heads_first, qkv))).astype(q.dtype)
+        # (groups, N, H / groups, T, D): neighbouring heads share a group, as they share a key/value head
+        groups_first = lambda a: a.reshape(*a.shape[:2], groups, -1, d).transpose(2, 0, 3, 1, 4)
+        out = jax.lax.map(lambda group: kernel(*group), tuple(map(groups_first, qkv)))
+        return out.transpose(1, 3, 0, 2, 4).reshape(q.shape).astype(q.dtype)
 
     if window is None:
         return attend(masks.CausalMask((t, t)), q, k, v)
