@@ -335,6 +335,7 @@ def test_the_lowering_rule_for_heads_of_64(backend, head_dim, t, per_replica, wa
     (16384, 128, None, (32, 4), 1, 1),    # the window-and-full cell's full layer: as it was
     (16384, 128, 1024, (32, 4), 1, 1),    # and its band
     (16384, 128, None, (16, 16), 1, 1),   # the looped cell
+    (16384, 256, None, (20, 20), 1, 1),   # the latent-attention cell: 20 ungrouped heads of 192 + 64, one call
     (16384, 64, None, (32, 8), 1, 1),
     (8192, 256, None, (16, 2), 2, 1),     # the DeltaNet hybrid's cell
     (32768, 128, 1024, (32, 4), 1, 1),    # a band's calls see 2,048 keys whatever the sequence
@@ -367,6 +368,7 @@ def test_the_bound_a_call_is_one_key_value_head_of_this_cell():
     (1, 16384, (32, 4), 128, None, 0),    # the window-and-full cell's full layer
     (1, 16384, (32, 4), 128, 1024, 0),    # and its band
     (1, 16384, (16, 16), 128, None, 0),   # the looped cell
+    (1, 16384, (20, 20), 256, None, 0),   # the latent-attention cell
     (1, 32768, (32, 8), 64, None, 1),     # this cell: the groups' loop
     (1, 65536, (8, 2), 64, None, 0),      # the two kernels take every head at once
 ])

@@ -134,13 +134,14 @@ def test_the_bias_rides_through_train_step_many_as_through_single_steps(system, 
 
 
 def _no_bias_in_the_choice(real):
-    def route(x, router, *, top_k, bias=None):
-        return real(x, router, top_k=top_k, bias=None if bias is None else jnp.zeros_like(bias))
+    def route(x, router, *, top_k, bias=None, **scale):
+        return real(x, router, top_k=top_k, bias=None if bias is None else jnp.zeros_like(bias), **scale)
     return route
 
 
 def _bias_in_the_weights(real):
-    def route(x, router, *, top_k, bias=None):
+    def route(x, router, *, top_k, bias=None, scale=1.0):
+        assert scale == 1.0  # this family's routes are not scaled
         _, experts, scores = real(x, router, top_k=top_k, bias=bias)
         chosen = jnp.take_along_axis(scores + bias, experts, axis=-1)
         return chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-6), experts, scores

@@ -499,6 +499,7 @@ def v5e():
     (32768, 32, 8, 64, None),  # the convolution-and-attention cell's: heads of 64, a key/value head's group a call
     (65536, 8, 2, 64, None),  # one group past the bound a call: the two-kernel backward
     (1536, 4, 2, 64, None),  # heads of 64 under the one-kernel backward
+    (16384, 20, 20, 256, None),  # the latent-attention cell's: ungrouped heads of 192 + 64, every head in one call
 ])
 def test_the_fused_lowering_compiles_for_a_v5e(v5e, t, hq, hkv, d, window):
     """Forward and backward kernels at the blocks the rule picks (the first
@@ -519,6 +520,8 @@ def test_the_fused_lowering_compiles_for_a_v5e(v5e, t, hq, hkv, d, window):
     assert ("splash_mha_dq" in text) == (t > 32768)
     if t > 16384:
         assert compiled.memory_analysis().temp_size_in_bytes < 3e9
+    if (hq, d) == (20, 256):  # the widest call of any cell: 16 partial dq of 20 heads x 256, 2,855,433,728 bytes
+        assert compiled.memory_analysis().temp_size_in_bytes < 2.9e9
 
 
 @pytest.mark.parametrize("t,hk,hv,d", [(8192, 16, 32, 128), (512, 2, 2, 256)])
@@ -561,6 +564,8 @@ def test_the_fused_conv_compiles_for_a_v5e(v5e, t, channels, key_width, d, taps)
     ("mellum2_ep4_t16k_fused", 1, "train_step"), ("mellum2_ep4_t16k_fused", 1, "train_step_many"),
     ("lfm2_ep4_t32k_fused", 1, "train_step"), ("lfm2_ep4_t32k_fused", 1, "train_step_many"),
     ("ouro_loop4_t16k_fused", 1, "train_step"), ("ouro_loop4_t16k_fused", 1, "train_step_many"),  # four rolled passes
+    # the largest state of any cell beside the widest attention call
+    ("glm47flash_ep8_t16k_fused", 1, "train_step"), ("glm47flash_ep8_t16k_fused", 1, "train_step_many"),
 ])
 def test_the_token_cells_step_compiles_for_a_v5e(v5e, monkeypatch, workload, sequences, method):
     """The whole step at published widths (the check's single step at one
@@ -591,6 +596,8 @@ def test_the_token_cells_step_compiles_for_a_v5e(v5e, monkeypatch, workload, seq
     assert "splash_mha_dq" not in text  # the one-kernel backward, past 16,384 tokens a key/value head's group a call
     memory = compiled.memory_analysis()  # nothing donated here: the state once as argument, once as result
     assert memory.temp_size_in_bytes + memory.argument_size_in_bytes < 14.5e9
+    if "LatentAttention" in model.layer_types:  # 8.48 GB of arguments: the scratch stays where it compiled (3.64 GB)
+        assert memory.temp_size_in_bytes < 3.8e9
     if t > 16384 and method == "train_step_many":  # the timed program: no more scratch than with the two kernels (PR 42)
         assert memory.temp_size_in_bytes <= 7_031_718_912
     deltanet_layers = "GatedDeltaNet" in model.layer_types
@@ -599,6 +606,13 @@ def test_the_token_cells_step_compiles_for_a_v5e(v5e, monkeypatch, workload, seq
     assert ("deltanet_conv_fwd" in text and "deltanet_conv_bwd" in text) == deltanet_layers  # the short convolution's
     # the grouped products: Pallas where an expert's rows are many (nn/moe.py: grouped_tiles)
     many_rows = cell.traffic["batch_per_chip"] * t * model.top_k // model.n_experts >= 1024
+    if "LatentAttention" in model.layer_types:  # 1,024 rows an expert, but a round of 13,184 rows is no whole tiles
+        tokens = cell.traffic["batch_per_chip"] * t
+        rows = moe_lib._round_rows(tokens, model.top_k, model.experts_held, model.n_experts)
+        assert rows % 256 and moe_lib.grouped_tiles(
+            "tpu", rows, model.experts_held, model.hidden_size, 2 * model.expert_width, per_replica=True
+        ) is None
+        many_rows = False
     # forward kernel, weight-gradient kernel; by the instruction's name (a kernel's serialised body is
     # base64, in which three letters turn up by chance)
     named = lambda kernel: re.search(rf"%{kernel}(\.\d+)? = ", text) is not None

@@ -4,13 +4,15 @@ adds genuinely small toy models for fast CI (per SURVEY.md scale calibration),
 ResNet-18/34 (BasicBlock) + ResNet-50/101/152 (Bottleneck), VGG-11/13/16/19, and
 CIFAR-stem variants (the ``*_s2d`` names are aliases of the plain ones); all
 torch-importable. Token models: a small GPT-2-style ``TransformerLM`` and
-``HybridMoELM``, expert decoders whose layers are listed by type (five mixer
+``HybridMoELM``, expert decoders whose layers are listed by type (six mixer
 types: Gated DeltaNet, gated attention, sliding-window and full attention, a
-gated short convolution), with a dense or a sparse feed-forward a layer, a
-tied or an untied head and, where the router chooses by a bias, that bias as
-the trunk's state; built at published widths as one chip's share of an
-expert-parallel job. The same trunk walks a dense stack several times over the
-same weights, every pass an exit that a learned gate weighs."""
+gated short convolution, latent attention), with a dense or a sparse
+feed-forward a layer, a tied or an untied head and, where the router chooses
+by a bias, that bias as the trunk's state; built at published widths as one
+chip's share of an expert-parallel job. The same trunk walks a dense stack
+several times over the same weights, every pass an exit that a learned gate
+weighs, or carries a module after the stack whose head predicts the token
+after next."""
 
 from tpuddp.models.toy import ToyCNN, ToyMLP  # noqa: F401
 from tpuddp.models.alexnet import AlexNet  # noqa: F401
@@ -20,7 +22,7 @@ from tpuddp.models.resnet import (  # noqa: F401
 )
 from tpuddp.models.vgg import VGG11, VGG13, VGG16, VGG19  # noqa: F401
 from tpuddp.models.hybrid_moe import (  # noqa: F401
-    LFM2_EP4, LFM2_TINY, MELLUM2_EP4, MELLUM2_TINY, OURO_2_6B_L6, OURO_TINY, QWEN3_NEXT_EP16, QWEN3_NEXT_TINY,
+    GLM_4_7_FLASH_EP8, GLM_4_7_FLASH_TINY, LFM2_EP4, LFM2_TINY, MELLUM2_EP4, MELLUM2_TINY, OURO_2_6B_L6, OURO_TINY, QWEN3_NEXT_EP16, QWEN3_NEXT_TINY,
     HybridMoELM,
 )
 
@@ -76,6 +78,15 @@ _REGISTRY = {
     # exit weighted by a learned gate; and a CPU-test size of it
     "ouro_2_6b_l6": _partial(HybridMoELM, **OURO_2_6B_L6),
     "ouro_tiny": _partial(HybridMoELM, **OURO_TINY),
+    # the same trunk with latent attention (GLM-4.7-Flash: 20 heads whose keys
+    # and values come through a 512-wide latent, queries through a 768-wide
+    # one, a head 192 without position + 64 rotary, the rotary key shared by
+    # the heads; a dense leading layer, 64 routed experts chosen by sigmoid
+    # score plus bias and scaled by 1.8, a shared expert added ungated) and a
+    # module after the stack whose head predicts the token after next, as one
+    # chip of an 8-way expert-parallel job holds them; and a CPU-test size
+    "glm_4_7_flash_ep8": _partial(HybridMoELM, **GLM_4_7_FLASH_EP8),
+    "glm_4_7_flash_tiny": _partial(HybridMoELM, **GLM_4_7_FLASH_TINY),
     # aliases of the plain names: nn.Conv2d picks the space-to-depth lowering
     # of a thin-channel strided stem from its own shapes, so these build the
     # same program (kept for settings files and checkpoints that name them)

@@ -9,7 +9,7 @@ Every layer is ``h = x + Mixer(RMSNorm(x))``, ``y = h + FF(RMSNorm(h))``;
 where its tree has ``mixer_out_norm`` and ``ff_out_norm`` (``sandwich_norms``)
 a second norm stands on each half's result before the add.
 ``layer_types`` lists each layer's type, which names its mixer and its scope
-(``<i>_<type>``), five of them:
+(``<i>_<type>``), six of them:
 
 - ``GatedDeltaNet`` (``nn/deltanet.py``) and ``GatedAttention`` (softmax
   attention with a per-head norm on queries and keys, rotary on part of the
@@ -31,10 +31,22 @@ a second norm stands on each half's result before the add.
   convolution of ``conv_kernel`` taps over ``b * u`` and the gate ``c`` after
   it (``seq.gated_short_conv``), one projection back: no activation, no
   norm, no scan.
+- ``LatentAttention``: the DeepSeek-V2 family's attention as GLM-4.7-Flash has
+  it, in the decompressed form training runs. Queries through a
+  ``q_lora_rank`` latent, keys and values through a ``kv_lora_rank`` one, a
+  norm inside each low-rank pair; ``n_heads`` ungrouped heads whose queries
+  and keys are ``qk_nope_dim`` without position beside ``qk_rope_dim`` under
+  rotary, the rotary key ONE a token that every head shares; values
+  ``v_head_dim`` wide (``qk_nope_dim + qk_rope_dim``: scores and values one
+  width, so ``seq.causal_attention`` takes the heads as any others). Scopes
+  ``q_latent``, ``kv_latent``, ``attention``, ``o_proj``.
 
 A layer's feed-forward is what its tree holds. ``moe``: a router over all
-``n_experts``, ``top_k`` of them a token, of which this chip computes the
-``experts_held`` it holds (``nn/moe.py``); with ``expert_bias`` the router
+``n_experts``, ``top_k`` of them a token (their renormalised weights times
+``routed_scale``), of which this chip computes the ``experts_held`` it holds
+(``nn/moe.py``), and a shared expert where ``shared_width`` is not 0, behind
+a sigmoid gate or, without ``shared_gate``, added as it is; with
+``expert_bias`` the router
 scores by sigmoid and chooses by score plus a per-expert bias, which is the
 model's state (a tuple a layer; no gradient reaches it): after each training
 step ``nn/moe.py:balanced_bias`` moves it by ``bias_update_rate`` towards an
@@ -43,7 +55,13 @@ axis. ``mlp``: a dense SwiGLU of ``dense_width``, the first ``dense_layers``
 layers', ``mlp_chunk`` tokens at a time. The embedding covers the
 ``num_classes`` rows of the vocabulary that this chip holds; the head is a
 leaf of its own or, with ``tied_head``, the embedding transposed (no ``head``
-leaf: the one leaf's gradient is the sum of both uses).
+leaf: the one leaf's gradient is the sum of both uses). Every projection,
+the router and the head are drawn at ``init_std``; so is the embedding unless
+``embed_std`` gives it a scale of its own (at 0.02 beside unit-normed layer
+inputs a fresh model whose first mixer is full attention adds one common
+vector, the uniform softmax's mean value, several times the embedding's size
+to every position, and every router downstream then sees the same token:
+PERF.md, PR 44).
 
 A layer runs once a token unless ``loop_steps`` is more than 1: then the whole
 stack (dense layers, no state) is walked that many times over the SAME leaves
@@ -57,6 +75,20 @@ entropy in the gradient alone; the exits' counters in place of an expert
 layer's) and evaluation the last pass's logits. Without the leaf the last
 pass's state alone goes to the head. In training a pass keeps its input and
 is walked again in the backward pass (``_pass``).
+
+With ``next_token_modules`` 1 a module stands after the stack (multi-token
+prediction at depth 1, arXiv:2412.19437 section 2.2; leaf ``mtp``): position
+``i``'s trunk state before the final norm and the embedding of token ``i + 1``,
+each normed, joined and projected back to the model's width, go through one
+more layer of the last layer's type with a sparse feed-forward of its own (its
+selection bias is the state's last entry, its counts add to the expert
+counters), then a norm of the module's own; embedding and head are the
+model's. Training returns these states beside the trunk's in the one
+:class:`~tpuddp.nn.sequence.DeferredLogits` (the second head's loss times
+``next_token_loss_weight`` in the gradient alone, ``mtp_loss_sum`` and
+``mtp_tokens`` among the counters); evaluation does not run the module. Scope
+``mtp`` (``mtp/proj``, then the layer's own scopes) under ``tpuddp.forward``
+and ``mtp`` round the second head under ``tpuddp.loss``.
 
 The training forward returns :class:`~tpuddp.nn.sequence.DeferredLogits`
 (the criterion takes the loss from the hidden states in chunks); evaluation
@@ -81,8 +113,11 @@ full one); ``lfm2_ep4``, those of LFM2-8B-A1B as share 0 of 4 (a leading
 dense layer and one period: a full-attention layer and three convolution
 layers); ``ouro_2_6b_l6``, those of Ouro-2.6B with every layer whole on the
 chip (six dense full-attention layers with sandwich norms and no per-head
-norm, walked four times, every pass an exit); ``qwen3_next_tiny``,
-``mellum2_tiny``, ``lfm2_tiny`` and ``ouro_tiny`` for the CPU tests.
+norm, walked four times, every pass an exit); ``glm_4_7_flash_ep8``, those of
+GLM-4.7-Flash as share 0 of 8 (the dense leading layer and four sparse
+latent-attention layers, the prediction module); ``qwen3_next_tiny``,
+``mellum2_tiny``, ``lfm2_tiny``, ``ouro_tiny`` and ``glm_4_7_flash_tiny`` for
+the CPU tests.
 """
 
 from __future__ import annotations
@@ -101,14 +136,15 @@ from tpuddp.observability import profiling as _prof
 DELTANET, ATTENTION = "GatedDeltaNet", "GatedAttention"
 SLIDING, FULL = "SlidingAttention", "FullAttention"
 SHORT_CONV = "ShortConv"
-LAYER_TYPES = (DELTANET, ATTENTION, SLIDING, FULL, SHORT_CONV)
+LATENT = "LatentAttention"
+LAYER_TYPES = (DELTANET, ATTENTION, SLIDING, FULL, SHORT_CONV, LATENT)
 
 
 class HybridMoELM(Module):
     """``num_classes`` is the number of vocabulary rows held (the zoo's
     ``load_model(name, num_classes)`` protocol)."""
 
-    counter_names = moe.COUNTERS  # what the step carries out beside its metrics
+    counter_names = moe.COUNTERS  # what the step carries out beside its metrics; a second head adds its own
 
     def __init__(
         self,
@@ -126,6 +162,12 @@ class HybridMoELM(Module):
         rope_theta: float = 1e7,
         sliding_window=None,  # keys a SlidingAttention query sees, its own among them
         yarn=None,  # FullAttention's scaling of the rotary table (seq.rotary_frequencies)
+        # LatentAttention: n_heads heads whose queries and keys are a part without position beside a rotary part
+        q_lora_rank: int = 768,
+        kv_lora_rank: int = 512,
+        qk_nope_dim: int = 192,
+        qk_rope_dim: int = 64,
+        v_head_dim: int = 256,
         # Gated DeltaNet; conv_kernel is the gated short convolution's too
         linear_k_heads: int = 16,
         linear_v_heads: int = 32,
@@ -140,6 +182,8 @@ class HybridMoELM(Module):
         top_k: int = 10,
         expert_width: int = 512,
         shared_width: int = 512,
+        shared_gate: bool = True,  # the shared expert stands behind a sigmoid gate
+        routed_scale: float = 1.0,  # what a token's renormalised routed weights are multiplied by
         aux_loss_weight: float = 0.001,
         expert_bias: bool = False,  # a sigmoid router that chooses by score plus a bias (the model's state)
         expert_bias_std: float = 0.0,  # the bias as init draws it: 0, or a seeded normal of this scale
@@ -154,8 +198,13 @@ class HybridMoELM(Module):
         qk_norm: bool = True,  # softmax attention's per-head norm on queries and keys
         exit_gate: bool = False,  # every pass is an exit and a learned gate spreads a token's loss over them
         exit_entropy_weight: float = 0.05,  # what the exit distribution's entropy weighs in the gradient
+        # a module after the stack that predicts the token after next: one more layer of the last layer's type
+        # with a sparse feed-forward, between a projection of [state ; next token's embedding] and the shared head
+        next_token_modules: int = 0,
+        next_token_loss_weight: float = 0.3,  # what the second head's loss weighs in the gradient
         rms_eps: float = 1e-6,
         init_std: float = 0.02,
+        embed_std=None,  # the embedding's own scale where it is not init_std's (an untied head keeps init_std)
         compute_dtype=jnp.float32,
         attention_q_block: int = 512,
         loss_chunk: int = 2048,
@@ -185,6 +234,10 @@ class HybridMoELM(Module):
         self.yarn = None if yarn is None else dict(yarn)
         self.n_heads, self.n_kv_heads, self.head_dim = int(n_heads), int(n_kv_heads), int(head_dim)
         self.rotary_dim = int(head_dim * partial_rotary_factor)
+        self.q_lora_rank, self.kv_lora_rank = int(q_lora_rank), int(kv_lora_rank)
+        self.qk_nope_dim, self.qk_rope_dim, self.v_head_dim = int(qk_nope_dim), int(qk_rope_dim), int(v_head_dim)
+        if LATENT in self.layer_types and self.qk_nope_dim + self.qk_rope_dim != self.v_head_dim:
+            raise ValueError("a latent-attention head's scores and values are one width: qk_nope_dim + qk_rope_dim")
         self.rope_theta = float(rope_theta)
         self.linear_k_heads, self.linear_v_heads = int(linear_k_heads), int(linear_v_heads)
         self.linear_k_dim, self.linear_v_dim = int(linear_k_dim), int(linear_v_dim)
@@ -192,6 +245,7 @@ class HybridMoELM(Module):
         self.n_experts, self.experts_held = int(n_experts), int(experts_held)
         self.first_expert, self.top_k = int(first_expert), int(top_k)
         self.expert_width, self.shared_width = int(expert_width), int(shared_width)
+        self.shared_gate, self.routed_scale = bool(shared_gate), float(routed_scale)
         self.aux_loss_weight = float(aux_loss_weight)
         self.expert_bias, self.expert_bias_std = bool(expert_bias), float(expert_bias_std)
         self.bias_update_rate = float(bias_update_rate)
@@ -207,7 +261,15 @@ class HybridMoELM(Module):
             raise ValueError("a looped stack's layers are dense: a pass carries neither counters nor state")
         if self.exit_gate:  # what the exits' loss counts (nn/sequence.py); an expert layer's otherwise
             self.counter_names = seq.exit_counter_names(self.loop_steps)
+        self.next_token_modules, self.next_token_loss_weight = int(next_token_modules), float(next_token_loss_weight)
+        if self.next_token_modules not in (0, 1) or (self.next_token_modules and self.loop_steps > 1):
+            raise ValueError("one module predicts the token after next, after a stack that is walked once")
+        if self.next_token_modules:
+            self.counter_names = moe.COUNTERS + seq.NEXT_COUNTERS
         self.rms_eps, self.init_std = float(rms_eps), float(init_std)
+        self.embed_std = self.init_std if embed_std is None else float(embed_std)
+        if self.tied_head and self.embed_std != self.init_std:
+            raise ValueError("a tied head is the embedding: one scale for both")
         self.compute_dtype = jnp.dtype(compute_dtype)
         self.attention_q_block, self.loss_chunk = int(attention_q_block), int(loss_chunk)
         self.mlp_chunk = int(mlp_chunk)
@@ -258,6 +320,19 @@ class HybridMoELM(Module):
                 "o_proj": normal(ks[3], (hq * d, e)),
             }
 
+        def latent_mixer(k):
+            ks = jax.random.split(k, 5)
+            h, rq, rkv = self.n_heads, self.q_lora_rank, self.kv_lora_rank
+            dn, dr, dv = self.qk_nope_dim, self.qk_rope_dim, self.v_head_dim
+            return {
+                "q_a_proj": normal(ks[0], (e, rq)), "q_a_norm": norm(rq),
+                "q_b_proj": normal(ks[1], (rq, h * (dn + dr))),  # per head: without position (dn) | rotary (dr)
+                "kv_a_proj": normal(ks[2], (e, rkv + dr)),  # the latent (rkv) | the one rotary key of all heads (dr)
+                "kv_a_norm": norm(rkv),
+                "kv_b_proj": normal(ks[3], (rkv, h * (dn + dv))),  # per head: key without position (dn) | value (dv)
+                "o_proj": normal(ks[4], (h * dv, e)),
+            }
+
         def short_conv_mixer(k):
             ks = jax.random.split(k, 3)
             return {
@@ -284,32 +359,37 @@ class HybridMoELM(Module):
             return {
                 **routed,
                 "shared": {"gate_up": normal(ks[3], (e, 2 * s)), "down": normal(ks[4], (s, e))},
-                "shared_gate": normal(ks[5], (e, 1)),
+                **({"shared_gate": normal(ks[5], (e, 1))} if self.shared_gate else {}),  # no leaf, no gate
             }
+
+        def mixer_of(kind, k):
+            if kind == DELTANET:
+                return deltanet_mixer(k)
+            if kind == SHORT_CONV:
+                return short_conv_mixer(k)
+            if kind == LATENT:
+                return latent_mixer(k)
+            return attention_mixer(k, kind == ATTENTION)
+
+        def drawn_bias(k):
+            return {"expert_bias": self.expert_bias_std * jax.random.normal(
+                jax.random.fold_in(k, 1), (self.n_experts,), jnp.float32
+            )}
 
         k_embed, k_head, k_layers = jax.random.split(key, 3)
         layers, biases = [], []
         for i in range(self.n_layers):
             k_mixer, k_moe = jax.random.split(jax.random.fold_in(k_layers, i))
-            kind = self.layer_kind(i)
-            if kind == DELTANET:
-                mixer = deltanet_mixer(k_mixer)
-            elif kind == SHORT_CONV:
-                mixer = short_conv_mixer(k_mixer)
-            else:
-                mixer = attention_mixer(k_mixer, kind == ATTENTION)
             sparse = i >= self.dense_layers
             layers.append({
-                "input_norm": norm(e), "mixer": mixer, "post_norm": norm(e),
+                "input_norm": norm(e), "mixer": mixer_of(self.layer_kind(i), k_mixer), "post_norm": norm(e),
                 **({"mixer_out_norm": norm(e), "ff_out_norm": norm(e)} if self.sandwich_norms else {}),
                 **({"moe": experts(k_moe)} if sparse else {"mlp": dense(k_moe)}),
             })
             if self.expert_bias:
-                biases.append({"expert_bias": self.expert_bias_std * jax.random.normal(
-                    jax.random.fold_in(k_moe, 1), (self.n_experts,), jnp.float32
-                )} if sparse else ())
+                biases.append(drawn_bias(k_moe) if sparse else ())
         params = {
-            "embed": {"weight": normal(k_embed, (self.vocab_size, e))},
+            "embed": {"weight": self.embed_std * jax.random.normal(k_embed, (self.vocab_size, e), jnp.float32)},
             "layers": tuple(layers),
             "final_norm": norm(e),
         }
@@ -317,7 +397,18 @@ class HybridMoELM(Module):
             params["head"] = {"weight": normal(k_head, (e, self.vocab_size))}
         if self.exit_gate:  # from 0: a fresh gate halves what is left at every exit
             params["exit_gate"] = {"weight": jnp.zeros((e, 1), jnp.float32), "bias": jnp.zeros((1,), jnp.float32)}
-        return params, tuple(biases)  # a selection bias a sparse layer, or nothing
+        if self.next_token_modules:
+            k_proj, k_mixer, k_moe = jax.random.split(jax.random.fold_in(k_layers, self.n_layers), 3)
+            params["mtp"] = {
+                "hidden_norm": norm(e), "embed_norm": norm(e),
+                "proj": normal(k_proj, (2 * e, e)),  # rows: the state's (e) | the next token's embedding's (e)
+                "layer": {"input_norm": norm(e), "mixer": mixer_of(self.layer_types[-1], k_mixer),
+                          "post_norm": norm(e), "moe": experts(k_moe)},
+                "head_norm": norm(e),
+            }
+            if self.expert_bias:  # the module's router has a bias of its own, after the layers'
+                biases.append(drawn_bias(k_moe))
+        return params, tuple(biases)  # a selection bias a sparse layer (the module's last), or nothing
 
     # --------------------------------------------------------------- mixers --
     def _norm(self, x, w):
@@ -381,6 +472,36 @@ class HybridMoELM(Module):
                 o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
             return seq.matmul(o.reshape(b, t, hq * d), p["o_proj"], cd)
 
+    def _latent_attention(self, p, x):
+        """Attention whose queries, keys and values come through low-rank
+        latents (arXiv:2405.04434, section 2.1), in the decompressed form
+        training runs: a norm inside each low-rank pair, a head's queries and
+        keys a part without position beside a rotary part, and ONE rotary key
+        a token that every head shares (it is projected from the input, not
+        from the latent). Scores are ``qk_nope_dim + qk_rope_dim`` wide,
+        values ``v_head_dim``: the same width, so :func:`seq.causal_attention`
+        takes the heads as it takes any others, ungrouped."""
+        b, t, _ = x.shape
+        cd = self.compute_dtype
+        h, rkv, dn, dr, dv = self.n_heads, self.kv_lora_rank, self.qk_nope_dim, self.qk_rope_dim, self.v_head_dim
+        rope = functools.partial(seq.rotary, positions=jnp.arange(t), rotary_dim=dr, theta=self.rope_theta)
+        with _prof.scope("q_latent"):
+            q = seq.matmul(self._norm(seq.matmul(x, p["q_a_proj"], cd), p["q_a_norm"]), p["q_b_proj"], cd)
+            q = q.reshape(b, t, h, dn + dr)
+            q = jnp.concatenate([q[..., :dn], rope(q[..., dn:])], axis=-1)
+        with _prof.scope("kv_latent"):
+            latent = seq.matmul(x, p["kv_a_proj"], cd)
+            kv = seq.matmul(self._norm(latent[..., :rkv], p["kv_a_norm"]), p["kv_b_proj"], cd).reshape(b, t, h, dn + dv)
+            k_rope = rope(latent[..., None, rkv:])  # (B, T, 1, dr): the same rotated key for every head
+            k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(k_rope, (b, t, h, dr))], axis=-1)
+            v = kv[..., dn:]
+        with _prof.scope("attention"):
+            o = seq.causal_attention(
+                q, k, v, scale=(dn + dr) ** -0.5, compute_dtype=cd, q_block=self.attention_q_block
+            )
+        with _prof.scope("o_proj"):
+            return seq.matmul(o.reshape(b, t, h * dv), p["o_proj"], cd)
+
     def _short_conv(self, p, x):
         cd = self.compute_dtype
         with _prof.scope("in_proj"):
@@ -398,6 +519,8 @@ class HybridMoELM(Module):
             mixer = self._deltanet
         elif kind == SHORT_CONV:
             mixer = self._short_conv
+        elif kind == LATENT:
+            mixer = self._latent_attention
         else:
             mixer = functools.partial(self._attention, kind=kind)
         y = mixer(p["mixer"], self._norm(x[None], p["input_norm"]))[0]
@@ -410,6 +533,7 @@ class HybridMoELM(Module):
             y, aux, counters, router_counts = moe.expert_share_moe(
                 p["moe"], self._norm(h, p["post_norm"]).reshape(-1, self.hidden_size),
                 top_k=self.top_k, first_expert=self.first_expert, compute_dtype=self.compute_dtype, bias=bias,
+                scale=self.routed_scale,
             )
         y = y.reshape(h.shape)
         if "ff_out_norm" in p:
@@ -457,25 +581,59 @@ class HybridMoELM(Module):
         return experts(p, bias, h)
 
     # -------------------------------------------------------------- forward --
+    def _scoped_layer(self, i: int, kind: str, p, layer_state, h, ctx: Context):
+        """Layer ``i`` under its scope ``<i>_<kind>``: ``(y, aux_loss,
+        counters, the layer's state after the step)``; in training a sparse
+        layer's selection bias moves by the step's counts."""
+        bias = layer_state["expert_bias"] if layer_state else None
+        with _prof.scope(f"{i}_{kind}"):
+            h, aux, counters, router_counts = self._layer(kind, p, bias, h, ctx.train)
+            if bias is not None and ctx.train:
+                with _prof.scope("moe"), _prof.scope("router"):
+                    layer_state = {"expert_bias": moe.balanced_bias(
+                        bias, router_counts, self.bias_update_rate, ctx.axis_name
+                    )}
+        return h, aux, counters, layer_state
+
     def _layers(self, params, state, h, ctx: Context):
         """One walk over the layers: ``(h, aux_loss, counters, new_state)``."""
         aux_total = jnp.zeros((), jnp.float32)
-        totals = {name: jnp.zeros((), jnp.float32) for name in self.counter_names}
+        totals = {name: jnp.zeros((), jnp.float32) for name in moe.COUNTERS if name in self.counter_names}
         new_state = list(state)  # a selection bias a sparse layer, where the model has them
         for i, p in enumerate(params["layers"]):
-            kind = self.layer_kind(i)
-            bias = state[i]["expert_bias"] if state and state[i] else None
-            with _prof.scope(f"{i}_{kind}"):
-                h, aux, counters, router_counts = self._layer(kind, p, bias, h, ctx.train)
-                if bias is not None and ctx.train:
-                    with _prof.scope("moe"), _prof.scope("router"):
-                        new_state[i] = {"expert_bias": moe.balanced_bias(
-                            bias, router_counts, self.bias_update_rate, ctx.axis_name
-                        )}
+            h, aux, counters, layer_state = self._scoped_layer(
+                i, self.layer_kind(i), p, state[i] if state else (), h, ctx
+            )
+            if state:
+                new_state[i] = layer_state
             if counters is not None:
                 aux_total = aux_total + aux
                 totals = {name: totals[name] + counters[name] for name in totals}
         return h, aux_total, totals, tuple(new_state)
+
+    def _next_token_module(self, p, layer_state, embed, tokens, h, ctx: Context):
+        """The states the second head reads (arXiv:2412.19437, section 2.2, at
+        depth 1): position ``i``'s trunk state ``h_i`` (before the final norm)
+        and the embedding of token ``i + 1``, each normed, joined and
+        projected back to the model's width, through one more layer of the
+        last layer's type with a sparse feed-forward of its own, then the
+        module's own norm; the head and the embedding are the model's.
+        ``(states, aux_loss, counters, the module's state after the step)``.
+        A sequence's last position has no successor: it is fed the first
+        token's embedding, and the loss gives it weight 0
+        (:class:`~tpuddp.nn.sequence.DeferredLogits`); causal, it reaches no
+        other position's state, only the router's counts, as one token."""
+        with _prof.scope("mtp"):
+            with _prof.scope("proj"):
+                after = seq.round_to(jnp.take(embed, jnp.roll(tokens, -1, axis=-1), axis=0), self.compute_dtype)
+                joined = jnp.concatenate(
+                    [self._norm(h, p["hidden_norm"]), self._norm(after, p["embed_norm"])], axis=-1
+                )
+                h = seq.matmul(joined, p["proj"], self.compute_dtype)
+            h, aux, counters, layer_state = self._scoped_layer(
+                self.n_layers, self.layer_types[-1], p["layer"], layer_state, h, ctx
+            )
+            return self._norm(h, p["head_norm"]), aux, counters, layer_state
 
     def _pass(self, params, state, h, ctx: Context):
         """One pass of a looped stack: ``(what the next pass takes in, this
@@ -496,9 +654,18 @@ class HybridMoELM(Module):
         # the residual stream is kept in the products' input type (an 8-bit
         # type only rounds the products' inputs: round_to)
         h = seq.round_to(jnp.take(params["embed"]["weight"], tokens, axis=0), self.compute_dtype)
-        exits = None
+        exits = next_hidden = None
         if self.loop_steps == 1:
             h, aux_total, totals, new_state = self._layers(params, state, h, ctx)
+            if "mtp" in params and ctx.train:  # the tree says whether a second head predicts the token after next
+                module_state = state[self.n_layers] if state else ()
+                next_hidden, aux, counters, module_state = self._next_token_module(
+                    params["mtp"], module_state, params["embed"]["weight"], tokens, h, ctx
+                )
+                aux_total = aux_total + aux
+                totals = {name: totals[name] + counters[name] for name in totals}
+                if state:
+                    new_state = (*new_state[:self.n_layers], module_state)
             h = self._norm(h, params["final_norm"])
         else:
             # one rolled loop over the same leaves: the program holds the
@@ -517,8 +684,8 @@ class HybridMoELM(Module):
                 compute_dtype=self.compute_dtype, chunk=self.loss_chunk,
             ), new_state
         out = seq.DeferredLogits(
-            h, head, None if aux_total is None else self.aux_loss_weight * aux_total, totals,
-            compute_dtype=self.compute_dtype, chunk=self.loss_chunk,
+            h, head, None if aux_total is None else self.aux_loss_weight * aux_total, totals, next_hidden,
+            compute_dtype=self.compute_dtype, chunk=self.loss_chunk, next_weight=self.next_token_loss_weight,
         )
         return (out if ctx.train else out.logits()), new_state
 
@@ -548,6 +715,14 @@ OURO_2_6B_L6 = dict(  # Ouro-2.6B's widths, heads and passes; depth cut. Every l
     n_heads=16, n_kv_heads=16, head_dim=128, partial_rotary_factor=1.0, rope_theta=1e6, qk_norm=False,
     dense_layers=6, dense_width=5632, sandwich_norms=True, loop_steps=4, exit_gate=True, exit_entropy_weight=0.05,
 )
+GLM_4_7_FLASH_EP8 = dict(  # GLM-4.7-Flash's widths, heads and ranks; depth, experts held and vocabulary cut
+    hidden_size=2048, n_layers=5, layer_types=(LATENT,) * 5, zero_centred_norms=False,
+    n_heads=20, n_kv_heads=20, q_lora_rank=768, kv_lora_rank=512, qk_nope_dim=192, qk_rope_dim=64, v_head_dim=256,
+    rope_theta=1e6, dense_layers=1, dense_width=10240, rms_eps=1e-5,
+    n_experts=64, experts_held=8, first_expert=0, top_k=4, expert_width=1536, shared_width=1536, shared_gate=False,
+    routed_scale=1.8, expert_bias=True, bias_update_rate=1e-3, aux_loss_weight=0.0,
+    next_token_modules=1, next_token_loss_weight=0.3, embed_std=1.0,
+)
 QWEN3_NEXT_TINY = dict(
     hidden_size=64, n_layers=4, full_attention_interval=4,
     n_heads=4, n_kv_heads=2, head_dim=16, partial_rotary_factor=0.25, rope_theta=1e7,
@@ -575,5 +750,14 @@ OURO_TINY = dict(  # three layers four times over; the dense feed-forward and th
     hidden_size=64, n_layers=3, layer_types=(FULL,) * 3, zero_centred_norms=False,
     n_heads=4, n_kv_heads=4, head_dim=16, partial_rotary_factor=1.0, rope_theta=1e4, qk_norm=False,
     dense_layers=3, dense_width=96, sandwich_norms=True, loop_steps=4, exit_gate=True, exit_entropy_weight=0.05,
+    attention_q_block=16, loss_chunk=64, mlp_chunk=32,
+)
+GLM_4_7_FLASH_TINY = dict(  # a dense leading layer and two sparse ones, heads of 12 + 4, the second head's module
+    hidden_size=64, n_layers=3, layer_types=(LATENT,) * 3, zero_centred_norms=False,
+    n_heads=4, n_kv_heads=4, q_lora_rank=24, kv_lora_rank=16, qk_nope_dim=12, qk_rope_dim=4, v_head_dim=16,
+    rope_theta=1e4, dense_layers=1, dense_width=96, rms_eps=1e-5,
+    n_experts=8, experts_held=2, first_expert=0, top_k=2, expert_width=32, shared_width=32, shared_gate=False,
+    routed_scale=1.8, expert_bias=True, bias_update_rate=1e-3, aux_loss_weight=0.0,
+    next_token_modules=1, next_token_loss_weight=0.3, embed_std=1.0,
     attention_q_block=16, loss_chunk=64, mlp_chunk=32,
 )

@@ -9,7 +9,8 @@ sigmoid scores chosen by score plus bias and weighted by the scores alone),
 the held experts ``[first, first + H)`` through a grouped matrix product
 (:func:`grouped_product`) over the assignments that chose them, and the shared
 expert once where the layer has one (its parameters say: a tree without a
-``shared`` leaf has none). What absent experts would have added is left out.
+``shared`` leaf has none, and one without ``shared_gate`` adds it ungated).
+What absent experts would have added is left out.
 
 No assignment is ever dropped and every shape is static. The ``N k``
 assignments are sorted so that the held ones come first, grouped by expert;
@@ -225,25 +226,32 @@ def _routed_bwd(rows, compute_dtype, saved, cotangent):
 _routed_experts.defvjp(_routed_fwd, _routed_bwd)
 
 
-def route(x, router, *, top_k: int, bias=None):
+def route(x, router, *, top_k: int, bias=None, scale: float = 1.0):
     """``(weights, experts, scores)`` over all experts in float32. Without a
     ``bias``: the softmax, its ``top_k`` largest renormalised to sum 1, and
     their ids. With one (``(n_experts,)``, the layer's state: no gradient
     reaches it): sigmoid scores ``s``; the experts are the ``top_k`` largest
     of ``s + bias`` and their weights ``s / (sum of the chosen s + 1e-6)``,
     the bias in the choice and in no weight (auxiliary-loss-free balancing,
-    arXiv:2408.15664)."""
+    arXiv:2408.15664). The ``1e-6`` keeps a token whose chosen scores all
+    underflow at weight 0 and not 0/0; against scores in (0, 1) it moves a
+    weight by a millionth (the DeepSeek family's code has ``1e-20`` there).
+    ``scale``: what the renormalised weights are multiplied by, either
+    router's (a family's ``routed_scaling_factor``; 1: as they are), so a
+    token's weights sum to ``scale``."""
     logits = jnp.matmul(
         x.astype(jnp.float32), router.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST
     )
     if bias is None:
-        probs = jax.nn.softmax(logits, axis=-1)
-        top_w, top_e = jax.lax.top_k(probs, top_k)
-        return top_w / jnp.sum(top_w, axis=-1, keepdims=True), top_e, probs
-    scores = jax.nn.sigmoid(logits)
-    _, top_e = jax.lax.top_k(scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
-    top_w = jnp.take_along_axis(scores, top_e, axis=-1)
-    return top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-6), top_e, scores
+        scores = jax.nn.softmax(logits, axis=-1)
+        top_w, top_e = jax.lax.top_k(scores, top_k)
+        top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+    else:
+        scores = jax.nn.sigmoid(logits)
+        _, top_e = jax.lax.top_k(scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
+        top_w = jnp.take_along_axis(scores, top_e, axis=-1)
+        top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-6)
+    return (top_w if scale == 1.0 else scale * top_w), top_e, scores
 
 
 def balanced_bias(bias, counts, rate: float, axis_name=None):
@@ -274,21 +282,23 @@ def load_balance_loss(probs, experts):
 
 
 def expert_share_moe(params, x, *, top_k: int, first_expert: int, compute_dtype,
-                     round_rows=None, bias=None):
+                     round_rows=None, bias=None, scale: float = 1.0):
     """This chip's part of the layer for tokens ``x`` of ``(N, E)``. Returns
     ``(y, aux_loss, counters, router_counts)``: ``router_counts`` is how many
     of the tokens chose each of all ``n_experts`` (float32; what
     :func:`balanced_bias` balances). ``bias``: the layer's selection bias
     where it has one (:func:`route`; such a layer has no load-balancing loss,
-    ``aux_loss`` is 0). ``params``: ``router (E, n_experts)``,
+    ``aux_loss`` is 0). ``scale``: :func:`route`'s, on the routed weights
+    alone. ``params``: ``router (E, n_experts)``,
     ``experts.gate_up (H, E, 2F)`` and ``experts.down (H, F, E)`` for the
-    held experts ``first_expert .. first_expert + H - 1`` and, where the layer
-    has a shared expert, ``shared.gate_up``, ``shared.down`` and
-    ``shared_gate (E, 1)``."""
+    held experts ``first_expert .. first_expert + H - 1``; where the layer
+    has a shared expert, ``shared.gate_up`` and ``shared.down``; and, where
+    that expert stands behind a sigmoid gate, ``shared_gate (E, 1)`` (the
+    tree says: without the leaf the shared expert is added as it is)."""
     n, n_experts = x.shape[0], params["router"].shape[-1]
     held = params["experts"]["gate_up"].shape[0]
     with _prof.scope("router"):
-        top_w, top_e, probs = route(x, params["router"], top_k=top_k, bias=bias)
+        top_w, top_e, probs = route(x, params["router"], top_k=top_k, bias=bias, scale=scale)
         aux = load_balance_loss(probs, top_e) if bias is None else jnp.zeros((), jnp.float32)
         router_counts = chosen_counts(top_e, n_experts)
     with _prof.scope("dispatch"):
@@ -310,9 +320,11 @@ def expert_share_moe(params, x, *, top_k: int, first_expert: int, compute_dtype,
     y = routed
     if "shared" in params:
         with _prof.scope("shared_expert"):
-            gate = jax.nn.sigmoid(matmul(x, params["shared_gate"], compute_dtype, jnp.float32))
+            gated = "shared_gate" in params  # the tree says whether the shared expert stands behind a gate
+            if gated:
+                gate = jax.nn.sigmoid(matmul(x, params["shared_gate"], compute_dtype, jnp.float32))
             shared = swiglu(x, params["shared"]["gate_up"], params["shared"]["down"], compute_dtype)
-            y = routed + gate * shared.astype(jnp.float32)
+            y = routed + (gate * shared.astype(jnp.float32) if gated else shared.astype(jnp.float32))
     total = jnp.sum(counts)
     counters = {
         "moe_expert_tokens_max": jnp.max(counts),

@@ -4,7 +4,8 @@ table, a causal depthwise 1-D convolution, plain and between two gates, SwiGLU,
 causal attention over
 grouped key/value heads, full or within a sliding window, that never holds a
 ``T x T`` score block, and a head-plus-cross-entropy that never holds
-``(B, T, V)`` logits, over one final state or over the exits of a looped stack.
+``(B, T, V)`` logits, over one final state (beside it, a second head's states
+for the token after next) or over the exits of a looped stack.
 
 Attention has one mask with one parameter (a query sees itself and the keys
 before it, all of them or the ``window - 1`` nearest) and two lowerings, and
@@ -325,7 +326,13 @@ def fused_attention_blocks(t: int, head_dim: int, window=None, *, hq: int = 1, h
     one kernel goes a group of whole key/value heads at a time, and where one
     key/value head's query heads are past the bound a call (65,536 tokens at
     32/8 heads) the backward pass is the library's two kernels, 7 products
-    and no partials."""
+    and no partials. The widest call of any cell is latent attention's 20
+    ungrouped heads of 192 + 64 at 16,384 tokens: attention's gradient alone,
+    compiled for a described v5e, has 2,855,433,728 bytes of scratch in the
+    one call the rule gives it (2,438,981,120 in 2 groups of 10 heads,
+    1,516,232,704 in 4 of 5), and the whole step compiles to 3.64 GB of
+    scratch beside its 8.48 GB of state, so the one call stays (PERF.md,
+    PR 44)."""
     if (head_dim % _LANES and head_dim != _LANES // 2) or head_dim > _WIDEST_HEAD or t % 512:
         return None
     block = _fused_block(t)
@@ -495,6 +502,17 @@ def linear_cross_entropy(hidden, head, labels, weights, *, compute_dtype, chunk:
     return total
 
 
+def targets_after_next(labels, weights):
+    """A second head's targets and their weights, ``(B, T)`` each: position
+    ``i`` predicts the label of position ``i + 1`` under that label's weight;
+    a sequence's last position has no such label and weight 0."""
+    last = jnp.arange(labels.shape[-1]) == labels.shape[-1] - 1
+    return jnp.roll(labels, -1, axis=-1), jnp.where(last, 0.0, jnp.roll(weights, -1, axis=-1))
+
+
+NEXT_COUNTERS = ("mtp_loss_sum", "mtp_tokens")  # what a second head adds to the counters: its loss summed over its weighted tokens, and their count
+
+
 @jax.tree_util.register_pytree_node_class
 class DeferredLogits:
     """What a token model's training forward returns in place of ``(B, T, V)``
@@ -502,20 +520,33 @@ class DeferredLogits:
     the loss from in chunks (``nn.CrossEntropyLoss`` binds itself through
     ``_tpuddp_bind_loss``, as it does to the managed path's lazy forward); ``aux_loss``, which enters the gradient and not
     the reported loss; and ``counters``, additive program counters that the
-    step carries out beside its metrics (``training/step.py``)."""
+    step carries out beside its metrics (``training/step.py``).
 
-    def __init__(self, hidden, head, aux_loss=None, counters=None, *, compute_dtype, chunk=2048):
+    ``next_hidden``: the states of a second head over the same matrix, which
+    predicts the token after next (multi-token prediction at depth 1,
+    arXiv:2412.19437 section 2.2). Position ``i``'s target is the label of
+    position ``i + 1`` under that label's weight, the last position of a
+    sequence has none (weight 0), and the head's loss is a mean over its own
+    weighted tokens; ``next_weight`` times it enters the gradient and not the
+    reported loss, as ``aux_loss`` does, and :data:`NEXT_COUNTERS` carry it
+    out, filled when the loss is taken (which is before the step reads
+    ``counters``)."""
+
+    def __init__(self, hidden, head, aux_loss=None, counters=None, next_hidden=None, *,
+                 compute_dtype, chunk=2048, next_weight: float = 0.0):
         self.hidden, self.head = hidden, head
         self.aux_loss = aux_loss
         self.counters = counters or {}
+        self.next_hidden, self.next_weight = next_hidden, float(next_weight)
         self.compute_dtype, self.chunk = jnp.dtype(compute_dtype), chunk
 
     def tree_flatten(self):
-        return (self.hidden, self.head, self.aux_loss, self.counters), (self.compute_dtype, self.chunk)
+        children = (self.hidden, self.head, self.aux_loss, self.counters, self.next_hidden)
+        return children, (self.compute_dtype, self.chunk, self.next_weight)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        return cls(*children, compute_dtype=aux[0], chunk=aux[1])
+        return cls(*children, compute_dtype=aux[0], chunk=aux[1], next_weight=aux[2])
 
     def logits(self):
         """The logits whole, in float32: for evaluation and small sizes."""
@@ -525,26 +556,41 @@ class DeferredLogits:
     def _tpuddp_bind_loss(self, criterion, labels, weights=None):
         return self.cross_entropy(labels, weights, criterion.reduction)
 
-    def cross_entropy(self, labels, weights: Optional[jax.Array], reduction: str = "mean"):
-        labels = labels.reshape(-1)
-        if weights is None:
-            weights = jnp.ones(labels.shape, jnp.float32)
-        else:
-            weights = per_token_weights(weights, self.hidden.shape[:-1]).reshape(-1)
+    def _reduced(self, hidden, labels, weights, reduction: str):
+        """``(loss, its sum, its weights' sum)`` of one head's states."""
         total = linear_cross_entropy(
-            self.hidden.reshape(-1, self.hidden.shape[-1]), self.head, labels, weights,
+            hidden.reshape(-1, hidden.shape[-1]), self.head, labels, weights,
             compute_dtype=self.compute_dtype, chunk=self.chunk,
         )
+        denom = jnp.sum(weights)
         if reduction == "sum":
-            loss = total
-        elif reduction == "mean":
-            denom = jnp.sum(weights)
-            loss = total / jnp.where(denom == 0, 1.0, denom)
+            return total, total, denom
+        if reduction == "mean":
+            return total / jnp.where(denom == 0, 1.0, denom), total, denom
+        raise ValueError(f"deferred logits reduce to 'mean' or 'sum', not {reduction!r}")
+
+    def cross_entropy(self, labels, weights: Optional[jax.Array], reduction: str = "mean"):
+        tokens = self.hidden.shape[:-1]
+        if weights is None:
+            weights = jnp.ones(tokens, jnp.float32)
         else:
-            raise ValueError(f"deferred logits reduce to 'mean' or 'sum', not {reduction!r}")
-        if self.aux_loss is not None:
+            weights = per_token_weights(weights, tokens)
+        labels = labels.reshape(tokens)
+        loss = self._reduced(self.hidden, labels.reshape(-1), weights.reshape(-1), reduction)[0]
+        extra = self.aux_loss
+        if self.next_hidden is not None:
+            with _prof.scope("mtp"):
+                after, their_weights = targets_after_next(labels, weights)
+                next_loss, total, count = self._reduced(
+                    self.next_hidden, after.reshape(-1), their_weights.reshape(-1), reduction
+                )
+            self.counters = {
+                **self.counters, **dict(zip(NEXT_COUNTERS, jax.lax.stop_gradient((total, count)))),
+            }
+            extra = self.next_weight * next_loss + (0.0 if extra is None else extra)
+        if extra is not None:
             # value of the cross-entropy alone, gradient of the sum
-            loss = loss + (self.aux_loss - jax.lax.stop_gradient(self.aux_loss))
+            loss = loss + (extra - jax.lax.stop_gradient(extra))
         return loss
 
 
