@@ -606,13 +606,12 @@ def test_the_token_cells_step_compiles_for_a_v5e(v5e, monkeypatch, workload, seq
     assert ("deltanet_conv_fwd" in text and "deltanet_conv_bwd" in text) == deltanet_layers  # the short convolution's
     # the grouped products: Pallas where an expert's rows are many (nn/moe.py: grouped_tiles)
     many_rows = cell.traffic["batch_per_chip"] * t * model.top_k // model.n_experts >= 1024
-    if "LatentAttention" in model.layer_types:  # 1,024 rows an expert, but a round of 13,184 rows is no whole tiles
+    if "LatentAttention" in model.layer_types:  # 1,024 rows an expert in a round of 52 whole row tiles
         tokens = cell.traffic["batch_per_chip"] * t
         rows = moe_lib._round_rows(tokens, model.top_k, model.experts_held, model.n_experts)
-        assert rows % 256 and moe_lib.grouped_tiles(
+        assert rows == 52 * 256 and many_rows and moe_lib.grouped_tiles(
             "tpu", rows, model.experts_held, model.hidden_size, 2 * model.expert_width, per_replica=True
-        ) is None
-        many_rows = False
+        ) == (256, 2048, 512)
     # forward kernel, weight-gradient kernel; by the instruction's name (a kernel's serialised body is
     # base64, in which three letters turn up by chance)
     named = lambda kernel: re.search(rf"%{kernel}(\.\d+)? = ", text) is not None

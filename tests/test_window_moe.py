@@ -408,23 +408,54 @@ def test_a_quarter_of_the_experts_held_goes_through_one_round_when_routing_is_ev
     ("cpu", 52480, 16, 2304, 1792, True, None),
     ("tpu", 52352, 16, 2304, 1792, True, None),  # no whole row tiles
     ("tpu", 52480, 16, 2304, 1800, True, None),  # no whole lane registers
+    # the latent-attention cell, 1,664 rows a group: what is contracted in one tile, 512 columns
+    ("tpu", 13312, 8, 2048, 3072, True, (256, 2048, 512)),  # its gate and up
+    ("tpu", 13312, 8, 1536, 2048, True, (256, 1536, 512)),  # and its down
+    ("tpu", 13184, 8, 2048, 3072, True, None),  # that cell's round as a multiple of 128: 51.5 row tiles
+    ("tpu", 16384, 8, 2048, 3072, True, (256, 512, 768)),  # 2,048 rows a group: the widest divisors again
+    ("tpu", 13312, 8, 2304, 3072, True, (256, 768, 768)),  # short groups, but more contracted than was swept
+    ("tpu", 13312, 8, 2048, 1792, True, (256, 512, 896)),  # and columns that 512 does not divide
 ])
 def test_the_grouped_products_lowering_rule(backend, rows, groups, k, n, per_replica, want):
     assert moe_lib.grouped_tiles(backend, rows, groups, k, n, per_replica=per_replica) == want
 
 
-@pytest.fixture(scope="module")
-def grouped():
+@pytest.mark.parametrize("n_tokens,top_k,held,n_experts,want", [
+    (16384, 4, 8, 64, 13312),  # the latent-attention cell: 52 row tiles (13,184, 51.5 of them, as a multiple of 128)
+    (16384, 8, 16, 64, 52480),  # the window-and-full cell: 205 tiles, as it was
+    (32768, 4, 8, 32, 52480),  # the convolution-and-attention cell, as it was
+    (16384, 10, 32, 512, 16384),  # the DeltaNet hybrid's cell: 64 tiles, as it was
+    (4096, 2, 2, 8, 3328),  # 1.6 times 2,048 is 3,276.8: up to 13 tiles
+    (80, 2, 2, 8, 160),  # tiny: one tile would be more than every assignment
+    (256, 2, 1, 64, 256),  # eight expected rows: one whole tile at the least
+])
+def test_a_round_is_whole_row_tiles_or_every_assignment(n_tokens, top_k, held, n_experts, want):
+    rows = moe_lib._round_rows(n_tokens, top_k, held, n_experts)
+    assert rows == want and (rows % moe_lib._ROW_TILE == 0 or rows == n_tokens * top_k)
+    assert rows >= min(n_tokens * top_k, 1.6 * n_tokens * top_k * held / n_experts - 1)
+
+
+@pytest.fixture(scope="module", params=[
+    # rows, contracted, columns, the groups' sizes, the kernel's tiles
+    (512, 256, 384, (150, 0, 200, 70), (128, 128, 128)),  # tiles that several groups share
+    # the latent-attention cell's kind of tiles (all that is contracted in one, 512 columns), at what its round looks
+    # like under an imbalance of 3.5: a group shorter than one row tile, an empty group beside one of several tiles
+    (1280, 512, 1024, (180, 0, 900, 60), (256, 512, 512)),
+    (1280, 768, 512, (60, 900, 0, 180), (256, 768, 512)),
+], ids=["128x128x128", "256x512x512", "256x768x512"])
+def grouped(request):
     """Both lowerings' value and gradients at one shape: an empty group among
-    the groups, rows past the last group, tiles that several groups share."""
+    the groups and rows past the last group."""
+    rows, k, n, sizes, tiles = request.param
     ks = jax.random.split(jax.random.key(5), 3)
-    x = jax.random.normal(ks[0], (512, 256), jnp.float32).astype(jnp.bfloat16)
-    w = (0.1 * jax.random.normal(ks[1], (4, 256, 384), jnp.float32)).astype(jnp.bfloat16)
-    probe = jax.random.normal(ks[2], (512, 384), jnp.float32).astype(jnp.bfloat16).astype(jnp.float32)
-    sizes = jnp.asarray([150, 0, 200, 70], jnp.int32)
-    live = (jnp.arange(512) < 420)[:, None]
+    x = jax.random.normal(ks[0], (rows, k), jnp.float32).astype(jnp.bfloat16)
+    w = (0.1 * jax.random.normal(ks[1], (len(sizes), k, n), jnp.float32)).astype(jnp.bfloat16)
+    probe = jax.random.normal(ks[2], (rows, n), jnp.float32).astype(jnp.bfloat16).astype(jnp.float32)
+    live = (jnp.arange(rows) < sum(sizes))[:, None]
+    empty = sizes.index(0)
+    sizes = jnp.asarray(sizes, jnp.int32)
     plain = lambda x, w: jax.lax.ragged_dot(x, w, sizes, preferred_element_type=jnp.float32)
-    pallas = lambda x, w: moe_lib._pallas_grouped_product(x, w, sizes, (128, 128, 128), True)
+    pallas = lambda x, w: moe_lib._pallas_grouped_product(x, w, sizes, tiles, True)
 
     def of(product):
         def masked(x, w):
@@ -433,7 +464,7 @@ def grouped():
         (_, y), (dx, dw) = jax.value_and_grad(masked, argnums=(0, 1), has_aux=True)(x, w)
         return {"y": y, "dx": jnp.where(live, dx, 0), "dw": dw}
 
-    return of(plain), of(pallas)
+    return of(plain), of(pallas), empty
 
 
 @pytest.mark.parametrize("what", ["y", "dx", "dw"])
@@ -441,13 +472,13 @@ def test_the_grouped_products_lowerings_agree(grouped, what):
     """The Pallas kernel in the interpreter against ``ragged_dot``: the
     product, and the gradients that its own backward makes from the cotangent
     (which here holds bfloat16 values, so rounding it changes nothing)."""
-    plain, pallas = grouped
+    plain, pallas, empty = grouped
     assert pallas[what].dtype == plain[what].dtype
     np.testing.assert_allclose(
         np.asarray(pallas[what], np.float32), np.asarray(plain[what], np.float32), rtol=1e-2, atol=1e-2
     )
     if what == "dw":
-        assert not np.any(np.asarray(pallas[what][1], np.float32))  # the empty group's matrix gets no gradient
+        assert not np.any(np.asarray(pallas[what][empty], np.float32))  # the empty group's matrix gets no gradient
 
 
 # -- the model -------------------------------------------------------------------
