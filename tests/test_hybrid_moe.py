@@ -606,12 +606,19 @@ def test_the_token_cells_step_compiles_for_a_v5e(v5e, monkeypatch, workload, seq
     assert ("deltanet_conv_fwd" in text and "deltanet_conv_bwd" in text) == deltanet_layers  # the short convolution's
     # the grouped products: Pallas where an expert's rows are many (nn/moe.py: grouped_tiles)
     many_rows = cell.traffic["batch_per_chip"] * t * model.top_k // model.n_experts >= 1024
-    if "LatentAttention" in model.layer_types:  # 1,024 rows an expert in a round of 52 whole row tiles
-        tokens = cell.traffic["batch_per_chip"] * t
-        rows = moe_lib._round_rows(tokens, model.top_k, model.experts_held, model.n_experts)
-        assert rows == 52 * 256 and many_rows and moe_lib.grouped_tiles(
+    if many_rows:  # the cell's round, and the tiles the rule answers its gate-and-up call: forward, rows', matrices'
+        rows = moe_lib._round_rows(
+            cell.traffic["batch_per_chip"] * t, model.top_k, model.experts_held, model.n_experts
+        )
+        assert (workload, rows, moe_lib.grouped_tiles(
             "tpu", rows, model.experts_held, model.hidden_size, 2 * model.expert_width, per_replica=True
-        ) == (256, 2048, 512)
+        )) in (
+            # 1,664 rows a group, 52 whole row tiles: one triple, swapped for the rows' gradient (PR 45)
+            ("glm47flash_ep8_t16k_fused", 13312, ((256, 2048, 512), (256, 512, 2048), (256, 2048, 512))),
+            # long groups: all that is contracted in one tile, a triple a kernel (PR 47)
+            ("mellum2_ep4_t16k_fused", 52480, ((256, 2304, 896), (256, 1792, 1152), (256, 1152, 896))),
+            ("lfm2_ep4_t32k_fused", 52480, ((256, 2048, 896), (256, 3584, 512), (256, 2048, 512))),
+        )
     # forward kernel, weight-gradient kernel; by the instruction's name (a kernel's serialised body is
     # base64, in which three letters turn up by chance)
     named = lambda kernel: re.search(rf"%{kernel}(\.\d+)? = ", text) is not None

@@ -5,8 +5,10 @@ YaRN table, nn/moe.py without a shared expert) against its plain reference
 seeded random weights, float32 unless a test says otherwise. Whole training
 steps are in tests/test_window_moe_training.py."""
 
+import contextlib
 import math
 import re
+import signal
 
 import jax
 import jax.numpy as jnp
@@ -400,24 +402,74 @@ def test_a_quarter_of_the_experts_held_goes_through_one_round_when_routing_is_ev
     assert -(-16384 * 8 // rows) == 3
 
 
+def _same(tiles):
+    """One triple for the three kernels, as the short groups' rounds keep it:
+    the forward's, swapped for the rows' gradient, the forward's again."""
+    tm, tk, tn = tiles
+    return tiles, (tm, tn, tk), tiles
+
+
 @pytest.mark.parametrize("backend,rows,groups,k,n,per_replica,want", [
-    ("tpu", 52480, 16, 2304, 1792, True, (256, 768, 896)),  # the window-and-full cell's gate and up
-    ("tpu", 52480, 16, 896, 2304, True, (256, 896, 768)),  # and its down
+    # the window-and-full cell, 3,280 rows a group: forward, rows' gradient, matrices' gradient, each its own
+    ("tpu", 52480, 16, 2304, 1792, True, ((256, 2304, 896), (256, 1792, 1152), (256, 1152, 896))),  # its gate and up
+    ("tpu", 52480, 16, 896, 2304, True, ((256, 896, 1152), (256, 2304, 896), (256, 896, 1152))),  # and its down
     ("tpu", 16384, 32, 2048, 1024, True, None),  # the DeltaNet hybrid's cell: 512 rows an expert, out of the kernel's scope (ROADMAP S16)
     ("tpu", 52480, 16, 2304, 1792, False, None),  # mode="auto": GSPMD cannot partition the call
     ("cpu", 52480, 16, 2304, 1792, True, None),
     ("tpu", 52352, 16, 2304, 1792, True, None),  # no whole row tiles
     ("tpu", 52480, 16, 2304, 1800, True, None),  # no whole lane registers
-    # the latent-attention cell, 1,664 rows a group: what is contracted in one tile, 512 columns
-    ("tpu", 13312, 8, 2048, 3072, True, (256, 2048, 512)),  # its gate and up
-    ("tpu", 13312, 8, 1536, 2048, True, (256, 1536, 512)),  # and its down
+    # the latent-attention cell, 1,664 rows a group: what is contracted in one tile, 512 columns, one triple for all
+    ("tpu", 13312, 8, 2048, 3072, True, _same((256, 2048, 512))),  # its gate and up
+    ("tpu", 13312, 8, 1536, 2048, True, _same((256, 1536, 512))),  # and its down
     ("tpu", 13184, 8, 2048, 3072, True, None),  # that cell's round as a multiple of 128: 51.5 row tiles
-    ("tpu", 16384, 8, 2048, 3072, True, (256, 512, 768)),  # 2,048 rows a group: the widest divisors again
-    ("tpu", 13312, 8, 2304, 3072, True, (256, 768, 768)),  # short groups, but more contracted than was swept
-    ("tpu", 13312, 8, 2048, 1792, True, (256, 512, 896)),  # and columns that 512 does not divide
+    # 2,048 rows a group: the long groups' rule, 13 MiB of blocks to the byte
+    ("tpu", 16384, 8, 2048, 3072, True, ((256, 2048, 1024), (256, 3072, 512), (256, 1024, 1024))),
+    # short groups, but more contracted than was swept: a 2,304 x 512 result block would pass 4 MiB
+    ("tpu", 13312, 8, 2304, 3072, True, ((256, 2304, 768), (256, 3072, 384), (256, 1152, 768))),
+    ("tpu", 13312, 8, 2048, 1792, True, ((256, 2048, 896), (256, 1792, 1024), (256, 1024, 896))),  # and columns that 512 does not divide
+    # the convolution-and-attention cell, 6,560 rows a group
+    ("tpu", 52480, 8, 2048, 3584, True, ((256, 2048, 896), (256, 3584, 512), (256, 2048, 512))),  # its gate and up
+    ("tpu", 52480, 8, 1792, 2048, True, ((256, 1792, 1024), (256, 2048, 896), (256, 896, 1024))),  # and its down
+    ("tpu", 16128, 8, 2048, 3072, True, _same((256, 2048, 512))),  # 2,016 rows a group (63 tiles in 8): still the short groups' tiles
+    ("tpu", 8192, 8, 2048, 3072, True, _same((256, 2048, 512))),  # 1,024 rows a group: the first the kernel serves
+    ("tpu", 7936, 8, 2048, 3072, True, None),  # 992 rows a group: the compiler's
+    ("tpu", 52224, 8, 2048, 3584, True, ((256, 2048, 896), (256, 3584, 512), (256, 2048, 512))),  # 204 whole row tiles
+    ("tpu", 52352, 8, 2048, 3584, True, None),  # 204.5 of them
+    # a matrix whose whole contraction fits no block: the widest divisors up to 896 for gmm, tgmm's largest block
+    ("tpu", 65536, 8, 16384, 2048, True, ((256, 512, 512), (256, 2048, 1024), (256, 1024, 1024))),
 ])
 def test_the_grouped_products_lowering_rule(backend, rows, groups, k, n, per_replica, want):
     assert moe_lib.grouped_tiles(backend, rows, groups, k, n, per_replica=per_replica) == want
+
+
+@pytest.mark.parametrize("k,n,itemsize", [
+    (2048, 3584, 2), (1792, 2048, 2), (2304, 1792, 2), (896, 2304, 2), (2048, 3072, 2), (2048, 3584, 4), (896, 2304, 4),
+    (4096, 1024, 2), (128, 128, 2), (16384, 2048, 2),
+])
+def test_the_long_groups_tiles_fit_the_chips_fast_memory(k, n, itemsize):
+    """What the rule answers for a round of long groups, from the blocks the
+    kernels keep in VMEM: ``gmm``'s rows and matrix double-buffered, its
+    float32 result double-buffered beside the accumulator, within 13 MiB with
+    all that is contracted in one tile (or, where that fits with no columns,
+    the widest divisors up to 896); ``tgmm``'s float32 result block within
+    4 MiB and no other whole-register block larger."""
+    forward, rows_gradient, matrices_gradient = moe_lib.grouped_tiles(
+        "tpu", 4096 * 8, 8, k, n, per_replica=True, itemsize=itemsize
+    )
+    for (tm, tk, tn), (contracted, columns) in ((forward, (k, n)), (rows_gradient, (n, k))):
+        assert tm == moe_lib._ROW_TILE and contracted % tk == 0 and columns % tn == 0 and tk % 128 == 0 and tn % 128 == 0
+        blocks = lambda tn: 2 * itemsize * (tm * tk + tk * tn) + 3 * 4 * tm * tn
+        if tk == contracted:
+            assert blocks(tn) <= 13 * 2**20
+            wider = [t for t in range(tn + 128, columns + 1, 128) if columns % t == 0]
+            assert all(blocks(t) > 13 * 2**20 for t in wider)  # the widest that fits
+        else:
+            assert 2 * itemsize * (tm * contracted + contracted * 128) + 3 * 4 * tm * 128 > 13 * 2**20
+            assert tk <= 896 and tn <= 896
+    tm, tk, tn = matrices_gradient
+    assert tm == moe_lib._ROW_TILE and k % tk == 0 and n % tn == 0 and 4 * tk * tn <= 4 * 2**20
+    whole = lambda d: [t for t in range(128, d + 1, 128) if d % t == 0]
+    assert tk * tn == max(a * b for a in whole(k) for b in whole(n) if 4 * a * b <= 4 * 2**20)
 
 
 @pytest.mark.parametrize("n_tokens,top_k,held,n_experts,want", [
@@ -435,14 +487,42 @@ def test_a_round_is_whole_row_tiles_or_every_assignment(n_tokens, top_k, held, n
     assert rows >= min(n_tokens * top_k, 1.6 * n_tokens * top_k * held / n_experts - 1)
 
 
+@contextlib.contextmanager
+def _time_limit(seconds: int):
+    """Raises in the test after ``seconds``: the interpreter walks a kernel's
+    grid a step at a time, and a case that grew must fail here, not eat the
+    tier's time."""
+    def late(signum, frame):
+        raise TimeoutError(f"over {seconds} s in the Pallas interpreter")
+
+    before = signal.signal(signal.SIGALRM, late)
+    signal.alarm(seconds)
+    try:
+        yield
+    finally:
+        signal.alarm(0)
+        signal.signal(signal.SIGALRM, before)
+
+
 @pytest.fixture(scope="module", params=[
-    # rows, contracted, columns, the groups' sizes, the kernel's tiles
-    (512, 256, 384, (150, 0, 200, 70), (128, 128, 128)),  # tiles that several groups share
+    # rows, contracted, columns, the groups' sizes, the kernels' tiles: forward, rows' gradient, matrices' gradient
+    (512, 256, 384, (150, 0, 200, 70), _same((128, 128, 128))),  # tiles that several groups share
     # the latent-attention cell's kind of tiles (all that is contracted in one, 512 columns), at what its round looks
     # like under an imbalance of 3.5: a group shorter than one row tile, an empty group beside one of several tiles
-    (1280, 512, 1024, (180, 0, 900, 60), (256, 512, 512)),
-    (1280, 768, 512, (60, 900, 0, 180), (256, 768, 512)),
-], ids=["128x128x128", "256x512x512", "256x768x512"])
+    (1280, 512, 1024, (180, 0, 900, 60), _same((256, 512, 512))),
+    (1280, 768, 512, (60, 900, 0, 180), _same((256, 768, 512))),
+    # the long groups' kind, a triple a kernel, at an eighth and less of the cells' sizes with the tiles' ratios kept:
+    # groups of several row tiles that end inside one, an empty group, two whole tiles of dead rows past the last.
+    # The convolution-and-attention cell's gate and up (2,048 x 3,584 in 256 x 2,048 x 896, 256 x 3,584 x 512,
+    # 256 x 2,048 x 512): all that is contracted in one tile in all three, four, two and four column tiles
+    (1536, 256, 512, (500, 0, 610, 170), ((128, 256, 128), (128, 512, 128), (128, 256, 128))),
+    # the window-and-full cell's gate and up (2,304 x 1,792 in 256 x 2,304 x 896, 256 x 1,792 x 1,152,
+    # 256 x 1,152 x 896): two column tiles each way, the matrices' gradient in half of what is contracted
+    (1536, 768, 512, (610, 170, 0, 500), ((128, 768, 256), (128, 512, 384), (128, 384, 256))),
+    # its down (896 x 2,304 in 256 x 896 x 1,152, 256 x 2,304 x 896, 256 x 896 x 1,152): the rows' gradient's
+    # columns in one tile, so one fetch of a group's whole matrix serves its rows
+    (1536, 384, 768, (170, 610, 500, 0), ((128, 384, 384), (128, 768, 384), (128, 384, 384))),
+], ids=["128x128x128", "256x512x512", "256x768x512", "long-2048x3584", "long-2304x1792", "long-896x2304"])
 def grouped(request):
     """Both lowerings' value and gradients at one shape: an empty group among
     the groups and rows past the last group."""
@@ -464,7 +544,8 @@ def grouped(request):
         (_, y), (dx, dw) = jax.value_and_grad(masked, argnums=(0, 1), has_aux=True)(x, w)
         return {"y": y, "dx": jnp.where(live, dx, 0), "dw": dw}
 
-    return of(plain), of(pallas), empty
+    with _time_limit(60):
+        return of(plain), of(pallas), empty
 
 
 @pytest.mark.parametrize("what", ["y", "dx", "dw"])

@@ -22,9 +22,13 @@ only held experts. The round count is a run-time value, so the loop is a
 
 The grouped product has one contract and two lowerings, picked from what a
 call can see (:func:`grouped_tiles`): on a TPU, for a round of 1,024 rows an
-expert or more, the library's Pallas kernel (``megablox``), whose row tiles a
-round is a whole number of (:func:`_round_rows`); everywhere else the
-compiler's own ``ragged_dot``.
+expert or more, the library's Pallas kernels (``megablox``), whose row tiles a
+round is a whole number of (:func:`_round_rows`), in tiles of their own for
+the product and each of its two gradients; everywhere else the compiler's own
+``ragged_dot``. The tiles keep an expert's matrix in fast memory while its
+rows stream past (all that is contracted in one tile): on a v5e a round of
+long groups runs a layer's eight grouped products in 19 ms where the first
+tiles that compiled took 29 (PERF.md, PR 47).
 """
 
 from __future__ import annotations
@@ -59,19 +63,23 @@ def _round_rows(n_tokens: int, top_k: int, held: int, n_experts: int) -> int:
 
 # -- the grouped product ----------------------------------------------------------
 
-_WIDEST_TILE = 896  # of the contracted and of the result's columns: 7 lane registers
-_SHORT_GROUP = 2048  # rows a group under which a round gets the tiles swept at 1,664 rows a group
+_WIDEST_TILE = 896  # of the contracted and of the result's columns where no whole contraction fits: 7 lane registers
+_SHORT_GROUP = 2048  # rows a group under which a round keeps the tiles swept at 1,664 rows a group
+_GMM_BLOCKS = 13 * 2**20  # bytes of gmm's blocks, of the chip's 16 MiB of scoped VMEM: the most that compiled in a whole step
+_TGMM_RESULT = 4 * 2**20  # bytes of tgmm's float32 result block: three of them live in VMEM, and past this it runs out
 
 
-def grouped_tiles(backend: str, rows: int, groups: int, k: int, n: int, *, per_replica: bool):
-    """The Pallas kernel's tile ``(rows, contracted, columns)`` for ``rows``
-    rows in ``groups`` groups against ``(k, n)`` matrices, or ``None`` where
-    the compiler's ``ragged_dot`` serves: off the TPU, under ``mode="auto"``
-    (a custom call, which GSPMD cannot partition: it serves only a call that
-    is traced once a device), for rows that are no whole number of the
-    kernel's row tiles (a round of :func:`_round_rows` is) or matrices that
-    are no whole lane registers, and where a round has under 1,024 rows a
-    group.
+def grouped_tiles(backend: str, rows: int, groups: int, k: int, n: int, *, per_replica: bool, itemsize: int = 2):
+    """The Pallas kernels' tiles ``(rows, contracted, columns)`` for ``rows``
+    rows of ``itemsize`` bytes an element in ``groups`` groups against
+    ``(k, n)`` matrices, one triple a kernel (the forward ``gmm``, the rows'
+    gradient ``gmm(transpose_rhs=True)``, which contracts the forward's
+    columns, and the matrices' gradient ``tgmm``), or ``None`` where the
+    compiler's ``ragged_dot`` serves: off the TPU, under ``mode="auto"`` (a
+    custom call, which GSPMD cannot partition: it serves only a call that is
+    traced once a device), for rows that are no whole number of the kernel's
+    row tiles (a round of :func:`_round_rows` is) or matrices that are no
+    whole lane registers, and where a round has under 1,024 rows a group.
 
     Alone on a v5e, milliseconds forward, rows' gradient, matrices' gradient
     (PERF.md, PR 35). 52,480 rows in 16 groups, 31,500 live, against
@@ -87,31 +95,102 @@ def grouped_tiles(backend: str, rows: int, groups: int, k: int, n: int, *, per_r
     goes in a PR of its own, after the metric's reader knows both names
     (ROADMAP S16).
 
-    13,312 rows in 8 groups, 8,500 live (3,600 down to 50 a group: the
-    latent-attention cell's round), the same three as the layer calls them
-    (PERF.md, PR 45). Against 2,048 x 3,072: ``ragged_dot`` 1.20, 1.63, 2.03;
-    this kernel 1.25, 1.59, 1.88 in the widest divisors, 256 x 512 x 768.
-    Against 1,536 x 2,048: 0.64, 0.85, 0.74 and 0.71, 0.87, 0.86 in
-    256 x 768 x 512. So at short groups the widest divisors do not beat the
-    compiler alone, and the tiles were swept there (the kernels apart,
-    bfloat16 in): all that is contracted in one tile and 512 columns reads
-    0.84, 0.96, 1.55 (1.26, 1.23, 1.74 in the widest divisors) and 0.47,
-    0.55, 0.57 (0.72, 0.64, 0.63), the best or within 2% of it at both shapes;
-    ``tgmm`` runs out of VMEM where its float32 result block passes 4 MB,
-    which a contracted size of 2,048 by 512 columns just is. Inside that
-    cell's step the grouped products are 53.8 ms as ``ragged_dot``, 38.2 in
-    the widest divisors and 27.0 in these. Rounds of under 2,048 rows a group
-    get them; longer groups keep the widest divisors, the first that compiled
-    inside a whole step, until someone sweeps them too (ROADMAP S21): a
-    boundary of what was measured."""
+    **Short groups.** 13,312 rows in 8 groups, 8,500 live (3,600 down to 50 a
+    group: the latent-attention cell's round), the same three as the layer
+    calls them (PERF.md, PR 45). Against 2,048 x 3,072: ``ragged_dot`` 1.20,
+    1.63, 2.03; this kernel 1.25, 1.59, 1.88 in the widest divisors up to
+    896, 256 x 512 x 768. Against 1,536 x 2,048: 0.64, 0.85, 0.74 and 0.71,
+    0.87, 0.86 in 256 x 768 x 512. Swept there (the kernels apart, bfloat16
+    in), all that is contracted in one tile and 512 columns reads 0.84, 0.96,
+    1.55 (1.26, 1.23, 1.74 in the widest divisors) and 0.47, 0.55, 0.57
+    (0.72, 0.64, 0.63); inside that cell's step the grouped products are
+    53.8 ms as ``ragged_dot``, 38.2 in the widest divisors and 27.0 in these.
+    Rounds of under 2,048 rows a group, at most 2,048 contracted and columns
+    that 512 divides keep that one triple, the rows' gradient with it
+    swapped: that cell's program is what was measured, and its rows' gradient
+    (512 of 3,072 contracted a step) is not swept yet (ROADMAP S21).
+
+    **Long groups** (PERF.md, PR 47: the three kernels apart, bfloat16 in,
+    52,480 rows; ms in the widest divisors up to 896, which every such round
+    had until then, and in the best tiles of the sweep). The
+    convolution-and-attention cell's round, 8 groups of 6,370 down to 2,780
+    rows (34,900 live): against 2,048 x 3,584, forward 4.94 in
+    256 x 512 x 896 and **3.03** in 256 x 2,048 x 896; rows' gradient 4.97 in
+    256 x 896 x 512 and **3.02** in 256 x 3,584 x 512; matrices' 4.05 in
+    256 x 512 x 896 and **3.55** in 256 x 2,048 x 512 (3.50 in
+    512 x 1,024 x 896). Against 1,792 x 2,048: 2.82 and **1.56**
+    (256 x 1,792 x 1,024); 2.57 and **1.56** (256 x 2,048 x 896); 2.06 and
+    **1.85** (256 x 896 x 1,024; 1.79 in 512 x 896 x 1,024). The
+    window-and-full cell's, 16 groups of 7,230 down to 160 rows (31,500
+    live): against 2,304 x 1,792, 2.52 and **1.71** (256 x 2,304 x 896);
+    2.69 and **1.73** (256 x 1,792 x 1,152); 2.28 and **2.18**
+    (256 x 1,152 x 896). Against 896 x 2,304: 1.07 and **0.98**
+    (256 x 896 x 1,152; 0.94 in 256 x 896 x 2,304, whose blocks are 15.5 MiB);
+    1.31 and **0.90** (256 x 2,304 x 896); 1.18 and **1.13**
+    (256 x 896 x 1,152). A layer's eight calls: 29.2 to 19.1 ms and 14.6 to
+    11.3. Inside the cells' steps (four sparse layers, a traced pair each)
+    the grouped kernels went 98.2 to 70.7 ms and 54.7 to 42.5, tokens/s/chip
+    +3.1 to +3.7% and +2.3%.
+
+    Why: ``gmm``'s grid is (column tiles, row tiles, tiles of what is
+    contracted), the last innermost, and its matrix block is (the row tile's
+    group, ``k_i``, ``n_i``). With part of ``k`` a tile the block changes
+    every grid step, and an expert's matrix is fetched from HBM again for
+    every 256 rows: 1.18 MB a step for 117 M multiply-adds at
+    256 x 512 x 896, on the chip's ridge. With all of ``k`` in one tile the
+    block is the same for all of a group's row tiles, the pipeline does not
+    fetch it again, and a step moves its rows alone. Half of ``k`` a tile is
+    slower than the whole at every width (3.82 at best against 3.03), and of
+    the widths the widest that fits is the fastest: the rows are read once a
+    column tile. So :func:`_gmm_tiles`: all that is contracted, and the widest
+    columns whose blocks stay within 13 MiB of the 16 MiB a kernel may use
+    (the most that compiled in a whole step; XLA keeps buffers of its own
+    there). ``tgmm`` keeps its float32 result block, three times (two
+    buffers and the accumulator), for all of a group's rows, and reads the
+    rows once a column tile and the cotangent once a tile of ``k``: the
+    largest block wins by 4 to 12%, and past 4 MiB it runs out of VMEM
+    (:func:`_tgmm_tiles`). A 512-row tile is level with 256 rows in ``gmm``
+    (3.12 against 3.12 at 2,048 x 512) and 1 to 3% ahead in ``tgmm``, for
+    which a round would have to be a whole number of 512 rows: not taken.
+    Unswept: row tiles past 512, and what ``tgmm`` would do with a block
+    past 4 MiB under a larger VMEM limit, which the library's call does not
+    take."""
     if backend != "tpu" or not per_replica or rows % _ROW_TILE or k % 128 or n % 128:
         return None
     if rows < 1024 * groups:  # see above: a boundary of scope, not of speed
         return None
-    if rows < _SHORT_GROUP * groups and k <= 2048 and n % 512 == 0:  # see above: where the tiles were swept
-        return (_ROW_TILE, k, 512)  # tgmm's float32 result block, k x 512, within 4 MB
-    widest = lambda d: max(t for t in range(128, _WIDEST_TILE + 1, 128) if d % t == 0)
+    if rows < _SHORT_GROUP * groups and k <= 2048 and n % 512 == 0:  # see above: where short groups were swept
+        tiles = (_ROW_TILE, k, 512)  # tgmm's float32 result block, k x 512, within 4 MB
+        return tiles, (_ROW_TILE, 512, k), tiles  # the rows' gradient contracts the forward's columns: swapped
+    return _gmm_tiles(k, n, itemsize), _gmm_tiles(n, k, itemsize), _tgmm_tiles(k, n)
+
+
+def _lane_divisors(d: int):
+    """The whole lane registers that divide ``d``, widest first."""
+    return [t for t in range(d, 127, -128) if d % t == 0]
+
+
+def _gmm_tiles(k: int, n: int, itemsize: int):
+    """``gmm``'s tiles for ``k`` contracted and ``n`` columns: all that is
+    contracted in one tile, so that a group's row tiles share one fetch of its
+    matrix, and the widest columns whose blocks (rows and matrix
+    double-buffered, the float32 result double-buffered beside its
+    accumulator) stay within :data:`_GMM_BLOCKS`; where not even 128 columns
+    do, the widest divisors up to :data:`_WIDEST_TILE` of both."""
+    fits = lambda tn: 2 * itemsize * (_ROW_TILE * k + k * tn) + 3 * 4 * _ROW_TILE * tn <= _GMM_BLOCKS
+    tn = next((t for t in _lane_divisors(n) if fits(t)), None)
+    if tn is not None:
+        return (_ROW_TILE, k, tn)
+    widest = lambda d: next(t for t in _lane_divisors(d) if t <= _WIDEST_TILE)
     return (_ROW_TILE, widest(k), widest(n))
+
+
+def _tgmm_tiles(k: int, n: int):
+    """``tgmm``'s tiles for a ``(k, n)`` result: the largest float32 result
+    block within :data:`_TGMM_RESULT`, of equals the squarest (the kernel
+    reads the rows once a column tile and the cotangent once a tile of ``k``)."""
+    blocks = [(tk, tn) for tk in _lane_divisors(k) for tn in _lane_divisors(n) if 4 * tk * tn <= _TGMM_RESULT]
+    return (_ROW_TILE, *max(blocks, key=lambda block: (block[0] * block[1], min(block))))
 
 
 def _megablox():
@@ -127,7 +206,8 @@ def grouped_product(x, w, sizes):
     lowering writes them (they hold what the buffer held), so the caller
     masks them."""
     tiles = grouped_tiles(
-        jax.default_backend(), x.shape[0], w.shape[0], w.shape[1], w.shape[2], per_replica=traced_per_replica()
+        jax.default_backend(), x.shape[0], w.shape[0], w.shape[1], w.shape[2],
+        per_replica=traced_per_replica(), itemsize=x.dtype.itemsize,
     )
     if tiles is None:
         return jax.lax.ragged_dot(x, w, sizes, preferred_element_type=jnp.float32)
@@ -136,7 +216,9 @@ def grouped_product(x, w, sizes):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _pallas_grouped_product(x, w, sizes, tiles, interpret):
-    return _megablox().gmm(x, w, sizes, jnp.float32, tiles, interpret=interpret)
+    """``tiles``: :func:`grouped_tiles`' three, of which this is the first's
+    kernel and the backward below the other two's."""
+    return _megablox().gmm(x, w, sizes, jnp.float32, tiles[0], interpret=interpret)
 
 
 def _pallas_grouped_fwd(x, w, sizes, tiles, interpret):
@@ -146,13 +228,14 @@ def _pallas_grouped_fwd(x, w, sizes, tiles, interpret):
 def _pallas_grouped_bwd(tiles, interpret, saved, d):
     """Both gradients are grouped products again, of the cotangent rounded to
     the inputs' type like every product's input: ``d w[g]^T`` for the rows
-    and ``x^T d`` over a group's rows for its matrix, each a cotangent in its
+    (which contracts the forward's columns, in tiles of its own) and
+    ``x^T d`` over a group's rows for its matrix, each a cotangent in its
     primal's type as ``ragged_dot``'s own transposes return them."""
     x, w, sizes = saved
-    kernels, (tm, tk, tn) = _megablox(), tiles
+    kernels, (_, rows_tiles, matrix_tiles) = _megablox(), tiles
     d = d.astype(x.dtype)
-    d_x = kernels.gmm(d, w, sizes, jnp.float32, (tm, tn, tk), transpose_rhs=True, interpret=interpret)
-    d_w = kernels.tgmm(x.swapaxes(0, 1), d, sizes, jnp.float32, tiles, interpret=interpret)
+    d_x = kernels.gmm(d, w, sizes, jnp.float32, rows_tiles, transpose_rhs=True, interpret=interpret)
+    d_w = kernels.tgmm(x.swapaxes(0, 1), d, sizes, jnp.float32, matrix_tiles, interpret=interpret)
     return d_x.astype(x.dtype), d_w.astype(w.dtype), None
 
 
