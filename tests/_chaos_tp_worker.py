@@ -19,7 +19,7 @@ Env levers (the supervisor/fleet relaunch contract):
   ``{"comm_hook": "bf16_ef"}``; the default is the f32 ``none`` hook so the
   cross-shape loss-parity legs compare float-reassociation-only drift).
 
-The loader is bench_mesh's matched-global-batch contract: the same seed
+The loader keeps the matched-global-batch contract: the same seed
 yields the SAME global batches on any mesh shape, which is what makes
 "resumed at a different shape, landed the same loss trajectory" a testable
 claim rather than a vibe.
@@ -28,6 +28,8 @@ claim rather than a vibe.
 import json
 import os
 import sys
+
+import numpy as np
 
 out_dir, num_epochs = sys.argv[1], int(sys.argv[2])
 world_size = int(os.environ.get("TPUDDP_WORLD_SIZE") or 4)
@@ -53,6 +55,52 @@ PARALLEL = json.loads(os.environ.get("TPUDDP_CHAOS_PARALLEL") or "null")
 OBSERVABILITY = json.loads(os.environ.get("TPUDDP_CHAOS_OBS") or "null")
 
 
+class TokenLMLoader:
+    """Synthetic next-token LM loader with the epoch-driver loader protocol
+    (len / set_epoch / make_batch_plan / iter): a fixed token corpus sampled
+    per epoch into ``(tokens, shifted targets, weights)`` batches. The same
+    seed yields the same global batches on ANY mesh shape — the matched-
+    global-batch contract the DP-vs-TP parity comparison needs."""
+
+    def __init__(self, vocab: int, seq_len: int, global_batch: int,
+                 n_batches: int, seed: int = 0):
+        self.vocab = vocab
+        self.seq_len = seq_len
+        self.global_batch = global_batch
+        self.n_batches = n_batches
+        self.seed = seed
+        self.epoch = 0
+        self.batch_nbytes = global_batch * seq_len * 4
+
+    def set_epoch(self, epoch: int) -> None:
+        self.epoch = int(epoch)
+
+    def __len__(self) -> int:
+        return self.n_batches
+
+    def make_batch_plan(self):
+        rng = np.random.default_rng((self.seed, self.epoch))
+        # one contiguous token stream per epoch; batches slice it
+        data = rng.integers(
+            0, self.vocab,
+            (self.n_batches, self.global_batch, self.seq_len + 1),
+        ).astype(np.int32)
+
+        def fetch(s: int):
+            chunk = data[s]
+            x = chunk[:, :-1]
+            y = chunk[:, 1:].astype(np.int32)
+            w = np.ones(x.shape, np.float32)
+            return x, y, w
+
+        return self.n_batches, fetch
+
+    def __iter__(self):
+        steps, fetch = self.make_batch_plan()
+        for s in range(steps):
+            yield fetch(s)
+
+
 def tp_training_loop(rank, world, save_dir, optional_args):
     import jax
     import jax.numpy as jnp
@@ -62,9 +110,6 @@ def tp_training_loop(rank, world, save_dir, optional_args):
     from tpuddp.models import load_model
     from tpuddp.parallel.ddp import DistributedDataParallel
     from tpuddp.training.loop import run_training_loop
-
-    sys.path.insert(0, os.path.join(_REPO, "tools"))
-    from bench_mesh import TokenLMLoader
 
     # resolve_parallel honors $TPUDDP_MODEL_SIZE (data falls back to
     # "auto" = world // model) — the exact lever the supervisor/fleet

@@ -46,9 +46,9 @@ TRAINING = {
 TRAINING.update(json.loads(os.environ.get("TPUDDP_CHAOS_TRAINING") or "{}"))
 OBSERVABILITY = json.loads(os.environ.get("TPUDDP_CHAOS_OBS") or "null")
 # 2-D mesh override (e.g. '{"data": 2, "model": 2}') for ad-hoc chaos
-# scenarios on a factored mesh. The full gate's mesh leg drives
-# tools/bench_mesh.py (a token workload — this worker's CNN data cannot
-# feed a tensor-parallel transformer); this env hook exists so future
+# scenarios on a factored mesh. The tensor-parallel legs run
+# tests/_chaos_tp_worker.py (a token workload — this worker's CNN data
+# cannot feed a tensor-parallel transformer); this env hook exists so future
 # chaos legs can pin the mesh shape without a worker per knob.
 PARALLEL = json.loads(os.environ.get("TPUDDP_CHAOS_PARALLEL") or "null")
 

@@ -289,7 +289,7 @@ def test_topk_density_validation(cpu_devices):
 
 
 def test_comm_bytes_counter_class():
-    from tpuddp.utils.observability import CommBytesCounter
+    from tpuddp.observability import CommBytesCounter
 
     c = CommBytesCounter(1000)
     c.add_updates(3)

@@ -10,7 +10,6 @@ use trivial python children (prints/sleeps) — the full jax chaos proof
 lives in tests/test_chaos.py and ``tools/fleet.py chaos-demo``.
 """
 
-import json
 import os
 import signal
 import subprocess
@@ -768,81 +767,3 @@ def test_serving_config_honors_replica_env_override(monkeypatch):
     monkeypatch.setenv("TPUDDP_SERVING_REPLICAS", "3")
     cfg = config_lib.serving_config({"serving": {"num_replicas": 1}})
     assert cfg["num_replicas"] == 3
-
-
-# -------------------------------------------------------------- bench_trend --
-def test_bench_trend_empty_trajectory_exits_zero(tmp_path, capsys):
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    try:
-        import bench_trend
-    finally:
-        sys.path.pop(0)
-    rc = bench_trend.main(["--repo", str(tmp_path)])
-    out = capsys.readouterr().out
-    assert rc == 0
-    assert "nothing to compare" in out
-
-
-def test_bench_trend_fresh_without_rows_exits_zero(tmp_path, capsys):
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    try:
-        import bench_trend
-    finally:
-        sys.path.pop(0)
-    committed = {
-        "metric": "samples_per_sec_per_chip", "device": "cpu",
-        "configs": {"toy": {"samples_per_sec_per_chip": 100.0}},
-    }
-    (tmp_path / "BENCH_r01.json").write_text(json.dumps(committed))
-    empty = tmp_path / "bench_results.json"
-    empty.write_text(json.dumps({"metric": "x", "device": "cpu",
-                                 "configs": {}}))
-    rc = bench_trend.main(["--repo", str(tmp_path), "--fresh", str(empty)])
-    out = capsys.readouterr().out
-    assert rc == 0
-    assert "no candidate to judge" in out
-
-
-def test_bench_trend_tracks_tokens_per_sec_rows(tmp_path, capsys):
-    """Decode-flavored rows (tokens_per_sec, ISSUE 12) ride the trajectory
-    and the regression gate instead of being silently dropped — and a row
-    name shared with a request-rate artifact is judged per metric, never
-    across them."""
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    try:
-        import bench_trend
-    finally:
-        sys.path.pop(0)
-    # a request-granularity serving artifact and two decode artifacts that
-    # REUSE the row name "closed_loop" under the other rate metric
-    (tmp_path / "SERVING_r01.json").write_text(json.dumps({
-        "metric": "rps", "device": "cpu",
-        "configs": {"closed_loop": {"samples_per_sec_per_chip": 5000.0}},
-    }))
-    (tmp_path / "SERVING_r02.json").write_text(json.dumps({
-        "metric": "tps", "device": "cpu",
-        "configs": {"closed_loop": {"tokens_per_sec": 1000.0}},
-    }))
-    rc = bench_trend.main(["--repo", str(tmp_path)])
-    out = capsys.readouterr().out
-    assert rc == 0
-    assert "1,000t/s" in out  # the decode row is IN the trajectory
-    # a decode regression against the decode best is caught...
-    fresh = tmp_path / "bench_results.json"
-    fresh.write_text(json.dumps({
-        "metric": "tps", "device": "cpu",
-        "configs": {"closed_loop": {"tokens_per_sec": 500.0}},
-    }))
-    rc = bench_trend.main(["--repo", str(tmp_path), "--fresh", str(fresh)])
-    err = capsys.readouterr().err
-    assert rc == 1
-    assert "tokens/s" in err and "closed_loop" in err
-    # ...but 1000 tokens/s is NOT judged against the 5000 samples/s row of
-    # the same name (cross-metric comparison would flag a phantom 80% drop)
-    fresh.write_text(json.dumps({
-        "metric": "tps", "device": "cpu",
-        "configs": {"closed_loop": {"tokens_per_sec": 1000.0}},
-    }))
-    assert bench_trend.main(
-        ["--repo", str(tmp_path), "--fresh", str(fresh)]
-    ) == 0

@@ -17,13 +17,13 @@ from tpuddp import optim
 from tpuddp.data import ShardedDataLoader, SyntheticClassification
 from tpuddp.models import ToyMLP
 from tpuddp.nn import CrossEntropyLoss
+from tpuddp.observability import MetricsWriter
 from tpuddp.parallel.ddp import DistributedDataParallel
 from tpuddp.resilience import faults, integrity, preemption, retry as retry_mod, watchdog
 from tpuddp.resilience.preemption import TrainingPreempted
 from tpuddp.resilience.retry import RetryError, RetryPolicy, retry
 from tpuddp.training import checkpoint as ckpt
 from tpuddp.training.loop import run_training_loop
-from tpuddp.utils.observability import MetricsWriter
 
 
 # ---------------------------------------------------------------- retry

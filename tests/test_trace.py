@@ -8,10 +8,9 @@ OFF writes nothing); serving requests are one span tree each, and a decode
 session that fails over stays ONE trace; the exporter's /metrics, /snapshot
 and /trace endpoints never serve a torn payload under a concurrent writer
 (the MetricsExporter concurrency satellite); and the trace tooling
-(tpuddp_inspect trace, trace_breakdown --merge-host) consumes the artifacts.
+(tpuddp_inspect trace) consumes the artifacts.
 """
 
-import gzip
 import json
 import os
 import subprocess
@@ -799,87 +798,3 @@ def test_inspect_trace_subcommand(tmp_path):
         [sys.executable, inspect, "trace", str(bad), "--validate"],
         capture_output=True,
     ).returncode == 1
-
-
-def _device_capture(path, with_meta_name=True):
-    """A minimal profiler-shaped capture: one TPU process, one 'XLA Ops'
-    thread, two ops — one fully annotated, one BARE (no args at all, the
-    shape that used to KeyError the breakdown)."""
-    events = [
-        {"ph": "M", "name": "process_name", "pid": 1,
-         "args": {"name": "/device:TPU:0"}},
-        {"ph": "M", "name": "thread_name", "pid": 1, "tid": 2,
-         "args": ({"name": "XLA Ops"} if with_meta_name else {})},
-        {"ph": "X", "pid": 1, "tid": 2, "name": "fusion.1", "ts": 1000,
-         "dur": 50,
-         "args": {"tf_op": "dot_general", "source": "model.py"}},
-        {"ph": "X", "pid": 1, "tid": 2, "name": "bare.op", "ts": 1100,
-         "dur": 30},  # no args: the bare-op tolerance case
-        {"ph": "X", "pid": 1, "tid": 2, "name": "no.dur", "ts": 1200},
-    ]
-    with gzip.open(path, "wt") as f:
-        json.dump({"traceEvents": events}, f)
-
-
-def test_trace_breakdown_tolerates_bare_ops_and_merges_all_captures(
-    tmp_path, capsys
-):
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    try:
-        import importlib
-
-        import trace_breakdown
-
-        importlib.reload(trace_breakdown)
-        # TWO capture files: both must contribute (the old code silently
-        # analyzed only the last glob hit)
-        _device_capture(str(tmp_path / "a.trace.json.gz"))
-        _device_capture(str(tmp_path / "b.trace.json.gz"))
-        ops = trace_breakdown.load_ops(str(tmp_path))
-        assert len(ops) == 6  # 3 X events per file, bare ops included
-        trace_breakdown.breakdown(str(tmp_path))
-        out = capsys.readouterr().out
-        assert "device op time" in out
-        # a capture whose thread meta lacks args.name must not crash either
-        _device_capture(
-            str(tmp_path / "c.trace.json.gz"), with_meta_name=False
-        )
-        trace_breakdown.load_ops(str(tmp_path))
-    finally:
-        sys.path.remove(os.path.join(REPO, "tools"))
-
-
-def test_trace_breakdown_merge_host(tmp_path):
-    _device_capture(str(tmp_path / "dev.trace.json.gz"))
-    tracer = Tracer("train", run_dir=str(tmp_path), process_index=0)
-    root = tracer.start_span("epoch 0", trace_mod.KIND_EPOCH)
-    tracer.end_span(
-        tracer.start_span("dispatch", trace_mod.KIND_DISPATCH, parent=root)
-    )
-    tracer.end_span(root)
-    host_art = tracer.export()
-    merged_path = str(tmp_path / "merged.json")
-    out = subprocess.run(
-        [
-            sys.executable, os.path.join(REPO, "tools", "trace_breakdown.py"),
-            str(tmp_path), "--merge-host", host_art, "--out", merged_path,
-        ],
-        capture_output=True, text=True,
-    )
-    assert out.returncode == 0, out.stderr
-    merged = json.load(open(merged_path))
-    cats = {e.get("cat") for e in merged["traceEvents"] if e.get("ph") == "X"}
-    assert "epoch" in cats  # host spans present
-    names = {e.get("name") for e in merged["traceEvents"]}
-    assert "fusion.1" in names  # device ops present
-    # host pids were remapped off the device pid space
-    host_pids = {
-        e["pid"] for e in merged["traceEvents"]
-        if e.get("cat") in ("epoch", "dispatch")
-    }
-    assert all(p >= 1000 for p in host_pids)
-    # earliest-alignment shifted host spans onto the device epoch
-    host_ts = [
-        e["ts"] for e in merged["traceEvents"] if e.get("cat") == "epoch"
-    ]
-    assert min(host_ts) == pytest.approx(1000, abs=1)

@@ -7,8 +7,8 @@ baseline dryrun on the given knobs, then a tuned dryrun launched under the
 advisor's ``$TPUDDP_TUNE_OVERLAY`` — measures both runs from their own
 history artifacts (``tpuddp.observability.advisor.measure_run``), and
 writes every recommendation's predicted-vs-measured delta into a
-schema-v12-validated ``TUNE_rNN.json`` (the BENCH_r*/SERVING_r* artifact
-family). A rule whose measured delta regresses ships ``endorsed: false``
+schema-v12-validated ``TUNE_rNN.json``. A rule whose measured delta
+regresses ships ``endorsed: false``
 — the probe refuses to endorse it, whatever the prediction promised — and
 the fleet tuner (tpuddp/tune/online.py) only ever acts on endorsed rules.
 
@@ -16,7 +16,7 @@ Honesty note: on the CPU rung (forced host-platform devices) the measured
 deltas calibrate the RULES' direction, not TPU magnitudes — wire-byte and
 counter metrics (grad_comm_bytes, snapshot skips) transfer; wall-clock
 ratios largely do not. ``device`` in the artifact records the rung so
-bench_trend never mixes rungs.
+a reader never mixes rungs.
 
 Usage:
     python tools/autotune.py --quick                  # CPU-rung probe

@@ -19,7 +19,7 @@ Artifacts:
 - ``--history-dir`` — the engine's own ``history.jsonl`` (run_meta +
   serving_stats windows + drain event), same validation;
 - stdout            — progress on stderr-like log lines, and the LAST line
-  is one compact JSON summary (bench.py's driver-parseable contract).
+  is one compact JSON summary (the driver-parseable contract).
 
 Runs entirely in-process on the local mesh (CPU-friendly: the gate's serving
 leg drives ~100 requests against 2 replicas over 2 tenants); the same flags
@@ -30,7 +30,7 @@ the curve becomes tokens/sec + time-to-first-token vs offered request rate,
 and ``vs_baseline`` anchors against request-level SEQUENTIAL decode (one
 sequence in flight, no continuous batching — the regime the decode engine
 exists to beat). Rows carry ``tokens_per_sec`` instead of
-``samples_per_sec_per_chip``; ``tools/bench_trend.py`` tracks either.
+``samples_per_sec_per_chip``.
 
 Usage:
     python tools/loadgen.py --quick --history-dir /tmp/serve \\
@@ -785,7 +785,7 @@ def main(argv=None) -> int:
     if args.history_dir:
         log(f"history -> {os.path.join(args.history_dir, 'history.jsonl')}")
 
-    # last stdout line: compact driver-parseable summary (bench.py contract)
+    # last stdout line: compact driver-parseable summary
     print(json.dumps(json_sanitize({
         "metric": payload["metric"],
         "value": payload["value"],

@@ -39,21 +39,12 @@ baseline, final-epoch losses must sit within the documented per-hook parity
 bound of the uncompressed run, and hierarchical rows must report inter-host
 bytes below the flat total.
 
-Mesh gate (after the comm-matrix gate): ``tools/bench_mesh.py --quick``
-trains transformer_small on the 2-D ``("data", "model")`` mesh (TP=2xDP=2)
-AND as pure DP=4 at matched global batch through the real epoch driver,
-asserting loss-trajectory parity and the per-chip parameter-byte cut; the
-gate independently re-validates the TP history (schema v8, the run_meta
-``mesh`` block with a real tp_rules_hash), runs the ``model=1`` HLO
-byte-identity test against the flat DDP path, and feeds the fresh
-MULTICHIP-format payload through ``tools/bench_trend.py --fresh``.
-
-Serving gate (after the mesh gate): ``tools/loadgen.py --quick`` stands the continuous-
-batching engine up on the CPU mesh (2 replicas, 2 tenants, ~170 requests
-across a closed-loop calibration + 3 offered-load points) and both emitted
-artifacts — the engine's ``history.jsonl`` (run_meta + serving_stats +
-events) and the latency-vs-throughput ``bench_results.json`` curve — must
-pass ``tpuddp_inspect --validate``. The serving SLO record stream drifting
+Serving gate (after the comm-matrix gate): ``tools/loadgen.py --quick`` stands
+the continuous-batching engine up on the CPU mesh (2 replicas, 2 tenants,
+~170 requests across a closed-loop calibration + 3 offered-load points) and
+both emitted artifacts — the engine's ``history.jsonl`` (run_meta +
+serving_stats + events) and the latency-vs-throughput ``bench_results.json``
+curve — must pass ``tpuddp_inspect --validate``. The serving SLO record stream drifting
 off schema v2 fails the gate the same way training telemetry drift does.
 
 Decode gate (after the serving gate): ``tools/loadgen.py --decode --quick``
@@ -136,16 +127,14 @@ tpuddp.serving --demo`` with tracing on) must drain to a schema-valid
 ``trace_serving.json`` with request/admission/queue_wait span trees and a
 ``trace_summary`` history row.
 
-Observability gate: tools/bench_trend.py across the committed
-BENCH_r*.json artifacts (a >10% regression of any same-device best row
-fails), a live exporter scrape (a serving engine with the
+Observability gate: a live exporter scrape (a serving engine with the
 observability.exporter block must answer /healthz + the serving /metrics
 families while running, then SIGTERM-drain to exit 75 with a schema-v5
 history), and a flight-recorder leg (a chaos-preempted training run must
 leave a tpuddp_inspect-valid flightrec_preempt.json which the restart
 supervisor summarizes — --flight-dir — before resuming the run to
-completion). A dead endpoint, schema-v5 drift, a missing crash recording,
-or a bench regression all fail here.
+completion). A dead endpoint, schema-v5 drift or a missing crash recording
+fails here.
 
 Autotune gate (last): the self-tuning loop (ISSUE 19). A deliberately
 mis-knobbed traced dryrun (synchronous pipeline, per-step snapshots, no
@@ -1002,105 +991,6 @@ def _pipeline_gate(env) -> int:
     return 0
 
 
-def _mesh_gate(env) -> int:
-    """2-D mesh leg (ISSUE 14): ``tools/bench_mesh.py --quick`` trains
-    transformer_small TP=2xDP=2 AND pure DP=4 at matched global batch
-    through the real epoch driver on the 4-device CPU mesh, asserting
-    loss-trajectory parity and the per-chip parameter-byte cut in-process.
-    This leg re-checks the observable evidence independently: the TP
-    history validates under schema v8 and its run_meta carries the mesh
-    block ({data: 2, model: 2} + a real tp_rules_hash); the ``model=1``
-    configuration lowers to HLO byte-identical with the flat DDP path (the
-    dedicated test lowers both programs and compares text); and
-    ``tools/bench_trend.py --fresh`` ingests the fresh MULTICHIP-format
-    payload without a regression verdict."""
-    import json
-
-    inspect = os.path.join(REPO, "tools", "tpuddp_inspect.py")
-    with tempfile.TemporaryDirectory(prefix="tpuddp_mesh_gate_") as tmp:
-        worker_env = dict(env)
-        worker_env.update({
-            "XLA_FLAGS": "--xla_force_host_platform_device_count=4",
-            "TPUDDP_BACKEND": "cpu",
-            "PYTHONPATH": REPO + os.pathsep + env.get("PYTHONPATH", ""),
-        })
-        bench_json = os.path.join(tmp, "mesh_bench.json")
-        out = subprocess.run(
-            [
-                sys.executable, "-u",
-                os.path.join(REPO, "tools", "bench_mesh.py"),
-                "--quick", "--history-dir", tmp, "--out", bench_json,
-            ],
-            cwd=REPO, env=worker_env, stdout=subprocess.PIPE, text=True,
-        )
-        sys.stdout.write(out.stdout)
-        if out.returncode != 0:
-            print(f"mesh gate: bench_mesh exited {out.returncode}",
-                  file=sys.stderr)
-            return out.returncode or 1
-        summary = json.loads(
-            [l for l in out.stdout.splitlines() if l.strip()][-1]
-        )
-        history = summary["tp_history"]
-        rc = subprocess.call(
-            [sys.executable, inspect, "--validate", history],
-            cwd=REPO, env=env,
-        )
-        if rc != 0:
-            print("mesh gate: TP=2xDP=2 history failed validation",
-                  file=sys.stderr)
-            return rc
-        with open(history) as f:
-            meta = next(
-                json.loads(l) for l in f
-                if l.strip() and json.loads(l).get("type") == "run_meta"
-            )
-        mesh_block = meta.get("mesh")
-        if (
-            not isinstance(mesh_block, dict)
-            or mesh_block.get("data") != 2
-            or mesh_block.get("model") != 2
-            or not mesh_block.get("tp_rules_hash")
-        ):
-            print(f"mesh gate: run_meta mesh block wrong: {mesh_block!r}",
-                  file=sys.stderr)
-            return 1
-        # model=1 HLO byte-identity with the flat DDP path: the dedicated
-        # test lowers both programs and compares text. Plain env —
-        # tests/conftest.py owns its own 8-device XLA_FLAGS.
-        rc = subprocess.call(
-            [
-                sys.executable, "-m", "pytest", "-q",
-                "tests/test_mesh2d.py", "-k", "hlo_identity",
-                "-p", "no:cacheprovider",
-            ],
-            cwd=REPO, env=env,
-        )
-        if rc != 0:
-            print("mesh gate: model=1 HLO identity test failed",
-                  file=sys.stderr)
-            return rc
-        rc = subprocess.call(
-            [
-                sys.executable,
-                os.path.join(REPO, "tools", "bench_trend.py"),
-                "--fresh", bench_json,
-            ],
-            cwd=REPO, env=env,
-        )
-        if rc != 0:
-            print("mesh gate: bench_trend rejected the fresh mesh payload",
-                  file=sys.stderr)
-            return rc
-        print(
-            "mesh gate: TP=2xDP=2 parity "
-            f"(worst |dloss| {summary['parity_worst_abs']:.2e}), per-chip "
-            f"param cut {summary['param_bytes_cut'] * 100:.1f}%, schema-v8 "
-            "mesh block + model=1 HLO identity + trend ingest verified"
-        )
-    return 0
-
-
 def _fleet_gate(env) -> int:
     """Fleet-control-plane leg (ISSUE 11): the scripted multi-job chaos
     demo (2 training + 1 serving + 1 late high-priority arrival on one
@@ -1144,12 +1034,10 @@ def _fleet_gate(env) -> int:
 
 
 def _observability_gate(env) -> int:
-    """Live-telemetry leg (ISSUE 10): (a) tools/bench_trend.py across the
-    committed BENCH_r*.json artifacts — a >10% regression of any best
-    same-device row fails the gate; (b) exporter scrape — a serving engine
+    """Live-telemetry leg (ISSUE 10): (a) exporter scrape — a serving engine
     stood up with the observability.exporter block must answer /healthz and
     serve the expected /metrics families while live, then drain to exit 75
-    with a schema-v5-valid history; (c) flight recorder — a chaos-preempted
+    with a schema-v5-valid history; (b) flight recorder — a chaos-preempted
     training run (exit 75) must leave a flightrec_preempt.json that
     tpuddp_inspect validates, and the restart supervisor must summarize it
     (--flight-dir) before resuming the run to completion."""
@@ -1159,13 +1047,6 @@ def _observability_gate(env) -> int:
     import urllib.request
 
     inspect = os.path.join(REPO, "tools", "tpuddp_inspect.py")
-    rc = subprocess.call(
-        [sys.executable, os.path.join(REPO, "tools", "bench_trend.py")],
-        cwd=REPO, env=env,
-    )
-    if rc != 0:
-        print("observability gate: bench_trend regression", file=sys.stderr)
-        return rc
 
     # -- exporter scrape leg ------------------------------------------------
     with tempfile.TemporaryDirectory(prefix="tpuddp_obs_gate_") as tmp:
@@ -1318,7 +1199,7 @@ def _observability_gate(env) -> int:
             print("observability gate: supervisor never summarized the "
                   "flight recording", file=sys.stderr)
             return 1
-    print("observability gate: bench trend + live scrape + flight "
+    print("observability gate: live scrape + flight "
           "recording verified")
     return 0
 
@@ -1621,9 +1502,6 @@ def main(argv=None):
     if rc != 0:
         return rc
     rc = _comm_matrix_gate(env)
-    if rc != 0:
-        return rc
-    rc = _mesh_gate(env)
     if rc != 0:
         return rc
     rc = _serving_gate(env)

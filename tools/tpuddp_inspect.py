@@ -10,7 +10,7 @@ Works on both machine-readable artifacts the framework writes:
   table with step-time percentiles, serving/decode SLO window tables
   (tokens/sec, TTFT, ITL, KV occupancy for decode), the event timeline,
   and the gradient-comm byte savings a compressed hook achieved;
-- ``bench_results.json`` (the bench harness's full per-config payload);
+- ``bench_results.json`` (``tools/loadgen.py``'s payload, a row a config);
 - ``flightrec_<reason>.json`` (the crash flight recorder's post-mortem
   sidecar, tpuddp/observability/flight.py) — validates the ring contents
   against the same per-record schema and pretty-prints the last windows,
@@ -630,35 +630,6 @@ def summarize_bench(path: str) -> None:
           f"{payload.get('vs_baseline_basis')})")
     configs = payload.get("configs", {})
     if any(
-        isinstance(r, dict) and "comm_topology" in r for r in configs.values()
-    ):
-        # comm-matrix rows (bench.py --comm): hook x topology A/B with the
-        # per-row wire-byte accounting and the loss-parity evidence
-        rows = []
-        for name, r in configs.items():
-            base = r.get("grad_comm_bytes_per_step_f32")
-            per = r.get("grad_comm_bytes_per_step")
-            cut = (
-                f"{(1 - per / base) * 100:.1f}%"
-                if per is not None and base else "-"
-            )
-            rows.append([
-                name,
-                str(r.get("comm_hook", "-")),
-                str(r.get("comm_topology", "-")),
-                _fmt(r.get("samples_per_sec_per_chip"), 0),
-                _fmt(r.get("ms_per_step"), 2),
-                str(per if per is not None else "-"),
-                str(r.get("grad_comm_bytes_inter_host", "-")),
-                cut,
-                _fmt(r.get("final_loss")),
-            ])
-        _print_table(rows, [
-            "config", "hook", "topo", "sps/chip", "ms", "wire B/step",
-            "interB", "cut", "loss",
-        ])
-        return
-    if any(
         isinstance(r, dict) and "tokens_per_sec" in r for r in configs.values()
     ):
         # decode token-curve rows (tools/loadgen.py --decode): tokens/sec +
@@ -712,8 +683,8 @@ def summarize_bench(path: str) -> None:
             _fmt(r.get("ms_per_step_p50"), 2),
             _fmt(r.get("ms_per_step_p99"), 2),
             _fmt(r.get("mfu")),
-            # async-pipeline columns (every row since r6): wall/device ratio
-            # and host-stall percentiles — '-' on rows predating them
+            # async-pipeline columns: wall/device ratio and host-stall
+            # percentiles — '-' on rows without them
             _fmt(r.get("wall_to_device_ratio"), 2),
             _fmt(r.get("host_stall_ms_p50"), 2),
             _fmt(r.get("host_stall_ms_p95"), 2),

@@ -55,7 +55,7 @@ def make_shard(
     tracing plane's merge workflow uses: per-host ``trace_<role>.json``
     artifacts timestamp spans through their OWN anchor, and differencing
     two hosts' shard anchors bounds the wall-clock skew between their
-    timelines (tools/trace_breakdown.py --merge-host)."""
+    timelines."""
     return {
         "window_index": (
             int(window_index)

@@ -39,7 +39,6 @@ logger = logging.getLogger("tpuddp")
 # Peak bf16 MXU FLOP/s per chip by device kind (public spec sheets). MFU is
 # always reported against the bf16 peak: on TPU, f32 matmuls execute on the
 # MXU with bf16 multiplies by default, so bf16 peak is the one ceiling.
-# (bench.py imports this table — one source of truth for both artifacts.)
 PEAK_FLOPS = {
     "TPU v5 lite": 197e12,  # v5e
     "TPU v5e": 197e12,

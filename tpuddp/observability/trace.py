@@ -20,9 +20,8 @@ This module is the span model that closes that gap:
   slowest-span table;
 - export is two-way: :meth:`Tracer.export` writes ``trace_<role>.json`` — a
   Chrome-trace-event artifact (``traceEvents`` + a ``tpuddp`` provenance
-  block, schema v9) loadable directly in Perfetto and mergeable with the
-  device-side ``*.trace.json.gz`` via ``tools/trace_breakdown.py
-  --merge-host`` — and the live ``/trace`` endpoint on the
+  block, schema v9) loadable directly in Perfetto and read by
+  ``tpuddp_inspect trace`` — and the live ``/trace`` endpoint on the
   :class:`~tpuddp.observability.exporter.MetricsExporter` serves the last-N
   completed spans (:meth:`Tracer.endpoint_payload`).
 

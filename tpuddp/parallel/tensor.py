@@ -204,8 +204,8 @@ def local_param_template(tp_params, specs, model_width: int):
 
 
 def per_chip_param_bytes(tp_params, specs, model_width: int) -> int:
-    """Parameter bytes ONE chip holds under this sharding — the number the
-    MULTICHIP bench row reports against the replicated (model=1) footprint."""
+    """Parameter bytes ONE chip holds under this sharding — to be read
+    against the replicated (model=1) footprint."""
     total = 0
     for leaf, spec in zip(
         jax.tree_util.tree_leaves(tp_params),

@@ -12,8 +12,8 @@ lockstep program:
 - :mod:`scheduler` — coalesces variable-size requests into padded,
   power-of-two-bucketed device batches (the shared shape-key bucketing and
   staging-budget policy of ``tpuddp/utils/batching.py`` — the same machinery
-  whose scan-fused eval measured ~85x the per-batch facade in BENCH_r04/r05
-  — so the compile cache stays warm and compile storms are impossible);
+  the scan-fused eval uses — so the compile cache stays warm and compile
+  storms are impossible);
 - :mod:`replica`   — N independent model replicas across the local devices,
   loaded from a training checkpoint via the existing sha256-verified
   ``restore_latest`` path;

@@ -30,7 +30,7 @@ Modes:
   in ``<out_dir>/exporter.port``).
 
 Stdout contract: the LAST line is one compact JSON object (the SLO summary)
-for driver parsing, mirroring bench.py's output contract.
+for driver parsing: diagnostics go to stderr or to earlier lines.
 """
 
 from __future__ import annotations

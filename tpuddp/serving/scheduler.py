@@ -7,8 +7,7 @@ concatenate row-wise, then pad to the smallest power-of-two bucket that
 holds them (``tpuddp/utils/batching.bucket_for``): at most
 ``log2(max_batch_size) + 1`` compiled programs per sample shape per replica
 — a compile storm is structurally impossible, the same property the
-FusedEvaluator's shape_key bucketing proved out for eval (~85x the
-per-batch facade, BENCH_r04/r05).
+FusedEvaluator's shape_key bucketing proved out for eval.
 
 Padding rows ride with weight 0 (``batching.pad_batch``) and their logits
 are never sliced back to any request; occupancy (real rows / bucket rows) is

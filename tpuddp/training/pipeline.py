@@ -23,7 +23,7 @@ module owns the overlap:
   accumulated device-side in dispatch order, fetched only at the telemetry
   window fence / epoch boundary. No per-dispatch ``block_until_ready``,
   ever, unless ``sync_readback`` explicitly asks for the serial cadence
-  (the A/B baseline ``bench.py --pipeline`` measures against).
+  (the baseline an on/off A/B of the pipeline measures against).
 - **occupancy accounting**: the pass reports, per dispatch, the time it spent
   blocked acquiring host batches (``host_stall``), the staged-chunk queue
   depth, and the number of issued-but-unobserved dispatches (in-flight

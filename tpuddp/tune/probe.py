@@ -139,8 +139,7 @@ _TUNE_NAME_RE = re.compile(r"^TUNE_r(\d+)\.json$")
 
 
 def next_tune_path(root: str) -> str:
-    """Next free ``TUNE_rNN.json`` path under ``root`` (r01, r02, ...) —
-    the BENCH_r*/SERVING_r* artifact-family naming."""
+    """Next free ``TUNE_rNN.json`` path under ``root`` (r01, r02, ...)."""
     highest = 0
     try:
         names = os.listdir(root)
