@@ -524,6 +524,33 @@ def test_the_fused_lowering_compiles_for_a_v5e(v5e, t, hq, hkv, d, window):
         assert compiled.memory_analysis().temp_size_in_bytes < 2.9e9
 
 
+def test_a_group_of_queries_under_a_selection_compiles_for_a_v5e(v5e):
+    """The sparse-attention cell's group at its last stretch (1,024 queries
+    against 32,768 keys, 32/4 heads of 128, an indexer of 16 heads of 64,
+    2,048 keys a query), forward and backward: the index scores' kernel pair,
+    the threshold's, the library's attention kernels under a mask that is
+    data with their one-kernel backward, and the mean probability's."""
+    g, t = seq.SPARSE_GROUP, 32768
+    sds = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+    f32 = jnp.float32
+
+    def loss(q, qi, wi, k, v, ki, start):
+        out, kl, _ = seq.sparse_attention_rows(
+            q, qi, wi, k, v, ki, start, scale=128 ** -0.5, top_k=2048, compute_dtype=jnp.bfloat16, lowering="fused"
+        )
+        return jnp.sum(out.astype(f32)) + kl
+
+    args = (sds(g, 32, 128), sds(g, 16, 64, dtype=f32), sds(g, 16, dtype=f32), sds(t, 4, 128, dtype=f32),
+            sds(t, 4, 128, dtype=f32), sds(t, 64, dtype=f32), sds(dtype=jnp.int32))
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(*args).compile()
+    text = compiled.as_text()
+    for kernel in ("sparse_index_scores_fwd", "sparse_index_scores_bwd", "sparse_kth_largest",
+                   "sparse_mean_probabilities", "splash_mha_fwd", "splash_mha_dkv"):
+        assert kernel in text, kernel
+    assert "splash_mha_dq" not in text  # 32 partial dq of a group's 1,024 queries: the one-kernel backward
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9
+
+
 @pytest.mark.parametrize("t,hk,hv,d", [(8192, 16, 32, 128), (512, 2, 2, 256)])
 def test_the_fused_scan_compiles_for_a_v5e(v5e, t, hk, hv, d):
     """Forward and backward kernels of the scan's chunk-local phase and of
@@ -566,6 +593,8 @@ def test_the_fused_conv_compiles_for_a_v5e(v5e, t, channels, key_width, d, taps)
     ("ouro_loop4_t16k_fused", 1, "train_step"), ("ouro_loop4_t16k_fused", 1, "train_step_many"),  # four rolled passes
     # the largest state of any cell beside the widest attention call
     ("glm47flash_ep8_t16k_fused", 1, "train_step"), ("glm47flash_ep8_t16k_fused", 1, "train_step_many"),
+    # attention under a mask that is data, a group of 1,024 queries a call of the kernel, three stretches a layer
+    ("keye2_ep8_t32k_fused", 1, "train_step"), ("keye2_ep8_t32k_fused", 1, "train_step_many"),
 ])
 def test_the_token_cells_step_compiles_for_a_v5e(v5e, monkeypatch, workload, sequences, method):
     """The whole step at published widths (the check's single step at one
@@ -598,7 +627,9 @@ def test_the_token_cells_step_compiles_for_a_v5e(v5e, monkeypatch, workload, seq
     assert memory.temp_size_in_bytes + memory.argument_size_in_bytes < 14.5e9
     if "LatentAttention" in model.layer_types:  # 8.48 GB of arguments: the scratch stays where it compiled (3.64 GB)
         assert memory.temp_size_in_bytes < 3.8e9
-    if t > 16384 and method == "train_step_many":  # the timed program: no more scratch than with the two kernels (PR 42)
+    if "SparseAttention" in model.layer_types:  # 6.75 GB of arguments beside the scratch of three stretches a layer (2.90 GB; six: 3.87, PR 49)
+        assert memory.temp_size_in_bytes < 3.1e9
+    elif t > 16384 and method == "train_step_many":  # the timed program: no more scratch than with the two kernels (PR 42)
         assert memory.temp_size_in_bytes <= 7_031_718_912
     deltanet_layers = "GatedDeltaNet" in model.layer_types
     assert ("deltanet_chunk_fwd" in text and "deltanet_chunk_bwd" in text) == deltanet_layers
@@ -618,6 +649,7 @@ def test_the_token_cells_step_compiles_for_a_v5e(v5e, monkeypatch, workload, seq
             # long groups: all that is contracted in one tile, a triple a kernel (PR 47)
             ("mellum2_ep4_t16k_fused", 52480, ((256, 2304, 896), (256, 1792, 1152), (256, 1152, 896))),
             ("lfm2_ep4_t32k_fused", 52480, ((256, 2048, 896), (256, 3584, 512), (256, 2048, 512))),
+            ("keye2_ep8_t32k_fused", 52480, ((256, 2048, 768), (256, 1536, 1024), (256, 2048, 512))),  # PR 49
         )
     # forward kernel, weight-gradient kernel; by the instruction's name (a kernel's serialised body is
     # base64, in which three letters turn up by chance)

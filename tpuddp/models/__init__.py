@@ -4,9 +4,10 @@ adds genuinely small toy models for fast CI (per SURVEY.md scale calibration),
 ResNet-18/34 (BasicBlock) + ResNet-50/101/152 (Bottleneck), VGG-11/13/16/19, and
 CIFAR-stem variants (the ``*_s2d`` names are aliases of the plain ones); all
 torch-importable. Token models: a small GPT-2-style ``TransformerLM`` and
-``HybridMoELM``, expert decoders whose layers are listed by type (six mixer
+``HybridMoELM``, expert decoders whose layers are listed by type (seven mixer
 types: Gated DeltaNet, gated attention, sliding-window and full attention, a
-gated short convolution, latent attention), with a dense or a sparse
+gated short convolution, latent attention, attention over the keys a learned
+indexer chose), with a dense or a sparse
 feed-forward a layer, a tied or an untied head and, where the router chooses
 by a bias, that bias as the trunk's state; built at published widths as one
 chip's share of an expert-parallel job. The same trunk walks a dense stack
@@ -22,7 +23,7 @@ from tpuddp.models.resnet import (  # noqa: F401
 )
 from tpuddp.models.vgg import VGG11, VGG13, VGG16, VGG19  # noqa: F401
 from tpuddp.models.hybrid_moe import (  # noqa: F401
-    GLM_4_7_FLASH_EP8, GLM_4_7_FLASH_TINY, LFM2_EP4, LFM2_TINY, MELLUM2_EP4, MELLUM2_TINY, OURO_2_6B_L6, OURO_TINY, QWEN3_NEXT_EP16, QWEN3_NEXT_TINY,
+    GLM_4_7_FLASH_EP8, GLM_4_7_FLASH_TINY, KEYE_VL_2_0_EP8, KEYE_VL_2_0_TINY, LFM2_EP4, LFM2_TINY, MELLUM2_EP4, MELLUM2_TINY, OURO_2_6B_L6, OURO_TINY, QWEN3_NEXT_EP16, QWEN3_NEXT_TINY,
     HybridMoELM,
 )
 
@@ -87,6 +88,15 @@ _REGISTRY = {
     # chip of an 8-way expert-parallel job holds them; and a CPU-test size
     "glm_4_7_flash_ep8": _partial(HybridMoELM, **GLM_4_7_FLASH_EP8),
     "glm_4_7_flash_tiny": _partial(HybridMoELM, **GLM_4_7_FLASH_TINY),
+    # the same trunk with learned sparse attention (Keye-VL-2.0-30B-A3B's
+    # language model: 32 query heads over 4 key/value heads of 128 with the
+    # per-head norm; in every layer an indexer of 16 heads of 64 over one key
+    # head scores every earlier key and a query attends only its 2,048 best;
+    # the indexer learns from an objective of its own inside the layer; 128
+    # routed experts, 8 a token, none shared) as one chip of an 8-way
+    # expert-parallel job holds it; and a CPU-test size
+    "keye_vl_2_0_ep8": _partial(HybridMoELM, **KEYE_VL_2_0_EP8),
+    "keye_vl_2_0_tiny": _partial(HybridMoELM, **KEYE_VL_2_0_TINY),
     # aliases of the plain names: nn.Conv2d picks the space-to-depth lowering
     # of a thin-channel strided stem from its own shapes, so these build the
     # same program (kept for settings files and checkpoints that name them)

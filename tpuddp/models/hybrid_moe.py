@@ -1,7 +1,8 @@
 """Hybrid mixture-of-experts language models on one decoder trunk, as one chip
 of an expert-parallel job holds them: layers whose mixers differ in kind
-(linear attention, softmax attention over every earlier key or over a sliding
-window, a gated short convolution), whose feed-forward is sparse or dense,
+(linear attention, softmax attention over every earlier key, over a sliding
+window or over the keys a learned indexer chose, a gated short convolution),
+whose feed-forward is sparse or dense,
 whose head is the embedding's transpose or a matrix of its own, and which may
 carry state beside their parameters (a router's selection bias).
 
@@ -9,7 +10,7 @@ Every layer is ``h = x + Mixer(RMSNorm(x))``, ``y = h + FF(RMSNorm(h))``;
 where its tree has ``mixer_out_norm`` and ``ff_out_norm`` (``sandwich_norms``)
 a second norm stands on each half's result before the add.
 ``layer_types`` lists each layer's type, which names its mixer and its scope
-(``<i>_<type>``), six of them:
+(``<i>_<type>``), seven of them:
 
 - ``GatedDeltaNet`` (``nn/deltanet.py``) and ``GatedAttention`` (softmax
   attention with a per-head norm on queries and keys, rotary on part of the
@@ -40,6 +41,30 @@ a second norm stands on each half's result before the add.
   ``v_head_dim`` wide (``qk_nope_dim + qk_rope_dim``: scores and values one
   width, so ``seq.causal_attention`` takes the heads as any others). Scopes
   ``q_latent``, ``kv_latent``, ``attention``, ``o_proj``.
+- ``SparseAttention``: learned sparse attention as Keye-VL-2.0's language
+  model has it (DeepSeek Sparse Attention's indexer on grouped-query
+  attention with the per-head norm and rotary on the whole head). Beside
+  attention's own projections the mixer's tree holds an ``indexer``
+  (``q_proj`` to ``index_heads`` heads of ``index_head_dim``, ``k_proj`` to
+  ONE key head under a LayerNorm, ``w_proj`` to a weight a head), which reads
+  the layer's normed input behind a stop-gradient and scores every earlier
+  key, ``I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s])``; a query attends
+  only the ``index_top_k`` keys of largest score (all of them while it has
+  that many or fewer; exact, ties to the earlier key), the same set for every
+  head: the mask is data, decided in the step. The selection cannot be
+  differentiated, so the language model's loss never reaches the indexer: it
+  learns from an objective of its own inside the layer, the KL divergence of
+  the heads' mean attention distribution (behind a second stop-gradient) to
+  ``softmax_{S_t}(I)``, a mean over rows, summed over layers, which enters
+  the gradient at ``indexer_loss_weight`` beside the routers' auxiliary loss
+  and reaches the indexer's leaves alone; ``nn/sequence.py``'s
+  ``SPARSE_COUNTERS`` carry it out. The layer is its own checkpoint: queries
+  go a group of rows at a time with everything that is rows by keys, each
+  group recomputed in the backward pass. Scopes ``qkv``, ``index_proj``,
+  ``index_scores``, ``index_select``, ``attention``, ``indexer_loss``,
+  ``o_proj``. The family's dense warm-up stage (the model frozen, the indexer
+  alone trained under dense attention) needs a mask of trainable leaves and
+  is not built.
 
 A layer's feed-forward is what its tree holds. ``moe``: a router over all
 ``n_experts``, ``top_k`` of them a token (their renormalised weights times
@@ -115,9 +140,10 @@ layers); ``ouro_2_6b_l6``, those of Ouro-2.6B with every layer whole on the
 chip (six dense full-attention layers with sandwich norms and no per-head
 norm, walked four times, every pass an exit); ``glm_4_7_flash_ep8``, those of
 GLM-4.7-Flash as share 0 of 8 (the dense leading layer and four sparse
-latent-attention layers, the prediction module); ``qwen3_next_tiny``,
-``mellum2_tiny``, ``lfm2_tiny``, ``ouro_tiny`` and ``glm_4_7_flash_tiny`` for
-the CPU tests.
+latent-attention layers, the prediction module); ``keye_vl_2_0_ep8``, those of
+Keye-VL-2.0-30B-A3B's language model as share 0 of 8 (five sparse-attention
+layers); ``qwen3_next_tiny``, ``mellum2_tiny``, ``lfm2_tiny``, ``ouro_tiny``,
+``glm_4_7_flash_tiny`` and ``keye_vl_2_0_tiny`` for the CPU tests.
 """
 
 from __future__ import annotations
@@ -137,7 +163,8 @@ DELTANET, ATTENTION = "GatedDeltaNet", "GatedAttention"
 SLIDING, FULL = "SlidingAttention", "FullAttention"
 SHORT_CONV = "ShortConv"
 LATENT = "LatentAttention"
-LAYER_TYPES = (DELTANET, ATTENTION, SLIDING, FULL, SHORT_CONV, LATENT)
+SPARSE = "SparseAttention"
+LAYER_TYPES = (DELTANET, ATTENTION, SLIDING, FULL, SHORT_CONV, LATENT, SPARSE)
 
 
 class HybridMoELM(Module):
@@ -168,6 +195,12 @@ class HybridMoELM(Module):
         qk_nope_dim: int = 192,
         qk_rope_dim: int = 64,
         v_head_dim: int = 256,
+        # SparseAttention: an indexer of index_heads heads over one key head scores every earlier key, and a
+        # query attends the index_top_k best
+        index_heads: int = 16,
+        index_head_dim: int = 64,
+        index_top_k: int = 2048,
+        indexer_loss_weight: float = 1.0,  # what the indexer's own objective weighs in the gradient
         # Gated DeltaNet; conv_kernel is the gated short convolution's too
         linear_k_heads: int = 16,
         linear_v_heads: int = 32,
@@ -238,6 +271,8 @@ class HybridMoELM(Module):
         self.qk_nope_dim, self.qk_rope_dim, self.v_head_dim = int(qk_nope_dim), int(qk_rope_dim), int(v_head_dim)
         if LATENT in self.layer_types and self.qk_nope_dim + self.qk_rope_dim != self.v_head_dim:
             raise ValueError("a latent-attention head's scores and values are one width: qk_nope_dim + qk_rope_dim")
+        self.index_heads, self.index_head_dim = int(index_heads), int(index_head_dim)
+        self.index_top_k, self.indexer_loss_weight = int(index_top_k), float(indexer_loss_weight)
         self.rope_theta = float(rope_theta)
         self.linear_k_heads, self.linear_v_heads = int(linear_k_heads), int(linear_v_heads)
         self.linear_k_dim, self.linear_v_dim = int(linear_k_dim), int(linear_v_dim)
@@ -266,6 +301,10 @@ class HybridMoELM(Module):
             raise ValueError("one module predicts the token after next, after a stack that is walked once")
         if self.next_token_modules:
             self.counter_names = moe.COUNTERS + seq.NEXT_COUNTERS
+        if SPARSE in self.layer_types:
+            if self.loop_steps > 1 or self.next_token_modules or self.sandwich_norms or self.index_top_k < 1:
+                raise ValueError(f"{SPARSE} layers stand in a plain stack that is walked once and choose a key or more")
+            self.counter_names = moe.COUNTERS + seq.SPARSE_COUNTERS
         self.rms_eps, self.init_std = float(rms_eps), float(init_std)
         self.embed_std = self.init_std if embed_std is None else float(embed_std)
         if self.tied_head and self.embed_std != self.init_std:
@@ -333,6 +372,20 @@ class HybridMoELM(Module):
                 "o_proj": normal(ks[4], (h * dv, e)),
             }
 
+        def sparse_mixer(k):
+            k_attention, *ks = jax.random.split(k, 4)
+            hi, di = self.index_heads, self.index_head_dim
+            return {
+                **attention_mixer(k_attention, False),
+                # the indexer: its queries a head, its one key head and a weight a query head, all from the
+                # layer's normed input; the language model's loss never reaches them
+                "indexer": {
+                    "q_proj": normal(ks[0], (e, hi * di)), "k_proj": normal(ks[1], (e, di)),
+                    "k_norm": {"weight": jnp.ones((di,), jnp.float32), "bias": jnp.zeros((di,), jnp.float32)},
+                    "w_proj": normal(ks[2], (e, hi)),
+                },
+            }
+
         def short_conv_mixer(k):
             ks = jax.random.split(k, 3)
             return {
@@ -369,6 +422,8 @@ class HybridMoELM(Module):
                 return short_conv_mixer(k)
             if kind == LATENT:
                 return latent_mixer(k)
+            if kind == SPARSE:
+                return sparse_mixer(k)
             return attention_mixer(k, kind == ATTENTION)
 
         def drawn_bias(k):
@@ -502,6 +557,74 @@ class HybridMoELM(Module):
         with _prof.scope("o_proj"):
             return seq.matmul(o.reshape(b, t, h * dv), p["o_proj"], cd)
 
+    def _sparse_mix(self, p, x, remat: bool):
+        """``x + Attn(RMSNorm(x))`` for one sequence ``(T, E)`` of a
+        ``SparseAttention`` layer, the indexer's objective summed over the
+        rows and the pairs its selections hold: grouped-query attention with
+        the per-head norm and rotary on the whole head, in which a query
+        attends only the ``index_top_k`` keys its indexer scored highest
+        (``nn/sequence.py``: ``sparse_attention_rows``). The indexer reads the
+        layer's normed input behind a stop-gradient: queries a head under
+        rotary, ONE key head under a LayerNorm and rotary, a weight a head
+        scaled by ``index_heads^-1/2 index_head_dim^-1/2``.
+
+        Keys, values and the indexer's keys are made once a sequence and kept
+        in float32 (their gradients add up over the groups); the queries go a
+        group of rows at a time with everything that is ``rows x keys`` (index
+        scores, the selection, attention, the objective), the groups of a
+        stretch in one rolled loop. With ``remat`` the keys and each group are
+        recomputed in the backward pass: the layer is its own checkpoint, so
+        that a group is computed twice a step and not three times."""
+        m, ix, cd, f32 = p["mixer"], p["mixer"]["indexer"], self.compute_dtype, jnp.float32
+        t = x.shape[0]
+        hq, hkv, d, hi, di = self.n_heads, self.n_kv_heads, self.head_dim, self.index_heads, self.index_head_dim
+        lowering = seq.sparse_attention_lowering(jax.default_backend(), d, t, per_replica=seq.traced_per_replica())
+        group = seq.SPARSE_GROUP if lowering == "fused" else min(self.attention_q_block, t)
+        rope = lambda a, positions: seq.rotary(a, positions, rotary_dim=a.shape[-1], theta=self.rope_theta)
+        # the tree says whether queries and keys have a norm a head
+        head_norm = (lambda a, w: self._norm(a, m[w])) if "q_norm" in m else (lambda a, w: a)
+
+        def keys(m, ix, norm, x):
+            h, positions = self._norm(x, norm)[None], jnp.arange(t)
+            with _prof.scope("qkv"):
+                k = rope(head_norm(seq.matmul(h, m["k_proj"], cd, f32).reshape(1, t, hkv, d), "k_norm"), positions)
+                v = seq.matmul(h, m["v_proj"], cd, f32).reshape(t, hkv, d)
+            with _prof.scope("index_proj"):
+                ki = seq.matmul(seq.indexer_input(h), ix["k_proj"], cd, f32)
+                ki = seq.layer_norm(ki, ix["k_norm"]["weight"], ix["k_norm"]["bias"], self.rms_eps)
+                ki = rope(ki[:, :, None, :], positions)
+            return k[0], v, ki[0, :, 0]
+
+        def rows(m, ix, norm, x_rows, start, k, v, ki):
+            g = x_rows.shape[0]
+            h, positions = self._norm(x_rows, norm)[None], start + jnp.arange(g)
+            with _prof.scope("qkv"):
+                q = rope(head_norm(seq.matmul(h, m["q_proj"], cd).reshape(1, g, hq, d), "q_norm"), positions)
+            with _prof.scope("index_proj"):
+                behind = seq.indexer_input(h)
+                qi = rope(seq.matmul(behind, ix["q_proj"], cd, f32).reshape(1, g, hi, di), positions)
+                wi = seq.matmul(behind, ix["w_proj"], cd, f32) * (hi ** -0.5 * di ** -0.5)
+            out, kl, pairs = seq.sparse_attention_rows(
+                q[0], qi[0], wi[0], k, v, ki, start, scale=d ** -0.5, top_k=self.index_top_k,
+                compute_dtype=cd, lowering=lowering,
+            )
+            with _prof.scope("o_proj"):
+                return x_rows + seq.matmul(out.reshape(g, hq * d), m["o_proj"], cd), kl, pairs
+
+        if remat:
+            keys, rows = jax.checkpoint(keys), jax.checkpoint(rows)
+        k, v, ki = keys(m, ix, p["input_norm"], x)
+        out, kl, pairs = [], jnp.zeros((), f32), jnp.zeros((), f32)
+        for first, n, end in seq.sparse_row_groups(t, group):
+            size = min(group, n)
+            y, kl_groups, pairs_groups = jax.lax.map(
+                lambda args: rows(m, ix, p["input_norm"], *args, k[:end], v[:end], ki[:end]),
+                (x[first:first + n].reshape(n // size, size, -1), first + size * jnp.arange(n // size)),
+            )
+            out.append(y.reshape(n, -1))
+            kl, pairs = kl + jnp.sum(kl_groups), pairs + jnp.sum(pairs_groups)
+        return jnp.concatenate(out), kl, pairs
+
     def _short_conv(self, p, x):
         cd = self.compute_dtype
         with _prof.scope("in_proj"):
@@ -572,44 +695,68 @@ class HybridMoELM(Module):
         (a dense one in chunks of them); with ``remat`` each of the two is
         recomputed in the backward pass, so a step keeps the residual stream
         at both and one sequence's mixer or one expert layer's activations."""
-        mix, experts = functools.partial(self._mix, kind), self._experts
+        mix = functools.partial(self._mix, kind)
         if remat:
-            mix, experts = jax.checkpoint(mix), jax.checkpoint(experts)
-        h = jax.lax.map(lambda sequence: mix(p, sequence), x)
+            mix = jax.checkpoint(mix)
+        return self._feed_forward(p, bias, jax.lax.map(lambda sequence: mix(p, sequence), x), remat)
+
+    def _feed_forward(self, p, bias, h, remat: bool):
+        """The second half of a layer, whichever its tree holds."""
         if "moe" not in p:  # the tree says which feed-forward a layer has
             return self._dense(p, h, remat), None, None, None
-        return experts(p, bias, h)
+        return (jax.checkpoint(self._experts) if remat else self._experts)(p, bias, h)
+
+    def _sparse_layer(self, p, bias, x, remat: bool):
+        """A ``SparseAttention`` layer: :meth:`_layer`'s four and a fifth, the
+        layer's own objective as a mean over the rows (its gradient reaches
+        the indexer alone); its counters stand beside an expert layer's. The
+        mixer is its own checkpoint, a group of queries each."""
+        h, kl, pairs = jax.lax.map(lambda sequence: self._sparse_mix(p, sequence, remat), x)
+        rows = jnp.asarray(kl.size * x.shape[1], jnp.float32)
+        counters = dict(zip(seq.SPARSE_COUNTERS, jax.lax.stop_gradient((jnp.sum(kl), rows, jnp.sum(pairs)))))
+        y, aux, expert_counters, router_counts = self._feed_forward(p, bias, h, remat)
+        return y, aux, {**counters, **(expert_counters or {})}, router_counts, jnp.sum(kl) / rows
 
     # -------------------------------------------------------------- forward --
     def _scoped_layer(self, i: int, kind: str, p, layer_state, h, ctx: Context):
         """Layer ``i`` under its scope ``<i>_<kind>``: ``(y, aux_loss,
-        counters, the layer's state after the step)``; in training a sparse
-        layer's selection bias moves by the step's counts."""
+        counters, the layer's state after the step, index_loss)``; in
+        training a sparse layer's selection bias moves by the step's counts."""
         bias = layer_state["expert_bias"] if layer_state else None
         with _prof.scope(f"{i}_{kind}"):
-            h, aux, counters, router_counts = self._layer(kind, p, bias, h, ctx.train)
+            if kind == SPARSE:
+                h, aux, counters, router_counts, index_loss = self._sparse_layer(p, bias, h, ctx.train)
+            else:
+                (h, aux, counters, router_counts), index_loss = self._layer(kind, p, bias, h, ctx.train), None
             if bias is not None and ctx.train:
                 with _prof.scope("moe"), _prof.scope("router"):
                     layer_state = {"expert_bias": moe.balanced_bias(
                         bias, router_counts, self.bias_update_rate, ctx.axis_name
                     )}
-        return h, aux, counters, layer_state
+        return h, aux, counters, layer_state, index_loss
 
     def _layers(self, params, state, h, ctx: Context):
-        """One walk over the layers: ``(h, aux_loss, counters, new_state)``."""
-        aux_total = jnp.zeros((), jnp.float32)
-        totals = {name: jnp.zeros((), jnp.float32) for name in moe.COUNTERS if name in self.counter_names}
+        """One walk over the layers: ``(h, aux_loss, counters, new_state,
+        index_loss)``: the routers' load-balancing losses summed, and the
+        indexers' objectives summed (``None`` where no layer has one); each
+        enters the gradient at a weight of its own (:meth:`apply`)."""
+        aux_total, index_total = jnp.zeros((), jnp.float32), None
+        layer_counters = moe.COUNTERS + seq.SPARSE_COUNTERS
+        totals = {name: jnp.zeros((), jnp.float32) for name in layer_counters if name in self.counter_names}
         new_state = list(state)  # a selection bias a sparse layer, where the model has them
         for i, p in enumerate(params["layers"]):
-            h, aux, counters, layer_state = self._scoped_layer(
+            h, aux, counters, layer_state, index_loss = self._scoped_layer(
                 i, self.layer_kind(i), p, state[i] if state else (), h, ctx
             )
             if state:
                 new_state[i] = layer_state
-            if counters is not None:
+            if aux is not None:
                 aux_total = aux_total + aux
-                totals = {name: totals[name] + counters[name] for name in totals}
-        return h, aux_total, totals, tuple(new_state)
+            if index_loss is not None:
+                index_total = index_loss if index_total is None else index_total + index_loss
+            if counters is not None:
+                totals = {name: total + counters.get(name, 0.0) for name, total in totals.items()}
+        return h, aux_total, totals, tuple(new_state), index_total
 
     def _next_token_module(self, p, layer_state, embed, tokens, h, ctx: Context):
         """The states the second head reads (arXiv:2412.19437, section 2.2, at
@@ -630,7 +777,7 @@ class HybridMoELM(Module):
                     [self._norm(h, p["hidden_norm"]), self._norm(after, p["embed_norm"])], axis=-1
                 )
                 h = seq.matmul(joined, p["proj"], self.compute_dtype)
-            h, aux, counters, layer_state = self._scoped_layer(
+            h, aux, counters, layer_state, _ = self._scoped_layer(
                 self.n_layers, self.layer_types[-1], p["layer"], layer_state, h, ctx
             )
             return self._norm(h, p["head_norm"]), aux, counters, layer_state
@@ -656,7 +803,7 @@ class HybridMoELM(Module):
         h = seq.round_to(jnp.take(params["embed"]["weight"], tokens, axis=0), self.compute_dtype)
         exits = next_hidden = None
         if self.loop_steps == 1:
-            h, aux_total, totals, new_state = self._layers(params, state, h, ctx)
+            h, aux_total, totals, new_state, index_total = self._layers(params, state, h, ctx)
             if "mtp" in params and ctx.train:  # the tree says whether a second head predicts the token after next
                 module_state = state[self.n_layers] if state else ()
                 next_hidden, aux, counters, module_state = self._next_token_module(
@@ -675,16 +822,20 @@ class HybridMoELM(Module):
                     lambda h, _: self._pass(params, state, h, ctx), h, None, length=self.loop_steps
                 )
             # dense layers, no state (the constructor holds that): nothing to add up over the passes
-            aux_total, new_state = None, tuple(state)
+            aux_total, index_total, new_state = None, None, tuple(state)
             totals = {name: jnp.zeros((), jnp.float32) for name in self.counter_names}
         head = params["head"]["weight"] if "head" in params else params["embed"]["weight"].T
+        # what enters the gradient beside the language model's loss, each at its own weight
+        extra = None if aux_total is None else self.aux_loss_weight * aux_total
+        if index_total is not None:
+            extra = extra + self.indexer_loss_weight * index_total
         if exits is not None and "exit_gate" in params and ctx.train:  # the tree says whether the passes are exits
             return seq.DeferredExits(
                 exits, head, params["exit_gate"], entropy_weight=self.exit_entropy_weight,
                 compute_dtype=self.compute_dtype, chunk=self.loss_chunk,
             ), new_state
         out = seq.DeferredLogits(
-            h, head, None if aux_total is None else self.aux_loss_weight * aux_total, totals, next_hidden,
+            h, head, extra, totals, next_hidden,
             compute_dtype=self.compute_dtype, chunk=self.loss_chunk, next_weight=self.next_token_loss_weight,
         )
         return (out if ctx.train else out.logits()), new_state
@@ -723,6 +874,13 @@ GLM_4_7_FLASH_EP8 = dict(  # GLM-4.7-Flash's widths, heads and ranks; depth, exp
     routed_scale=1.8, expert_bias=True, bias_update_rate=1e-3, aux_loss_weight=0.0,
     next_token_modules=1, next_token_loss_weight=0.3, embed_std=1.0,
 )
+KEYE_VL_2_0_EP8 = dict(  # Keye-VL-2.0-30B-A3B's language model: widths, heads and indexer; depth, experts held and vocabulary cut
+    hidden_size=2048, n_layers=5, layer_types=(SPARSE,) * 5, zero_centred_norms=False,
+    n_heads=32, n_kv_heads=4, head_dim=128, partial_rotary_factor=1.0, rope_theta=1e7,
+    index_heads=16, index_head_dim=64, index_top_k=2048, indexer_loss_weight=1.0,
+    n_experts=128, experts_held=16, first_expert=0, top_k=8, expert_width=768, shared_width=0,
+    aux_loss_weight=16.0, embed_std=1.0,
+)
 QWEN3_NEXT_TINY = dict(
     hidden_size=64, n_layers=4, full_attention_interval=4,
     n_heads=4, n_kv_heads=2, head_dim=16, partial_rotary_factor=0.25, rope_theta=1e7,
@@ -760,4 +918,12 @@ GLM_4_7_FLASH_TINY = dict(  # a dense leading layer and two sparse ones, heads o
     routed_scale=1.8, expert_bias=True, bias_update_rate=1e-3, aux_loss_weight=0.0,
     next_token_modules=1, next_token_loss_weight=0.3, embed_std=1.0,
     attention_q_block=16, loss_chunk=64, mlp_chunk=32,
+)
+KEYE_VL_2_0_TINY = dict(  # two sparse-attention layers whose queries choose 8 keys, an indexer of 2 heads of 16
+    hidden_size=64, n_layers=2, layer_types=(SPARSE,) * 2, zero_centred_norms=False,
+    n_heads=4, n_kv_heads=2, head_dim=16, partial_rotary_factor=1.0, rope_theta=1e4,
+    index_heads=2, index_head_dim=16, index_top_k=8, indexer_loss_weight=1.0,
+    n_experts=8, experts_held=2, first_expert=0, top_k=2, expert_width=32, shared_width=0,
+    aux_loss_weight=16.0, embed_std=1.0,
+    attention_q_block=16, loss_chunk=64,
 )

@@ -2,13 +2,17 @@
 positions over part or all of a head from a plain or a YaRN-scaled frequency
 table, a causal depthwise 1-D convolution, plain and between two gates, SwiGLU,
 causal attention over
-grouped key/value heads, full or within a sliding window, that never holds a
+grouped key/value heads, full, within a sliding window or over the keys a
+learned indexer chose for each query, that never holds a
 ``T x T`` score block, and a head-plus-cross-entropy that never holds
 ``(B, T, V)`` logits, over one final state (beside it, a second head's states
 for the token after next) or over the exits of a looped stack.
 
-Attention has one mask with one parameter (a query sees itself and the keys
-before it, all of them or the ``window - 1`` nearest) and two lowerings, and
+Attention under a static mask has one mask with one parameter (a query sees
+itself and the keys before it, all of them or the ``window - 1`` nearest); under
+a mask that is data (:func:`sparse_attention_rows`: which keys a query sees is
+decided in the step, by an indexer's exact top-k over its scores of every
+earlier key) it goes a group of queries at a time. Either has two lowerings, and
 picks one from what a call can see (:func:`attention_lowering`): on a TPU,
 for heads of 64, 128 or 256, sequences that are a multiple of 512 and a window
 whose chunks divide them, traced once a device (the default
@@ -247,7 +251,7 @@ def _blockwise_causal_attention(q, k, v, *, scale, compute_dtype, q_block, windo
     )
     if window is not None and q_block + window - 1 < t:
         return _banded_blocks(block, q, k, v, q_block, window).reshape(b, t, hq, d)
-    span = q_block * (math.isqrt(max(-(-t // q_block) - 1, 0)) + 1)  # tokens a group
+    span = _stretch(t, q_block)
     outs = []
     for start in range(0, t, span):
         end = min(start + span, t)
@@ -263,6 +267,12 @@ def _blockwise_causal_attention(q, k, v, *, scale, compute_dtype, q_block, windo
         if start + whole * q_block < end:  # what is left of a sequence that is no multiple
             outs.append(block(q[:, start + whole * q_block:end], start + whole * q_block, keys, values))
     return jnp.concatenate(outs, axis=1).reshape(b, t, hq, d)
+
+
+def _stretch(t: int, block: int) -> int:
+    """Tokens a group of neighbouring blocks that share their keys and one
+    rolled loop: about ``sqrt(t / block)`` blocks."""
+    return block * (math.isqrt(max(-(-t // block) - 1, 0)) + 1)
 
 
 def _banded_blocks(block, q, k, v, q_block: int, window: int):
@@ -461,6 +471,287 @@ def _banded_chunks(attend, band, q, k, v, *, chunk: int):
     with_before = lambda a: flat(jnp.concatenate([chunks(a)[:, :-1], chunks(a)[:, 1:]], axis=2))
     rest = attend(band(2 * chunk, chunk), flat(chunks(q)[:, 1:]), with_before(k), with_before(v))
     return jnp.concatenate([head, rest.reshape(b, t - chunk, *q.shape[2:])], axis=1)
+
+
+# -- attention over the keys a query's indexer chose --------------------------------
+
+SPARSE_COUNTERS = (  # additive, as an expert layer's: summed over layers here and over steps by whoever reads them
+    "indexer_kl_sum",  # the indexer's objective summed over rows: KL(attention's distribution || the indexer's) over a row's selection
+    "indexer_rows",  # the rows it was summed over, a layer each: their quotient is the objective a row a layer
+    "index_selected_pairs",  # (query, key) pairs the selections hold, summed over layers: what the sparse product needs
+)
+SPARSE_GROUP = 1024  # queries a call of the fused kernel under a selection: the mask and the index scores are (group, keys)
+# Stretches a sequence's groups go in. Every stretch is one more copy of a group's program in the step (the
+# mask's processing, six kernels, the passes over rows by keys), and the copies are what the step's trace, compile
+# time and executable are made of; a group of three stretches meets 0.67 of the sequence's keys on average, of six
+# 0.59, of one all: the kernels skip the blocks no query sees, what is elementwise pays for them. Measured at
+# 32,768 tokens (PERF.md section 6, PR 49): three for six cost 3.0% of the step and took 80 s off its set-up.
+SPARSE_STRETCHES = 3
+
+
+def layer_norm(x, weight, bias, eps: float = 1e-6):
+    """``(x - mean) / sqrt(var + eps) * w + b`` over the last axis, statistics
+    in float32."""
+    x32 = x.astype(jnp.float32)
+    centred = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+    y = centred * jax.lax.rsqrt(jnp.mean(jnp.square(centred), axis=-1, keepdims=True) + eps)
+    return (y * weight.astype(jnp.float32) + bias.astype(jnp.float32)).astype(x.dtype)
+
+
+def indexer_input(h):
+    """What a layer's indexer reads: the layer's normed input, which no
+    gradient leaves through. The indexer's objective moves the indexer's own
+    leaves and nothing below them."""
+    return jax.lax.stop_gradient(h)
+
+
+def sparse_attention_lowering(backend: str, head_dim: int, t: int, *, per_replica: bool) -> str:
+    """``"fused"`` or ``"blockwise"`` for attention under a selection, by
+    :func:`attention_lowering`'s reasons: the library's kernel under a mask
+    that is data, ``SPARSE_GROUP`` queries a call, on a TPU where the
+    sequence is whole groups and the call is traced once a device; query
+    blocks in plain XLA everywhere else."""
+    if backend == "tpu" and per_replica and head_dim % _LANES == 0 and head_dim <= _WIDEST_HEAD and t % SPARSE_GROUP == 0:
+        return "fused"
+    return "blockwise"
+
+
+def sparse_row_groups(t: int, group: int) -> list:
+    """``(first row, rows, keys)`` of the stretches a sequence's queries go
+    in: whole groups of ``group`` queries that share the keys up to the
+    stretch's end and one rolled loop, ``SPARSE_STRETCHES`` stretches of as
+    many groups each, and what is left of a sequence that is no multiple as
+    a stretch of its own."""
+    groups = -(-t // group)
+    span, out = group * -(-groups // SPARSE_STRETCHES), []
+    for start in range(0, t, span):
+        end = min(start + span, t)
+        whole = (end - start) // group * group
+        if whole:
+            out.append((start, whole, end))
+        if start + whole < end:
+            out.append((start + whole, end - start - whole, end))
+    return out
+
+
+@jax.custom_vjp
+def index_scores(qi, ki, wi):
+    """``I[t, s] = sum_j wi[t, j] relu(qi[t, j] . ki[s])``, ``(G, S)`` float32,
+    of ``qi (G, H, D)`` and ``ki (S, D)`` as they are given (the products'
+    inputs, already rounded) and ``wi (G, H)`` float32. A head at a time, in
+    the forward and the backward pass alike: the ``(G, S)`` block of one head
+    is all that lives beside the sum, and nothing of it is kept."""
+    def head(total, args):
+        q_j, w_j = args
+        z = jnp.matmul(q_j, ki.T, preferred_element_type=jnp.float32)
+        return total + w_j[:, None] * jax.nn.relu(z), None
+
+    total, _ = jax.lax.scan(
+        head, jnp.zeros((qi.shape[0], ki.shape[0]), jnp.float32), (jnp.moveaxis(qi, 1, 0), wi.T)
+    )
+    return total
+
+
+def _index_scores_fwd(qi, ki, wi):
+    return index_scores(qi, ki, wi), (qi, ki, wi)
+
+
+def _index_scores_bwd(saved, d):
+    qi, ki, wi = saved
+
+    def head(d_ki, args):
+        q_j, w_j = args
+        z = jnp.matmul(q_j, ki.T, preferred_element_type=jnp.float32)
+        d_w = jnp.sum(d * jax.nn.relu(z), axis=1)
+        d_z = jnp.where(z > 0, d * w_j[:, None], 0.0).astype(qi.dtype)  # a product's input, as the forward's are
+        d_q = jnp.matmul(d_z, ki, preferred_element_type=jnp.float32)
+        return d_ki + jnp.matmul(d_z.T, q_j, preferred_element_type=jnp.float32), (d_q, d_w)
+
+    d_ki, (d_qi, d_wi) = jax.lax.scan(
+        head, jnp.zeros(ki.shape, jnp.float32), (jnp.moveaxis(qi, 1, 0), wi.T)
+    )
+    return jnp.moveaxis(d_qi, 0, 1).astype(qi.dtype), d_ki.astype(ki.dtype), d_wi.T.astype(wi.dtype)
+
+
+index_scores.defvjp(_index_scores_fwd, _index_scores_bwd)
+
+
+def _ordered_bits(x):
+    """float32 as uint32 in the floats' order (``-inf`` lowest, above 0)."""
+    u = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.uint32)
+    return jnp.where(u >> 31 == 1, ~u, u | jnp.uint32(0x80000000))
+
+
+def top_k_mask(scores, visible, k: int, *, fused: bool = False, interpret: bool = False):
+    """``(G, S)`` bool: a row's ``k`` ``visible`` entries of largest score,
+    equal scores to the lower index; every visible entry where they are ``k``
+    or fewer. Exact, and no sort: the ``k``-th largest score is found a bit
+    at a time on the floats' ordered bit patterns (32 counts over the row;
+    ``fused``: in a kernel that keeps the row in VMEM for all of them),
+    entries above it are in, and of those equal to it the first that fill the
+    ``k``. Only where some row has more equal to it than it needs (a tie at
+    the threshold: rare) are they counted along the row."""
+    keys = jnp.where(visible, _ordered_bits(scores), jnp.uint32(0))  # what is not visible lies under every score
+    if keys.shape[1] < k:
+        threshold = jnp.zeros((keys.shape[0], 1), jnp.uint32)  # fewer keys than k: all that is visible
+    elif fused:
+        from tpuddp.nn import sparse_attention_kernels as kernels
+
+        threshold = kernels.kth_largest(keys, k, interpret)[:, None]
+    else:
+        def bit(i, found):
+            candidate = found | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(jnp.uint32)))
+            enough = jnp.sum(keys >= candidate[:, None], axis=1, dtype=jnp.int32) >= k
+            return jnp.where(enough, candidate, found)
+
+        threshold = jax.lax.fori_loop(0, 32, bit, jnp.zeros((keys.shape[0],), jnp.uint32))[:, None]
+    above, equal = keys > threshold, keys == threshold
+    need = k - jnp.sum(above, axis=1, dtype=jnp.int32)
+    surplus = (jnp.sum(equal, axis=1, dtype=jnp.int32) > need) & (threshold[:, 0] > 0)
+    equal = jax.lax.cond(
+        jnp.any(surplus),
+        lambda: equal & (jnp.cumsum(equal, axis=1, dtype=jnp.int32) <= need[:, None]),
+        lambda: equal,
+    )
+    return (above | equal) & visible
+
+
+def _attend_selected_block(q, k, v, selected, *, scale, compute_dtype):
+    """Plain XLA: ``q (G, Hkv, g, D)`` against ``k``, ``v (S, Hkv, D)`` under
+    ``selected (G, S)``: the output, and the heads' mean probabilities
+    ``(G, S)`` float32."""
+    scores = jnp.einsum(
+        "qhgd,shd->hgqs", round_to(q, compute_dtype), round_to(k, compute_dtype), preferred_element_type=jnp.float32
+    ) * scale
+    probs = jax.nn.softmax(jnp.where(selected, scores, _NEG_INF), axis=-1)
+    out = jnp.einsum(
+        "hgqs,shd->qhgd", round_to(probs, compute_dtype), round_to(v, compute_dtype), preferred_element_type=jnp.float32
+    )
+    return out.astype(q.dtype), jnp.mean(jax.lax.stop_gradient(probs), axis=(0, 1))
+
+
+def _selected_kernel(selected, interpret: bool):
+    """The library's kernel under ``selected (G, S)``, a mask that is data:
+    one mask for all heads (the kernel reads a one-head mask's blocks for
+    every head), its blocks whole groups of queries where they divide."""
+    kernels, _ = _splash()
+    g, s = selected.shape
+    bq = next(b for b in (1024, 512, 256, 128) if g % b == 0)
+    bkv = next(b for b in (1024, 512, 256, 128) if s % b == 0)
+    blocks = kernels.BlockSizes(
+        block_q=bq, block_kv=bkv, block_kv_compute=min(bkv, 256),
+        block_q_dkv=bq, block_kv_dkv=bkv, block_kv_dkv_compute=min(bkv, 512), use_fused_bwd_kernel=True,
+    )
+    return kernels.make_splash_mha(selected[None], block_sizes=blocks, head_shards=1, q_seq_shards=1, interpret=interpret)
+
+
+def _fused_selected_attention(q, k, v, selected, *, scale, compute_dtype, interpret: bool = False):
+    """The fused lowering of one group: ``q (G, Hq, D)``, ``k``, ``v (S, Hkv,
+    D)`` under ``selected (G, S)``. The output ``(G, Hq, D)`` and each
+    head's log-sum-exp ``(Hq, G)`` float32, which the kernel computes for its
+    own backward pass and the indexer's objective reads (the public call
+    keeps it to itself, so the kernel's forward and backward functions are
+    called as its own rule calls them: ``_splash_attention_forward`` and
+    ``_splash_attention_bwd`` of jax 0.9.0, their arguments by name, the
+    residuals ``(q, k, v, segment_ids, sinks, out, lse, dq_mask_info,
+    dkv_mask_info)`` and the results ``(three mask infos, dq, dk, dv, ...)``
+    by place; ``tests/test_sparse_moe.py`` holds both layouts, so a library
+    that changes them fails a test before a step). Blocks with no selected
+    pair are skipped, in the forward and the backward pass."""
+    kernels, _ = _splash()
+    kernel = _selected_kernel(selected, interpret)
+    static = {name: value for name, value in kernel.kwargs.items() if name != "save_residuals"}
+    flat = lambda info: info if info is None else info._replace(
+        partial_mask_blocks=info.partial_mask_blocks.reshape(-1, *info.partial_mask_blocks.shape[-2:])
+    )
+    infos = tuple(flat(info) for info in (kernel.fwd_mask_info, kernel.dq_mask_info, kernel.dkv_mask_info))
+
+    @jax.custom_vjp
+    def attend(infos, q, k, v):
+        out, (lse,) = kernels._splash_attention_forward(
+            infos[0], q, k, v, None, None, save_residuals=True, **static
+        )
+        return out, lse
+
+    def forward(infos, q, k, v):
+        out, lse = attend(infos, q, k, v)
+        return (out, lse), (infos, q, k, v, out, lse)
+
+    def backward(saved, cotangents):
+        infos, q, k, v, out, lse = saved
+        grads = kernels._splash_attention_bwd(  # by name: a library that moves an argument then says so
+            save_residuals=False, **static, res=(q, k, v, None, None, out, lse, infos[1], infos[2]),
+            do=cotangents[0],  # none reaches the log-sum-exp: its reader stops it
+        )
+        return (None, *grads[3:6])
+
+    attend.defvjp(forward, backward)
+    heads_first = lambda a: jnp.swapaxes(a, 0, 1)
+    out, lse = attend(
+        infos, heads_first(round_to(q, compute_dtype) * scale), heads_first(round_to(k, compute_dtype)),
+        heads_first(round_to(v, compute_dtype)),
+    )
+    return heads_first(out).astype(q.dtype), lse
+
+
+def sparse_attention_rows(q, qi, wi, k, v, ki, start, *, scale, top_k: int, compute_dtype,
+                          lowering: str = "blockwise", interpret: bool = False):
+    """A group of queries under learned sparse attention (DeepSeek Sparse
+    Attention's indexer and its sparse training objective): ``q (G, Hq, D)``,
+    the first at position ``start``, against ``k``, ``v (S, Hkv, D)`` from
+    position 0 that reach at least to the group's end, with the indexer's
+    queries ``qi (G, Hi, Di)``, their weights ``wi (G, Hi)`` (float32) and
+    its keys ``ki (S, Di)``.
+
+    ``I[t, s] = sum_j wi[t, j] relu(qi[t, j] . ki[s])`` in float32 (the
+    products' inputs in ``compute_dtype``); ``S_t``: the ``top_k`` keys ``s <=
+    t`` of largest ``I[t, s]`` (all of them while ``t < top_k``; equal scores
+    to the earlier key; exact: :func:`top_k_mask`); every head attends ``S_t``
+    alone. Returns ``(out (G, Hq, D), kl, pairs)``: ``kl`` the sum over the
+    rows of ``sum_{s in S_t} p (log p - log r)`` with ``p`` the heads' mean
+    attention probability (no gradient through it) and ``r = softmax_{S_t}
+    I``, so its gradient reaches ``qi``, ``ki`` and ``wi`` alone; ``pairs``
+    the selected pairs. Scopes ``index_scores``, ``index_select``,
+    ``attention``, ``indexer_loss``.
+
+    Two lowerings (:func:`sparse_attention_lowering`). Fused: the library's
+    kernel under the selection as a mask that is data, and the two sums over
+    heads that are rows by keys (the index scores with their backward pass,
+    the heads' mean probability) as kernels that hold a block of the sum in
+    VMEM and walk the heads inside (``nn/sparse_attention_kernels.py``).
+    Blockwise: the group's score block, and a head's at a time of the index
+    scores, in plain XLA. Either scores whole blocks under the mask."""
+    g, s = q.shape[0], k.shape[0]
+    fused = lowering == "fused"
+    if fused:
+        from tpuddp.nn import sparse_attention_kernels as kernels  # pulls in Pallas, as _splash does
+    with _prof.scope("index_scores"):
+        operands = round_to(qi, compute_dtype), round_to(ki, compute_dtype), wi.astype(jnp.float32)
+        scores = kernels.index_scores(*operands, start, interpret) if fused else index_scores(*operands)
+        scores = jnp.where(scores == 0, 0.0, scores)  # one zero: -0 and +0 are equal scores
+    with _prof.scope("index_select"):
+        visible = jnp.arange(s)[None, :] <= (start + jnp.arange(g))[:, None]
+        selected = top_k_mask(jax.lax.stop_gradient(scores), visible, top_k, fused=fused, interpret=interpret)
+    with _prof.scope("attention"):
+        if fused:
+            out, lse = _fused_selected_attention(
+                q, k, v, selected, scale=scale, compute_dtype=compute_dtype, interpret=interpret
+            )
+        else:
+            hkv = k.shape[1]
+            out, p = _attend_selected_block(
+                q.reshape(g, hkv, -1, q.shape[-1]), k, v, selected, scale=scale, compute_dtype=compute_dtype
+            )
+            out = out.reshape(q.shape)
+    with _prof.scope("indexer_loss"):
+        if fused:  # no gradient: the indexer's target; the queries scaled as the attention kernel was given them
+            p = kernels.mean_probabilities(*jax.lax.stop_gradient((
+                round_to(q, compute_dtype) * scale, round_to(k, compute_dtype), lse
+            )), selected, start, interpret)
+        log_r = jax.nn.log_softmax(jnp.where(selected, scores, -jnp.inf), axis=-1)
+        counted = selected & (p > 0)  # a probability that underflowed adds nothing
+        kl = jnp.sum(jnp.where(counted, p * (jnp.log(jnp.where(counted, p, 1.0)) - jnp.where(counted, log_r, 0.0)), 0.0))
+    return out, kl, jnp.sum(selected, dtype=jnp.float32)
 
 
 # -- the head and its loss ------------------------------------------------------
